@@ -677,6 +677,11 @@ def main(argv: Optional[List[str]] = None) -> int:
             return 1
         return preprocess_main(args)
 
+    # one compile-cache rule for every entry point (utils/startup.py), set
+    # before anything can compile
+    from fira_tpu.utils import startup
+
+    cache_dir = startup.configure_compile_cache()
     cfg = _resolve_cfg(args)
 
     # Raw-diff ingest admission (docs/INGEST.md) validates BEFORE the
@@ -724,6 +729,22 @@ def main(argv: Optional[List[str]] = None) -> int:
             for e in ingest_errs:
                 print(f"parse-time validation: {e}", file=sys.stderr)
             return 2
+
+    # Say what this run is on BEFORE any work (and leave the same facts in
+    # <out-dir>/run_info.json once the knobs are admitted): jax falls back
+    # to the CPU with a log line when JAX_PLATFORMS is unset and no
+    # accelerator answers, and a run that lost its chip must not read like
+    # one that had it. device_info initializes the backend, so a
+    # JAX_PLATFORMS naming a missing one raises here.
+    info = {**startup.device_info(), "compile_cache_dir": cache_dir}
+    print(startup.device_line(info), flush=True)
+
+    def finished() -> int:
+        # a run that completed adds each device's peak HBM (None where
+        # the backend keeps no memory stats — the CPU)
+        startup.write_run_info(args.out_dir, {
+            **info, "peak_bytes_in_use": startup.peak_bytes_per_device()})
+        return 0
 
     from fira_tpu.data.dataset import FiraDataset
 
@@ -822,7 +843,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         # serve.disagg.disagg_errors
         from fira_tpu.serve.disagg import disagg_errors
 
-        errs += disagg_errors(cfg)
+        errs += disagg_errors(cfg, platform=info["platform"])
     # robustness knob admission (fault-spec grammar, watchdog timeout,
     # quarantine retry count) — same exit-2 contract, every command
     # (the watchdog also guards train's dev gates) —
@@ -840,6 +861,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             print(f"parse-time validation: {e}", file=sys.stderr)
         return 2
 
+    startup.write_run_info(args.out_dir, info)
     var_maps = _load_var_maps(args.data_dir)
     suffix = f"_{args.ablation}" if args.ablation else ""
     ckpt_dir = args.ckpt_dir or os.path.join(args.out_dir, f"ckpt{suffix}")
@@ -864,7 +886,7 @@ def main(argv: Optional[List[str]] = None) -> int:
               f"throughput: {result.commits_per_sec_per_chip:.1f} "
               f"commits/sec/chip  "
               f"feed_stall_frac: {result.feed_stall_frac:.3f}")
-        return 0
+        return finished()
 
     # test/serve: load best params, beam-decode, write OUTPUT file
     import jax
@@ -916,7 +938,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             # input (the serve path records-and-sheds the same errors)
             print(f"message: {args.target} rejected: {e}", file=sys.stderr)
             return 1
-        return 0
+        return finished()
 
     if args.command == "serve":
         from fira_tpu.serve import poisson_times, read_trace, serve_split
@@ -1002,7 +1024,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                   f"({ing['truncated']} truncated, {ing['degraded']} "
                   f"degraded)  p50 ingest {ing['p50_total_s']} s  "
                   f"ingest_stall_frac {ing['stall_frac']}")
-        return 0
+        return finished()
 
     metrics = run_test(model, params, dataset, cfg, out_dir=args.out_dir,
                        ablation=args.ablation, var_maps=var_maps,
@@ -1010,7 +1032,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     print(f"test sentence-bleu: {metrics['sentence_bleu']:.4f} "
           f"({int(metrics['n'])} commits) -> "
           f"{os.path.join(args.out_dir, output_name(args.ablation))}")
-    return 0
+    return finished()
 
 
 if __name__ == "__main__":

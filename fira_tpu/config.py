@@ -91,7 +91,7 @@ class FiraConfig:
     encoder_buffer: str = "single"
     # "xla": pointer scores materialize the (B,T,S,D) tanh intermediate;
     # "pallas": fused kernel streams it through VMEM (ops/copy_score.py) —
-    #   same math, no HBM intermediate (runs interpreted off-TPU).
+    #   same math, no HBM intermediate (interpreted on the CPU backend only).
     copy_head_impl: str = "xla"
 
     # --- precision ---
@@ -642,17 +642,15 @@ PRODUCTION_PERF_KNOBS = {
 }
 
 
-# The decode-side production set (VERDICT r5 item 5, the CPU-provable
-# half): the three beam levers whose output equivalence is already pinned —
+# The decode-side production set (the CPU-provable half): the three beam
+# levers whose output equivalence is already pinned —
 # beam_kv_cache (token-identical to full-prefix re-decode), factored
 # per-side top-k (token-exact vs the assembled 25,020-way fused tensor),
 # and the while_loop early exit (bit-exact tokens AND probs in all four
-# kv x factored modes, tests/test_beam_early_exit.py). TPU bracket rows
-# for the set (DECODE_BATCH 170/512, random + eos-saturated paramsets) are
-# queued in the watchdog harvest (scripts/tpu_watchdog2.sh ->
-# scripts/tpu_decode_bench.py); per-config defaults stay parity until
-# those rows land. `--perf production` on the CLI applies this set
-# alongside PRODUCTION_PERF_KNOBS.
+# kv x factored modes, tests/test_beam_early_exit.py). The set is not
+# measured on the chip (scripts/tpu_decode_bench.py has the rows to run);
+# per-config defaults stay parity until it is. `--perf production` on the
+# CLI applies this set alongside PRODUCTION_PERF_KNOBS.
 DECODE_PERF_KNOBS = {
     "beam_kv_cache": True,
     "beam_factored_topk": True,

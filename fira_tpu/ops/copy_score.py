@@ -8,15 +8,17 @@ rematerialization and small batches. The Pallas kernel streams S in chunks
 through VMEM and never writes the intermediate to HBM: forward emits only
 the (B, T, S) scores; the custom-VJP backward recomputes tanh chunkwise and
 emits exactly the gradients (dsrc, dtgt, dw, dbias). Peak memory is
-O(B.S.D) — the win is memory headroom, i.e. batch size. Wall-clock is at
-parity in f32 (the op is tanh-VPU-bound: 8.1 vs 8.4 ms fwd at B=64 on v5e)
-and ~8% behind XLA in bf16 training (the kernel pins tanh to f32 for
-precision; XLA's fused path runs it in bf16) — so "xla" stays the default
-and "pallas" is the choice when the intermediate doesn't fit.
+O(B.S.D) — the win is memory headroom, i.e. batch size, not speed (the
+kernel pins tanh to f32 for precision; XLA's fused path runs it in the
+compute dtype) — so "xla" stays the default and "pallas" is the choice when
+the intermediate doesn't fit. Its time on the chip is not measured;
+``chip_smoke.py`` compiles it there (forward and backward, d 256 and d 512),
+checks it against the oracle and runs a train dispatch through it (PERF.md
+"Bring-up"; ROADMAP D1 decides whether it stays).
 
-Off-TPU the same kernels run under the Pallas interpreter, so CPU tests
-validate the math; ``copy_scores_reference`` is the XLA oracle both paths
-are checked against.
+On the CPU backend the same kernels run under the Pallas interpreter, so
+CPU tests validate the math; ``copy_scores_reference`` is the XLA oracle
+both paths are checked against.
 """
 
 from __future__ import annotations
@@ -97,9 +99,12 @@ def _bwd_kernel(src_ref, tgt_ref, w_ref, dout_ref,
 
 
 def _use_interpret(interpret: Optional[bool]) -> bool:
+    """Interpret only on the CPU backend (where Mosaic cannot compile);
+    any other backend compiles the kernel or fails loudly — an
+    accelerator run never falls to the interpreter unasked."""
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))

@@ -28,14 +28,10 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# Sharding-invariant RNG is part of the mesh contract: with the default
-# (non-partitionable) threefry lowering, a sharded program's random draws
-# (dropout masks) depend on the mesh factorization — bisected on the
-# tier-1 dp4xtp2 mesh-vs-single-device loss check, where dropout drift
-# reached 3.0e-3 while the partitionable lowering agrees to 6.6e-8 (pure
-# f32 reassociation). Every sharded entrypoint imports this module, so
-# the flag flips before any mesh exists.
-jax.config.update("jax_threefry_partitionable", True)
+# Sharding-invariant RNG is part of the mesh contract: a sharded program's
+# dropout draws must not depend on the mesh factorization. That needs the
+# partitionable threefry lowering, which is jax's default
+# (jax_threefry_partitionable=True) on the installed 0.9.0 — nothing to set.
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"

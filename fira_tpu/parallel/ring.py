@@ -30,18 +30,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 exports shard_map at top level (check_vma keyword)
-    from jax import shard_map as _shard_map
 
-    def _sharded(body, mesh, in_specs, out_specs):
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except ImportError:  # jax 0.4.x: experimental module, check_rep keyword
-    from jax.experimental.shard_map import shard_map as _shard_map
+def _sharded(body, mesh, in_specs, out_specs):
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
-    def _sharded(body, mesh, in_specs, out_specs):
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
 
 SEQ_AXIS = "seq"
 NEG_INF = -1e9

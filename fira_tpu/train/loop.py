@@ -156,10 +156,10 @@ def run_dev(dev_step, params, dataset: FiraDataset, cfg: FiraConfig,
 
 
 def _materialize(x) -> None:
-    """Honest device sync: copy computed data to host. block_until_ready is
-    NOT a sync on some remote PJRT backends — it acks before execution
-    finishes (scripts/tpu_sync_check.py), which would close throughput-meter
-    intervals early and inflate commits/sec up to 20x."""
+    """Device sync by copying computed data to the host. On the local chip
+    ``jax.block_until_ready`` waits just as long (chip_smoke.py times a
+    train dispatch ended both ways; PERF.md "Bring-up"); this form also
+    leaves the value on the host, where the callers want it next."""
     # firacheck: allow[HOST-SYNC] THE designated sync helper: every hot-loop sync funnels through here so the boundaries stay enumerable (called only at meter/log/epoch edges)
     np.asarray(jax.device_get(x))
 

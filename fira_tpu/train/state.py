@@ -56,7 +56,14 @@ def init_state(model: FiraModel, cfg: FiraConfig, sample_batch: Dict[str, Any],
     # split(PRNGKey(seed))[1] layout.
     state_rng = jax.random.key_data(
         jax.random.split(jax.random.key(s, impl=impl))[1])
-    params = model.init(init_rng, sample_batch, deterministic=True)["params"]
+    # Parameter shapes do not depend on the batch size, and flax runs init's
+    # forward pass op by op on the default device: at a mesh's global batch
+    # that is the whole (B, T, S, D) copy-head intermediate on device 0
+    # (10 GB at 4 x 170, seen on the v5e — PERF.md "Bring-up"). One row gives
+    # bit-identical parameters.
+    one_row = {k: v[:1] for k, v in sample_batch.items()
+               if not k.startswith("_")}
+    params = model.init(init_rng, one_row, deterministic=True)["params"]
     opt_state = make_optimizer(cfg).init(params)
     return TrainState(
         step=jnp.zeros((), jnp.int32), params=params,

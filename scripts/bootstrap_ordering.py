@@ -1,6 +1,6 @@
 """Bootstrap the Table-3 ordering statistics on a fullscale2-style artifact
-directory (VERDICT r4 item 6: the round-4 bootstrap used 300 resamples and
-was run ad-hoc; this is the committed version at 10k).
+directory (the round-4 bootstrap used 300 resamples and was run ad-hoc;
+this is the committed version at 10k).
 
 B-Norm BLEU (the paper's metric of record, /root/reference/Metrics/
 Bleu-B-Norm.py) is a mean of per-sentence smoothed BLEU-4 scores, so the
@@ -36,7 +36,7 @@ def per_sentence_scores(hyp_path: str, ref_path: str) -> np.ndarray:
 
 
 def main() -> None:
-    root = sys.argv[1] if len(sys.argv) > 1 else "fullscale2_cpu"
+    root = sys.argv[1] if len(sys.argv) > 1 else "fullscale3_cpu"
     resamples = int(sys.argv[2]) if len(sys.argv) > 2 else 10_000
     ref = os.path.join(root, "ground_truth")
     scores = {}

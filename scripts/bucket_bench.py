@@ -31,7 +31,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from fira_tpu.utils.backend_guard import force_cpu_backend  # noqa: E402
+from fira_tpu.utils.startup import force_cpu_backend  # noqa: E402
 
 force_cpu_backend()
 

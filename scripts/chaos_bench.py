@@ -972,7 +972,7 @@ def main() -> int:
                          f"(default {DEFAULT_OUT2})")
     args = ap.parse_args()
 
-    from fira_tpu.utils.backend_guard import force_cpu_backend
+    from fira_tpu.utils.startup import force_cpu_backend
 
     force_cpu_backend()
     if args.resume_child:

@@ -28,7 +28,7 @@ from scripts.dress_rehearsal import REHEARSAL_VOCAB, pad_vocab_file  # noqa: E40
 
 def main() -> None:
     if os.environ.get("FULLSCALE_CPU") == "1":
-        from fira_tpu.utils.backend_guard import force_cpu_backend
+        from fira_tpu.utils.startup import force_cpu_backend
 
         force_cpu_backend()
 

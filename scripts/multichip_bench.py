@@ -58,7 +58,7 @@ def _median(xs):
 # --------------------------------------------------------------------------
 
 def measure(n_devices: int) -> dict:
-    from fira_tpu.utils.backend_guard import force_cpu_backend
+    from fira_tpu.utils.startup import force_cpu_backend
 
     force_cpu_backend(n_virtual_devices=n_devices)
 
@@ -210,7 +210,7 @@ def smoke() -> None:
     """Fast 2-device sanity: one sharded grouped-bucketed train window and
     a 2-replica fleet drain, both under the compile guard. Keeps the mesh
     paths green in CI without the full scaling sweep."""
-    from fira_tpu.utils.backend_guard import force_cpu_backend
+    from fira_tpu.utils.startup import force_cpu_backend
 
     force_cpu_backend(n_virtual_devices=2)
 

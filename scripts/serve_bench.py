@@ -1709,7 +1709,7 @@ def main() -> int:
                          f"{DEFAULT_INGEST_OUT} with --ingest)")
     args = ap.parse_args()
 
-    from fira_tpu.utils.backend_guard import force_cpu_backend
+    from fira_tpu.utils.startup import force_cpu_backend
 
     force_cpu_backend()
     if args.smoke:

@@ -17,7 +17,7 @@
   stacked     all cheap knobs together (the candidate production config)
 
 Baseline to compare against: 106.87 ms/step (pre-optimization base,
-BENCH_ATTEMPTS_r03.json attempt 7).
+builders' 2026-07-31 window, docs/PERF.md).
 """
 
 import json
@@ -38,8 +38,9 @@ from fira_tpu.model.model import FiraModel
 from fira_tpu.train import step as step_lib
 from fira_tpu.train.state import init_state
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/fira_xla_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from fira_tpu.utils.startup import configure_compile_cache  # noqa: E402
+
+configure_compile_cache()
 
 N = 16
 

@@ -19,8 +19,9 @@ from fira_tpu.decode.beam import make_beam_search
 from fira_tpu.model.model import FiraModel
 from fira_tpu.train.state import init_state
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/fira_xla_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from fira_tpu.utils.startup import configure_compile_cache  # noqa: E402
+
+configure_compile_cache()
 
 N = int(os.environ.get("DECODE_N", "5"))
 BATCH = int(os.environ.get("DECODE_BATCH", "170"))
@@ -283,8 +284,8 @@ print(json.dumps({
 # The spec_verdict row names the CPU caveat explicitly: CPU executes the
 # verify's while-loop frames serially, so commits/s parity is expected
 # here; the machine-recorded steps_per_commit / dispatch reduction is the
-# claim, and wall-clock is the TPU bracket's to measure
-# (scripts/tpu_watchdog2.sh). DECODE_SPEC=0 skips the leg. Mirrored by
+# claim, and wall-clock is not measured on the chip. DECODE_SPEC=0 skips
+# the leg. Mirrored by
 # bench.py's FIRA_BENCH_SPEC leg — keep the protocols in lockstep.
 # --------------------------------------------------------------------------
 if os.environ.get("DECODE_SPEC", "1") == "1":
@@ -371,9 +372,8 @@ if os.environ.get("DECODE_SPEC", "1") == "1":
             "CPU executes the verify while-loop frames SERIALLY, so "
             "commits/s parity (not speedup) is expected on this backend; "
             "the machine-recorded steps_per_commit / dispatch reduction "
-            "at recorded acceptance is the claim here, and wall-clock is "
-            "measured by the TPU spec bracket (scripts/tpu_watchdog2.sh) "
-            "where verify frames ride the chip's parallel headroom"
+            "at recorded acceptance is the claim here; wall-clock is not "
+            "measured on the chip"
             if jax.devices()[0].platform == "cpu" else ""),
     }), flush=True)
 

@@ -36,8 +36,9 @@ from fira_tpu.data.batching import make_batch
 from fira_tpu.data.synthetic import make_memory_split
 from fira_tpu.model.model import dense_adjacency
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/fira_xla_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from fira_tpu.utils.startup import configure_compile_cache  # noqa: E402
+
+configure_compile_cache()
 
 N = 8
 B = 170

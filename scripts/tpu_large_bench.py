@@ -1,7 +1,7 @@
-"""Honest (D2H-synced) fira-large numbers (VERDICT r3 item 7): train-step
-throughput + MFU and KV-beam decode rate at the 8-layer d=512 beam-8
-geometry (BASELINE.json's v4-32 config). Prints one JSON line per
-measurement; the watchdog appends them to tpu_watchdog.log.
+"""fira-large numbers, each window ended by materializing its result on
+the host: train-step throughput + MFU and KV-beam decode rate at the
+8-layer d=512 beam-8 geometry (BASELINE.json's v4-32 config). Prints one
+JSON line per measurement.
 """
 
 import json
@@ -23,8 +23,9 @@ from fira_tpu.model.model import FiraModel
 from fira_tpu.train import step as step_lib
 from fira_tpu.train.state import init_state
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/fira_xla_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from fira_tpu.utils.startup import configure_compile_cache  # noqa: E402
+
+configure_compile_cache()
 
 BATCH = int(os.environ.get("FIRA_LARGE_BATCH", "64"))
 N_STEPS = int(os.environ.get("FIRA_LARGE_STEPS", "12"))
@@ -70,7 +71,7 @@ def main() -> None:
         "tag": "fira-large-train", "batch": BATCH,
         "step_ms": round(dt * 1e3, 2),
         "commits_per_sec_per_chip": round(BATCH / dt, 1),
-        "mfu": round(flops / dt / peak, 4) if peak else None,
+        "mfu": round(flops / dt / peak, 4),
         "flops_per_step": flops,
         "loss_finite": bool(np.isfinite(loss)),
         "compile_s": round(compile_s, 1),

@@ -2,9 +2,8 @@
 chip and print the top ops by self time (parsed offline with
 tensorboard_plugin_profile — no TensorBoard UI needed).
 
-The result attributes the measured ~107 ms step (BENCH_ATTEMPTS_r03.json
-attempt 7) op by op; ablation (scripts/tpu_ablate.py) only narrowed it to
-"~68 ms in the 6+6 layer stack".
+The result attributes the train step op by op; ablation
+(scripts/tpu_ablate.py) only narrows it to whole sub-stacks.
 """
 
 import glob
@@ -26,8 +25,9 @@ from fira_tpu.model.model import FiraModel
 from fira_tpu.train import step as step_lib
 from fira_tpu.train.state import init_state
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/fira_xla_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from fira_tpu.utils.startup import configure_compile_cache  # noqa: E402
+
+configure_compile_cache()
 
 TRACE_DIR = os.environ.get("PROFILE_DIR", "/tmp/fira_tpu_trace")
 BATCH = int(os.environ.get("PROFILE_BATCH", "170"))
@@ -35,7 +35,7 @@ if os.environ.get("PROFILE_CPU") == "1":
     # CPU mode: op-relative attribution only (CPU cost model != TPU), but
     # op NAMES match — a grossly dominant op (e.g. the adjacency scatter)
     # shows up on either backend
-    from fira_tpu.utils.backend_guard import force_cpu_backend
+    from fira_tpu.utils.startup import force_cpu_backend
 
     force_cpu_backend()
 
@@ -115,8 +115,8 @@ out = os.path.join(TRACE_DIR, "op_times.json")
 with open(out, "w") as f:
     json.dump(report, f, indent=1)
 # The aggregated table is the committable evidence (the raw xplane trace is
-# tens of MB of /tmp); land it in docs/ so a watchdog harvest gets
-# committed. Provenance rules: the parity-default TPU capture owns
+# tens of MB of /tmp); land it in docs/ so it can be committed.
+# Provenance rules: the parity-default TPU capture owns
 # TPU_OP_TIMES.json, an overridden config gets its own file, and a CPU
 # capture never overwrites TPU evidence.
 if not report["cpu_backend"]:

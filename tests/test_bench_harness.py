@@ -1,7 +1,6 @@
 """Drive bench.py's full orchestrator -> probe -> worker -> JSON contract on
 CPU at fira-tiny geometry. This is the driver's artifact generator: its
-one-JSON-line-in-every-outcome promise (VERDICT r2 item 1) gets a test, not
-just a docstring."""
+one-JSON-line-in-every-outcome promise gets a test, not just a docstring."""
 
 import json
 import os
@@ -35,12 +34,11 @@ def _run_bench(extra_env, timeout=420):
 
 
 def test_bench_harness_failure_emits_json():
-    # The worker dies deterministically (unknown config field) -> after its
-    # retries the orchestrator must still print exactly one structured JSON
-    # line with value null and the worker's own error, and exit nonzero.
+    # The worker dies deterministically (unknown config field) -> the
+    # orchestrator must still print a final structured JSON line with value
+    # null and the worker's failure, and exit nonzero.
     rc, result = _run_bench({
         "FIRA_BENCH_OVERRIDES": '{"no_such_field": 1}',
-        "FIRA_BENCH_RETRY_SLEEP": "0",
     })
     assert rc != 0
     assert result["metric"] == "train_commits_per_sec_per_chip"
@@ -51,6 +49,36 @@ def test_bench_harness_failure_emits_json():
                if isinstance(a, dict))
 
 
+def test_bench_failed_leg_exits_nonzero():
+    # A leg that was asked for, ran and raised sinks the run: no record
+    # with a value beside an {"error": ...} block and exit 0. The decode
+    # leg dies on its first line (unparseable EOS delta), after the train
+    # legs measured fine.
+    rc, result = _run_bench({
+        "FIRA_BENCH_COMPOSED": "0",
+        "FIRA_BENCH_DECODE_ENGINE": "1",
+        "FIRA_BENCH_DECODE_EOS_DELTA": "not-a-number",
+    })
+    assert rc != 0
+    assert result["value"] is None
+    assert not result.get("in_progress"), result
+    assert any(a.get("phase") == "worker" and a.get("rc") not in (0, None)
+               and "not-a-number" in a.get("tail", "")
+               for a in result["attempts"]), result
+
+
+def test_peak_flops_exact_match_or_error():
+    import pytest
+
+    sys.path.insert(0, REPO)
+    import bench
+
+    assert bench._peak_flops("TPU v5 lite", "bfloat16") == 197e12
+    assert bench._peak_flops("TPU v5 lite", "float32") == 197e12 / 2
+    with pytest.raises(KeyError, match="TPU v5x"):
+        bench._peak_flops("TPU v5x", "bfloat16")
+
+
 def test_bench_harness_cpu_success():
     rc, result = _run_bench(
         {"FIRA_BENCH_OVERRIDES": '{"sort_edges": true}'})
@@ -58,6 +86,8 @@ def test_bench_harness_cpu_success():
     assert result["metric"] == "train_commits_per_sec_per_chip"
     assert result["value"] is not None and result["value"] > 0
     assert result["platform"] == "cpu"
+    # MFU and the peak are device metrics: a CPU harness run carries none
+    assert result["mfu"] is None and result["peak_flops"] is None
     assert result["compute_step_time_s"] > 0
     assert result["step_time_s"] > 0
     assert result["flops_per_step"] > 0

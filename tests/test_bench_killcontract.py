@@ -1,10 +1,9 @@
-"""The driver contract bench.py must honor (VERDICT r4 items 2 and 7b): the
-driver wrapping `python bench.py` parses the LAST JSON line of stdout and may
-SIGKILL the process at ANY time (rounds 1-4's BENCH_r*.json artifacts were
-null exactly when a kill landed before the single final print). These tests
-run the real orchestrator against an always-hanging probe (the
-FIRA_BENCH_TEST_HANG_S hook — no backend is touched), SIGKILL it at varied
-times, and assert the stdout tail is always a parseable structured record."""
+"""The driver contract bench.py must honor: the driver wrapping
+`python bench.py` parses the LAST JSON line of stdout and may SIGKILL the
+process at ANY time. These tests run the real orchestrator against a hanging
+probe (the FIRA_BENCH_TEST_HANG_S hook — no backend is touched), SIGKILL it
+at varied times, and assert the stdout tail is always a parseable structured
+record."""
 
 import json
 import os
@@ -12,7 +11,6 @@ import signal
 import subprocess
 import sys
 import tempfile
-import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "bench.py")
@@ -22,17 +20,10 @@ def _kill_after(delay_s: float, env_extra: dict) -> str:
     """Launch the orchestrator, SIGKILL it after delay_s, return stdout."""
     env = dict(os.environ)
     env.update({
-        # every probe hangs (simulated tunnel outage), killed at the 1-s
-        # probe timeout, retried until the 30-s budget — the orchestrator
-        # is mid-probe-loop whenever the kill lands
+        # the probe hangs (simulated stuck backend init) well past every
+        # kill point below, so the orchestrator is mid-probe when it lands
         "FIRA_BENCH_TEST_HANG_S": "999",
-        "FIRA_BENCH_PROBE_TIMEOUT": "1",
-        "FIRA_BENCH_PROBE_BUDGET": "30",
-        "FIRA_BENCH_RETRY_SLEEP": "0",
-        "FIRA_BENCH_PROBE_RETRY_SLEEP": "0",
-        # identical-failure backoff off: these tests pin the kill contract
-        # mid-probe-loop, so the loop must keep looping until the kill
-        "FIRA_BENCH_PROBE_IDENTICAL_LIMIT": "0",
+        "FIRA_BENCH_PROBE_TIMEOUT": "60",
     })
     env.update(env_extra)
     with tempfile.TemporaryFile(mode="w+") as out:
@@ -55,11 +46,7 @@ def _last_json_line(out: str) -> dict:
 
 
 def test_sigkill_at_random_times_leaves_parseable_tail():
-    # Kill points chosen to land (a) right after startup, before the first
-    # probe resolves, (b) mid probe-retry loop, (c) deeper into the loop.
-    # (Interpreter boot on this image is ~1.5-2 s — the sandbox's
-    # sitecustomize — so the earliest meaningful kill is just after that;
-    # the driver's real kill window is minutes, not milliseconds.)
+    # Kill points from just after interpreter boot to well into the probe.
     # The contract: whatever the timing, the last stdout line parses as the
     # structured record with the metric name and a null value.
     for delay in (2.5, 4.0, 6.5):
@@ -70,21 +57,18 @@ def test_sigkill_at_random_times_leaves_parseable_tail():
         assert rec["unit"] == "commits/sec/chip"
         assert rec["vs_baseline"] is None
         assert "error" in rec and rec["error"], rec
+        assert rec.get("in_progress"), rec
         assert isinstance(rec.get("attempts"), list)
 
 
-def test_budget_exhaustion_emits_final_record():
-    # No kill: the orchestrator exhausts a tiny probe budget on hung probes
-    # and must exit nonzero with a FINAL (not in_progress) record whose
-    # attempts list the probe failures.
+def test_probe_timeout_emits_final_record():
+    # No kill: the probe hangs past its timeout, is killed, and is NOT
+    # retried — the orchestrator exits nonzero with a FINAL (not
+    # in_progress) record whose attempts hold the one probe failure.
     env = dict(os.environ)
     env.update({
         "FIRA_BENCH_TEST_HANG_S": "999",
         "FIRA_BENCH_PROBE_TIMEOUT": "1",
-        "FIRA_BENCH_PROBE_BUDGET": "3",
-        "FIRA_BENCH_RETRY_SLEEP": "0",
-        "FIRA_BENCH_PROBE_RETRY_SLEEP": "0",
-        "FIRA_BENCH_PROBE_IDENTICAL_LIMIT": "0",  # pin the budget path
     })
     p = subprocess.run([sys.executable, BENCH], capture_output=True,
                        text=True, timeout=60, env=env, cwd=REPO)
@@ -92,51 +76,6 @@ def test_budget_exhaustion_emits_final_record():
     assert p.returncode != 0
     assert rec["value"] is None
     assert not rec.get("in_progress"), rec
-    assert any(a.get("phase") == "probe" for a in rec["attempts"]
-               if isinstance(a, dict))
-
-
-def test_status_records_updated_every_probe():
-    # Run long enough for several probe attempts; every attempt must have
-    # appended a fresh flushed status line (not just the startup one), so a
-    # kill between attempts always sees the newest state.
-    t0 = time.time()
-    out = _kill_after(6.0, {})
-    assert time.time() - t0 < 30
-    lines = [ln for ln in out.strip().splitlines()
-             if ln.strip().startswith("{")]
-    # startup record + >=2 probe-failure status records in ~4s of 1-s
-    # probe timeouts after the ~2s interpreter boot
-    assert len(lines) >= 3, out
-    in_progress = [json.loads(ln) for ln in lines]
-    assert all(r.get("in_progress") for r in in_progress), lines[-1]
-    # later records carry the probe attempts
-    assert any("probe attempt" in (r.get("error") or "")
-               for r in in_progress), lines
-
-
-def test_identical_probe_failures_abort_early():
-    # BENCH_r05 burned the full 900 s budget on 7 byte-identical 90-s
-    # backend-init timeouts. The backoff contract: after N consecutive
-    # identical-signature probe failures, emit the structured final record
-    # and exit — well before the budget deadline.
-    env = dict(os.environ)
-    env.update({
-        "FIRA_BENCH_TEST_HANG_S": "999",     # every probe hangs identically
-        "FIRA_BENCH_PROBE_TIMEOUT": "1",
-        "FIRA_BENCH_PROBE_BUDGET": "120",    # would burn 2 min without backoff
-        "FIRA_BENCH_PROBE_RETRY_SLEEP": "0",
-        "FIRA_BENCH_PROBE_IDENTICAL_LIMIT": "3",
-    })
-    t0 = time.time()
-    p = subprocess.run([sys.executable, BENCH], capture_output=True,
-                       text=True, timeout=90, env=env, cwd=REPO)
-    elapsed = time.time() - t0
-    rec = _last_json_line(p.stdout)
-    assert p.returncode != 0
-    assert elapsed < 60, f"backoff did not fire ({elapsed:.0f}s)"
-    assert rec["value"] is None
-    assert not rec.get("in_progress"), rec
-    assert "identical probe failures" in rec["error"], rec
-    assert sum(1 for a in rec["attempts"]
-               if isinstance(a, dict) and a.get("phase") == "probe") == 3
+    assert "probe timeout" in rec["error"], rec
+    assert [a["phase"] for a in rec["attempts"]] == ["probe"]
+    assert rec["attempts"][0]["rc"] is None

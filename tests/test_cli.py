@@ -32,9 +32,8 @@ def test_production_preset_is_valid_and_applies():
     cfg = _cfg(["train", "--perf", "production"])
     for k, v in PRODUCTION_PERF_KNOBS.items():
         assert getattr(cfg, k) == v, k
-    # the decode-side set rides the same preset (VERDICT r5 item 5:
-    # equivalence pinned by tests/test_beam_early_exit.py; TPU bracket
-    # rows queued in scripts/tpu_watchdog2.sh)
+    # the decode-side set rides the same preset (equivalence pinned by
+    # tests/test_beam_early_exit.py; not measured on the chip)
     for k, v in DECODE_PERF_KNOBS.items():
         assert getattr(cfg, k) == v, k
     # parity defaults stay parity: early exit / factored top-k off

@@ -236,6 +236,21 @@ def test_disagg_errors_named_messages():
     assert any("serve_artifact_budget_mb" in e for e in errs)
 
 
+def test_disagg_refuses_an_accelerator_parent():
+    # the workers are processes and an accelerator belongs to one process:
+    # armed on anything but the CPU the tier is a parse-time error (ROADMAP
+    # S6), never a pool of dead children and a recorded fallback
+    ok = fira_tiny(decode_engine=True, prefix_cache=True,
+                   serve_tiers="prefill-pool")
+    assert disagg_errors(ok, platform="cpu") == []
+    assert disagg_errors(ok) == []          # asks jax: this session is cpu
+    errs = disagg_errors(ok, platform="tpu")
+    assert len(errs) == 1 and "serve_tiers=prefill-pool" in errs[0]
+    assert "'tpu'" in errs[0] and "ROADMAP S6" in errs[0]
+    # tier off: nothing to refuse wherever it runs
+    assert disagg_errors(ok.replace(serve_tiers="off"), platform="tpu") == []
+
+
 def test_cli_disagg_knob_validation_exit2(tmp_path, capsys):
     data = str(tmp_path / "DataSet")
     write_corpus_dir(data, n_commits=16, seed=5)

@@ -221,3 +221,23 @@ def test_flat_scatter_segment_path_is_rejected(tiny):
                                   flat_scatter=True)
     with pytest.raises(ValueError, match="dense"):
         FiraModel(cfg_bad).apply(params, jbatch, deterministic=True)
+
+
+def test_init_state_params_do_not_depend_on_batch_rows(tiny):
+    # init_state initializes from ONE row of the sample batch (flax runs
+    # init's forward eagerly on the default device; at a mesh's global
+    # batch that put 10 GB on device 0 of a 4 x v5e host): the parameters
+    # must be bit-identical to a full-batch init from the same seed.
+    from fira_tpu.train.state import init_state
+
+    cfg, model, params, jbatch = tiny
+    batch = {k: np.asarray(v) for k, v in jbatch.items()}
+    batch["_positions"] = np.arange(4)      # host-only fields are ignored
+    state = init_state(model, cfg, batch, seed=0)
+    full = model.init(jax.random.split(jax.random.PRNGKey(0))[0], jbatch,
+                      deterministic=True)["params"]
+    same = jax.tree_util.tree_map(
+        lambda a, b: bool(np.array_equal(a, b)), state.params, full)
+    assert all(jax.tree_util.tree_leaves(same))
+    assert (jax.tree_util.tree_structure(state.params)
+            == jax.tree_util.tree_structure(full))

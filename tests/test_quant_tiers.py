@@ -30,9 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-# match the full-suite RNG regime (see tests/test_spec.py for why)
-jax.config.update("jax_threefry_partitionable", True)
-
 from fira_tpu.config import fira_tiny
 from fira_tpu.decode import paging
 from fira_tpu.decode import quant

@@ -21,14 +21,6 @@ import jax
 import numpy as np
 import pytest
 
-# parallel/mesh.py pins jax_threefry_partitionable=True at import (the
-# PR-15 dropout-determinism fix) and earlier tier-1 modules import it,
-# so the full suite reaches this file with partitionable RNG draws while
-# a standalone run would not. Pin it here too: the fixture's init draws
-# — and therefore the acceptance pattern the count asserts below are
-# calibrated against — must be identical in both.
-jax.config.update("jax_threefry_partitionable", True)
-
 from fira_tpu.analysis import sanitizer
 from fira_tpu.config import fira_tiny
 from fira_tpu.data.dataset import FiraDataset
