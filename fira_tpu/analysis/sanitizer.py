@@ -105,6 +105,17 @@ class CompileWatcher(logging.Handler):
     Host-side only: reading ``count`` never touches the device. The
     messages are also kept (most recent first-N) so a RetraceError can
     name the program that recompiled.
+
+    This one GUARDS: it exists only under the sanitizer, where
+    :class:`CompileGuard` turns its count into a raise. The one that
+    COUNTS, in every run, is ``utils/profiling.py``'s ``jax.monitoring``
+    listener (``xla.compile`` events in the recorder's ring, with the
+    program's name and the span it ran under, and the ``compiles`` /
+    ``compile_s`` / ``cache_hits`` / ``cache_misses`` counters of every
+    ``phases`` block). The two are not fed from each other: the guard needs
+    jax's log record (its message names shapes the RetraceError quotes,
+    and ``jax_log_compiles`` is its arming switch), the listener needs
+    neither.
     """
 
     def __init__(self, keep: int = 20) -> None:

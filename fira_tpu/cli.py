@@ -744,6 +744,15 @@ def main(argv: Optional[List[str]] = None) -> int:
         # the backend keeps no memory stats — the CPU)
         startup.write_run_info(args.out_dir, {
             **info, "peak_bytes_in_use": startup.peak_bytes_per_device()})
+        # ... and the recorder's ring (utils/profiling.py): every span and
+        # compile of the run, beside its metrics — and beside the device
+        # trace they share a clock with, where train wrote one
+        from fira_tpu.utils import profiling
+
+        profiling.dump(os.path.join(args.out_dir, "spans.jsonl"))
+        if args.command == "train" and args.profile_dir \
+                and os.path.isdir(args.profile_dir):
+            profiling.dump(os.path.join(args.profile_dir, "spans.jsonl"))
         return 0
 
     from fira_tpu.data.dataset import FiraDataset

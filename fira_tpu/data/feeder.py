@@ -50,7 +50,13 @@ training loop:
   blocked waiting for it — the feed-stall numerator train/loop.py feeds
   into profiling.Meter) and ``queue_depth`` (ready-but-unconsumed batches
   when the consumer arrived — persistently 0 means the feed can't keep
-  up); ``stats()`` aggregates them.
+  up); ``stats()`` aggregates them. The clock behind them is the
+  program's recorder (utils/profiling.py): ``feeder.assemble`` and
+  ``feeder.put`` on the worker threads, ``feeder.next`` on the consumer —
+  ``stall_s`` IS that span's duration, one source. ``per_request=True``
+  says one task is ONE REQUEST (the serve paths): the feed keeps the
+  timing and drops the record, since a span each would break the
+  recorder's never-per-request rule.
 
 ``num_workers=0`` is the synchronous mode: same interface, tasks run
 inline on the consumer thread (assembly time then IS stall), no threads
@@ -70,6 +76,8 @@ import queue
 import threading
 import time
 from typing import Any, Callable, Dict, Iterable, Iterator, Optional
+
+from fira_tpu.utils import profiling
 
 Batch = Dict[str, Any]
 Task = Callable[[], Batch]
@@ -127,7 +135,8 @@ class Feeder:
     def __init__(self, tasks: Iterable[Task], *, num_workers: int = 2,
                  depth: int = 4, sharding=None, put: bool = True,
                  on_error: str = "raise", retries: int = 0,
-                 retry_backoff_s: Optional[float] = None, faults=None):
+                 retry_backoff_s: Optional[float] = None, faults=None,
+                 per_request: bool = False):
         if depth < 1:
             raise ValueError(f"depth must be >= 1, got {depth}")
         if num_workers < 0:
@@ -145,6 +154,8 @@ class Feeder:
         self._retries = retries
         self._retry_backoff_s = retry_backoff_s
         self._faults = faults          # robust.faults.FaultInjector or None
+        self._span = (profiling.stopwatch if per_request
+                      else profiling.span)
         self._next = 0                 # next sequence number to emit
         self._n_stalls = 0
         self._stall_s = 0.0
@@ -247,22 +258,26 @@ class Feeder:
         attempt = 0
         while True:
             try:
-                t0 = time.perf_counter()
-                if self._faults is not None:
-                    self._faults.check("feeder.assemble", key=(seq, attempt))
-                host = task()
-                if self._faults is not None:
-                    host = self._faults.corrupt("feeder.assemble", seq, host)
-                # host-side row count BEFORE the transfer — reading it
-                # back from the device array would force a mid-epoch sync
-                n_valid = int(host["valid"].sum())
-                if self._faults is not None:
-                    self._faults.check("feeder.device_put",
-                                       key=(seq, attempt))
-                device = self._device_put(host)
+                with self._span("feeder.assemble") as assemble:
+                    if self._faults is not None:
+                        self._faults.check("feeder.assemble",
+                                           key=(seq, attempt))
+                    host = task()
+                    if self._faults is not None:
+                        host = self._faults.corrupt("feeder.assemble", seq,
+                                                    host)
+                    # host-side row count BEFORE the transfer — reading it
+                    # back from the device array would force a mid-epoch
+                    # sync
+                    n_valid = int(host["valid"].sum())
+                with self._span("feeder.put") as put:
+                    if self._faults is not None:
+                        self._faults.check("feeder.device_put",
+                                           key=(seq, attempt))
+                    device = self._device_put(host)
                 return FedBatch(seq, host, device, n_valid, 0.0, 0,
                                 retries=attempt,
-                                task_s=time.perf_counter() - t0)
+                                task_s=assemble.duration_s + put.duration_s)
             except Exception as e:
                 if attempt < self._retries:
                     attempt += 1
@@ -310,8 +325,7 @@ class Feeder:
     def __next__(self) -> FedBatch:
         if self._num_workers == 0:
             return self._next_sync()
-        t0 = time.perf_counter()
-        with self._cond:
+        with self._span("feeder.next") as waited, self._cond:
             depth_seen = len(self._ready)
             while True:
                 if self._error is not None:
@@ -328,7 +342,7 @@ class Feeder:
         if err is not None:
             self.close()
             raise err
-        stall = time.perf_counter() - t0
+        stall = waited.duration_s
         self._next += 1
         self._inflight.release()
         item.stall_s = stall
@@ -337,14 +351,14 @@ class Feeder:
         return item
 
     def _next_sync(self) -> FedBatch:
-        t0 = time.perf_counter()
-        try:
-            task = next(self._task_iter)
-        except StopIteration:
-            self._closed = True
-            raise
-        item = self._execute(self._next, task)
-        stall = time.perf_counter() - t0
+        with self._span("feeder.next") as waited:
+            try:
+                task = next(self._task_iter)
+            except StopIteration:
+                self._closed = True
+                raise
+            item = self._execute(self._next, task)
+        stall = waited.duration_s
         self._next += 1
         item.stall_s = stall
         self._record(item, stall, 0)

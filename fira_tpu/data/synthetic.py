@@ -16,6 +16,7 @@ from typing import Dict, List, Tuple
 
 from fira_tpu.data.schema import Corpus
 from fira_tpu.data.vocab import LEMMATIZATION, Vocab, normalize_token
+from fira_tpu.utils import profiling
 
 _PARTS = [
     "get", "set", "add", "remove", "update", "check", "user", "name",
@@ -272,6 +273,7 @@ def write_extracted_corpus_dir(data_dir: str, n_commits: int, seed: int = 0,
     return corpus
 
 
+@profiling.span("corpus.build")
 def make_memory_split(cfg, n: int, seed: int = 0, pad_vocab_to: int = 0,
                       pad_ast_vocab_to: int = 0):
     """Generate a fully in-memory ProcessedSplit (no disk): returns

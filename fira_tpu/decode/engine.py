@@ -145,6 +145,7 @@ from fira_tpu.decode import spec as spec_lib
 from fira_tpu.decode.beam import (_init_beam, _select, _select_factored,
                                   step_valid_mask)
 from fira_tpu.model.model import FiraModel
+from fira_tpu.utils import profiling
 
 PREFILL_KIND = "engine_prefill"
 STEP_LABEL = "engine_step"
@@ -216,6 +217,12 @@ class EngineStats:
     # the pool fields, so stats resets between timed windows re-learn them
     kv_dtype: str = "f32"        # K/V arena storage dtype (f32|bf16)
     serve_precision: str = "f32"  # decode weight tier (f32|bf16|int8w)
+    # per span name count/total_s/max_s and the compile counters, over
+    # the spans that closed while THIS stats object lived (utils/
+    # profiling.Phases) — a stats reset between timed windows resets the
+    # phases with it; wall seconds, so honest but schedule-dependent
+    phases: profiling.Phases = dataclasses.field(
+        default_factory=profiling.collect, repr=False, compare=False)
 
     @property
     def cache_hit_rate(self) -> float:
@@ -290,6 +297,7 @@ class EngineStats:
             "spec_frames": self.spec_frames,
             "kv_dtype": self.kv_dtype,
             "serve_precision": self.serve_precision,
+            "phases": self.phases.summary(),
         }
 
 
@@ -627,12 +635,14 @@ class SlotEngine:
                     valid[:, None, None, :],
                     method=FiraModel.dist_parts_step_paged,
                 )
-                new_tokens, new_probs, new_finished, src_beam = \
-                    _select_factored(
-                        gen[:, 0, :].reshape(S, K, -1),
-                        copy[:, 0, :].reshape(S, K, -1),
-                        gate[:, 0, :].reshape(S, K, 2),
-                        tokens, probs, finished, pos_c, slot_src, cfg, neg)
+                with jax.named_scope("topk"):
+                    new_tokens, new_probs, new_finished, src_beam = \
+                        _select_factored(
+                            gen[:, 0, :].reshape(S, K, -1),
+                            copy[:, 0, :].reshape(S, K, -1),
+                            gate[:, 0, :].reshape(S, K, 2),
+                            tokens, probs, finished, pos_c, slot_src, cfg,
+                            neg)
             else:
                 fused, k_pool, v_pool = model.apply(
                     {"params": params}, mask_k, tok_in, pos_bk,
@@ -642,8 +652,10 @@ class SlotEngine:
                     method=FiraModel.fused_probs_step_paged,
                 )
                 dist = fused[:, 0, :].reshape(S, K, -1)
-                new_tokens, new_probs, new_finished, src_beam = _select(
-                    dist, tokens, probs, finished, pos_c, slot_src, cfg, neg)
+                with jax.named_scope("topk"):
+                    new_tokens, new_probs, new_finished, src_beam = _select(
+                        dist, tokens, probs, finished, pos_c, slot_src, cfg,
+                        neg)
             # permute cached histories to follow their beams — the paged
             # twin of the unpaged gather below, moving block CONTENTS
             # within each active slot's own block set (table entries stay
@@ -657,8 +669,9 @@ class SlotEngine:
                 blocks = jnp.take_along_axis(blocks, idx, axis=3)
                 return pool.at[:, tab_step].set(blocks, mode="drop")
 
-            out_caches["k_pool"] = permute_pool(k_pool)
-            out_caches["v_pool"] = permute_pool(v_pool)
+            with jax.named_scope("kv_reorder"):
+                out_caches["k_pool"] = permute_pool(k_pool)
+                out_caches["v_pool"] = permute_pool(v_pool)
         elif cfg.beam_kv_cache:
             # same per-row validity rule as beam_search_cached, at the
             # per-slot position vector
@@ -672,12 +685,14 @@ class SlotEngine:
                     valid[:, None, None, :],
                     method=FiraModel.dist_parts_step_multi,
                 )
-                new_tokens, new_probs, new_finished, src_beam = \
-                    _select_factored(
-                        gen[:, 0, :].reshape(S, K, -1),
-                        copy[:, 0, :].reshape(S, K, -1),
-                        gate[:, 0, :].reshape(S, K, 2),
-                        tokens, probs, finished, pos_c, slot_src, cfg, neg)
+                with jax.named_scope("topk"):
+                    new_tokens, new_probs, new_finished, src_beam = \
+                        _select_factored(
+                            gen[:, 0, :].reshape(S, K, -1),
+                            copy[:, 0, :].reshape(S, K, -1),
+                            gate[:, 0, :].reshape(S, K, 2),
+                            tokens, probs, finished, pos_c, slot_src, cfg,
+                            neg)
             else:
                 fused, k_cache, v_cache = model.apply(
                     {"params": params}, mask_k, tok_in, pos_bk,
@@ -687,8 +702,10 @@ class SlotEngine:
                     method=FiraModel.fused_probs_step_multi,
                 )
                 dist = fused[:, 0, :].reshape(S, K, -1)
-                new_tokens, new_probs, new_finished, src_beam = _select(
-                    dist, tokens, probs, finished, pos_c, slot_src, cfg, neg)
+                with jax.named_scope("topk"):
+                    new_tokens, new_probs, new_finished, src_beam = _select(
+                        dist, tokens, probs, finished, pos_c, slot_src, cfg,
+                        neg)
             # permute cached histories to follow their beams (exactly the
             # batched beam's gather). Inactive rows are NOT blended back:
             # a done/idle slot's cache is never read again — it is not
@@ -714,8 +731,9 @@ class SlotEngine:
                 c = jnp.take_along_axis(c, idx, axis=2)
                 return c.reshape(L, S * K, H, T, d_head)
 
-            out_caches["k_cache"] = gather_cache(k_cache)
-            out_caches["v_cache"] = gather_cache(v_cache)
+            with jax.named_scope("kv_reorder"):
+                out_caches["k_cache"] = gather_cache(k_cache)
+                out_caches["v_cache"] = gather_cache(v_cache)
         else:
             tar_mask = (flat != 0).at[:, 0].set(True)
 
@@ -727,18 +745,23 @@ class SlotEngine:
                 gen, copy, gate = model.apply(
                     {"params": params}, state["states"], mask_k, flat,
                     tar_mask, method=FiraModel.dist_parts)
-                new_tokens, new_probs, new_finished, _ = _select_factored(
-                    at_pos(gen).reshape(S, K, -1),
-                    at_pos(copy).reshape(S, K, -1),
-                    at_pos(gate).reshape(S, K, 2),
-                    tokens, probs, finished, pos_c, slot_src, cfg, neg)
+                with jax.named_scope("topk"):
+                    new_tokens, new_probs, new_finished, _ = \
+                        _select_factored(
+                            at_pos(gen).reshape(S, K, -1),
+                            at_pos(copy).reshape(S, K, -1),
+                            at_pos(gate).reshape(S, K, 2),
+                            tokens, probs, finished, pos_c, slot_src, cfg,
+                            neg)
             else:
                 fused = model.apply(
                     {"params": params}, state["states"], mask_k, flat,
                     tar_mask, method=FiraModel.fused_probs)
                 dist = at_pos(fused).reshape(S, K, -1)
-                new_tokens, new_probs, new_finished, _ = _select(
-                    dist, tokens, probs, finished, pos_c, slot_src, cfg, neg)
+                with jax.named_scope("topk"):
+                    new_tokens, new_probs, new_finished, _ = _select(
+                        dist, tokens, probs, finished, pos_c, slot_src, cfg,
+                        neg)
 
         tokens = jnp.where(active[:, None, None], new_tokens, tokens)
         probs = jnp.where(active[:, None], new_probs, probs)
@@ -873,6 +896,7 @@ class SlotEngine:
         if self.guard is not None:
             self.guard.step(label)
 
+    @profiling.span("engine.prewarm")
     def prewarm(self, warm_batches: Iterable[Tuple[Dict, Optional[str]]]
                 ) -> None:
         """Compile the WHOLE program family up front: one all-pad batch
@@ -884,11 +908,21 @@ class SlotEngine:
         prewarm pays a compile — which the per-dispatch wall-clock
         watchdog (docs/FAULTS.md) depends on: a first-use XLA compile
         inside a watchdogged dispatch would read as a hung replica."""
+        # one span a program: what a span holds is that program's trace,
+        # compile (or cache load) and dispatch — the host's share — and the
+        # compile listener's xla.compile events land under the program
+        # that paid them (utils/profiling.py). The spans do NOT wait for
+        # the device: closed on block_until_ready they read the arena's
+        # upload (4-5 s at benchmark size, under `insert`) and each first
+        # run, but the waiting cost every process 2-5 s of set-up that
+        # otherwise overlaps the host work after prewarm (PERF.md, PR 26)
         chunk = None
         for host, tag in warm_batches:
-            wire = {k: v for k, v in host.items() if not k.startswith("_")}
-            chunk = self._prefill(self.params,
-                                  jax.device_put(wire, self.device))
+            with profiling.span("engine.prewarm.prefill"):
+                wire = {k: v for k, v in host.items()
+                        if not k.startswith("_")}
+                chunk = self._prefill(self.params,
+                                      jax.device_put(wire, self.device))
             self._guard_step(self.label(PREFILL_KIND, tag))
             self._ensure_state(chunk)
         if chunk is None:
@@ -898,15 +932,18 @@ class SlotEngine:
         limits = np.full((C,), self.cfg.tar_len, dtype=np.int32)
         block_rows = (np.full((C, self._table_width), self._pool_blocks,
                               dtype=np.int32) if self._paged else None)
-        self._state = self._insert(self._state, chunk, sentinel_ids,
-                                   limits, block_rows)
+        with profiling.span("engine.prewarm.insert"):
+            self._state = self._insert(self._state, chunk, sentinel_ids,
+                                       limits, block_rows)
         self._guard_step(self.label(INSERT_LABEL))
-        self._state, occ = self._step(self._decode_params, self._state)
+        with profiling.span("engine.prewarm.step"):
+            self._state, occ = self._step(self._decode_params, self._state)
         self._guard_step(self.label(STEP_LABEL))
         if self._pending_occ is None:
             self._pending_occ = occ  # zero: no slot was active
-        self._take_rows(self._state["tokens"], self._state["probs"],
-                        jnp.int32(0))
+        with profiling.span("engine.prewarm.take_rows"):
+            self._take_rows(self._state["tokens"], self._state["probs"],
+                            jnp.int32(0))
         self._guard_step(self.label(HARVEST_LABEL))
         if self._spec_tier is not None:
             # compile the (S, k) draft/verify pair over the all-dead arena:
@@ -914,11 +951,12 @@ class SlotEngine:
             # live row), so the state passes through unchanged — but both
             # programs compile here, not inside a watchdogged dispatch
             km = f"k{self._spec_k}"
-            drafts = self._draft(self._decode_params, self._state)
-            self._guard_step(self.label(spec_lib.DRAFT_LABEL, km))
-            self._state, occ, pend = self._verify(self._decode_params,
-                                                  self._state, drafts)
-            self._guard_step(self.label(spec_lib.VERIFY_LABEL, km))
+            with profiling.span("engine.prewarm.spec"):
+                drafts = self._draft(self._decode_params, self._state)
+                self._guard_step(self.label(spec_lib.DRAFT_LABEL, km))
+                self._state, occ, pend = self._verify(
+                    self._decode_params, self._state, drafts)
+                self._guard_step(self.label(spec_lib.VERIFY_LABEL, km))
             self._pending_occ = occ      # zeros: no slot was active
             self._pending_spec = pend
 
@@ -1167,6 +1205,7 @@ class SlotEngine:
         self._pending_fills.clear()   # a dead replica fills no cache
         return payloads
 
+    @profiling.span("engine.admit")
     def admit(self, host: Dict, index: int, device_batch=None) -> None:
         """Prefill one packed batch and stage its real rows for refill.
         ``device_batch``: the feeder's already-transferred wire batch;
@@ -1329,6 +1368,7 @@ class SlotEngine:
             rows=collections.deque(seat_rows), limit=limit))
         self._staged_rows += len(seat_rows)
 
+    @profiling.span("engine.refill")
     def refill(self, refill_order: str = "fifo") -> None:
         """Insert staged rows into every free slot (one insert dispatch
         per staged chunk touched). Paged arena: each seated row is granted
@@ -1383,6 +1423,7 @@ class SlotEngine:
             if not entry.rows:
                 self._staged.popleft()
 
+    @profiling.span("engine.step_dispatch")
     def step_dispatch(self) -> None:
         """Dispatch one step program (async — the fleet dispatches every
         replica's step before any harvest readback, so replica compute
@@ -1451,6 +1492,7 @@ class SlotEngine:
                     if pid in self._followers or pid in fan)
                 st.shared_block_peak = max(st.shared_block_peak, shared)
 
+    @profiling.span("engine.harvest")
     def harvest(self) -> List[EngineItem]:
         """Read back the dispatched step's done mask and return every
         newly settled slot's sample. The readback is SLICED: one jitted
@@ -1474,87 +1516,95 @@ class SlotEngine:
             # either the in-flight leader or the cached artifacts
             self._drain_pending_fills()
         stats = self.stats
-        occ_now = int(np.array(jax.device_get(self._pending_occ)))
-        stats.occupied_slot_steps += occ_now
-        if self._pending_spec is not None:
-            # drain the verify's device counters at the SAME sync boundary
-            # the occupancy/done readbacks already pay — spec metering
-            # adds no host sync of its own (decode/spec.run_verify)
-            tested, matched, iters = (
-                int(x) for x in np.array(jax.device_get(self._pending_spec)))
-            if self.retired:
-                # the counter readback is a sync window a watchdog expiry
-                # can abandon this thread inside; survivors own the
-                # engine's scheduling state now — touch nothing
-                return []
-            self._pending_spec = None
-            stats.drafted += self._spec_k * occ_now
-            stats.accepted += matched
-            stats.steps_saved += tested - occ_now
-            stats.spec_frames += iters
-            if occ_now and matched == 0:
-                # acceptance stalled (a rare-token span the drafter cannot
-                # see): run a few plain dispatches before re-arming, so a
-                # cold stretch does not pay draft+verify per emitted token
-                self._spec_cd = spec_lib.STALL_COOLDOWN
-        done = np.array(jax.device_get(self._state["done"]))
+        # engine.harvest.wait: the first blocking reads — the HOST waiting
+        # for the dispatched step (device busy); everything after it in
+        # this method is engine.harvest.read — the DEVICE waiting for the
+        # host (one gather dispatch + D2H per settled row)
+        with profiling.span("engine.harvest.wait"):
+            occ_now = int(np.array(jax.device_get(self._pending_occ)))
+            stats.occupied_slot_steps += occ_now
+            if self._pending_spec is not None:
+                # drain the verify's device counters at the SAME sync boundary
+                # the occupancy/done readbacks already pay — spec metering
+                # adds no host sync of its own (decode/spec.run_verify)
+                tested, matched, iters = (
+                    int(x) for x in np.array(
+                        jax.device_get(self._pending_spec)))
+                if self.retired:
+                    # the counter readback is a sync window a watchdog expiry
+                    # can abandon this thread inside; survivors own the
+                    # engine's scheduling state now — touch nothing
+                    return []
+                self._pending_spec = None
+                stats.drafted += self._spec_k * occ_now
+                stats.accepted += matched
+                stats.steps_saved += tested - occ_now
+                stats.spec_frames += iters
+                if occ_now and matched == 0:
+                    # acceptance stalled (a rare-token span the drafter cannot
+                    # see): run a few plain dispatches before re-arming, so a
+                    # cold stretch does not pay draft+verify per emitted token
+                    self._spec_cd = spec_lib.STALL_COOLDOWN
+            done = np.array(jax.device_get(self._state["done"]))
         newly = [s for s in self._busy if done[s]]
         items: List[EngineItem] = []
-        if newly:
-            tokens, probs = self._state["tokens"], self._state["probs"]
-            full_bytes = tokens.nbytes + probs.nbytes
-            row_bytes = full_bytes // self.slots
-            # PHASE 1 — readbacks only, no bookkeeping: a watchdog expiry
-            # mid-device_get abandons this thread with every settled slot
-            # still in _busy, so retire() requeues ALL of them (popping
-            # as we read would strand the already-popped, never-delivered
-            # requests). Phase 2 is pure host dict work — microseconds,
-            # nothing left to hang on.
-            reads = []
-            for s in newly:
+        if newly:   # a harvest that settles nothing records no read
+            with profiling.span("engine.harvest.read", rows=len(newly)):
+                tokens, probs = self._state["tokens"], self._state["probs"]
+                full_bytes = tokens.nbytes + probs.nbytes
+                row_bytes = full_bytes // self.slots
+                # PHASE 1 — readbacks only, no bookkeeping: a watchdog expiry
+                # mid-device_get abandons this thread with every settled slot
+                # still in _busy, so retire() requeues ALL of them (popping
+                # as we read would strand the already-popped, never-delivered
+                # requests). Phase 2 is pure host dict work — microseconds,
+                # nothing left to hang on.
+                reads = []
+                for s in newly:
+                    if self.retired:
+                        return []  # abandoned by a watchdog mid-harvest
+                    toks_s, probs_s = self._take_rows(tokens, probs,
+                                                      jnp.int32(s))
+                    toks_np = np.array(jax.device_get(toks_s))  # firacheck: allow[HOST-SYNC] harvest IS the engine's designated output boundary: settled beams must reach the host to be cooked into text, and the sliced row gather is exactly the copy this readback exists to make
+                    probs_np = np.array(jax.device_get(probs_s))  # firacheck: allow[HOST-SYNC] same harvest output boundary as the line above
+                    if self.retired:
+                        # the gather/readback above is exactly the window a
+                        # watchdog expiry abandons this thread inside: the
+                        # live loop owns the shared compile guard now
+                        return []
+                    self._guard_step(self.label(HARVEST_LABEL))
+                    reads.append((s, toks_np, probs_np))
                 if self.retired:
-                    return []  # abandoned by a watchdog mid-harvest
-                toks_s, probs_s = self._take_rows(tokens, probs,
-                                                  jnp.int32(s))
-                toks_np = np.array(jax.device_get(toks_s))  # firacheck: allow[HOST-SYNC] harvest IS the engine's designated output boundary: settled beams must reach the host to be cooked into text, and the sliced row gather is exactly the copy this readback exists to make
-                probs_np = np.array(jax.device_get(probs_s))  # firacheck: allow[HOST-SYNC] same harvest output boundary as the line above
-                if self.retired:
-                    # the gather/readback above is exactly the window a
-                    # watchdog expiry abandons this thread inside: the
-                    # live loop owns the shared compile guard now
                     return []
-                self._guard_step(self.label(HARVEST_LABEL))
-                reads.append((s, toks_np, probs_np))
-            if self.retired:
-                return []
-            # PHASE 2 — every readback landed: retire the bookkeeping
-            for s, toks_np, probs_np in reads:
-                pos_id, host, r = self._busy.pop(s)
-                self._free.append(s)
-                # the slot's block grant is RELEASED through the
-                # refcounted allocator — contents stay as the slot left
-                # them (unmapped, not zeroed; the next grantee's validity
-                # mask makes them an exact 0.0), and a block returns to
-                # the free deque only at refcount zero
-                self._release_blocks(self._slot_blocks.pop(s, ()))
-                stats.commits += 1
-                stats.harvest_row_reads += 1
-                stats.harvest_bytes_read += row_bytes
-                items.append(EngineItem(position=pos_id, host=host, row=r,
-                                        tokens=toks_np, probs=probs_np))
-                # dedup fan-out delivery: every follower coalesced onto
-                # this seat gets the leader's settled beams at its OWN
-                # output position (one decode, N commits — byte-identical
-                # by construction: same digest => same payload bytes)
-                d = self._row_digest.pop(pos_id, None)
-                if d is not None:
-                    self._inflight.pop(d, None)
-                for fpos, fhost, frow in self._followers.pop(pos_id, ()):
+                # PHASE 2 — every readback landed: retire the bookkeeping
+                for s, toks_np, probs_np in reads:
+                    pos_id, host, r = self._busy.pop(s)
+                    self._free.append(s)
+                    # the slot's block grant is RELEASED through the
+                    # refcounted allocator — contents stay as the slot left
+                    # them (unmapped, not zeroed; the next grantee's validity
+                    # mask makes them an exact 0.0), and a block returns to
+                    # the free deque only at refcount zero
+                    self._release_blocks(self._slot_blocks.pop(s, ()))
                     stats.commits += 1
-                    items.append(EngineItem(position=fpos, host=fhost,
-                                            row=frow, tokens=toks_np,
-                                            probs=probs_np))
-            stats.harvest_bytes_saved += full_bytes - row_bytes * len(reads)
+                    stats.harvest_row_reads += 1
+                    stats.harvest_bytes_read += row_bytes
+                    items.append(EngineItem(position=pos_id, host=host, row=r,
+                                            tokens=toks_np, probs=probs_np))
+                    # dedup fan-out delivery: every follower coalesced onto
+                    # this seat gets the leader's settled beams at its OWN
+                    # output position (one decode, N commits — byte-identical
+                    # by construction: same digest => same payload bytes)
+                    d = self._row_digest.pop(pos_id, None)
+                    if d is not None:
+                        self._inflight.pop(d, None)
+                    for fpos, fhost, frow in self._followers.pop(pos_id, ()):
+                        stats.commits += 1
+                        items.append(EngineItem(position=fpos, host=fhost,
+                                                row=frow, tokens=toks_np,
+                                                probs=probs_np))
+                stats.harvest_bytes_saved += (full_bytes
+                                              - row_bytes * len(reads))
         return items
 
     def run(self, feed, *, refill_order: str = "fifo"
@@ -1579,28 +1629,39 @@ class SlotEngine:
         self.begin_stream()
         feed_iter = iter(feed)
         exhausted = False
+        # engine.run is a generator: its root span is opened and closed
+        # explicitly and is the thread's parent only inside the `with
+        # root` stretches — never across a yield, where the consumer's
+        # own spans must not become this root's children
+        root = profiling.begin("engine.run")
+        try:
+            while True:
+                with root:
+                    # prefill ahead: keep `depth` chunks staged, and at
+                    # least enough rows to refill every currently free slot
+                    while not exhausted and self.wants_input():
+                        try:
+                            item = next(feed_iter)
+                        except StopIteration:
+                            exhausted = True
+                            break
+                        # a put=False feed (the fleet's shared queue)
+                        # leaves item.device == item.host; admit re-ships
+                        # it then
+                        self.admit(item.host, item.index,
+                                   None if item.device is item.host
+                                   else item.device)
 
-        while True:
-            # prefill ahead: keep `depth` chunks staged, and at least
-            # enough rows to refill every currently free slot
-            while not exhausted and self.wants_input():
-                try:
-                    item = next(feed_iter)
-                except StopIteration:
-                    exhausted = True
-                    break
-                # a put=False feed (the fleet's shared queue) leaves
-                # item.device == item.host; admit re-ships it then
-                self.admit(item.host, item.index,
-                           None if item.device is item.host else item.device)
+                    # refill every free slot from the staged queue
+                    self.refill(refill_order)
 
-            # refill every free slot from the staged queue
-            self.refill(refill_order)
+                    if not self._busy:
+                        if exhausted:
+                            break
+                        continue  # nothing in flight yet: pull more input
 
-            if not self._busy:
-                if exhausted:
-                    break
-                continue  # nothing in flight yet: pull more input
-
-            self.step_dispatch()
-            yield from self.harvest()
+                    self.step_dispatch()
+                    items = self.harvest()
+                yield from items
+        finally:
+            root.end()

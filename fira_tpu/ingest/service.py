@@ -669,7 +669,9 @@ def serve_diffs(model, params, word_vocab: Vocab, ast_change_vocab: Vocab,
                        depth=depth, put=False,
                        on_error="record",
                        retries=max(0, cfg.robust_retries),
-                       faults=faults) as feed:
+                       # one task is one REQUEST (its stamps are the
+                       # record's `ingest` block): no span each
+                       faults=faults, per_request=True) as feed:
             loop = ServeLoop(
                 engines, cfg, arrival_times=times, feed=feed, table=table,
                 assignment=None, templates=templates, clock=clk, emit=emit,

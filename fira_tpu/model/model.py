@@ -538,6 +538,9 @@ class FiraModel(nn.Module):
             # reference's flattened adjacency
             batch["values"] = batch["values"] * self.edge_gain.astype(
                 batch["values"].dtype)[batch["edge_kinds"].astype(jnp.int32)]
+        # jax.named_scope here and below: names on the DEVICE's work, in
+        # the op metadata only (same HLO, same compile-cache key) — the
+        # parts PERF.md section 5 otherwise knows by fusion number
         if cfg.adjacency_impl == "segment":
             if cfg.flat_scatter:
                 raise ValueError(
@@ -552,21 +555,23 @@ class FiraModel(nn.Module):
             # value per cell (dense_adjacency docstring), so this is
             # bit-identical to the f32 scatter + cast it replaces while
             # never materializing the f32 (B, N, N) buffer at all
-            adj = dense_adjacency(
-                batch["senders"], batch["receivers"], batch["values"],
-                graph_len, indices_sorted=cfg.sort_edges,
-                out_dtype=self.dtype, flat=cfg.flat_scatter,
-            )
+            with jax.named_scope("adjacency"):
+                adj = dense_adjacency(
+                    batch["senders"], batch["receivers"], batch["values"],
+                    graph_len, indices_sorted=cfg.sort_edges,
+                    out_dtype=self.dtype, flat=cfg.flat_scatter,
+                )
         else:
             raise ValueError(
                 f"adjacency_impl={cfg.adjacency_impl!r} not in "
                 f"{{'dense', 'segment'}}")
         sou_mask = batch["diff"] != 0
         sub_mask = batch["sub_token"] != 0
-        sou_emb, sub_emb = self.encoder(
-            batch["diff"], batch["diff_mark"], batch["ast_change"], adj,
-            batch["sub_token"], deterministic=deterministic,
-        )
+        with jax.named_scope("gcn"):
+            sou_emb, sub_emb = self.encoder(
+                batch["diff"], batch["diff_mark"], batch["ast_change"], adj,
+                batch["sub_token"], deterministic=deterministic,
+            )
         states = jnp.concatenate([sou_emb, sub_emb], axis=1)
         mask = jnp.concatenate([sou_mask, sub_mask], axis=1)
         return states, mask
@@ -581,14 +586,19 @@ class FiraModel(nn.Module):
         elementwise), skipping ~1.5 GB/step of full-vocab f32 assembly at
         flagship geometry; the beam consumes the assembled form via
         :meth:`fused_probs`."""
-        tar_emb = self.decoder(tar, states, mask, tar_mask_pad,
-                               deterministic=deterministic)
-        gen = jax.nn.softmax(
-            self.out_fc(tar_emb).astype(stable_dtype(self.dtype)), axis=-1
-        )
-        scores, gate = self.copy_net(states, tar_emb)
-        scores = jnp.where(mask[:, None, :], scores, jnp.asarray(-1e9, scores.dtype))
-        copy = jax.nn.softmax(scores.astype(stable_dtype(self.dtype)), axis=-1)
+        with jax.named_scope("decoder"):
+            tar_emb = self.decoder(tar, states, mask, tar_mask_pad,
+                                   deterministic=deterministic)
+        with jax.named_scope("output_head"):
+            gen = jax.nn.softmax(
+                self.out_fc(tar_emb).astype(stable_dtype(self.dtype)),
+                axis=-1)
+        with jax.named_scope("copy_head"):
+            scores, gate = self.copy_net(states, tar_emb)
+            scores = jnp.where(mask[:, None, :], scores,
+                               jnp.asarray(-1e9, scores.dtype))
+            copy = jax.nn.softmax(scores.astype(stable_dtype(self.dtype)),
+                                  axis=-1)
         return gen, copy, gate
 
     def fused_probs(self, states, mask, tar, tar_mask_pad, *,
@@ -638,13 +648,16 @@ class FiraModel(nn.Module):
         """Shared generation/copy/gate head of the cached one-position
         decode paths (scalar-position :meth:`dist_parts_step` and the
         engine's per-row :meth:`dist_parts_step_multi`)."""
-        gen = jax.nn.softmax(
-            self.out_fc(tar_emb).astype(stable_dtype(self.dtype)), axis=-1
-        )
-        scores, gate = self.copy_net.score_gate(src_proj, tar_emb)
-        scores = jnp.where(mask[:, None, :], scores,
-                           jnp.asarray(-1e9, scores.dtype))
-        copy = jax.nn.softmax(scores.astype(stable_dtype(self.dtype)), axis=-1)
+        with jax.named_scope("output_head"):
+            gen = jax.nn.softmax(
+                self.out_fc(tar_emb).astype(stable_dtype(self.dtype)),
+                axis=-1)
+        with jax.named_scope("copy_head"):
+            scores, gate = self.copy_net.score_gate(src_proj, tar_emb)
+            scores = jnp.where(mask[:, None, :], scores,
+                               jnp.asarray(-1e9, scores.dtype))
+            copy = jax.nn.softmax(scores.astype(stable_dtype(self.dtype)),
+                                  axis=-1)
         return gen, copy, gate
 
     def dist_parts_step(self, mask, tok, pos_idx, k_cache, v_cache,
@@ -655,9 +668,11 @@ class FiraModel(nn.Module):
         top-k from these directly (the fused distribution is the two sides
         scaled by their gate weights, so the global top-k lives in the
         union of the per-side top-ks)."""
-        tar_emb, k_cache, v_cache = self.decoder.decode_step(
-            tok, pos_idx, k_cache, v_cache, cross_k, cross_v, mask, self_mask,
-        )
+        with jax.named_scope("decoder"):
+            tar_emb, k_cache, v_cache = self.decoder.decode_step(
+                tok, pos_idx, k_cache, v_cache, cross_k, cross_v, mask,
+                self_mask,
+            )
         gen, copy, gate = self._step_heads(mask, src_proj, tar_emb)
         return gen, copy, gate, k_cache, v_cache
 
@@ -667,9 +682,11 @@ class FiraModel(nn.Module):
         a (B,) vector): the slot-refill engine's step program advances every
         slot at its own depth in one dispatch (decode/engine.py). Row-wise
         identical math — Decoder.decode_step_multi plus the same heads."""
-        tar_emb, k_cache, v_cache = self.decoder.decode_step_multi(
-            tok, pos_idx, k_cache, v_cache, cross_k, cross_v, mask, self_mask,
-        )
+        with jax.named_scope("decoder"):
+            tar_emb, k_cache, v_cache = self.decoder.decode_step_multi(
+                tok, pos_idx, k_cache, v_cache, cross_k, cross_v, mask,
+                self_mask,
+            )
         gen, copy, gate = self._step_heads(mask, src_proj, tar_emb)
         return gen, copy, gate, k_cache, v_cache
 
@@ -681,10 +698,11 @@ class FiraModel(nn.Module):
         indirection (Decoder.decode_step_paged) instead of whole-sequence
         stripes; heads are the shared :meth:`_step_heads`, so per row the
         distribution factors are bit-identical to the unpaged step."""
-        tar_emb, k_pool, v_pool = self.decoder.decode_step_paged(
-            tok, pos_idx, k_pool, v_pool, block_tab, cross_k, cross_v,
-            mask, self_mask,
-        )
+        with jax.named_scope("decoder"):
+            tar_emb, k_pool, v_pool = self.decoder.decode_step_paged(
+                tok, pos_idx, k_pool, v_pool, block_tab, cross_k, cross_v,
+                mask, self_mask,
+            )
         gen, copy, gate = self._step_heads(mask, src_proj, tar_emb)
         return gen, copy, gate, k_pool, v_pool
 
@@ -743,31 +761,37 @@ class FiraModel(nn.Module):
         gen, copy, gate = self._dist_parts(
             states, mask, tar, tar != 0, deterministic=deterministic
         )
-        # label = tar_label shifted left with a zero column (Model.py:71-79)
-        label = jnp.concatenate(
-            [batch["msg_tar"][:, 1:],
-             jnp.zeros((tar.shape[0], 1), dtype=batch["msg_tar"].dtype)],
-            axis=1,
-        )
-        label_mask = label != 0
-        # Gather the label's probability from the distribution FACTORS, then
-        # log-clamp (Model.py:69's clip to [1e-10, 1]). Equivalent to
-        # assembling the fused (B, T, 25k) tensor, log-clamping it, and
-        # gathering after — gate multiplication and log are elementwise, so
-        # both commute with the gather — but neither the concatenation nor
-        # the full-vocab gate products nor the full f32 log tensor
-        # (~2 GB/step combined at flagship) is ever materialized.
-        V = self.cfg.vocab_size
-        label = label.astype(jnp.int32)
-        is_gen = label < V
-        gi = jnp.where(is_gen, label, 0)[..., None]
-        ci = jnp.clip(label - V, 0, copy.shape[-1] - 1)[..., None]
-        pg = jnp.take_along_axis(gen, gi, axis=-1)[..., 0] * gate[..., 0]
-        pc = jnp.take_along_axis(copy, ci, axis=-1)[..., 0] * gate[..., 1]
-        p = jnp.where(is_gen, pg, pc)
-        nll = -jnp.log(jnp.clip(p, 1e-10, 1.0))
-        nll = jnp.where(label_mask, nll, 0.0)
-        return nll.sum(), label_mask.sum()
+        with jax.named_scope("loss"):
+            # label = tar_label shifted left with a zero column
+            # (Model.py:71-79)
+            label = jnp.concatenate(
+                [batch["msg_tar"][:, 1:],
+                 jnp.zeros((tar.shape[0], 1),
+                           dtype=batch["msg_tar"].dtype)],
+                axis=1,
+            )
+            label_mask = label != 0
+            # Gather the label's probability from the distribution FACTORS,
+            # then log-clamp (Model.py:69's clip to [1e-10, 1]). Equivalent
+            # to assembling the fused (B, T, 25k) tensor, log-clamping it,
+            # and gathering after — gate multiplication and log are
+            # elementwise, so both commute with the gather — but neither
+            # the concatenation nor the full-vocab gate products nor the
+            # full f32 log tensor (~2 GB/step combined at flagship) is ever
+            # materialized.
+            V = self.cfg.vocab_size
+            label = label.astype(jnp.int32)
+            is_gen = label < V
+            gi = jnp.where(is_gen, label, 0)[..., None]
+            ci = jnp.clip(label - V, 0, copy.shape[-1] - 1)[..., None]
+            pg = (jnp.take_along_axis(gen, gi, axis=-1)[..., 0]
+                  * gate[..., 0])
+            pc = (jnp.take_along_axis(copy, ci, axis=-1)[..., 0]
+                  * gate[..., 1])
+            p = jnp.where(is_gen, pg, pc)
+            nll = -jnp.log(jnp.clip(p, 1e-10, 1.0))
+            nll = jnp.where(label_mask, nll, 0.0)
+            return nll.sum(), label_mask.sum()
 
     def dev_predict(self, batch: Dict[str, jnp.ndarray]) -> jnp.ndarray:
         """Teacher-forced greedy ids for all positions at once (Model.py:86).

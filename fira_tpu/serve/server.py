@@ -84,6 +84,7 @@ from fira_tpu.model.model import FiraModel
 from fira_tpu.robust import faults as faults_lib
 from fira_tpu.robust.watchdog import WatchdogTimeout, run_with_watchdog
 from fira_tpu.serve import disagg as disagg_lib
+from fira_tpu.utils import profiling
 
 # serve_metrics snapshot cadence: the partial artifact refreshes every
 # this many scheduler rounds (plus once at startup and once on abort),
@@ -205,6 +206,10 @@ class RequestRecord:
     arrival_round: int = -1  # step-dispatch counter at arrival (deadline base)
     admit_t: float = math.nan       # prefill dispatched (chunk staged)
     seat_t: float = math.nan        # inserted into a slot
+    seat_round: int = -1            # step-dispatch counter at seating: with
+                                    # arrival_round/done_round it links the
+                                    # request to the serve.round spans it
+                                    # lived through (utils/profiling.py)
     first_step_t: float = math.nan  # end of its first step dispatch's
                                     # harvest phase — the TTFT stamp
     done_t: float = math.nan        # harvested (all beams settled)
@@ -324,6 +329,13 @@ class ServeStats:
     # summary reads END-of-run counters — None with serve_tiers=off, so
     # tier-less summaries stay byte-stable (the ingest_cache pattern)
     tiers: Optional[object] = None
+    # per span name count/total_s/max_s and the compile counters, over
+    # the spans that closed while this loop's stats lived (utils/
+    # profiling.Phases): a round's composition — poll, admit, step
+    # dispatch, harvest wait/read, emit — without a profiler. Wall
+    # seconds, so honest but schedule-dependent, like the ingest stamps
+    phases: profiling.Phases = dataclasses.field(
+        default_factory=profiling.collect, repr=False, compare=False)
 
     def summary(self) -> Dict:
         done = [r for r in self.records if r.status == "done"]
@@ -379,6 +391,7 @@ class ServeStats:
             **({"tiers": dict(self.tiers()
                               if callable(self.tiers) else self.tiers)}
                if self.tiers is not None else {}),
+            "phases": self.phases.summary(),
         }
 
     def _ingest_summary(self) -> Dict:
@@ -594,6 +607,7 @@ class ServeLoop:
                         rec.status = "staged"
                     if lrec.status == "seated":
                         rec.seat_t = now
+                        rec.seat_round = self.stats.rounds
                         rec.status = "seated"
                         self._awaiting_first_step.append(rec)
                     self.stats.dedup_coalesced += 1
@@ -706,6 +720,7 @@ class ServeLoop:
                 continue
             rec.status = "queued"
             rec.admit_t = rec.seat_t = rec.first_step_t = math.nan
+            rec.seat_round = -1
             self._queue.append(e)
 
     def _shed_deadlines(self) -> None:
@@ -901,6 +916,7 @@ class ServeLoop:
             rec.requeues += 1
             rec.status = "queued"
             rec.admit_t = rec.seat_t = rec.first_step_t = math.nan
+            rec.seat_round = -1
             # a requeued leader drags its coalesced followers back to the
             # queued milestone with it (they stay attached — re-admission
             # payloads survive dedup; the deadline clocks do not reset)
@@ -908,6 +924,7 @@ class ServeLoop:
                 f.record.status = "queued"
                 f.record.admit_t = f.record.seat_t = math.nan
                 f.record.first_step_t = math.nan
+                f.record.seat_round = -1
         self.stats.requeues += len(entries)
         for e in reversed(entries):
             self._queue.appendleft(e)
@@ -975,8 +992,9 @@ class ServeLoop:
                     #        MISS_HOLD_ROUNDS once rounds advance
                 for gi, group in enumerate(groups):
                     before = eng.stats.prefills
-                    staged = self._prefill_quarantined(
-                        eng, self._form_batch(bucket, group), group)
+                    with profiling.span("serve.form_batch"):
+                        batch = self._form_batch(bucket, group)
+                    staged = self._prefill_quarantined(eng, batch, group)
                     if staged is None:
                         retired = True
                         # the replica died dispatching THIS group (it was
@@ -1027,7 +1045,8 @@ class ServeLoop:
             # analysis, shed statuses+errors that would otherwise exist
             # only in the metrics snapshot, and the progress probe the
             # kill legs poll (scripts/chaos_bench.py)
-            self._journal.admit(admitted_pos)
+            with profiling.span("serve.journal"):
+                self._journal.admit(admitted_pos)
         self.stats.admits += admitted
         self.stats.max_admits_per_round = max(
             self.stats.max_admits_per_round, admitted)
@@ -1037,6 +1056,7 @@ class ServeLoop:
                 rec = self._rec_by_pos[pid]
                 if math.isnan(rec.seat_t):
                     rec.seat_t = t
+                    rec.seat_round = self.stats.rounds
                     rec.status = "seated"
                     self._awaiting_first_step.append(rec)
                     # a seated leader seats its whole fan-out group: each
@@ -1045,6 +1065,7 @@ class ServeLoop:
                     for f in self._followers.get(pid, []):
                         if math.isnan(f.record.seat_t):
                             f.record.seat_t = t
+                            f.record.seat_round = self.stats.rounds
                             f.record.status = "seated"
                             self._awaiting_first_step.append(f.record)
 
@@ -1091,7 +1112,8 @@ class ServeLoop:
         """Flush the round's buffered shed WAL records (one fsync for
         the whole batch — see _shed)."""
         if self._journal is not None and self._shed_log:
-            self._journal.append_many(self._shed_log)
+            with profiling.span("serve.journal"):
+                self._journal.append_many(self._shed_log)
             self._shed_log = []
 
     def _heal(self) -> None:
@@ -1120,7 +1142,22 @@ class ServeLoop:
     # --- the loop -------------------------------------------------------
 
     def run(self) -> ServeStats:
-        t0 = time.perf_counter()  # firacheck: allow[WALL-CLOCK] ServeStats.wall_s is DEFINED as real elapsed seconds (the stall-fraction denominator must be wall over wall — PR 11 fourth-pass review); it never feeds the scheduling clock
+        """The whole loop under the ``serve.run`` root span;
+        ``ServeStats.wall_s`` — REAL elapsed seconds, the stall fraction's
+        denominator (wall over wall, PR 11 fourth-pass review), never the
+        scheduling clock — IS the root's duration (utils/profiling.py owns
+        the wall-clock reads)."""
+        with profiling.span("serve.run") as whole:
+            self._serve_rounds()
+        self.stats.wall_s = whole.duration_s
+        return self.stats
+
+    def _serve_rounds(self) -> None:
+        # each pass of the scheduler is one ``serve.round``: a pass that
+        # dispatched holds a ``serve.step_dispatch``, an idle pass a
+        # ``serve.idle_wait``. The round stays INLINE in this loop: a loop
+        # body in a driver module is what firacheck scans for blocking
+        # calls and device syncs (analysis/astutil.hot_spans)
         n = len(self._times)
         for eng in self.engines:
             # fresh host scheduling state per request stream (a no-op on
@@ -1128,171 +1165,187 @@ class ServeLoop:
             # warmed engine across serving runs — scripts/serve_bench.py)
             eng.begin_stream()
         if self._snapshot is not None:
-            self._snapshot(self)   # a valid partial artifact exists from
-            #                        the very first moment (kill contract)
+            with profiling.span("serve.snapshot"):
+                self._snapshot(self)   # a valid partial artifact exists
+            #                            from the first moment (kill contract)
         while self._final < n:
-            self._heal()
-            if not self.engines:
-                if (self._recovery is not None
-                        and self._recovery.can_recover()):
-                    # all replicas lost but respawn budget remains: PAUSE
-                    # admission (nothing dispatches) while arrivals keep
-                    # queuing and deadline clocks keep ticking at their
-                    # TRUE rounds — the recorded queue-depth/deadline-
-                    # pressure signal stays honest through the outage —
-                    # and let the round clock tick so the respawn backoff
-                    # elapses: a recoverable outage, not a shed-the-
-                    # remainder collapse. The budget is finite, so this
-                    # loop always terminates: either a replacement
-                    # attaches or can_recover goes False.
-                    self._poll_arrivals(self.clock.now())
-                    self._shed_deadlines()
+            with profiling.span("serve.round", round=self.stats.rounds):
+                self._heal()
+                if not self.engines:
+                    if (self._recovery is not None
+                            and self._recovery.can_recover()):
+                        # all replicas lost but respawn budget remains: PAUSE
+                        # admission (nothing dispatches) while arrivals keep
+                        # queuing and deadline clocks keep ticking at their
+                        # TRUE rounds — the recorded queue-depth/deadline-
+                        # pressure signal stays honest through the outage —
+                        # and let the round clock tick so the respawn backoff
+                        # elapses: a recoverable outage, not a shed-the-
+                        # remainder collapse. The budget is finite, so this
+                        # loop always terminates: either a replacement
+                        # attaches or can_recover goes False.
+                        self._poll_arrivals(self.clock.now())
+                        self._shed_deadlines()
+                        self._flush_shed_log()
+                        self.stats.admission_paused_rounds += 1
+                        if isinstance(self.clock, WallClock):
+                            # wall outage: the respawn gate is wall-time
+                            # (RecoveryManager.due) and rounds are STEP
+                            # DISPATCHES — nothing dispatches, so the
+                            # deadline clock must not inflate with spin
+                            # iterations; just wait a beat
+                            with profiling.span("serve.idle_wait"):
+                                time.sleep(0.01)  # firacheck: allow[SCHED-BLOCK] bounded 10ms beat on the ALL-REPLICAS-LOST pause branch: nothing can dispatch, arrivals are polled each beat, and the alternative is a busy-spin (PR 12 review)
+                        else:
+                            # virtual replay: the round clock IS the backoff
+                            # gate — tick it deterministically
+                            self.clock.on_step()
+                            self.stats.rounds += 1
+                        continue
+                    # every replica retired and no respawn budget left: shed
+                    # the remainder with the reason recorded —
+                    # position-complete output, no hang
+                    last = (self.stats.retirements[-1]["error"]
+                            if self.stats.retirements else "unknown")
+                    self._shed_all_remaining(
+                        f"no live replicas (all retired; last error: {last})")
                     self._flush_shed_log()
-                    self.stats.admission_paused_rounds += 1
-                    if isinstance(self.clock, WallClock):
-                        # wall outage: the respawn gate is wall-time
-                        # (RecoveryManager.due) and rounds are STEP
-                        # DISPATCHES — nothing dispatches, so the
-                        # deadline clock must not inflate with spin
-                        # iterations; just wait a beat
-                        time.sleep(0.01)  # firacheck: allow[SCHED-BLOCK] bounded 10ms beat on the ALL-REPLICAS-LOST pause branch: nothing can dispatch, arrivals are polled each beat, and the alternative is a busy-spin (PR 12 review)
-                    else:
-                        # virtual replay: the round clock IS the backoff
-                        # gate — tick it deterministically
-                        self.clock.on_step()
-                        self.stats.rounds += 1
-                    continue
-                # every replica retired and no respawn budget left: shed
-                # the remainder with the reason recorded —
-                # position-complete output, no hang
-                last = (self.stats.retirements[-1]["error"]
-                        if self.stats.retirements else "unknown")
-                self._shed_all_remaining(
-                    f"no live replicas (all retired; last error: {last})")
+                    break
+                with profiling.span("serve.poll"):
+                    self._poll_arrivals(self.clock.now())
+                if self._tier is not None:
+                    # disaggregated prefill tier tick (serve/disagg.py):
+                    # sweep dead workers, deliver checksum-verified
+                    # artifacts into every replica's cache, submit fresh
+                    # misses — pure host work before admission, so this
+                    # round's walk can already seat freshly-landed hits
+                    self._tier.service(self._queue, self.engines)
+                self._shed_deadlines()
+                with profiling.span("serve.admit"):
+                    self._admit()
+                live = [e for e in self.engines if e.in_flight()]
+                if not live:
+                    if self._queue or self._promoted \
+                            or any(e.staged_rows for e in self.engines):
+                        if self._tier is not None \
+                                and not any(e.staged_rows
+                                            for e in self.engines):
+                            # nothing dispatchable and the queue is waiting
+                            # on the prefill tier: block briefly on the
+                            # worker pipes instead of busy-spinning
+                            with profiling.span("serve.idle_wait"):
+                                self._tier.idle_wait(0.05)
+                        continue    # seats free up / budget admits next round
+                    if self._arr_idx < n:
+                        # idle: jump (virtual) / sleep (wall) to the next
+                        # scheduled arrival — open loop, the generator never
+                        # waits for us, only we for it
+                        with profiling.span("serve.idle_wait"):
+                            self.clock.advance_to(self._times[self._arr_idx])
+                        continue
+                    if self._final < n:   # pragma: no cover - loop invariant
+                        # a retirement always requeues into self._queue, so
+                        # final < n still implies queued/staged/arriving work
+                        raise RuntimeError(
+                            "serve loop stalled with requests unaccounted for")
+                    break
+                if self._dedup_on:
+                    # tell each replica which of its seats serve a fan-out
+                    # group (loop-level dedup keeps the followers up here) so
+                    # the engine's shared-block high-water meter covers them
+                    leaders = {p for p, fl in self._followers.items() if fl}
+                    for eng in live:
+                        eng.shared_positions = leaders
+                with profiling.span("serve.step_dispatch"):
+                    for eng in live:
+                        try:
+                            if self._faults is not None:
+                                self._faults.check("fleet.replica")
+                            run_with_watchdog(
+                                eng.step_dispatch, self._watchdog,
+                                label=f"serve_step[{eng.tag or 'r0'}]")
+                        except Exception as e:
+                            self._retire_replica(eng, e)
+                self.clock.on_step()
+                self.stats.rounds += 1
+                self._stamp_heartbeats()
+                items = []
+                with profiling.span("serve.harvest"):
+                    for eng in live:
+                        if eng.retired:
+                            continue
+                        try:
+                            items.extend(run_with_watchdog(
+                                eng.harvest, self._watchdog,
+                                label=f"serve_harvest[{eng.tag or 'r0'}]"))
+                        except Exception as e:
+                            self._retire_replica(eng, e)
+                with profiling.span("serve.emit"):
+                    t = self.clock.now()   # post-harvest: the honest reading
+                    for rec in self._awaiting_first_step:
+                        if rec.status == "seated":   # not requeued mid-round
+                            rec.first_step_t = t
+                    self._awaiting_first_step = []
+                    done_now: List[int] = []
+                    for it in items:
+                        rec = self._rec_by_pos[it.position]
+                        rec.done_t = t
+                        rec.done_round = self.stats.rounds
+                        rec.status = "done"
+                        if self._deadline and (
+                                rec.done_round - rec.arrival_round
+                                > self._deadline):
+                            rec.deadline_missed = True
+                        self._final += 1
+                        self._payloads.pop(it.position, None)
+                        self.stats.completions.append(it.position)
+                        done_now.append(it.position)
+                        self.emit(it.position, it.host, it.row, it.tokens,
+                                  it.probs)
+                        # dedup fan-out delivery: the leader's settled beams
+                        # are byte-identical to what every coalesced
+                        # follower's own decode would have produced (same
+                        # digest => same packed payload), so each follower
+                        # emits them at its OWN output position with its OWN
+                        # lifecycle stamps
+                        d = self._leader_digest.pop(it.position, None)
+                        if d is not None:
+                            self._leaders.pop(d, None)
+                        group = self._followers.pop(it.position, [])
+                        if group:
+                            self.stats.dedup_groups += 1
+                            self.stats.dedup_fanout_max = max(
+                                self.stats.dedup_fanout_max, 1 + len(group))
+                        for f in group:
+                            fr = f.record
+                            if math.isnan(fr.first_step_t):
+                                # coalesced after the leader's first step: its
+                                # first observable progress IS this harvest
+                                fr.first_step_t = t
+                            fr.done_t = t
+                            fr.done_round = self.stats.rounds
+                            fr.status = "done"
+                            if self._deadline and (
+                                    fr.done_round - fr.arrival_round
+                                    > self._deadline):
+                                fr.deadline_missed = True
+                            self._final += 1
+                            self.stats.completions.append(fr.position)
+                            done_now.append(fr.position)
+                            self.emit(fr.position, f.host, 0, it.tokens,
+                                      it.probs)
+                if self._journal is not None and done_now:
+                    # terminal WAL records AFTER the writer took the lines
+                    # (line-buffered — on disk): one record per request, one
+                    # fsync per harvest round
+                    with profiling.span("serve.journal"):
+                        self._journal.done(done_now)
                 self._flush_shed_log()
-                break
-            self._poll_arrivals(self.clock.now())
-            if self._tier is not None:
-                # disaggregated prefill tier tick (serve/disagg.py):
-                # sweep dead workers, deliver checksum-verified
-                # artifacts into every replica's cache, submit fresh
-                # misses — pure host work before admission, so this
-                # round's walk can already seat freshly-landed hits
-                self._tier.service(self._queue, self.engines)
-            self._shed_deadlines()
-            self._admit()
-            live = [e for e in self.engines if e.in_flight()]
-            if not live:
-                if self._queue or self._promoted \
-                        or any(e.staged_rows for e in self.engines):
-                    if self._tier is not None \
-                            and not any(e.staged_rows
-                                        for e in self.engines):
-                        # nothing dispatchable and the queue is waiting
-                        # on the prefill tier: block briefly on the
-                        # worker pipes instead of busy-spinning
-                        self._tier.idle_wait(0.05)
-                    continue    # seats free up / budget admits next round
-                if self._arr_idx < n:
-                    # idle: jump (virtual) / sleep (wall) to the next
-                    # scheduled arrival — open loop, the generator never
-                    # waits for us, only we for it
-                    self.clock.advance_to(self._times[self._arr_idx])
-                    continue
-                if self._final < n:   # pragma: no cover - loop invariant
-                    # a retirement always requeues into self._queue, so
-                    # final < n still implies queued/staged/arriving work
-                    raise RuntimeError(
-                        "serve loop stalled with requests unaccounted for")
-                break
-            if self._dedup_on:
-                # tell each replica which of its seats serve a fan-out
-                # group (loop-level dedup keeps the followers up here) so
-                # the engine's shared-block high-water meter covers them
-                leaders = {p for p, fl in self._followers.items() if fl}
-                for eng in live:
-                    eng.shared_positions = leaders
-            for eng in live:
-                try:
-                    if self._faults is not None:
-                        self._faults.check("fleet.replica")
-                    run_with_watchdog(eng.step_dispatch, self._watchdog,
-                                      label=f"serve_step[{eng.tag or 'r0'}]")
-                except Exception as e:
-                    self._retire_replica(eng, e)
-            self.clock.on_step()
-            self.stats.rounds += 1
-            self._stamp_heartbeats()
-            items = []
-            for eng in live:
-                if eng.retired:
-                    continue
-                try:
-                    items.extend(run_with_watchdog(
-                        eng.harvest, self._watchdog,
-                        label=f"serve_harvest[{eng.tag or 'r0'}]"))
-                except Exception as e:
-                    self._retire_replica(eng, e)
-            t = self.clock.now()   # post-harvest: the honest observation
-            for rec in self._awaiting_first_step:
-                if rec.status == "seated":   # not requeued mid-round
-                    rec.first_step_t = t
-            self._awaiting_first_step = []
-            done_now: List[int] = []
-            for it in items:
-                rec = self._rec_by_pos[it.position]
-                rec.done_t = t
-                rec.done_round = self.stats.rounds
-                rec.status = "done"
-                if self._deadline and (rec.done_round - rec.arrival_round
-                                       > self._deadline):
-                    rec.deadline_missed = True
-                self._final += 1
-                self._payloads.pop(it.position, None)
-                self.stats.completions.append(it.position)
-                done_now.append(it.position)
-                self.emit(it.position, it.host, it.row, it.tokens, it.probs)
-                # dedup fan-out delivery: the leader's settled beams are
-                # byte-identical to what every coalesced follower's own
-                # decode would have produced (same digest => same packed
-                # payload), so each follower emits them at its OWN output
-                # position with its OWN lifecycle stamps
-                d = self._leader_digest.pop(it.position, None)
-                if d is not None:
-                    self._leaders.pop(d, None)
-                group = self._followers.pop(it.position, [])
-                if group:
-                    self.stats.dedup_groups += 1
-                    self.stats.dedup_fanout_max = max(
-                        self.stats.dedup_fanout_max, 1 + len(group))
-                for f in group:
-                    fr = f.record
-                    if math.isnan(fr.first_step_t):
-                        # coalesced after the leader's first step: its
-                        # first observable progress IS this harvest
-                        fr.first_step_t = t
-                    fr.done_t = t
-                    fr.done_round = self.stats.rounds
-                    fr.status = "done"
-                    if self._deadline and (fr.done_round - fr.arrival_round
-                                           > self._deadline):
-                        fr.deadline_missed = True
-                    self._final += 1
-                    self.stats.completions.append(fr.position)
-                    done_now.append(fr.position)
-                    self.emit(fr.position, f.host, 0, it.tokens, it.probs)
-            if self._journal is not None and done_now:
-                # terminal WAL records AFTER the writer took the lines
-                # (line-buffered — on disk): one record per request, one
-                # fsync per harvest round
-                self._journal.done(done_now)
-            self._flush_shed_log()
-            if (self._snapshot is not None
-                    and self.stats.rounds % SNAPSHOT_EVERY_ROUNDS == 0):
-                self._snapshot(self)
+                if (self._snapshot is not None
+                        and self.stats.rounds % SNAPSHOT_EVERY_ROUNDS == 0):
+                    with profiling.span("serve.snapshot"):
+                        self._snapshot(self)
         self._flush_shed_log()   # sheds recorded after the last harvest
-        self.stats.wall_s = time.perf_counter() - t0  # firacheck: allow[WALL-CLOCK] the wall_s meter's closing read — same real-wall stall-denominator contract as the t0 stamp above
-        return self.stats
 
 
 # --------------------------------------------------------------------------
@@ -1734,7 +1787,9 @@ def serve_split(model: FiraModel, params, dataset: FiraDataset,
                        # re-raise
                        on_error="record",
                        retries=max(0, cfg.robust_retries),
-                       faults=faults) as feed:
+                       # one task is one REQUEST: its life is the stamps
+                       # on its RequestRecord, not a span each
+                       faults=faults, per_request=True) as feed:
             # resume: the recovered lines re-enter the position-keyed
             # writer first (prefix + above-gap tails both), exactly once
             for p in sorted(recovered):

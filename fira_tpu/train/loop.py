@@ -22,6 +22,7 @@ Added beyond the reference: full train-state checkpointing with resume
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import threading
@@ -222,7 +223,8 @@ def train(dataset: FiraDataset, cfg: Optional[FiraConfig] = None, *,
     sample = make_batch(train_split, np.arange(min(cfg.batch_size,
                                                    len(train_split))),
                         cfg, batch_size=cfg.batch_size)
-    state = init_state(model, cfg, sample)
+    with profiling.span("train.init_state"):
+        state = init_state(model, cfg, sample)
     if mesh is not None:
         state = state.replace(
             params=pmesh.shard_params(state.params, mesh))
@@ -266,6 +268,7 @@ def train(dataset: FiraDataset, cfg: Optional[FiraConfig] = None, *,
     # jax.profiler trace of a steady-state step window (skips the compile
     # step); viewable in TensorBoard / xprof.
     profile_window = (range(2, 2 + profile_steps) if profile_dir else range(0))
+    profiler = contextlib.ExitStack()   # holds profiling.trace while open
     profiling_active = False
     profile_done = False
     global_step = 0
@@ -431,7 +434,13 @@ def train(dataset: FiraDataset, cfg: Optional[FiraConfig] = None, *,
         try:
             for item in epoch_feed:
                 batch, n_valid = item.device, item.n_valid
-                pending_stall += item.stall_s
+                if meter.paused:
+                    # the epoch's first batch: the checkpoint save and this
+                    # feeder's pipeline fill lie behind it and are not train
+                    # time (nor is that fill a steady-state feed stall)
+                    meter.start()
+                else:
+                    pending_stall += item.stall_s
                 stacked = item.host["valid"].ndim == 2
                 # cadence counts REAL batches: the accum tail is padded with
                 # all-zero micro-batches, so the stacked leading dim overstates
@@ -484,7 +493,7 @@ def train(dataset: FiraDataset, cfg: Optional[FiraConfig] = None, *,
                     # the REAL program is profiled — grouped dispatches and
                     # all — so profiled numbers are production-path numbers;
                     # a K-group's annotation spans its whole scan dispatch
-                    jax.profiler.start_trace(profile_dir)
+                    profiler.enter_context(profiling.trace(profile_dir))
                     profiling_active = True
                 dispatch = grouped_step if stacked else train_step
                 if profiling_active:
@@ -506,7 +515,7 @@ def train(dataset: FiraDataset, cfg: Optional[FiraConfig] = None, *,
                 global_step += 1 if (stacked and accum > 1) else k
                 if profiling_active and global_step > profile_window[-1]:
                     _materialize(metrics["loss"])
-                    jax.profiler.stop_trace()
+                    profiler.close()
                     profiling_active = False
                     profile_done = True
                     log.console(f"profile trace written to {profile_dir}")
@@ -533,11 +542,12 @@ def train(dataset: FiraDataset, cfg: Optional[FiraConfig] = None, *,
         if last_metrics is not None:
             _materialize(last_metrics["loss"])
         sync_tick()
+        meter.pause()  # until the next epoch's first batch arrives
         ckpt.save_latest(state, best_bleu=best_bleu, epoch=epoch + 1,
                          rng_impl=cfg.rng_impl)
 
     if profiling_active:  # run ended inside the profile window
-        jax.profiler.stop_trace()
+        profiler.close()
         log.console(f"profile trace written to {profile_dir}")
     elif profile_dir and not profile_window:
         log.console("profile trace NOT written: profile_steps=0")

@@ -51,11 +51,12 @@ def make_train_step(model: FiraModel, cfg: FiraConfig
         loss, grads = jax.value_and_grad(
             partial(loss_fn, model)
         )(state.params, batch, step_rng)
-        updates, opt_state = optimizer.update(grads, state.opt_state,
-                                              state.params)
-        params = jax.tree_util.tree_map(
-            lambda p, u: (p + u).astype(p.dtype), state.params, updates
-        )
+        with jax.named_scope("optimizer"):   # a name in the device trace
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  state.params)
+            params = jax.tree_util.tree_map(
+                lambda p, u: (p + u).astype(p.dtype), state.params, updates
+            )
         new_state = TrainState(
             step=state.step + 1, params=params, opt_state=opt_state,
             rng=next_rng,

@@ -80,7 +80,10 @@ def device_info() -> Dict:
     the machine does not have."""
     import jax
 
-    devs = jax.devices()
+    from fira_tpu.utils import profiling
+
+    with profiling.span("startup.backend"):
+        devs = jax.devices()
     return {"platform": devs[0].platform,
             "device_kind": devs[0].device_kind,
             "n_devices": len(devs)}

@@ -194,7 +194,17 @@ def test_serve_completion_sequence_stable_across_worker_counts(
                         clock="virtual")
         runs.append(m)
     assert runs[0]["request_records"] == runs[1]["request_records"]
-    assert runs[0]["serve"] == runs[1]["serve"]
+
+    def scheduled(m):
+        # `phases` is the recorder's block (utils/profiling.py): wall
+        # seconds, the one part of the summary that is schedule-dependent
+        return {k: v for k, v in m["serve"].items() if k != "phases"}
+
+    assert scheduled(runs[0]) == scheduled(runs[1])
+    # ... but how many rounds it timed is part of the schedule
+    a, b = (m["serve"]["phases"] for m in runs)
+    assert a["serve.round"]["count"] == b["serve.round"]["count"]
+    assert a["serve.step_dispatch"]["count"] == runs[0]["serve"]["rounds"]
 
 
 def test_serve_latency_records_complete_and_ordered(setup, trace, tmp_path):
