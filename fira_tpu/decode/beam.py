@@ -8,7 +8,8 @@ sentinel probabilities (:281-298), takes one global top-k (:305-310), and
 resolves copy ids to source token ids at beam-extension time (:334-337).
 
 This rebuild runs the whole thing as ONE compiled program: beams fold into
-the batch dim, `lax.scan` drives the tar_len-1 steps, and top-k replaces the
+the batch dim, `lax.scan` drives the tar_len-1 steps, and an exact top-k
+(:func:`top_k`: K reduce passes, never a sort) replaces the reference's
 sort. Two accumulation modes:
 
 - compat (default, cfg.beam_compat_prob_space=True): probability-space
@@ -118,13 +119,78 @@ def _init_beam(B: int, cfg: FiraConfig):
     return tokens0, probs0, finished0, neg
 
 
+def _order_key(bits):
+    """A float's bit pattern (as a signed integer of its width) -> the
+    integer that ascends exactly as `lax.top_k` ranks floats, on the CPU
+    and on the chip: IEEE total order (-NaN < -inf < ... < -0.0 < +0.0 <
+    ... < +inf < NaN). An involution: applied to a key it gives the bit
+    pattern back."""
+    n = bits.dtype.itemsize * 8
+    return bits ^ ((bits >> (n - 1)) & jnp.iinfo(bits.dtype).max)
+
+
+def _better(a, b):
+    """(key, index) reduction monoid of :func:`top_k`: the larger key
+    wins, equal keys go to the lower index."""
+    (ak, ai), (bk, bi) = a, b
+    a_wins = (ak > bk) | ((ak == bk) & (ai < bi))
+    return jnp.where(a_wins, ak, bk), jnp.where(a_wins, ai, bi)
+
+
+def top_k(x, k: int):
+    """Exact ``jax.lax.top_k(x, k)`` over the last axis without a sort:
+    bit for bit the same ``(values, indices)`` — values descending and
+    **equal values in ascending index order** (`lax.top_k` is stable),
+    which the early-exit fixed point (:func:`_run_steps`) and the
+    byte-for-byte decode contracts rest on.
+
+    ``k`` is static and small (the beam size), so selection is k unrolled
+    passes, each ONE fused (key, index) max-reduce over ``x``: pass j
+    finds the best element ranked strictly after pass j-1's pick in
+    (value descending, index ascending) order. What ranks at or before
+    that pick is masked inside the pass (nothing is written) to (lowest
+    key, width), which loses to every live element, a real -inf included
+    (lower index), so rows of ties and rows of -inf come out in
+    `lax.top_k`'s order. On the chip `lax.top_k` is a full stable sort of
+    every row beside an index tensor of the same size: at the
+    vocabulary's width over half of the serve step, where the k passes
+    run at the memory's bandwidth (PERF.md §6, PR 27)."""
+    width = x.shape[-1]
+    if not 0 < k <= width:
+        raise ValueError(f"top_k: k={k} outside 1..{width}")
+    if not jnp.issubdtype(x.dtype, jnp.floating):
+        raise TypeError(f"top_k ranks floats by their bits, not {x.dtype}")
+    axis = x.ndim - 1
+    int_t = jnp.dtype(f"int{x.dtype.itemsize * 8}")
+    key = _order_key(jax.lax.bitcast_convert_type(x, int_t))
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    lowest = (jnp.asarray(jnp.iinfo(int_t).min, int_t), jnp.int32(width))
+    keys, idxs = [], []
+    for j in range(k):
+        live = (key, iota)
+        if j:
+            pk, pi = keys[-1][..., None], idxs[-1][..., None]
+            gone = (key > pk) | ((key == pk) & (iota <= pi))
+            live = (jnp.where(gone, lowest[0], key),
+                    jnp.where(gone, lowest[1], iota))
+        pk, pi = jax.lax.reduce(live, lowest, _better, (axis,))
+        keys.append(pk)
+        idxs.append(pi)
+    vals = jax.lax.bitcast_convert_type(
+        _order_key(jnp.stack(keys, axis=-1)), x.dtype)
+    return vals, jnp.stack(idxs, axis=-1)
+
+
 def _selection_tail(cand, ids, tokens, probs, finished, s, batch,
                     cfg: FiraConfig, neg):
     """Shared selection tail for :func:`_select` and
     :func:`_select_factored`: mask finished beams, append their sentinel
-    entries, one global top-k over K*W + K candidates, decode sentinels vs
-    real candidates, write the chosen token at position s+1
-    (run_model.py:267-310).
+    entries, one global :func:`top_k` over K*W + K candidates, decode
+    sentinels vs real candidates, write the chosen token at position s+1
+    (run_model.py:267-310). Equal candidates come out lowest index first
+    (:func:`top_k`'s guarantee): a beam's candidates before the next
+    beam's, every real candidate before the sentinels, sentinels in beam
+    order — the order :func:`_run_steps`' fixed point needs.
 
     cand: (B, K, W) candidate scores already in the selection space.
     ids: None when W is the fused output space itself (token id = index
@@ -141,7 +207,7 @@ def _selection_tail(cand, ids, tokens, probs, finished, s, batch,
     cand = jnp.where(finished[:, :, None], neg, cand)
     sentinel = jnp.where(finished, probs, neg)          # (B, K)
     allc = jnp.concatenate([cand.reshape(B, K * W), sentinel], axis=1)
-    top_vals, top_idx = jax.lax.top_k(allc, K)          # (B, K)
+    top_vals, top_idx = top_k(allc, K)                  # (B, K)
 
     is_sent = top_idx >= K * W
     src_beam = jnp.where(is_sent, top_idx - K * W, top_idx // W)
@@ -182,13 +248,16 @@ def _select_factored(gen, copy, gate, tokens, probs, finished, s, batch,
     softmax; gate: (B, K, 2). The fused distribution is
     [gate0*gen || gate1*copy], so each beam's global top-K lies in the
     union of its per-side top-Ks — selection runs over 2K candidates per
-    beam (6 for beam 3) instead of the 25,020-way assembled tensor. Same
-    candidate math as :func:`_select` (prob- or log-space, finished-beam
-    sentinels); only tie-breaking among exactly-equal probabilities can
-    differ from the fused scan order."""
+    beam (6 for beam 3) instead of the 25,020-way assembled tensor. Each
+    side's K best come from :func:`top_k` (K reduce passes, no sort of
+    the vocabulary), equal probabilities lowest index first — real on
+    peaked rows, most of which is exactly 0.0. Same candidate math as
+    :func:`_select` (prob- or log-space, finished-beam sentinels); only
+    tie-breaking among exactly-equal probabilities ACROSS the two sides
+    can differ from the fused scan order."""
     B, K, V = gen.shape
-    gv, gi = jax.lax.top_k(gen, K)                      # (B, K, K)
-    cv, ci = jax.lax.top_k(copy, K)
+    gv, gi = top_k(gen, K)                              # (B, K, K)
+    cv, ci = top_k(copy, K)
     side_vals = jnp.concatenate(
         [gv * gate[:, :, 0:1], cv * gate[:, :, 1:2]], axis=-1)  # (B, K, 2K)
     side_ids = jnp.concatenate([gi, ci + V], axis=-1)   # fused-space ids
@@ -227,9 +296,12 @@ def _run_steps(step, carry0, T: int, early_exit: bool):
     of every item is finished AND one settling step has run after
     saturation. The settling step matters for bit-exactness: the first
     all-finished step re-sorts beams prob-descending via the sentinel
-    top-k; after it the state is an element-wise fixed point (stable top_k
-    on a sorted vector), so skipping the remaining steps changes nothing.
-    `finished` is carry[2] in both beam variants.
+    top-k; after it the state is an element-wise fixed point — every real
+    candidate is masked to ``neg``, the K sentinels are already
+    descending, and :func:`top_k` returns equal values lowest index
+    first, so it picks the sentinels in place, ties included — and
+    skipping the remaining steps changes nothing. `finished` is carry[2]
+    in both beam variants.
 
     Returns (final_carry, steps_run) — steps_run is a traced scalar under
     early exit (T-1 exactly otherwise)."""
