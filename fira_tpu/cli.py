@@ -531,6 +531,10 @@ def _resolve_cfg(args):
         overrides["beam_early_exit"] = True
     if args.engine:
         overrides["decode_engine"] = True
+    elif cfg.arch != "fira":
+        # its presets keep the engine on (nothing else runs them); on the
+        # CLI the path is still asked for by name, and refused without it
+        overrides["decode_engine"] = False
     if args.engine_slots is not None:
         overrides["engine_slots"] = args.engine_slots
     if args.engine_prefill_depth is not None:
@@ -684,6 +688,21 @@ def main(argv: Optional[List[str]] = None) -> int:
     cache_dir = startup.configure_compile_cache()
     cfg = _resolve_cfg(args)
 
+    if cfg.arch != "fira":
+        # a decoder-only architecture is admitted HERE, before anything
+        # that belongs to FIRA's corpus is looked at: what it does not run
+        # yet exits 2 with its name (config.arch_errors)
+        from fira_tpu.config import arch_errors, config_errors
+        from fira_tpu.decode.paging import paging_errors
+
+        errs = list(dict.fromkeys(
+            config_errors(cfg) + arch_errors(cfg, args.command)
+            + paging_errors(cfg)))
+        if errs:
+            for e in errs:
+                print(f"parse-time validation: {e}", file=sys.stderr)
+            return 2
+
     # Raw-diff ingest admission (docs/INGEST.md) validates BEFORE the
     # dataset loads — a missing --diff-trace or a bad knob must exit 2
     # immediately, same named-knob contract as the blocks below.
@@ -754,6 +773,23 @@ def main(argv: Optional[List[str]] = None) -> int:
                 and os.path.isdir(args.profile_dir):
             profiling.dump(os.path.join(args.profile_dir, "spans.jsonl"))
         return 0
+
+    if cfg.arch != "fira":
+        # a decoder-only architecture: no corpus, no checkpoint (token-id
+        # prompts and weights from the seed) — the same engine
+        # (decode/runner.run_lm_test); admitted above
+        startup.write_run_info(args.out_dir, info)
+        from fira_tpu.analysis import sanitizer as sanitizer_lib
+        from fira_tpu.decode.runner import run_lm_test
+
+        metrics = run_lm_test(cfg, out_dir=args.out_dir,
+                              guard=sanitizer_lib.arm(args.sanitize))
+        eng = metrics["engine"]
+        print(f"test: {int(metrics['n'])} requests, "
+              f"{eng['prompt_tokens']} prompt tokens in "
+              f"{eng['prefills']} prefill dispatches, "
+              f"{eng['steps_run']} positions -> {metrics['output_path']}")
+        return finished()
 
     from fira_tpu.data.dataset import FiraDataset
 

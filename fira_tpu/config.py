@@ -14,7 +14,73 @@ from typing import Optional
 
 
 @dataclasses.dataclass(frozen=True)
+class LMConfig:
+    """A decoder-only language model's published keys (``arch="axk1"``), by
+    the names its ``config.json`` gives them; the defaults are A.X-K1's
+    (https://huggingface.co/skt/A.X-K1/blob/main/config.json). What this
+    engine holds of a deployment comes after them: the experts held here
+    of ``n_routed_experts`` (the router keeps its full width), the rows of
+    the vocabulary held, and the prefill geometry."""
+
+    hidden_size: int = 7168
+    intermediate_size: int = 18432       # the leading dense layers' SwiGLU
+    moe_intermediate_size: int = 2048    # each routed / shared expert
+    num_hidden_layers: int = 61
+    first_k_dense_replace: int = 1
+    num_attention_heads: int = 64
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    n_routed_experts: int = 192          # the router's outputs
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    n_group: int = 8
+    topk_group: int = 4
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_factor: float = 32.0            # rope_scaling (type "yarn"), flat
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    rope_original_max_position_embeddings: int = 4096
+    vocab_size: int = 163840             # rows of embedding and head held
+    # this chip's share of an expert-parallel deployment: experts
+    # [expert_offset, expert_offset + experts_held) of every expert layer
+    experts_held: int = 192
+    expert_offset: int = 0
+    # prefill: prompt-length buckets (the engine's geometry tags), the
+    # padded tokens one dispatch may hold, and the per-slot prompt arena
+    prompt_buckets: tuple = (512, 1024, 2048, 4096)
+    prefill_token_budget: int = 8192
+
+    @property
+    def prompt_len_max(self) -> int:
+        return int(self.prompt_buckets[-1])
+
+    @property
+    def latent_dim(self) -> int:
+        """What one token caches a layer: [c_kv | rotated k_rope]."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def bucket_rows(self, bucket: int) -> int:
+        """Requests one prefill dispatch of ``bucket``-long prompts holds."""
+        return max(1, self.prefill_token_budget // int(bucket))
+
+
+@dataclasses.dataclass(frozen=True)
 class FiraConfig:
+    # --- architecture: "fira" (the paper's encoder-decoder, every field
+    # below) or "axk1" (a latent-attention, routed-expert decoder: ``lm``
+    # holds its published keys; of the fields below it reads beam_size,
+    # tar_len, the engine/paging knobs and seed) ---
+    arch: str = "fira"
+    lm: Optional[LMConfig] = None
+
     # --- sequence geometry (reference run_model.py:31-35) ---
     sou_len: int = 210          # diff tokens incl. <start>/<eos>
     tar_len: int = 30           # message tokens incl. <start>/<eos>
@@ -664,10 +730,63 @@ DECODE_PERF_KNOBS = {
 }
 
 
+# What every "axk1" preset fixes outside ``lm``: the slot engine with a
+# paged pool for the generated positions, log-space beams (the head is a
+# log-softmax and has no copy side), bfloat16 weights and cache.
+_LM_ENGINE = dict(
+    arch="axk1", decode_engine=True, beam_kv_cache=True,
+    engine_paged_kv=True, beam_compat_prob_space=False,
+    compute_dtype="bfloat16", beam_size=3, tar_len=64,
+)
+
+
+def _lm_preset(lm: LMConfig, kw: dict) -> FiraConfig:
+    """``lm=`` in ``kw`` may be an LMConfig or a dict of its keys to
+    replace; ``vocab_size`` always follows the block's."""
+    over = kw.pop("lm", None)
+    if isinstance(over, LMConfig):
+        lm = over
+    elif over:
+        lm = dataclasses.replace(lm, **over)
+    base = dict(_LM_ENGINE, lm=lm)
+    base.update(kw)
+    base["vocab_size"] = lm.vocab_size
+    return FiraConfig(**base)
+
+
+def axk1_ep16(**kw) -> FiraConfig:
+    """A.X-K1 at its published widths, one chip's share of a 16-chip
+    expert-parallel deployment (benchmark/configs/axk1-ep16.json says how
+    it was cut): the dense layer + 6 expert layers, 12 of 192 routed
+    experts, an eighth of the vocabulary."""
+    base = dict(engine_slots=64, test_batch_size=16)
+    base.update(kw)
+    return _lm_preset(LMConfig(num_hidden_layers=7, experts_held=12,
+                               vocab_size=20480), base)
+
+
+def axk1_tiny(**kw) -> FiraConfig:
+    """Every mechanism of A.X-K1 at CPU-test widths: d 64, 4 heads, 16
+    routed experts in 4 groups, top-4, 3 layers (1 dense + 2 expert)."""
+    base = dict(engine_slots=4, test_batch_size=4, tar_len=16,
+                compute_dtype="float32")
+    base.update(kw)
+    return _lm_preset(LMConfig(
+        hidden_size=64, intermediate_size=160, moe_intermediate_size=32,
+        num_hidden_layers=3, num_attention_heads=4, q_lora_rank=48,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, n_routed_experts=16, num_experts_per_tok=4,
+        n_group=4, topk_group=2, vocab_size=512, experts_held=16,
+        rope_original_max_position_embeddings=32,
+        prompt_buckets=(16, 32, 64), prefill_token_budget=64), base)
+
+
 NAMED_CONFIGS = {
     "fira-tiny": fira_tiny,
     "fira-full": fira_full,
     "fira-large": fira_large,
+    "axk1-ep16": axk1_ep16,
+    "axk1-tiny": axk1_tiny,
 }
 
 
@@ -705,6 +824,63 @@ def config_errors(cfg: FiraConfig) -> list:
         errs.append(
             f"seq_shards {cfg.seq_shards} must be >= 0 (0/1 = dense "
             f"cross-attention, N > 1 ring-shards K/V over N devices)")
+    return errs + arch_errors(cfg)
+
+
+ARCHS = ("fira", "axk1")
+
+
+def arch_errors(cfg: FiraConfig, command: Optional[str] = None) -> list:
+    """What an architecture does not run yet is refused by name, never
+    run silently as something else. ``command``: the CLI's (``train`` /
+    ``test`` / ``serve`` / ``message``), where there is one."""
+    if cfg.arch not in ARCHS:
+        return [f"arch {cfg.arch!r} not in {list(ARCHS)}"]
+    if cfg.arch == "fira":
+        return ([f"arch 'fira' takes no lm block (got {cfg.lm!r})"]
+                if cfg.lm is not None else [])
+    lm, errs = cfg.lm, []
+    if lm is None:
+        return ["arch 'axk1' needs an lm block (config.LMConfig)"]
+
+    def no(what: str) -> None:
+        errs.append(f"arch 'axk1' does not support {what} yet")
+    if command in ("train", "serve", "message"):
+        no(f"cli {command} (cli test --engine runs it)")
+    if not cfg.decode_engine:
+        no("the batched non-engine beam (decode_engine off); run it "
+           "through the slot engine (--engine)")
+    if not (cfg.beam_kv_cache and cfg.engine_paged_kv):
+        no("an unpaged or uncached arena (beam_kv_cache and "
+           "engine_paged_kv must stay on)")
+    if cfg.beam_compat_prob_space:
+        no("probability-space beams (beam_compat_prob_space): its head "
+           "is a log-softmax")
+    if cfg.prefix_cache:
+        no("prefix_cache")
+    if cfg.spec_decode not in (None, "off"):
+        no(f"spec_decode {cfg.spec_decode!r}")
+    if cfg.serve_precision != "f32" or cfg.kv_dtype != "f32":
+        no(f"serve_precision {cfg.serve_precision!r} / kv_dtype "
+           f"{cfg.kv_dtype!r} tiers (int8w among them): its weights and "
+           f"cache are bfloat16 from creation")
+    if cfg.engine_replicas > 1:
+        no(f"engine_replicas {cfg.engine_replicas} > 1")
+    if cfg.serve_tiers != "off":
+        no(f"serve_tiers {cfg.serve_tiers!r} (serve/disagg.py)")
+    if cfg.buckets or cfg.decode_tar_buckets:
+        no("graph bucket tables (buckets / decode_tar_buckets): its "
+           "prefill buckets are lm.prompt_buckets")
+    if not 0 <= lm.expert_offset <= lm.n_routed_experts - lm.experts_held:
+        errs.append(
+            f"lm.expert_offset {lm.expert_offset} + lm.experts_held "
+            f"{lm.experts_held} must lie within lm.n_routed_experts "
+            f"{lm.n_routed_experts}")
+    if lm.n_routed_experts % lm.n_group:
+        errs.append(f"lm.n_group {lm.n_group} does not divide "
+                    f"lm.n_routed_experts {lm.n_routed_experts}")
+    if list(lm.prompt_buckets) != sorted(set(lm.prompt_buckets)):
+        errs.append(f"lm.prompt_buckets {lm.prompt_buckets} must ascend")
     return errs
 
 

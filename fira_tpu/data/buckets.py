@@ -448,3 +448,73 @@ def padding_report(split: ProcessedSplit, cfg: FiraConfig,
             float(assigned.sum()) / (full_cost * len(split)), 4),
         "buckets": per_bucket,
     }
+
+
+# --- prompt-length buckets (arch "axk1": cfg.lm.prompt_buckets) -------------
+
+def prompt_tag(bucket: int) -> str:
+    """Geometry tag of a prompt-length bucket in the engine's program
+    family: ``engine_prefill[p1024]``, ``engine_insert[p1024]``."""
+    return f"p{bucket}"
+
+
+def prompt_bucket(lm, length: int) -> int:
+    for b in lm.prompt_buckets:
+        if length <= b:
+            return b
+    raise ValueError(f"a prompt of {length} tokens exceeds the largest "
+                     f"bucket {lm.prompt_len_max} (lm.prompt_buckets)")
+
+
+def prompt_batch(lm, bucket: int, rows) -> Dict:
+    """One prefill dispatch of ``bucket``-long prompts. ``rows``: up to
+    ``lm.bucket_rows(bucket)`` of (position, prompt ids, max_new_tokens).
+    Host-only fields ("_"): the rows' stream positions, real lengths and
+    position limits (a request generates ``max_new`` tokens after
+    <start>, so its slot's limit is ``max_new + 1``), and the tag."""
+    B = lm.bucket_rows(bucket)
+    out = {"tokens": np.zeros((B, bucket), np.int32),
+           "lengths": np.zeros((B,), np.int32),
+           "valid": np.zeros((B,), bool),
+           "_positions": np.full((B,), -1, np.int64),
+           "_limits": np.ones((B,), np.int32),
+           "_tag": prompt_tag(bucket)}
+    for r, (pos, ids, max_new) in enumerate(rows):
+        out["tokens"][r, :len(ids)] = ids
+        out["lengths"][r] = len(ids)
+        out["valid"][r] = True
+        out["_positions"][r] = pos
+        out["_limits"][r] = max_new + 1
+    out["_prompt_len"] = out["lengths"]
+    return out
+
+
+def prompt_batches(lm, requests, flush: bool = True):
+    """Form prefill batches from a stream of (position, prompt ids,
+    max_new_tokens): a request waits in its length bucket until the bucket
+    holds a dispatch's worth (``lm.prefill_token_budget`` padded tokens),
+    so every dispatch but the stream's last few is full. ``flush``: emit
+    the part-filled buckets when the stream ends."""
+    waiting = {b: [] for b in lm.prompt_buckets}
+    for req in requests:
+        b = prompt_bucket(lm, len(req[1]))
+        waiting[b].append(req)
+        if len(waiting[b]) == lm.bucket_rows(b):
+            yield prompt_batch(lm, b, waiting[b])
+            waiting[b] = []
+    if flush:
+        for b, rows in waiting.items():
+            if rows:
+                yield prompt_batch(lm, b, rows)
+
+
+def prompt_tasks(lm, requests, flush: bool = True):
+    """:func:`prompt_batches` as feeder tasks (data/feeder.py)."""
+    for batch in prompt_batches(lm, requests, flush):
+        yield lambda b=batch: b
+
+
+def prompt_warm_batches(lm):
+    """One all-pad batch a bucket with its tag: the engine's prewarm."""
+    return [(prompt_batch(lm, b, []), prompt_tag(b))
+            for b in lm.prompt_buckets]

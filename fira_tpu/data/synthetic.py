@@ -12,6 +12,8 @@ edge family the graph builder assembles.
 from __future__ import annotations
 
 import random
+
+import numpy as np
 from typing import Dict, List, Tuple
 
 from fira_tpu.data.schema import Corpus
@@ -328,3 +330,33 @@ def make_memory_batch(cfg, n: int, seed: int = 0, pad_vocab_to: int = 0):
     cfg, split, word_vocab = make_memory_split(cfg, n, seed=seed,
                                                pad_vocab_to=pad_vocab_to)
     return cfg, make_batch(split, np.arange(n), cfg), word_vocab
+
+
+def make_prompt_requests(n: int, *, vocab_size: int, seed: int = 0,
+                         min_len: int = 256, max_len: int = 4096,
+                         round_size: int = 16,
+                         limits: Tuple[int, ...] = (16, 32, 48, 63),
+                         first_id: int = 4):
+    """Token-id prompts for a decoder-only model (no tokenizer ships): ids
+    uniform on [first_id, vocab_size) — the ids below are the engine's pad,
+    <eos>, <start> and <unk> — lengths log-uniform on [min_len, max_len).
+
+    Dealt in ROUNDS of ``round_size`` requests that each hold an equal
+    number of prompts from every octave of length ([min, 2 min), [2 min,
+    4 min), ...), and inside an octave each of ``limits`` (the positions a
+    request may generate) equally often: any stretch of a few rounds then
+    holds the same work, whatever the draw.
+    -> (prompts: list of (len,) int32 arrays, max_new: (n,) int32)."""
+    octaves = max(1, int(round(np.log2(max_len / min_len))))
+    per = max(1, round_size // octaves)
+    rng = np.random.default_rng(seed)
+    prompts, max_new = [], []
+    while len(prompts) < n:
+        for o in range(octaves):
+            lo = min_len * 2 ** o
+            for j in range(per):
+                length = min(int(lo * 2 ** rng.random()), max_len - 1)
+                prompts.append(rng.integers(first_id, vocab_size, length,
+                                            dtype=np.int32))
+                max_new.append(limits[j % len(limits)])
+    return prompts[:n], np.asarray(max_new[:n], np.int32)

@@ -217,7 +217,8 @@ def _selection_tail(cand, ids, tokens, probs, finished, s, batch,
         tok = jnp.take_along_axis(
             ids.reshape(B, K * W), jnp.where(is_sent, 0, top_idx), axis=1)
         tok = jnp.where(is_sent, 0, tok)
-    tok = _resolve_copy(tok, batch["diff"], batch["sub_token"], cfg)
+    if batch is not None:   # None: no copy side, a candidate is its token
+        tok = _resolve_copy(tok, batch["diff"], batch["sub_token"], cfg)
 
     new_tokens = jnp.take_along_axis(tokens, src_beam[:, :, None], axis=1)
     if jnp.ndim(s) == 0:
@@ -270,7 +271,8 @@ def _select_factored(gen, copy, gate, tokens, probs, finished, s, batch,
                            batch, cfg, neg)
 
 
-def _select(dist, tokens, probs, finished, s, batch, cfg: FiraConfig, neg):
+def _select(dist, tokens, probs, finished, s, batch, cfg: FiraConfig, neg,
+            log_input: bool = False):
     """One beam-selection round given this step's fused distribution.
 
     dist: (B, K, V_out) probability-space distribution at position ``s``.
@@ -279,8 +281,11 @@ def _select(dist, tokens, probs, finished, s, batch, cfg: FiraConfig, neg):
     to ``neg`` and contribute a sentinel entry carrying their own
     probability; one global top-k over K*V_out + K candidates
     (run_model.py:267-310). Returns (new_tokens, new_probs, new_finished,
-    src_beam)."""
-    if cfg.beam_compat_prob_space:
+    src_beam). ``log_input``: ``dist`` already holds log-probabilities (a
+    model whose head is one log-softmax, log-space beams only)."""
+    if log_input:
+        cand = dist + probs[:, :, None]
+    elif cfg.beam_compat_prob_space:
         cand = dist * probs[:, :, None]
     else:
         cand = jnp.log(jnp.clip(dist, 1e-10, 1.0)) + probs[:, :, None]

@@ -79,7 +79,8 @@ deterministic), and parse-time floors (decode/paging.paging_errors)
 guarantee it can always eventually be seated.
 
 Host scheduler (:meth:`SlotEngine.run`): drains the packer stream via the
-async feeder, prefills ahead (``cfg.engine_prefill_depth`` chunks),
+async feeder, prefills ahead (``cfg.engine_prefill_depth`` chunks, at most
+the model's ``prefill_budget`` dispatches a pass where it declares one),
 refills every freed slot, steps, harvests settled slots, and yields one
 :class:`EngineItem` per sample AS IT SETTLES (out of split order — the
 ordered streaming writer, decode/stream.py, restores order on disk). The
@@ -141,10 +142,9 @@ from fira_tpu.config import FiraConfig
 from fira_tpu.decode import paging
 from fira_tpu.decode import prefix_cache as prefix_cache_lib
 from fira_tpu.decode import quant
+from fira_tpu.decode import slot_model
 from fira_tpu.decode import spec as spec_lib
-from fira_tpu.decode.beam import (_init_beam, _select, _select_factored,
-                                  step_valid_mask)
-from fira_tpu.model.model import FiraModel
+from fira_tpu.decode.beam import _init_beam
 from fira_tpu.utils import profiling
 
 PREFILL_KIND = "engine_prefill"
@@ -217,6 +217,17 @@ class EngineStats:
     # the pool fields, so stats resets between timed windows re-learn them
     kv_dtype: str = "f32"        # K/V arena storage dtype (f32|bf16)
     serve_precision: str = "f32"  # decode weight tier (f32|bf16|int8w)
+    # prompt accounting (host-known at admit; zero for a model whose
+    # prefill has one fixed geometry and no notion of a prompt length)
+    prompt_tokens: int = 0        # real prompt tokens prefilled
+    prompt_tokens_padded: int = 0  # the same, padded to their buckets
+    # routed-expert accounting (model/axk1.COUNTERS; accumulated on the
+    # device in the arena's ``counters`` leaf, read with the harvest's own
+    # reads — zero for a model without an expert layer)
+    moe_assignments: int = 0      # (token, expert) choices, all experts
+    moe_assignments_held: int = 0  # ... of them, to experts held here
+    moe_held_load_max: int = 0    # the busiest held expert's load, summed
+    #                               over expert layers and dispatches
     # per span name count/total_s/max_s and the compile counters, over
     # the spans that closed while THIS stats object lived (utils/
     # profiling.Phases) — a stats reset between timed windows resets the
@@ -297,6 +308,11 @@ class EngineStats:
             "spec_frames": self.spec_frames,
             "kv_dtype": self.kv_dtype,
             "serve_precision": self.serve_precision,
+            "prompt_tokens": self.prompt_tokens,
+            "prompt_tokens_padded": self.prompt_tokens_padded,
+            "moe_assignments": self.moe_assignments,
+            "moe_assignments_held": self.moe_assignments_held,
+            "moe_held_load_max": self.moe_held_load_max,
             "phases": self.phases.summary(),
         }
 
@@ -325,6 +341,15 @@ class _Staged:
                                  # (the bucket's tar under decode_tar_buckets,
                                  # else cfg.tar_len) — sets the paged block
                                  # reservation AND the generation cap
+    row_limits: Optional[np.ndarray] = None  # per-row budgets where the
+                                 # host batch carries ``_limits`` (each
+                                 # request its own max_new_tokens + 1);
+                                 # they take ``limit``'s place row by row
+    fresh: bool = True           # no row of the chunk inserted yet
+
+    def limit_of(self, row: int) -> int:
+        return (self.limit if self.row_limits is None
+                else int(self.row_limits[row]))
 
 
 class SlotEngine:
@@ -341,7 +366,7 @@ class SlotEngine:
     compiles stay one-per-label under the guard.
     """
 
-    def __init__(self, model: FiraModel, params, cfg: FiraConfig, *,
+    def __init__(self, model, params, cfg: FiraConfig, *,
                  slots: Optional[int] = None, guard=None,
                  device=None, tag: Optional[str] = None,
                  pool_blocks: Optional[int] = None, faults=None):
@@ -368,6 +393,11 @@ class SlotEngine:
         self.guard = guard
         self.device = device
         self.tag = tag
+        from fira_tpu.config import arch_errors
+
+        aerrs = arch_errors(cfg)
+        if aerrs:
+            raise ValueError("; ".join(aerrs))
         # low-precision serving tiers (decode/quant.py). The tier tag
         # suffixes EVERY program label of this engine ("" on the f32/f32
         # contract path — the default label set is unchanged), and the
@@ -416,6 +446,14 @@ class SlotEngine:
             self._cache = prefix_cache_lib.PrefixCache(
                 cfg.prefix_cache_entries,
                 max_bytes=cfg.prefix_cache_bytes, faults=faults)
+        # the model behind the seam (decode/slot_model.py): what prefill
+        # leaves, what a step reads and writes, which arena leaves follow
+        # the beams — everything below is the model's, nothing named here
+        self.smodel = slot_model.for_config(
+            model, cfg, self.slots, self._paged, self._block_size,
+            self._pool_blocks)
+        self._leaves: Dict[str, slot_model.Leaf] = {}
+        self._counters_seen = None
         self.stats = EngineStats(slots=self.slots)
         self._state = None
         self._prefill = jax.jit(self._prefill_fn)
@@ -489,8 +527,11 @@ class SlotEngine:
         step/insert/harvest trio."""
         prefills = [self.label(PREFILL_KIND, t) for t in geom_tags] \
             or [self.label(PREFILL_KIND)]
-        return prefills + [self.label(STEP_LABEL), self.label(INSERT_LABEL),
-                           self.label(HARVEST_LABEL)] + self._spec_labels()
+        inserts = ([self.label(INSERT_LABEL, t) for t in geom_tags]
+                   if self.smodel.insert_by_geometry
+                   else [self.label(INSERT_LABEL)])
+        return prefills + [self.label(STEP_LABEL)] + inserts \
+            + [self.label(HARVEST_LABEL)] + self._spec_labels()
 
     def _spec_labels(self) -> List[str]:
         """The (S, k) draft/verify pair when spec is armed (the ``k<k>``
@@ -506,34 +547,11 @@ class SlotEngine:
     # --- jitted programs -------------------------------------------------
 
     def _prefill_fn(self, params, batch):
-        """Per-batch preamble of the batched beam, verbatim: encode once,
-        then (kv mode) per-layer cross K/V + copy-head source projection
-        replicated per beam, or (full-redecode mode) the per-beam encoder
-        states themselves. Identical program prefix => identical values."""
-        cfg, model = self.cfg, self.model
-        K = cfg.beam_size
-        states, mask = model.apply({"params": params}, batch,
-                                   method=FiraModel.encode)
-        out = {"src_mask": mask, "diff": batch["diff"],
-               "sub_token": batch["sub_token"]}
-        if cfg.beam_kv_cache:
-            cross_k, cross_v, src_proj = model.apply(
-                {"params": params}, states, method=FiraModel.decode_init)
-            out["cross_k"] = jnp.repeat(cross_k, K, axis=1)
-            out["cross_v"] = jnp.repeat(cross_v, K, axis=1)
-            out["src_proj"] = jnp.repeat(src_proj, K, axis=0)
-            # dtype marker only: fresh slots seed their self-attention
-            # cache at zeros of the ENCODER STATE dtype, exactly like the
-            # batched beam's cache0 (which may be wider than the compute
-            # dtype under stable_residual) — unless the low-precision KV
-            # tier pins the arena narrower (cfg.kv_dtype="bf16",
-            # decode/quant.py): _ensure_state allocates the pools/stripes
-            # at this dtype and the HBM accounting follows it
-            out["cache_seed"] = jnp.zeros(
-                (), quant.kv_seed_dtype(cfg, states.dtype))
-        else:
-            out["states"] = jnp.repeat(states, K, axis=0)
-        return out
+        """What one packed batch of new requests leaves for its slots: the
+        model's own prefill (slot_model: FIRA's encoder preamble, an LM's
+        prompt latents), traced under this name — the benchmark's readers
+        find the program by it."""
+        return self.smodel.prefill(params, batch)
 
     def _step_fn(self, params, state):
         """Advance every live, not-yet-done slot ``cfg.engine_harvest_every``
@@ -588,10 +606,8 @@ class SlotEngine:
         for idle/done slots — blended state, sentinel-masked paged table —
         with ONE extra care: the unpaged cache permute below must not
         scribble a row that will RESUME (see the gated identity blend)."""
-        cfg, model = self.cfg, self.model
+        cfg = self.cfg
         S, K, T = self.slots, cfg.beam_size, cfg.tar_len
-        L, H = cfg.num_layers, cfg.num_head
-        d_head = cfg.embedding_dim // H
         neg = (jnp.float32(-1.0) if cfg.beam_compat_prob_space
                else jnp.float32(-np.inf))
 
@@ -604,164 +620,61 @@ class SlotEngine:
         # idle/done rows clamp to a legal position; their computation is
         # garbage by construction and blended away below
         pos_c = jnp.minimum(pos, T - 2)
-        flat = tokens.reshape(S * K, T)
-        pos_bk = jnp.repeat(pos_c, K)
-        mask_k = jnp.repeat(state["src_mask"], K, axis=0)
-        slot_src = {"diff": state["diff"], "sub_token": state["sub_token"]}
         all_fin_before = jnp.all(finished, axis=1)   # (S,)
-
-        out_caches = {}
-        if cfg.beam_kv_cache and self._paged:
-            # same per-row validity rule as beam_search_cached, at the
-            # per-slot position vector (beam.step_valid_mask) — this mask
-            # is also what makes unwritten/stale POOL blocks read as an
-            # exact 0.0 contribution, so fresh slots need no zeroed cache
-            valid = step_valid_mask(flat, pos_bk, T)
-            tok_in = jnp.take_along_axis(flat, pos_bk[:, None], axis=1)
-            # idle and done slots must neither write nor permute: their
-            # table rows may still name blocks harvest already returned
-            # to the free list and insert re-granted to ANOTHER slot —
-            # the one aliasing hazard the whole-sequence arena never had.
-            # Masking their rows to the sentinel P turns every such
-            # gather into clamped (blended-away) garbage and every such
-            # scatter into a drop.
-            tab_step = jnp.where(active[:, None], state["block_tab"],
-                                 jnp.int32(self._pool_blocks))
-            if cfg.beam_factored_topk:
-                gen, copy, gate, k_pool, v_pool = model.apply(
-                    {"params": params}, mask_k, tok_in, pos_bk,
-                    state["k_pool"], state["v_pool"], tab_step,
-                    state["cross_k"], state["cross_v"], state["src_proj"],
-                    valid[:, None, None, :],
-                    method=FiraModel.dist_parts_step_paged,
-                )
-                with jax.named_scope("topk"):
-                    new_tokens, new_probs, new_finished, src_beam = \
-                        _select_factored(
-                            gen[:, 0, :].reshape(S, K, -1),
-                            copy[:, 0, :].reshape(S, K, -1),
-                            gate[:, 0, :].reshape(S, K, 2),
-                            tokens, probs, finished, pos_c, slot_src, cfg,
-                            neg)
-            else:
-                fused, k_pool, v_pool = model.apply(
-                    {"params": params}, mask_k, tok_in, pos_bk,
-                    state["k_pool"], state["v_pool"], tab_step,
-                    state["cross_k"], state["cross_v"], state["src_proj"],
-                    valid[:, None, None, :],
-                    method=FiraModel.fused_probs_step_paged,
-                )
-                dist = fused[:, 0, :].reshape(S, K, -1)
-                with jax.named_scope("topk"):
-                    new_tokens, new_probs, new_finished, src_beam = _select(
-                        dist, tokens, probs, finished, pos_c, slot_src, cfg,
-                        neg)
-            # permute cached histories to follow their beams — the paged
-            # twin of the unpaged gather below, moving block CONTENTS
-            # within each active slot's own block set (table entries stay
-            # put: a slot's grant is host-owned from insert to harvest).
-            # Scatter targets are disjoint across slots because grants
-            # never overlap; sentinel rows (idle/done, see tab_step) drop.
-            idx = src_beam[None, :, None, :, None, None, None]
-
-            def permute_pool(pool):
-                blocks = pool[:, tab_step]       # (L, S, W, K, H, BS, dh)
-                blocks = jnp.take_along_axis(blocks, idx, axis=3)
-                return pool.at[:, tab_step].set(blocks, mode="drop")
-
+        # idle and done slots must neither write nor permute the paged
+        # pool: their table rows may still name blocks harvest already
+        # returned to the free list and insert re-granted to ANOTHER slot
+        # — the one aliasing hazard the whole-sequence arena never had.
+        # Masking their rows to the sentinel P turns every such gather
+        # into clamped (blended-away) garbage and every such scatter into
+        # a drop.
+        tab_step = (jnp.where(active[:, None], state["block_tab"],
+                              jnp.int32(self._pool_blocks))
+                    if self._paged else None)
+        view = slot_model.StepView(
+            flat=tokens.reshape(S * K, T), pos_c=pos_c,
+            pos_bk=jnp.repeat(pos_c, K), active=active, tab_step=tab_step)
+        parts, out_caches = self.smodel.step(params, state, view)
+        with jax.named_scope("topk"):
+            new_tokens, new_probs, new_finished, src_beam = \
+                self.smodel.select(parts, tokens, probs, finished, pos_c,
+                                   state, neg)
+        # permute cached histories to follow their beams, leaf by leaf as
+        # the model DECLARED them (slot_model.Leaf.reorder): pool leaves
+        # move block contents inside each active slot's own grant;
+        # whole-sequence stripes take exactly the batched beam's gather.
+        # Inactive stripe rows are NOT blended back: a done/idle slot's
+        # cache is never read again — it is not stepped, and a refill
+        # overwrites its rows wholesale — so letting the step scribble on
+        # it saves two full-cache select passes per micro-step.
+        # tokens/probs/finished/pos DO blend below: they must survive
+        # until harvest.
+        #
+        # GATED mode is the one exception: a verify-frozen row RESUMES —
+        # permuting its stripes by this frame's garbage src_beam would
+        # hand the resumed step a shuffled history. Frozen rows get the
+        # identity permutation instead; the plain trace (gate=None) keeps
+        # the cheaper scribble, byte-for-byte as before.
+        reordered = {n: leaf.reorder for n, leaf in self._leaves.items()
+                     if leaf.reorder and n in out_caches}
+        if reordered:
+            stripe_beam = src_beam
+            if gate is not None and "stripe" in reordered.values():
+                stripe_beam = jnp.where(active[:, None], src_beam,
+                                        jnp.arange(K)[None, :])
+            idx = {}    # one index a (kind, rank), shared by its leaves
             with jax.named_scope("kv_reorder"):
-                out_caches["k_pool"] = permute_pool(k_pool)
-                out_caches["v_pool"] = permute_pool(v_pool)
-        elif cfg.beam_kv_cache:
-            # same per-row validity rule as beam_search_cached, at the
-            # per-slot position vector
-            valid = step_valid_mask(flat, pos_bk, T)
-            tok_in = jnp.take_along_axis(flat, pos_bk[:, None], axis=1)
-            if cfg.beam_factored_topk:
-                gen, copy, gate, k_cache, v_cache = model.apply(
-                    {"params": params}, mask_k, tok_in, pos_bk,
-                    state["k_cache"], state["v_cache"],
-                    state["cross_k"], state["cross_v"], state["src_proj"],
-                    valid[:, None, None, :],
-                    method=FiraModel.dist_parts_step_multi,
-                )
-                with jax.named_scope("topk"):
-                    new_tokens, new_probs, new_finished, src_beam = \
-                        _select_factored(
-                            gen[:, 0, :].reshape(S, K, -1),
-                            copy[:, 0, :].reshape(S, K, -1),
-                            gate[:, 0, :].reshape(S, K, 2),
-                            tokens, probs, finished, pos_c, slot_src, cfg,
-                            neg)
-            else:
-                fused, k_cache, v_cache = model.apply(
-                    {"params": params}, mask_k, tok_in, pos_bk,
-                    state["k_cache"], state["v_cache"],
-                    state["cross_k"], state["cross_v"], state["src_proj"],
-                    valid[:, None, None, :],
-                    method=FiraModel.fused_probs_step_multi,
-                )
-                dist = fused[:, 0, :].reshape(S, K, -1)
-                with jax.named_scope("topk"):
-                    new_tokens, new_probs, new_finished, src_beam = _select(
-                        dist, tokens, probs, finished, pos_c, slot_src, cfg,
-                        neg)
-            # permute cached histories to follow their beams (exactly the
-            # batched beam's gather). Inactive rows are NOT blended back:
-            # a done/idle slot's cache is never read again — it is not
-            # stepped, and a refill overwrites its cache rows wholesale
-            # (insert zeroes k/v, rewrites cross/src) — so letting the
-            # step scribble on it saves two full-cache select passes per
-            # micro-step. tokens/probs/finished/pos DO blend below: they
-            # must survive until harvest.
-            #
-            # GATED mode is the one exception: a verify-frozen row RESUMES
-            # — permuting its cache by this frame's garbage src_beam would
-            # hand the resumed step a shuffled history. Frozen rows get
-            # the identity permutation instead (their cache bytes pass
-            # through the gather unchanged); the plain trace (gate=None)
-            # keeps the cheaper scribble, byte-for-byte as before.
-            if gate is not None:
-                src_beam = jnp.where(active[:, None], src_beam,
-                                     jnp.arange(K)[None, :])
-            idx = src_beam[None, :, :, None, None, None]
-
-            def gather_cache(c):
-                c = c.reshape(L, S, K, H, T, d_head)
-                c = jnp.take_along_axis(c, idx, axis=2)
-                return c.reshape(L, S * K, H, T, d_head)
-
-            with jax.named_scope("kv_reorder"):
-                out_caches["k_cache"] = gather_cache(k_cache)
-                out_caches["v_cache"] = gather_cache(v_cache)
-        else:
-            tar_mask = (flat != 0).at[:, 0].set(True)
-
-            def at_pos(a):  # row b's own position out of the full-prefix decode
-                return jnp.take_along_axis(
-                    a, pos_bk[:, None, None], axis=1)[:, 0, :]
-
-            if cfg.beam_factored_topk:
-                gen, copy, gate = model.apply(
-                    {"params": params}, state["states"], mask_k, flat,
-                    tar_mask, method=FiraModel.dist_parts)
-                with jax.named_scope("topk"):
-                    new_tokens, new_probs, new_finished, _ = \
-                        _select_factored(
-                            at_pos(gen).reshape(S, K, -1),
-                            at_pos(copy).reshape(S, K, -1),
-                            at_pos(gate).reshape(S, K, 2),
-                            tokens, probs, finished, pos_c, slot_src, cfg,
-                            neg)
-            else:
-                fused = model.apply(
-                    {"params": params}, state["states"], mask_k, flat,
-                    tar_mask, method=FiraModel.fused_probs)
-                dist = at_pos(fused).reshape(S, K, -1)
-                with jax.named_scope("topk"):
-                    new_tokens, new_probs, new_finished, _ = _select(
-                        dist, tokens, probs, finished, pos_c, slot_src, cfg,
-                        neg)
+                for name, how in reordered.items():
+                    c = out_caches[name]
+                    key = (how, c.ndim)
+                    if key not in idx:
+                        idx[key] = slot_model.beam_index(
+                            src_beam if how == "pool" else stripe_beam,
+                            how, c.ndim)
+                    out_caches[name] = (
+                        slot_model.permute_pool(c, tab_step, idx[key])
+                        if how == "pool" else
+                        slot_model.permute_stripes(c, idx[key]))
 
         tokens = jnp.where(active[:, None, None], new_tokens, tokens)
         probs = jnp.where(active[:, None], new_probs, probs)
@@ -780,13 +693,15 @@ class SlotEngine:
                      pos=new_pos, done=done, **out_caches),
                 jnp.sum(active.astype(jnp.int32)))
 
-    def _insert_fn(self, state, chunk, slot_ids, limits, block_rows):
+    def _insert_fn(self, state, chunk, slot_ids, limits, block_rows,
+                   fresh=None):
         """Scatter chunk rows into slots. ``slot_ids``: (C,) int32, row j
         goes to slot ``slot_ids[j]``; the out-of-range sentinel S marks
         rows NOT consumed by this call (their scatter drops). ``limits``:
         (C,) int32 per-row tar budget. ``block_rows`` (paged arena only,
         else None): (C, W) int32 block grants, sentinel-P-padded past the
-        row's reservation.
+        row's reservation. ``fresh`` (None, or an int32 0/1 for a model
+        that counts on the device): 1 on a chunk's first insert.
 
         INVARIANT — no cache zeroing, in EITHER arena. A fresh slot's
         unwritten cache positions are exactly -1e9-masked by the step's
@@ -813,27 +728,18 @@ class SlotEngine:
         put("tokens", tokens0)
         put("probs", probs0)
         put("finished", finished0)
-        put("diff", chunk["diff"])
-        put("sub_token", chunk["sub_token"])
-        put("src_mask", chunk["src_mask"])
         new["pos"] = state["pos"].at[sid].set(0, mode="drop")
         new["live"] = state["live"].at[sid].set(True, mode="drop")
         new["done"] = state["done"].at[sid].set(False, mode="drop")
         new["limit"] = state["limit"].at[sid].set(
             limits.astype(jnp.int32), mode="drop")
-        if cfg.beam_kv_cache:
-            for f in ("cross_k", "cross_v"):
-                new[f] = state[f].at[:, sid_bk].set(chunk[f], mode="drop")
-            new["src_proj"] = state["src_proj"].at[sid_bk].set(
-                chunk["src_proj"], mode="drop")
-            if self._paged:
-                # hand the seated rows their block grants; k_pool/v_pool
-                # are untouched (see INVARIANT above)
-                new["block_tab"] = state["block_tab"].at[sid].set(
-                    block_rows.astype(jnp.int32), mode="drop")
-        else:
-            new["states"] = state["states"].at[sid_bk].set(
-                chunk["states"], mode="drop")
+        # the model's own leaves (slot_model): what prefill left for each
+        # seated row; pools and stripes are untouched (INVARIANT above)
+        new.update(self.smodel.insert(state, chunk, sid, sid_bk, fresh))
+        if self._paged:
+            # hand the seated rows their block grants
+            new["block_tab"] = state["block_tab"].at[sid].set(
+                block_rows.astype(jnp.int32), mode="drop")
         return new
 
     # --- state ----------------------------------------------------------
@@ -846,8 +752,6 @@ class SlotEngine:
             return
         cfg = self.cfg
         S, K, T = self.slots, cfg.beam_size, cfg.tar_len
-        L, H = cfg.num_layers, cfg.num_head
-        d_head = cfg.embedding_dim // H
         z = {
             "tokens": np.zeros((S, K, T), np.int32),
             "probs": np.zeros((S, K), np.float32),
@@ -855,38 +759,20 @@ class SlotEngine:
             "pos": np.zeros((S,), np.int32),
             "live": np.zeros((S,), bool),
             "done": np.zeros((S,), bool),
-            "diff": np.zeros((S,) + chunk["diff"].shape[1:],
-                             chunk["diff"].dtype),
-            "sub_token": np.zeros((S,) + chunk["sub_token"].shape[1:],
-                                  chunk["sub_token"].dtype),
-            "src_mask": np.zeros((S,) + chunk["src_mask"].shape[1:], bool),
             # per-slot tar budget: full until an insert seats a
-            # shorter-bucket sample (cfg.decode_tar_buckets)
+            # shorter-budget sample (cfg.decode_tar_buckets, or a request
+            # that carries its own limit)
             "limit": np.full((S,), T, np.int32),
         }
-        if cfg.beam_kv_cache:
-            ck = chunk["cross_k"]
-            z["cross_k"] = np.zeros((L, S * K) + ck.shape[2:], ck.dtype)
-            z["cross_v"] = np.zeros((L, S * K) + ck.shape[2:], ck.dtype)
-            sp = chunk["src_proj"]
-            z["src_proj"] = np.zeros((S * K,) + sp.shape[1:], sp.dtype)
-            cd = chunk["cache_seed"].dtype
-            if self._paged:
-                P, BS, W = (self._pool_blocks, self._block_size,
-                            self._table_width)
-                z["k_pool"] = np.zeros((L, P, K, H, BS, d_head), cd)
-                z["v_pool"] = np.zeros((L, P, K, H, BS, d_head), cd)
-                z["block_tab"] = np.full((S, W), P, np.int32)  # all unmapped
-            else:
-                z["k_cache"] = np.zeros((L, S * K, H, T, d_head), cd)
-                z["v_cache"] = np.zeros((L, S * K, H, T, d_head), cd)
-            self._kv_bytes_per_slot = paging.kv_bytes_per_slot(
-                cfg, paged=self._paged, block_size=self._block_size,
-                pool_blocks=self._pool_blocks, slots=S,
-                itemsize=np.dtype(cd).itemsize)
-        else:
-            st = chunk["states"]
-            z["states"] = np.zeros((S * K,) + st.shape[1:], st.dtype)
+        # the model's leaves, as it declares them (slot_model.Leaf)
+        self._leaves = self.smodel.leaves(chunk)
+        for name, leaf in self._leaves.items():
+            z[name] = np.zeros(leaf.shape, leaf.dtype)
+        if self._paged:
+            z["block_tab"] = np.full((S, self._table_width),
+                                     self._pool_blocks, np.int32)  # unmapped
+        self._kv_bytes_per_slot = paging.leaves_kv_bytes_per_slot(
+            self._leaves, S)
         # firacheck: allow[RETIRED-RECHECK] arena-state write: retire() deliberately leaves the arena in place ("the arena and stats stay") and a dead engine's _state is never read again — only scheduling/guard state needs the post-dispatch re-check
         self._state = jax.device_put(z, self.device)
 
@@ -925,17 +811,14 @@ class SlotEngine:
                                       jax.device_put(wire, self.device))
             self._guard_step(self.label(PREFILL_KIND, tag))
             self._ensure_state(chunk)
+            if self.smodel.insert_by_geometry:
+                # a chunk is as long as its bucket: one insert program a
+                # geometry, compiled here beside its prefill
+                self._prewarm_insert(chunk, tag)
         if chunk is None:
             return
-        C = int(chunk["diff"].shape[0])
-        sentinel_ids = np.full((C,), self.slots, dtype=np.int32)  # all drop
-        limits = np.full((C,), self.cfg.tar_len, dtype=np.int32)
-        block_rows = (np.full((C, self._table_width), self._pool_blocks,
-                              dtype=np.int32) if self._paged else None)
-        with profiling.span("engine.prewarm.insert"):
-            self._state = self._insert(self._state, chunk, sentinel_ids,
-                                       limits, block_rows)
-        self._guard_step(self.label(INSERT_LABEL))
+        if not self.smodel.insert_by_geometry:
+            self._prewarm_insert(chunk, None)
         with profiling.span("engine.prewarm.step"):
             self._state, occ = self._step(self._decode_params, self._state)
         self._guard_step(self.label(STEP_LABEL))
@@ -959,6 +842,28 @@ class SlotEngine:
                 self._guard_step(self.label(spec_lib.VERIFY_LABEL, km))
             self._pending_occ = occ      # zeros: no slot was active
             self._pending_spec = pend
+
+    def _prewarm_insert(self, chunk, tag: Optional[str]) -> None:
+        """One no-op insert of ``chunk``'s geometry: every slot id the
+        drop sentinel, so the arena passes through unchanged."""
+        C = self.smodel.chunk_rows(chunk)
+        sentinel_ids = np.full((C,), self.slots, dtype=np.int32)  # all drop
+        limits = np.full((C,), self.cfg.tar_len, dtype=np.int32)
+        block_rows = (np.full((C, self._table_width), self._pool_blocks,
+                              dtype=np.int32) if self._paged else None)
+        with profiling.span("engine.prewarm.insert"):
+            new_state = self._insert(self._state, chunk, sentinel_ids,
+                                     limits, block_rows,
+                                     self._fresh_arg(False))
+        if self.retired:
+            return
+        self._state = new_state
+        self._guard_step(self.label(INSERT_LABEL, tag))
+
+    def _fresh_arg(self, fresh: bool):
+        """The insert program's ``fresh`` argument: None for a model that
+        counts nothing on the device (its program takes no such input)."""
+        return np.int32(fresh) if self.smodel.arena_counters else None
 
     # --- steppable scheduler pieces (the fleet round-robins these) -------
 
@@ -1301,7 +1206,19 @@ class SlotEngine:
                 wire = {k: v for k, v in host.items()
                         if not k.startswith("_")}
                 device_batch = jax.device_put(wire, self.device)
-            chunk = self._prefill(self.params, device_batch)
+            lengths = host.get("_prompt_len")   # an LM's batches only
+            if lengths is None:
+                chunk = self._prefill(self.params, device_batch)
+            else:
+                real = int(np.sum(lengths[valid]))
+                padded = int(np.prod(host["tokens"].shape))
+                with profiling.span("engine.prefill",
+                                    bucket=host.get("_tag"),
+                                    requests=len(seat_rows), tokens=real,
+                                    padded_tokens=padded):
+                    chunk = self._prefill(self.params, device_batch)
+                st.prompt_tokens += real
+                st.prompt_tokens_padded += padded
             if self.retired:
                 # the watchdog expired while the prefill ran and the
                 # replica was retired: its requests were requeued
@@ -1365,7 +1282,8 @@ class SlotEngine:
                  else self.cfg.tar_len)
         self._staged.append(_Staged(
             chunk=chunk, host=host,
-            rows=collections.deque(seat_rows), limit=limit))
+            rows=collections.deque(seat_rows), limit=limit,
+            row_limits=host.get("_limits")))
         self._staged_rows += len(seat_rows)
 
     @profiling.span("engine.refill")
@@ -1383,9 +1301,13 @@ class SlotEngine:
         # retire() is handing to the survivors
         while not self.retired and self._free and self._staged:
             entry = self._staged[0]
-            need = (paging.blocks_per_seq(entry.limit, self._block_size)
-                    if self._paged else 0)
-            if self._paged and len(self._free_blocks) < need:
+
+            def need_of(row: int) -> int:
+                return (paging.blocks_per_seq(entry.limit_of(row),
+                                              self._block_size)
+                        if self._paged else 0)
+            if self._paged and len(self._free_blocks) < need_of(
+                    entry.rows[0][0]):
                 break  # head-of-line: blocks return at the next harvest
             C = entry.host["valid"].shape[0]
             slot_ids = np.full((C,), self.slots, dtype=np.int32)  # S = drop
@@ -1395,19 +1317,24 @@ class SlotEngine:
                           if self._paged else None)
             n_ins = 0
             while not self.retired and self._free and entry.rows and (
-                    not self._paged or len(self._free_blocks) >= need):
+                    not self._paged
+                    or len(self._free_blocks) >= need_of(entry.rows[0][0])):
                 r, pos_id = entry.rows.popleft()
                 slot = (self._free.popleft() if refill_order == "fifo"
                         else self._free.pop())
                 slot_ids[r] = slot
+                limits[r] = entry.limit_of(r)
                 if self._paged:
+                    need = need_of(r)
                     grant = self._acquire_blocks(need)
                     block_rows[r, :need] = grant
                     self._slot_blocks[slot] = grant
                 self._busy[slot] = (pos_id, entry.host, r)
                 n_ins += 1
             new_state = self._insert(self._state, entry.chunk, slot_ids,
-                                     limits, block_rows)
+                                     limits, block_rows,
+                                     self._fresh_arg(entry.fresh))
+            entry.fresh = False
             if self.retired:
                 # the watchdog expired while the insert dispatch ran and
                 # the replica was retired: retire() already requeued
@@ -1416,7 +1343,9 @@ class SlotEngine:
                 # touch them (RETIRED-RECHECK discipline)
                 return
             self._state = new_state
-            self._guard_step(self.label(INSERT_LABEL))
+            self._guard_step(self.label(
+                INSERT_LABEL, entry.host.get("_tag")
+                if self.smodel.insert_by_geometry else None))
             self.stats.refills += 1
             self.stats.slots_refilled += n_ins
             self._staged_rows -= n_ins
@@ -1546,6 +1475,20 @@ class SlotEngine:
                     # cold stretch does not pay draft+verify per emitted token
                     self._spec_cd = spec_lib.STALL_COOLDOWN
             done = np.array(jax.device_get(self._state["done"]))
+            if self.smodel.arena_counters:
+                # the model's device-side counts, at the SAME sync boundary
+                # (slot_model: model/axk1.COUNTERS); int32 on the device,
+                # so the window's share is the wrapped difference
+                now = np.array(jax.device_get(
+                    self._state["counters"])).astype(np.uint32)
+                if self.retired:
+                    return []  # abandoned by a watchdog mid-readback
+                grown = now - (self._counters_seen
+                               if self._counters_seen is not None else 0)
+                self._counters_seen = now
+                for name, n in zip(self.smodel.arena_counters,
+                                   grown.tolist()):
+                    setattr(stats, name, getattr(stats, name) + n)
         newly = [s for s in self._busy if done[s]]
         items: List[EngineItem] = []
         if newly:   # a harvest that settles nothing records no read
@@ -1629,6 +1572,7 @@ class SlotEngine:
         self.begin_stream()
         feed_iter = iter(feed)
         exhausted = False
+        budget = self.smodel.prefill_budget     # dispatches a pass; 0: all
         # engine.run is a generator: its root span is opened and closed
         # explicitly and is the thread's parent only inside the `with
         # root` stretches — never across a yield, where the consumer's
@@ -1639,7 +1583,12 @@ class SlotEngine:
                 with root:
                     # prefill ahead: keep `depth` chunks staged, and at
                     # least enough rows to refill every currently free slot
-                    while not exhausted and self.wants_input():
+                    # — at most `budget` dispatches a pass where the model
+                    # declares one (slot_model: prefill_budget)
+                    admitted = 0
+                    while not exhausted and self.wants_input() and not (
+                            budget and admitted >= budget):
+                        admitted += 1
                         try:
                             item = next(feed_iter)
                         except StopIteration:

@@ -205,3 +205,16 @@ def kv_bytes_per_slot(cfg: FiraConfig, *, paged: bool, block_size: int,
         return block_bytes(cfg, block_size, itemsize) * int(pool_blocks) \
             // max(1, int(slots))
     return block_bytes(cfg, 1, itemsize) * int(cfg.tar_len)
+
+
+def leaves_kv_bytes_per_slot(leaves, slots: int) -> int:
+    """:func:`kv_bytes_per_slot` from the arena's DECLARED leaves
+    (decode/slot_model.Leaf): the bytes of every leaf a model marks ``kv``
+    — pools, stripes, a latent cache of whatever width — amortized over
+    the slots they serve. For FIRA's K/V pools and stripes this is the
+    number the per-head formula above gives."""
+    import numpy as np
+
+    total = sum(int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+                for leaf in leaves.values() if leaf.kv)
+    return total // max(1, int(slots))

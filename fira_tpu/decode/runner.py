@@ -267,3 +267,52 @@ def run_test(model: FiraModel, params, dataset: FiraDataset,
     if engine_stats is not None:
         out["engine"] = engine_stats  # type: ignore[assignment]
     return out  # type: ignore[return-value]
+
+
+def run_lm_test(cfg: FiraConfig, *, out_dir: str = "OUTPUT",
+                n_requests: int = 64, guard=None, params=None,
+                requests=None) -> Dict:
+    """``cli test --engine`` for a decoder-only architecture
+    (``cfg.arch == "axk1"``): the SAME slot engine, over token-id prompts
+    from data/synthetic.py (no tokenizer ships, so the output file holds
+    ids: one line a request, its most probable beam after <start>) and
+    weights drawn from ``cfg.seed`` unless ``params`` are given.
+    ``requests``: (prompts, max_new) to use instead of the synthetic draw."""
+    import jax.numpy as jnp
+
+    from fira_tpu.data.synthetic import make_prompt_requests
+    from fira_tpu.model import axk1
+
+    lm = cfg.lm
+    if params is None:
+        params = axk1.init_params(lm, cfg.seed,
+                                  jnp.dtype(cfg.compute_dtype))
+    if requests is None:
+        T = cfg.tar_len
+        requests = make_prompt_requests(
+            n_requests, vocab_size=lm.vocab_size, seed=cfg.seed,
+            min_len=max(1, lm.prompt_buckets[0] // 2),
+            max_len=lm.prompt_len_max,
+            limits=tuple(max(1, (T - 1) * q // 4) for q in (1, 2, 3, 4)))
+    prompts, max_new = requests[0], [int(m) for m in requests[1]]
+    eng = engine_lib.SlotEngine(None, params, cfg, guard=guard)
+    warm = buckets_lib.prompt_warm_batches(lm)
+    if guard is not None:
+        guard.declare(eng.labels_for_tags([t for _b, t in warm]))
+    eng.prewarm(warm)
+    print(f"prompt buckets: {len(warm)} engine prefill programs pre-warmed "
+          f"({', '.join(t for _b, t in warm)})", flush=True)
+    os.makedirs(out_dir, exist_ok=True)
+    out_path = os.path.join(out_dir, "output_" + cfg.arch)
+    tasks = buckets_lib.prompt_tasks(
+        lm, ((i, p, m) for i, (p, m) in enumerate(zip(prompts, max_new))))
+    with OrderedStreamWriter(out_path, expected=len(prompts)) as writer, \
+            Feeder(tasks, num_workers=cfg.feeder_workers,
+                   depth=cfg.feeder_depth) as feed:
+        for item in eng.run(feed):
+            best = item.tokens[int(np.argmax(item.probs))]
+            n = max_new[item.position]
+            writer.add(item.position,
+                       " ".join(map(str, best[1:n + 1].tolist())) + "\n")
+    return {"n": float(len(prompts)), "output_path": out_path,
+            "engine": eng.stats.summary()}

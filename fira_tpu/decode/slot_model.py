@@ -1,0 +1,353 @@
+"""The seam between the slot engine and a model (ROADMAP D3, the part a
+second architecture needs).
+
+decode/engine.py schedules slots, beams, the paged pool and the harvest;
+what it asks of a model is this protocol, and nothing model-specific is
+bound by name in the engine any more:
+
+- ``prefill(params, batch) -> chunk``: what one packed batch of new
+  requests leaves behind for its slots (a dict of device arrays, rows along
+  each leaf's request axis). Traced inside the engine's ``_prefill_fn``.
+- ``leaves(chunk) -> {name: Leaf}``: the arena's model-owned leaves, each
+  with shape, dtype and whether it is PER BEAM (reordered with
+  ``src_beam`` after every selection: ``"pool"`` for a paged block pool,
+  ``"stripe"`` for whole-sequence rows) or SHARED by a slot's beams
+  (``reorder=None``). ``kv`` marks what ``kv_bytes_per_slot`` counts
+  (decode/paging.py follows these declarations).
+- ``insert(state, chunk, sid, sid_bk, fresh) -> {name: leaf}``:
+  scatter chunk rows into slots ``sid`` (sentinel = dropped).
+- ``step(params, state, view) -> (parts, writes)``: one position for every
+  beam of every slot, at each slot's own depth; ``view`` carries the
+  engine's per-dispatch cache view (tokens, positions, the active mask,
+  the sentinel-masked block table). ``parts`` go to ``select``; ``writes``
+  are the updated arena leaves, not yet reordered.
+- ``prefill_budget``: the prefill dispatches ``SlotEngine.run`` admits
+  between two step dispatches; 0 = as many as the free slots ask for.
+- ``select(parts, tokens, probs, finished, pos, state, neg)``: the beam
+  selection for this model's distribution -> (tokens, probs, finished,
+  src_beam). FIRA's copy head selects from the factors
+  (``beam._select_factored``); a model with one log-softmax goes through
+  ``beam._select``.
+
+:class:`FiraSlotModel` is the first implementation and wraps the calls the
+engine made before the seam AS THEY WERE (the three ``*_step{,_multi,
+_paged}`` variants stay; collapsing them is D3's own PR): the lowered
+programs of the FIRA configurations are unchanged. :class:`LMSlotModel`
+(``arch="axk1"``, model/axk1.py) is the second.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Dict, NamedTuple, Optional, Tuple
+
+import jax.numpy as jnp
+import numpy as np
+
+from fira_tpu.config import FiraConfig
+from fira_tpu.decode import quant
+from fira_tpu.decode.beam import _select, _select_factored, step_valid_mask
+
+
+@dataclasses.dataclass(frozen=True)
+class Leaf:
+    """One model-owned arena leaf, as the engine allocates it."""
+
+    shape: Tuple[int, ...]
+    dtype: np.dtype
+    reorder: Optional[str] = None   # "pool" | "stripe": per beam, follows
+    #                                 src_beam; None: shared by the beams
+    kv: bool = False                # counted by kv_bytes_per_slot
+
+
+class StepView(NamedTuple):
+    """What the engine hands a step: the slots' beams and where they are."""
+
+    flat: jnp.ndarray       # (S*K, T) tokens, beams folded into rows
+    pos_c: jnp.ndarray      # (S,) position of each slot, clamped legal
+    pos_bk: jnp.ndarray     # (S*K,) the same, a row
+    active: jnp.ndarray     # (S,) live and not done (and not gated off)
+    tab_step: Optional[jnp.ndarray]  # (S, W) block table, the sentinel in
+    #                                  rows that must not read or write
+
+
+def beam_index(src_beam, reorder: str, ndim: int):
+    """``src_beam`` (S, K) shaped to gather the beam axis of a leaf of
+    rank ``ndim``: (1, S, 1, K, 1...) over a pool's gathered blocks
+    (L, S, W, K, ...), (1, S, K, 1...) over stripes seen as (L, S, K, ...)."""
+    lead = ((None, slice(None), None, slice(None)) if reorder == "pool"
+            else (None, slice(None), slice(None)))
+    return src_beam[lead + (None,) * (ndim + 1 - len(lead))]
+
+
+def permute_pool(pool, tab_step, idx):
+    """Move block CONTENTS within each active slot's own block set so that
+    cached histories follow their beams (table entries stay put: a slot's
+    grant is host-owned from insert to harvest). pool: (L, P, K, ...);
+    ``idx``: :func:`beam_index`. Scatter targets are disjoint across slots
+    because grants never overlap; sentinel rows (idle/done) drop."""
+    blocks = pool[:, tab_step]           # (L, S, W, K, ...)
+    blocks = jnp.take_along_axis(blocks, idx, axis=3)
+    return pool.at[:, tab_step].set(blocks, mode="drop")
+
+
+def permute_stripes(cache, idx):
+    """The unpaged twin: (L, S*K, ...) whole-sequence rows gathered by
+    ``src_beam`` (exactly the batched beam's gather)."""
+    S, K = idx.shape[1:3]
+    L, rest = cache.shape[0], cache.shape[2:]
+    c = cache.reshape((L, S, K) + rest)
+    c = jnp.take_along_axis(c, idx, axis=2)
+    return c.reshape((L, S * K) + rest)
+
+
+class FiraSlotModel:
+    """FIRA behind the seam: encoder prefill, per-beam cross K/V and copy
+    projection, the decoder step in its three arena forms."""
+
+    insert_by_geometry = False
+    arena_counters: Tuple[str, ...] = ()
+    prefill_budget = 0      # a prefill is cheap beside a step: unpaced
+
+    def __init__(self, model, cfg: FiraConfig, slots: int, paged: bool,
+                 block_size: int, pool_blocks: int):
+        self.model, self.cfg, self.slots = model, cfg, slots
+        self.paged = paged
+        self.block_size, self.pool_blocks = block_size, pool_blocks
+
+    def chunk_rows(self, chunk) -> int:
+        return int(chunk["diff"].shape[0])
+
+    def prefill(self, params, batch):
+        """Per-batch preamble of the batched beam, verbatim: encode once,
+        then (kv mode) per-layer cross K/V + copy-head source projection
+        replicated per beam, or (full-redecode mode) the per-beam encoder
+        states themselves. Identical program prefix => identical values."""
+        from fira_tpu.model.model import FiraModel
+
+        cfg, model = self.cfg, self.model
+        K = cfg.beam_size
+        states, mask = model.apply({"params": params}, batch,
+                                   method=FiraModel.encode)
+        out = {"src_mask": mask, "diff": batch["diff"],
+               "sub_token": batch["sub_token"]}
+        if cfg.beam_kv_cache:
+            cross_k, cross_v, src_proj = model.apply(
+                {"params": params}, states, method=FiraModel.decode_init)
+            out["cross_k"] = jnp.repeat(cross_k, K, axis=1)
+            out["cross_v"] = jnp.repeat(cross_v, K, axis=1)
+            out["src_proj"] = jnp.repeat(src_proj, K, axis=0)
+            # dtype marker only: fresh slots seed their self-attention
+            # cache at zeros of the ENCODER STATE dtype, exactly like the
+            # batched beam's cache0 (which may be wider than the compute
+            # dtype under stable_residual) — unless the low-precision KV
+            # tier pins the arena narrower (cfg.kv_dtype="bf16",
+            # decode/quant.py): the arena allocates the pools/stripes
+            # at this dtype and the HBM accounting follows it
+            out["cache_seed"] = jnp.zeros(
+                (), quant.kv_seed_dtype(cfg, states.dtype))
+        else:
+            out["states"] = jnp.repeat(states, K, axis=0)
+        return out
+
+    def leaves(self, chunk) -> Dict[str, Leaf]:
+        cfg = self.cfg
+        S, K, T = self.slots, cfg.beam_size, cfg.tar_len
+        L, H = cfg.num_layers, cfg.num_head
+        d_head = cfg.embedding_dim // H
+        out = {
+            "diff": Leaf((S,) + chunk["diff"].shape[1:],
+                         chunk["diff"].dtype),
+            "sub_token": Leaf((S,) + chunk["sub_token"].shape[1:],
+                              chunk["sub_token"].dtype),
+            "src_mask": Leaf((S,) + chunk["src_mask"].shape[1:],
+                             np.dtype(bool)),
+        }
+        if cfg.beam_kv_cache:
+            ck = chunk["cross_k"]
+            out["cross_k"] = Leaf((L, S * K) + ck.shape[2:], ck.dtype)
+            out["cross_v"] = Leaf((L, S * K) + ck.shape[2:], ck.dtype)
+            sp = chunk["src_proj"]
+            out["src_proj"] = Leaf((S * K,) + sp.shape[1:], sp.dtype)
+            cd = chunk["cache_seed"].dtype
+            if self.paged:
+                P, BS = self.pool_blocks, self.block_size
+                out["k_pool"] = Leaf((L, P, K, H, BS, d_head), cd,
+                                     reorder="pool", kv=True)
+                out["v_pool"] = Leaf((L, P, K, H, BS, d_head), cd,
+                                     reorder="pool", kv=True)
+            else:
+                out["k_cache"] = Leaf((L, S * K, H, T, d_head), cd,
+                                      reorder="stripe", kv=True)
+                out["v_cache"] = Leaf((L, S * K, H, T, d_head), cd,
+                                      reorder="stripe", kv=True)
+        else:
+            st = chunk["states"]
+            out["states"] = Leaf((S * K,) + st.shape[1:], st.dtype)
+        return out
+
+    def insert(self, state, chunk, sid, sid_bk, fresh) -> Dict:
+        """No cache zeroing, in either arena (the engine's INVARIANT):
+        k/v pools and stripes are untouched here."""
+        new = {}
+        for f in ("diff", "sub_token", "src_mask"):
+            new[f] = state[f].at[sid].set(chunk[f], mode="drop")
+        if self.cfg.beam_kv_cache:
+            for f in ("cross_k", "cross_v"):
+                new[f] = state[f].at[:, sid_bk].set(chunk[f], mode="drop")
+            new["src_proj"] = state["src_proj"].at[sid_bk].set(
+                chunk["src_proj"], mode="drop")
+        else:
+            new["states"] = state["states"].at[sid_bk].set(
+                chunk["states"], mode="drop")
+        return new
+
+    def step(self, params, state, view: StepView):
+        from fira_tpu.model.model import FiraModel
+
+        cfg, model = self.cfg, self.model
+        T = cfg.tar_len
+        flat, pos_bk = view.flat, view.pos_bk
+        mask_k = jnp.repeat(state["src_mask"], cfg.beam_size, axis=0)
+        if not cfg.beam_kv_cache:
+            tar_mask = (flat != 0).at[:, 0].set(True)
+
+            def at_pos(a):  # row b's own position out of the full-prefix decode
+                return jnp.take_along_axis(
+                    a, pos_bk[:, None, None], axis=1)[:, 0, :]
+
+            if cfg.beam_factored_topk:
+                gen, copy, gate = model.apply(
+                    {"params": params}, state["states"], mask_k, flat,
+                    tar_mask, method=FiraModel.dist_parts)
+                return (at_pos(gen), at_pos(copy), at_pos(gate)), {}
+            fused = model.apply(
+                {"params": params}, state["states"], mask_k, flat,
+                tar_mask, method=FiraModel.fused_probs)
+            return (at_pos(fused),), {}
+        # same per-row validity rule as beam_search_cached, at the
+        # per-slot position vector (beam.step_valid_mask) — this mask
+        # is also what makes unwritten/stale POOL blocks read as an
+        # exact 0.0 contribution, so fresh slots need no zeroed cache
+        valid = step_valid_mask(flat, pos_bk, T)
+        tok_in = jnp.take_along_axis(flat, pos_bk[:, None], axis=1)
+        if self.paged:
+            caches = ("k_pool", "v_pool")
+            args = (state["k_pool"], state["v_pool"], view.tab_step)
+            methods = (FiraModel.dist_parts_step_paged,
+                       FiraModel.fused_probs_step_paged)
+        else:
+            caches = ("k_cache", "v_cache")
+            args = (state["k_cache"], state["v_cache"])
+            methods = (FiraModel.dist_parts_step_multi,
+                       FiraModel.fused_probs_step_multi)
+        if cfg.beam_factored_topk:
+            gen, copy, gate, k_new, v_new = model.apply(
+                {"params": params}, mask_k, tok_in, pos_bk, *args,
+                state["cross_k"], state["cross_v"], state["src_proj"],
+                valid[:, None, None, :], method=methods[0])
+            parts = (gen[:, 0, :], copy[:, 0, :], gate[:, 0, :])
+        else:
+            fused, k_new, v_new = model.apply(
+                {"params": params}, mask_k, tok_in, pos_bk, *args,
+                state["cross_k"], state["cross_v"], state["src_proj"],
+                valid[:, None, None, :], method=methods[1])
+            parts = (fused[:, 0, :],)
+        return parts, {caches[0]: k_new, caches[1]: v_new}
+
+    def select(self, parts, tokens, probs, finished, pos_c, state, neg):
+        S, K = probs.shape
+        slot_src = {"diff": state["diff"], "sub_token": state["sub_token"]}
+        if self.cfg.beam_factored_topk:
+            gen, copy, gate = parts
+            return _select_factored(
+                gen.reshape(S, K, -1), copy.reshape(S, K, -1),
+                gate.reshape(S, K, 2), tokens, probs, finished, pos_c,
+                slot_src, self.cfg, neg)
+        return _select(parts[0].reshape(S, K, -1), tokens, probs, finished,
+                       pos_c, slot_src, self.cfg, neg)
+
+
+class LMSlotModel:
+    """A.X-K1 behind the seam (model/axk1.py). Per slot: the prompt's
+    latents ``[L, P_max, 576]``, SHARED by the slot's beams and written by
+    prefill at insert; per beam: the generated positions' latents in the
+    engine's paged pool, block layout ``(L, blocks, K, block, 576)``,
+    reordered like every pool. ``counters`` accumulates model/axk1.COUNTERS
+    on the device; the harvest reads it with its own reads."""
+
+    insert_by_geometry = True      # a chunk is as long as its bucket
+    # one prefill dispatch of prompts (8,192 padded tokens at the published
+    # widths) outweighs a step dispatch three times: refilling every free
+    # slot first would stall the seated slots for seconds and seat whole
+    # waves in lockstep, so the engine alternates one prefill with one step
+    prefill_budget = 1
+
+    def __init__(self, cfg: FiraConfig, slots: int, block_size: int,
+                 pool_blocks: int):
+        from fira_tpu.model import axk1
+
+        self.cfg, self.lm, self.slots = cfg, cfg.lm, slots
+        self.block_size, self.pool_blocks = block_size, pool_blocks
+        self.dtype = jnp.dtype(cfg.compute_dtype)
+        self.arena_counters = axk1.COUNTERS
+
+    def chunk_rows(self, chunk) -> int:
+        return int(chunk["lengths"].shape[0])
+
+    def prefill(self, params, batch):
+        from fira_tpu.model import axk1
+
+        lat, counters = axk1.prefill(params, self.lm, batch["tokens"],
+                                     batch["lengths"], self.dtype)
+        return {"lat": lat, "lengths": batch["lengths"],
+                "counters": counters}
+
+    def leaves(self, chunk) -> Dict[str, Leaf]:
+        lm, S, K = self.lm, self.slots, self.cfg.beam_size
+        L, dt = lm.num_hidden_layers, chunk["lat"].dtype
+        return {
+            "prompt_lat": Leaf((L, S, lm.prompt_len_max, lm.latent_dim), dt,
+                               kv=True),
+            "prompt_len": Leaf((S,), np.dtype(np.int32)),
+            "lat_pool": Leaf((L, self.pool_blocks, K, self.block_size,
+                              lm.latent_dim), dt, reorder="pool", kv=True),
+            "counters": Leaf((len(self.arena_counters),),
+                             np.dtype(np.int32)),
+        }
+
+    def insert(self, state, chunk, sid, sid_bk, fresh) -> Dict:
+        P = chunk["lat"].shape[2]
+        return {
+            "prompt_lat": state["prompt_lat"].at[:, sid, :P].set(
+                chunk["lat"], mode="drop"),
+            "prompt_len": state["prompt_len"].at[sid].set(
+                chunk["lengths"].astype(jnp.int32), mode="drop"),
+            # a chunk's own counts enter once, with its first rows
+            "counters": state["counters"] + chunk["counters"] * fresh,
+        }
+
+    def step(self, params, state, view: StepView):
+        from fira_tpu.model import axk1
+
+        S, K = self.slots, self.cfg.beam_size
+        tok = jnp.take_along_axis(view.flat, view.pos_bk[:, None], axis=1)
+        logp, pool, counters = axk1.decode_step(
+            params, self.lm, tok.reshape(S, K), view.pos_c,
+            state["prompt_lat"], state["prompt_len"], state["lat_pool"],
+            view.tab_step, view.active, self.dtype)
+        return (logp,), {"lat_pool": pool,
+                         "counters": state["counters"] + counters}
+
+    def select(self, parts, tokens, probs, finished, pos_c, state, neg):
+        # no copy head: the generation log-softmax is the whole candidate
+        # space, and a token id is itself (batch=None: nothing to resolve)
+        return _select(parts[0], tokens, probs, finished, pos_c, None,
+                       self.cfg, neg, log_input=True)
+
+
+def for_config(model, cfg: FiraConfig, slots: int, paged: bool,
+               block_size: int, pool_blocks: int):
+    if cfg.arch == "axk1":
+        return LMSlotModel(cfg, slots, block_size, pool_blocks)
+    return FiraSlotModel(model, cfg, slots, paged, block_size, pool_blocks)
