@@ -37,8 +37,11 @@ zero post-warmup retraces):
   sentinel S marking rows not consumed this call, ``mode="drop"``).
 
 Equivalence contract (pinned by tests/test_engine.py in all four
-kv-cache x factored-topk modes): per sample, the engine's (tokens, probs)
-are BIT-EXACT equal to the batched beam's. The argument has three legs:
+kv-cache x factored-topk modes): per sample, the engine's tokens are
+BIT-EXACT equal to the batched beam's, and so are its probs over the
+whole-sequence arena and without a cache; over the paged arena probs agree
+to float32 rounding (its self-attention sums the same terms among the
+exact zeros of the other beam lanes). The argument has three legs:
 
 1. beam search is per-sample independent — every batched-beam op acts
    row-wise (embeds, per-row matmuls, attention over the row's own
@@ -60,15 +63,19 @@ docs/DECODE_ENGINE.md "Paged KV arena"): the per-slot self-attention
 caches live in a FIXED POOL of KV blocks — ``k_pool``/``v_pool``
 (L, P, beam, H, block, d_head) — addressed through a per-slot block
 table (S, W) instead of whole-sequence slot stripes. The step program
-appends into each live slot's current tail block and gathers its cache
-view by block id (model.Decoder.decode_step_paged); ``insert`` hands a
+appends each beam's new K/V into ITS lane of the live slot's current tail
+block and that is the last time those bytes move: what follows the beams
+after a selection is the ``ancestry`` table (S, beam, tar_len) — the lane
+that holds each position of each beam's history — and the step attends a
+slot's beams over all lanes of its blocks, gathered once by block id,
+under that table's mask (model.Decoder.decode_step_paged); ``insert`` hands a
 fresh slot exactly the blocks its decode bucket's tar budget reserves;
 ``harvest`` returns a settled slot's blocks to the host free list WHOLE
 — freed blocks are unmapped, never zeroed (beam.step_valid_mask already
 multiplies unwritten positions by an exact 0.0). Everything stays
 static-shape (fixed P, fixed W), so the program family above is
-unchanged and per-sample output is BIT-exact (tokens AND probs) vs the
-unpaged arena (tests/test_paged_kv.py). The point: slot residency
+unchanged and per-sample tokens are BIT-exact, probs equal to float32
+rounding, vs the unpaged arena (tests/test_paged_kv.py). The point: slot residency
 decouples from sequence length — ``engine_slots`` grows past what
 whole-sequence arenas allow at equal HBM, and longer-tar decode buckets
 (``cfg.decode_tar_buckets``) become smaller/larger block RESERVATIONS
@@ -631,17 +638,29 @@ class SlotEngine:
         tab_step = (jnp.where(active[:, None], state["block_tab"],
                               jnp.int32(self._pool_blocks))
                     if self._paged else None)
+        # beam ancestry (a model that declares it): this position goes
+        # into each beam's OWN lane of the slot's blocks, so the table the
+        # step reads through names lane k at ``pos`` for beam k
+        ancestry = None
+        if self.smodel.beam_ancestry:
+            ancestry = jnp.where(
+                jnp.arange(T)[None, None, :] == pos_c[:, None, None],
+                jnp.arange(K, dtype=jnp.int32)[None, :, None],
+                state["ancestry"])
         view = slot_model.StepView(
             flat=tokens.reshape(S * K, T), pos_c=pos_c,
-            pos_bk=jnp.repeat(pos_c, K), active=active, tab_step=tab_step)
+            pos_bk=jnp.repeat(pos_c, K), active=active, tab_step=tab_step,
+            ancestry=ancestry)
         parts, out_caches = self.smodel.step(params, state, view)
         with jax.named_scope("topk"):
             new_tokens, new_probs, new_finished, src_beam = \
                 self.smodel.select(parts, tokens, probs, finished, pos_c,
                                    state, neg)
-        # permute cached histories to follow their beams, leaf by leaf as
-        # the model DECLARED them (slot_model.Leaf.reorder): pool leaves
-        # move block contents inside each active slot's own grant;
+        # cached histories follow their beams as the model DECLARED: by
+        # the ancestry table (slot_model ``beam_ancestry``: FIRA's paged
+        # pools are never moved — further down), or leaf by leaf
+        # (slot_model.Leaf.reorder) — pool leaves move block contents
+        # inside each active slot's own grant (A.X-K1's ``lat_pool``);
         # whole-sequence stripes take exactly the batched beam's gather.
         # Inactive stripe rows are NOT blended back: a done/idle slot's
         # cache is never read again — it is not stepped, and a refill
@@ -675,6 +694,18 @@ class SlotEngine:
                         slot_model.permute_pool(c, tab_step, idx[key])
                         if how == "pool" else
                         slot_model.permute_stripes(c, idx[key]))
+
+        if ancestry is not None:
+            # the pools stay where they were written: what follows the
+            # beams is which lane holds each position of their histories,
+            # S x K x T small ints. Inactive rows keep their table as
+            # tokens/probs keep theirs below — a verify-frozen row RESUMES
+            # with its history intact.
+            with jax.named_scope("kv_reorder"):
+                followed = jnp.take_along_axis(
+                    ancestry, src_beam[:, :, None], axis=1)
+            out_caches["ancestry"] = jnp.where(
+                active[:, None, None], followed, state["ancestry"])
 
         tokens = jnp.where(active[:, None, None], new_tokens, tokens)
         probs = jnp.where(active[:, None], new_probs, probs)
@@ -740,6 +771,10 @@ class SlotEngine:
             # hand the seated rows their block grants
             new["block_tab"] = state["block_tab"].at[sid].set(
                 block_rows.astype(jnp.int32), mode="drop")
+        if self.smodel.beam_ancestry:
+            # a fresh slot's beams each start in their own lane
+            new["ancestry"] = state["ancestry"].at[sid].set(
+                jnp.arange(K, dtype=jnp.int32)[None, :, None], mode="drop")
         return new
 
     # --- state ----------------------------------------------------------
@@ -771,6 +806,9 @@ class SlotEngine:
         if self._paged:
             z["block_tab"] = np.full((S, self._table_width),
                                      self._pool_blocks, np.int32)  # unmapped
+        if self.smodel.beam_ancestry:
+            z["ancestry"] = np.broadcast_to(
+                np.arange(K, dtype=np.int32)[None, :, None], (S, K, T)).copy()
         self._kv_bytes_per_slot = paging.leaves_kv_bytes_per_slot(
             self._leaves, S)
         # firacheck: allow[RETIRED-RECHECK] arena-state write: retire() deliberately leaves the arena in place ("the arena and stats stay") and a dead engine's _state is never read again — only scheduling/guard state needs the post-dispatch re-check

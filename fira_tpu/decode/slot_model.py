@@ -9,10 +9,11 @@ bound by name in the engine any more:
   requests leaves behind for its slots (a dict of device arrays, rows along
   each leaf's request axis). Traced inside the engine's ``_prefill_fn``.
 - ``leaves(chunk) -> {name: Leaf}``: the arena's model-owned leaves, each
-  with shape, dtype and whether it is PER BEAM (reordered with
-  ``src_beam`` after every selection: ``"pool"`` for a paged block pool,
-  ``"stripe"`` for whole-sequence rows) or SHARED by a slot's beams
-  (``reorder=None``). ``kv`` marks what ``kv_bytes_per_slot`` counts
+  with shape, dtype and whether it is PER BEAM and reordered with
+  ``src_beam`` after every selection (``"pool"`` for a paged block pool,
+  ``"stripe"`` for whole-sequence rows) or not (``reorder=None``: shared
+  by a slot's beams, or per beam LANE and read through the ancestry
+  table). ``kv`` marks what ``kv_bytes_per_slot`` counts
   (decode/paging.py follows these declarations).
 - ``insert(state, chunk, sid, sid_bk, fresh) -> {name: leaf}``:
   scatter chunk rows into slots ``sid`` (sentinel = dropped).
@@ -23,6 +24,12 @@ bound by name in the engine any more:
   are the updated arena leaves, not yet reordered.
 - ``prefill_budget``: the prefill dispatches ``SlotEngine.run`` admits
   between two step dispatches; 0 = as many as the free slots ask for.
+- ``beam_ancestry``: True for a model whose per-beam pools are written
+  once, each beam into its own lane, and never reordered: the engine then
+  keeps ``ancestry`` (S, K, T) — the lane that holds position t of beam
+  k's history — follows the beams in IT after every selection, and hands
+  it to ``step`` in the view. False: per-beam leaves are moved as their
+  ``reorder`` says, and no table is allocated or carried.
 - ``select(parts, tokens, probs, finished, pos, state, neg)``: the beam
   selection for this model's distribution -> (tokens, probs, finished,
   src_beam). FIRA's copy head selects from the factors
@@ -55,8 +62,9 @@ class Leaf:
 
     shape: Tuple[int, ...]
     dtype: np.dtype
-    reorder: Optional[str] = None   # "pool" | "stripe": per beam, follows
-    #                                 src_beam; None: shared by the beams
+    reorder: Optional[str] = None   # "pool" | "stripe": per beam, moved by
+    #                                 src_beam; None: never moved (shared
+    #                                 by the beams, or per beam lane)
     kv: bool = False                # counted by kv_bytes_per_slot
 
 
@@ -69,6 +77,10 @@ class StepView(NamedTuple):
     active: jnp.ndarray     # (S,) live and not done (and not gated off)
     tab_step: Optional[jnp.ndarray]  # (S, W) block table, the sentinel in
     #                                  rows that must not read or write
+    ancestry: Optional[jnp.ndarray] = None  # (S, K, T) beam lane holding
+    #                                  position t of beam k's history, this
+    #                                  position already each beam's own lane
+    #                                  (a model with ``beam_ancestry`` only)
 
 
 def beam_index(src_beam, reorder: str, ndim: int):
@@ -85,7 +97,13 @@ def permute_pool(pool, tab_step, idx):
     cached histories follow their beams (table entries stay put: a slot's
     grant is host-owned from insert to harvest). pool: (L, P, K, ...);
     ``idx``: :func:`beam_index`. Scatter targets are disjoint across slots
-    because grants never overlap; sentinel rows (idle/done) drop."""
+    because grants never overlap; sentinel rows (idle/done) drop.
+
+    Three passes over every block of every active slot, every position:
+    only a pool declared ``reorder="pool"`` pays them — A.X-K1's
+    ``lat_pool`` (0.10 GB, no such op among its step's longest). FIRA's
+    pools are written once and never moved: its beams follow by the
+    engine's ancestry table (``beam_ancestry``)."""
     blocks = pool[:, tab_step]           # (L, S, W, K, ...)
     blocks = jnp.take_along_axis(blocks, idx, axis=3)
     return pool.at[:, tab_step].set(blocks, mode="drop")
@@ -113,6 +131,10 @@ class FiraSlotModel:
                  block_size: int, pool_blocks: int):
         self.model, self.cfg, self.slots = model, cfg, slots
         self.paged = paged
+        # the paged pools are written once, each beam into its own lane,
+        # and never moved: the engine keeps which lane holds each position
+        # of each beam's history and the step reads through that table
+        self.beam_ancestry = paged
         self.block_size, self.pool_blocks = block_size, pool_blocks
 
     def chunk_rows(self, chunk) -> int:
@@ -172,10 +194,9 @@ class FiraSlotModel:
             cd = chunk["cache_seed"].dtype
             if self.paged:
                 P, BS = self.pool_blocks, self.block_size
-                out["k_pool"] = Leaf((L, P, K, H, BS, d_head), cd,
-                                     reorder="pool", kv=True)
-                out["v_pool"] = Leaf((L, P, K, H, BS, d_head), cd,
-                                     reorder="pool", kv=True)
+                # per beam LANE, not per beam: no reorder (beam_ancestry)
+                out["k_pool"] = Leaf((L, P, K, H, BS, d_head), cd, kv=True)
+                out["v_pool"] = Leaf((L, P, K, H, BS, d_head), cd, kv=True)
             else:
                 out["k_cache"] = Leaf((L, S * K, H, T, d_head), cd,
                                       reorder="stripe", kv=True)
@@ -233,7 +254,8 @@ class FiraSlotModel:
         tok_in = jnp.take_along_axis(flat, pos_bk[:, None], axis=1)
         if self.paged:
             caches = ("k_pool", "v_pool")
-            args = (state["k_pool"], state["v_pool"], view.tab_step)
+            args = (state["k_pool"], state["v_pool"], view.tab_step,
+                    view.ancestry)
             methods = (FiraModel.dist_parts_step_paged,
                        FiraModel.fused_probs_step_paged)
         else:
@@ -277,6 +299,7 @@ class LMSlotModel:
     on the device; the harvest reads it with its own reads."""
 
     insert_by_geometry = True      # a chunk is as long as its bucket
+    beam_ancestry = False          # lat_pool is reordered (permute_pool)
     # one prefill dispatch of prompts (8,192 padded tokens at the published
     # widths) outweighs a step dispatch three times: refilling every free
     # slot first would stall the seated slots for seconds and seat whole

@@ -40,9 +40,10 @@ Drafter tiers (cfg.spec_decode):
   dispatch. Rides FIRA's measured verbatim-copy fraction.
 - ``draft``: a greedy argmax roll of the existing cached step program on
   each slot's TOP BEAM only — 1/beam of the step's decoder rows, against
-  scratch copies of the beam-0 caches (paged mode gathers the beam-0 lane
-  dense via layers.gather_block_kv_beam; the real pool/arena is never
-  written by a drafter). Costlier, higher acceptance on generated spans.
+  scratch copies of the beam-0 caches (paged mode gathers beam 0's history
+  dense via layers.gather_block_kv_beam, lane by lane through the engine's
+  ancestry table; the real pool/arena is never written by a drafter).
+  Costlier, higher acceptance on generated spans.
 
 Both tiers emit RESOLVED vocab ids (beam._resolve_copy — the same id space
 the beam stores at extension time), so drafted-vs-emitted comparison is a
@@ -246,14 +247,18 @@ def make_drafter(model: FiraModel, cfg: FiraConfig, slots: int, paged: bool):
         cross_v0 = state["cross_v"][:, 0::K]
         src_proj0 = state["src_proj"][0::K]
         if paged:
-            # dense SCRATCH view of each slot's beam-0 lane: the pool is
-            # read once per draft and never written (sentinel table rows of
-            # idle/done slots clamp to garbage the validity mask zeroes)
-            tab = state["block_tab"]
-            k_sc = jnp.stack([gather_block_kv_beam(state["k_pool"][l], tab, 0)
-                              for l in range(L)])
-            v_sc = jnp.stack([gather_block_kv_beam(state["v_pool"][l], tab, 0)
-                              for l in range(L)])
+            # dense SCRATCH view of each slot's top beam: the pool is read
+            # once per draft and never written (sentinel table rows of
+            # idle/done slots clamp to garbage the validity mask zeroes).
+            # Beam 0's history does not lie in lane 0: it is followed
+            # through the engine's ancestry table, position by position
+            tab, anc = state["block_tab"], state["ancestry"]
+            k_sc = jnp.stack([
+                gather_block_kv_beam(state["k_pool"][l], tab, 0, anc)
+                for l in range(L)])
+            v_sc = jnp.stack([
+                gather_block_kv_beam(state["v_pool"][l], tab, 0, anc)
+                for l in range(L)])
         else:
             k_sc = state["k_cache"].reshape(L, -1, K, H, T, d_head)[:, :, 0]
             v_sc = state["v_cache"].reshape(L, -1, K, H, T, d_head)[:, :, 0]

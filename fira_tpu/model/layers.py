@@ -318,44 +318,76 @@ class Attention(nn.Module):
 
 def gather_block_kv(pool_l: jnp.ndarray, block_tab: jnp.ndarray
                     ) -> jnp.ndarray:
-    """Assemble one layer's per-row K (or V) cache view from a paged block
-    pool (decode/engine.py; docs/DECODE_ENGINE.md "Paged KV arena").
+    """One layer's K (or V) cache of every slot out of a paged block pool,
+    ALL beam lanes (decode/engine.py; docs/DECODE_ENGINE.md "Paged KV
+    arena").
 
     pool_l: one layer's pool slice, (P, K, H, BS, d_head): P fixed pool
-    blocks, each holding BS cache positions for all K beams of the owning
-    slot. block_tab: (S, W) int32 — slot s's position range
-    [w*BS, (w+1)*BS) lives in block ``block_tab[s, w]``; the sentinel id P
-    marks unmapped entries (gather CLAMPS them to a garbage block whose
-    values are exactly zeroed by the validity mask's -1e9 —
-    beam.step_valid_mask).
+    blocks, each holding BS cache positions in K beam LANES of the owning
+    slot. The pool is written once — a step puts beam k's new K/V into
+    lane k of the slot's tail block (:func:`append_block_kv`) — and never
+    moved: after the selections that re-sorted the beams, position t of
+    beam q's history lies in lane ``ancestry[s, q, t]`` (the engine's
+    table), not in lane q. block_tab: (S, W) int32 — slot s's position
+    range [w*BS, (w+1)*BS) lives in block ``block_tab[s, w]``; the
+    sentinel id P marks unmapped entries (gather CLAMPS them to a garbage
+    block whose values are exactly zeroed by the mask's -1e9).
 
-    Returns (S*K, H, W*BS, d_head): row-major (slot, beam) rows in the
-    exact layout ``Attention.attend`` consumes, bit-identical for every
-    written position to the whole-sequence cache it replaces. A
+    Every slot's blocks are read ONCE. Returns (S, H, W*K*BS, d_head):
+    the slot's W*K*BS cached entries as ONE key axis, ordered (block,
+    lane, offset) — what ``Attention.attend`` consumes with the slot's K
+    beams as its query axis and :func:`lane_mask` as its mask. A
     low-precision pool (cfg.kv_dtype="bf16" — decode/quant.py) UPCASTS on
     read to the stable dtype, so the attention math downstream runs full
     precision whatever the arena stores; for an f32 pool the cast is a
-    no-op (the byte-identity contract path)."""
+    no-op."""
     P, K, H, BS, d_head = pool_l.shape
     S, W = block_tab.shape
     blocks = pool_l[block_tab]                      # (S, W, K, H, BS, dh)
-    blocks = blocks.transpose(0, 2, 3, 1, 4, 5)     # (S, K, H, W, BS, dh)
-    return blocks.reshape(S * K, H, W * BS, d_head).astype(
+    blocks = blocks.transpose(0, 3, 1, 2, 4, 5)     # (S, H, W, K, BS, dh)
+    return blocks.reshape(S, H, W * K * BS, d_head).astype(
         stable_dtype(pool_l.dtype))
 
 
+def lane_mask(ancestry: jnp.ndarray, valid: jnp.ndarray, block_size: int
+              ) -> jnp.ndarray:
+    """Which of a slot's cached entries each of its beams attends:
+    entry (block w, lane j, offset b) belongs to beam q's history iff
+    position t = w*BS + b is a valid one of q's (``valid``: (S, K, T)
+    bool, beam.step_valid_mask) and ``ancestry[s, q, t] == j``. Returns
+    (S, 1, K, W*K*BS) bool over :func:`gather_block_kv`'s key axis, the
+    heads broadcast. An entry outside a beam's history gets the -1e9 of
+    an unwritten position, so its softmax weight is an exact 0.0: per
+    beam the same 1..T keys and values are attended as over a
+    whole-sequence cache reordered after every selection."""
+    S, K, T = ancestry.shape
+    W = T // block_size
+    lanes = jnp.arange(K, dtype=ancestry.dtype)[:, None]
+    own = ancestry.reshape(S, K, W, 1, block_size) == lanes
+    own = own & valid.reshape(S, K, W, 1, block_size)   # (S, K, W, Kj, BS)
+    return own.reshape(S, 1, K, W * K * block_size)
+
+
 def gather_block_kv_beam(pool_l: jnp.ndarray, block_tab: jnp.ndarray,
-                         beam: int) -> jnp.ndarray:
-    """One BEAM LANE's dense cache view from the paged pool: the
-    (S, H, W*BS, d_head) slice of :func:`gather_block_kv` at beam lane
-    ``beam``, gathered without materializing the other K-1 lanes. The
-    speculative draft-tier roll (decode/spec.py) copies the top-beam lane
-    into a dense scratch cache once per draft and rolls on that — the
-    pool itself is never written by a drafter. Same read-upcast rule as
-    :func:`gather_block_kv` (no-op for an f32 pool)."""
+                         beam: int, ancestry: jnp.ndarray) -> jnp.ndarray:
+    """One BEAM's dense cache view from the paged pool,
+    (S, H, W*BS, d_head): what a whole-sequence cache would hold for beam
+    ``beam`` of every slot. The speculative draft-tier roll
+    (decode/spec.py) copies the top beam's history into a dense scratch
+    cache once per draft and rolls on that — the pool itself is never
+    written by a drafter. The pool is written once and never moved, so
+    lane ``beam`` does NOT hold the beam's history: position t is taken
+    from lane ``ancestry[s, beam, t]`` (the engine's table,
+    (S, K, W*BS)) — a chain of K-1 selects over the slot's blocks, no
+    arithmetic: the stored bits. Read-upcast as :func:`gather_block_kv`
+    (no-op for an f32 pool)."""
     P, K, H, BS, d_head = pool_l.shape
     S, W = block_tab.shape
-    blocks = pool_l[:, beam][block_tab]             # (S, W, H, BS, dh)
+    lanes = pool_l[block_tab]                       # (S, W, K, H, BS, dh)
+    lane = ancestry[:, beam].reshape(S, W, 1, BS, 1)
+    blocks = lanes[:, :, 0]
+    for j in range(1, K):
+        blocks = jnp.where(lane == j, lanes[:, :, j], blocks)
     blocks = blocks.transpose(0, 2, 1, 3, 4)        # (S, H, W, BS, dh)
     return blocks.reshape(S, H, W * BS, d_head).astype(
         stable_dtype(pool_l.dtype))

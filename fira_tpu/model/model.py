@@ -29,6 +29,7 @@ from fira_tpu.model.layers import (
     stable_dtype,
     append_block_kv,
     gather_block_kv,
+    lane_mask,
     Attention,
     Combination,
     FeedForward,
@@ -366,7 +367,7 @@ class Decoder(nn.Module):
         return x, k_cache, v_cache
 
     def decode_step_paged(self, tok, pos_idx, k_pool, v_pool, block_tab,
-                          cross_k, cross_v, sou_mask, self_mask):
+                          ancestry, cross_k, cross_v, sou_mask, self_mask):
         """:meth:`decode_step_multi` with the self-attention cache behind
         BLOCK-TABLE INDIRECTION (the slot engine's paged KV arena,
         decode/engine.py): instead of each row owning a whole-sequence
@@ -374,15 +375,29 @@ class Decoder(nn.Module):
         blocks — k_pool/v_pool: (L, P, K, H, block, d_head) — and
         ``block_tab`` (S, W) maps slot s's position range
         [w*block, (w+1)*block) to a pool block (sentinel id P = unmapped:
-        reads clamp to garbage the validity mask zeroes exactly, writes
-        drop). Per written position the gathered cache view is
-        bit-identical to the whole-sequence cache, so the attention math
-        — and therefore the beam trajectory — is unchanged
-        (tests/test_paged_kv.py pins tokens AND probs bitwise).
+        reads clamp to garbage the mask zeroes exactly, writes drop).
+
+        The pool is WRITTEN ONCE AND NEVER MOVED: row (s, k)'s new K/V
+        goes into lane k of slot s's tail block, and ``ancestry``
+        (S, K, tar_len) — the engine's, ``ancestry[s, k, t]`` the lane
+        that holds position t of beam k's history, this position already
+        lane k — says where a beam's past lies after the selections that
+        re-sorted the beams. Each layer reads every slot's blocks ONCE,
+        all K lanes (layers.gather_block_kv), and the slot's K beams
+        attend over its K x tar_len cached entries together, each under
+        its own mask ``valid[t] & (ancestry[s, k, t] == lane)``
+        (layers.lane_mask). A masked entry gets the -1e9 of an unwritten
+        position — softmax weight an exact 0.0 — so per beam this is the
+        attention of :meth:`decode_step_multi` over the same keys and
+        values at the same precision; what differs is the order in which
+        the softmax and the value product sum their exact zeros, i.e. the
+        last bits (tests/test_paged_kv.py pins tokens bitwise and probs to
+        float32 rounding; tests/test_beam_ancestry.py that the entries
+        selected ARE the reordered whole-sequence cache, bit for bit).
 
         tok: (S*K, 1) token ids; pos_idx: (S*K,) per-row positions (rows
-        of one slot share theirs); W*block must equal the attended cache
-        width ``self_mask.shape[-1]``."""
+        of one slot share theirs); self_mask: (S*K, 1, 1, tar_len) per-row
+        validity; W*block must equal tar_len."""
         _L, _P, K, _H, BS, _dh = k_pool.shape
         B = tok.shape[0]
         S, W = block_tab.shape
@@ -396,6 +411,7 @@ class Decoder(nn.Module):
         krow = jnp.arange(B, dtype=jnp.int32) % K
         blk = block_tab[slot, pos // BS]             # (B,) current tail block
         off = pos % BS
+        mask = lane_mask(ancestry, self_mask.reshape(S, K, W * BS), BS)
         x = self.embed(tok) + self._pos_table()[pos][:, None, :]
         for i in range(self.cfg.num_layers):
             sa = getattr(self, f"self_attn_{i}")
@@ -404,9 +420,11 @@ class Decoder(nn.Module):
                                      k_new[:, :, 0, :])
             v_pool = append_block_kv(v_pool, i, blk, krow, off,
                                      v_new[:, :, 0, :])
-            x = sa.attend(x, gather_block_kv(k_pool[i], block_tab),
+            # a slot's K beams share its blocks: they are the query axis
+            x = sa.attend(x.reshape(S, K, -1),
+                          gather_block_kv(k_pool[i], block_tab),
                           gather_block_kv(v_pool[i], block_tab),
-                          self_mask, deterministic=True)
+                          mask, deterministic=True).reshape(B, 1, -1)
             x = getattr(self, f"cross_attn_{i}").attend(
                 x, cross_k[i], cross_v[i], sou_mask, deterministic=True)
             x = getattr(self, f"ffn_{i}")(x, deterministic=True)
@@ -691,29 +709,30 @@ class FiraModel(nn.Module):
         return gen, copy, gate, k_cache, v_cache
 
     def dist_parts_step_paged(self, mask, tok, pos_idx, k_pool, v_pool,
-                              block_tab, cross_k, cross_v, src_proj,
-                              self_mask):
+                              block_tab, ancestry, cross_k, cross_v,
+                              src_proj, self_mask):
         """Paged-arena twin of :meth:`dist_parts_step_multi`: the self-
         attention cache is read and written through block-table
-        indirection (Decoder.decode_step_paged) instead of whole-sequence
-        stripes; heads are the shared :meth:`_step_heads`, so per row the
+        indirection and followed by beam ancestry
+        (Decoder.decode_step_paged) instead of whole-sequence stripes;
+        heads are the shared :meth:`_step_heads`, so per row the
         distribution factors are bit-identical to the unpaged step."""
         with jax.named_scope("decoder"):
             tar_emb, k_pool, v_pool = self.decoder.decode_step_paged(
-                tok, pos_idx, k_pool, v_pool, block_tab, cross_k, cross_v,
-                mask, self_mask,
+                tok, pos_idx, k_pool, v_pool, block_tab, ancestry, cross_k,
+                cross_v, mask, self_mask,
             )
         gen, copy, gate = self._step_heads(mask, src_proj, tar_emb)
         return gen, copy, gate, k_pool, v_pool
 
     def fused_probs_step_paged(self, mask, tok, pos_idx, k_pool, v_pool,
-                               block_tab, cross_k, cross_v, src_proj,
-                               self_mask):
+                               block_tab, ancestry, cross_k, cross_v,
+                               src_proj, self_mask):
         """Paged-arena twin of :meth:`fused_probs_step_multi` — the
         engine's non-factored step head over the block pool."""
         gen, copy, gate, k_pool, v_pool = self.dist_parts_step_paged(
-            mask, tok, pos_idx, k_pool, v_pool, block_tab, cross_k,
-            cross_v, src_proj, self_mask)
+            mask, tok, pos_idx, k_pool, v_pool, block_tab, ancestry,
+            cross_k, cross_v, src_proj, self_mask)
         fused = jnp.concatenate(
             [gate[:, :, 0:1] * gen, gate[:, :, 1:2] * copy], axis=-1
         )

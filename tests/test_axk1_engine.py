@@ -150,6 +150,38 @@ def test_arena_leaves_say_what_follows_the_beams():
         == [f"engine_insert[{t}]" for t in tags]
 
 
+def test_lat_pool_follows_src_beam_through_permute_pool_not_ancestry(
+        monkeypatch):
+    """FIRA's pools follow their beams by the engine's ancestry table; this
+    model did not ask for one: its state holds no such leaf, and its step
+    still moves ``lat_pool``'s block contents by ``src_beam``."""
+    from fira_tpu.data import buckets
+    from fira_tpu.decode import slot_model
+
+    cfg = get_config("axk1-tiny", engine_slots=2)
+    eng = SlotEngine(None, weights(cfg.lm), cfg)
+    eng.prewarm(buckets.prompt_warm_batches(cfg.lm))
+    assert eng.smodel.beam_ancestry is False
+    assert "ancestry" not in eng._state
+    assert eng._leaves["lat_pool"].reorder == "pool"
+    moved = []
+    inner = slot_model.permute_pool
+
+    def spy(pool, tab_step, idx):
+        moved.append((pool.shape, idx.shape))
+        return inner(pool, tab_step, idx)
+
+    monkeypatch.setattr(slot_model, "permute_pool", spy)
+    # a fresh function: jit would serve the prewarmed step's trace
+    text = jax.jit(lambda p, st: eng._step_fn(p, st)).lower(
+        eng._decode_params, eng._state).as_text(debug_info=True)
+    K = cfg.beam_size
+    assert moved == [(eng._state["lat_pool"].shape, (1, 2, 1, K, 1, 1))]
+    names = set(re.findall(r'loc\("(kv_reorder/[^"]*)"', text))
+    assert any("gather" in n for n in names)
+    assert any("scatter" in n for n in names)
+
+
 @pytest.mark.parametrize("arch", ["fira", "axk1"])
 def test_program_names_the_readers_find(arch):
     """benchmark/readers/module_time.py finds the engine's programs in the

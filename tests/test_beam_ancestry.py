@@ -1,0 +1,346 @@
+"""Beam ancestry in place of the self-KV reorder (decode/engine.py,
+model/layers.lane_mask; docs/DECODE_ENGINE.md "Paged KV arena").
+
+FIRA's paged pools are written once — beam k's new K/V into LANE k of the
+slot's tail block — and never moved; what follows the beams after a
+selection is the engine's ``ancestry`` table (S, K, T): the lane that holds
+position t of beam k's history. Pinned here, at fira-tiny on the CPU:
+
+- the entries the step's mask selects out of a slot's blocks ARE the dense
+  per-beam cache rebuilt from (pool, ``block_tab``, ``ancestry``), bit for
+  bit (a pure re-indexing), and so is the drafter's top-beam scratch;
+- that dense cache is the unpaged stripe cache after ``permute_stripes``,
+  position by position, over a drain with mixed settle depths and dirty
+  re-granted blocks — to float32 rounding, because the attention that
+  produced the cached values sums a beam's keys among the exact zeros of
+  the other lanes (another order, the same terms);
+- served tokens equal the unpaged arena's bitwise and probs to the same
+  rounding, in every kv-cache x factored-topk mode at the production
+  harvest cadence;
+- nothing as large as a pool layer is touched under the ``kv_reorder``
+  scope of the lowered step (and the detector sees the reorder when a
+  model declares one);
+- a verify-frozen row resumes with its history intact.
+"""
+
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fira_tpu.config import fira_tiny
+from fira_tpu.data.batching import make_batch
+from fira_tpu.data.dataset import FiraDataset
+from fira_tpu.data.feeder import Feeder
+from fira_tpu.data.synthetic import write_corpus_dir
+from fira_tpu.decode import slot_model
+from fira_tpu.decode.beam import eos_biased_params
+from fira_tpu.decode.engine import SlotEngine
+from fira_tpu.decode.runner import _decode_tasks
+from fira_tpu.model import layers
+from fira_tpu.model.model import FiraModel
+from fira_tpu.train.state import init_state
+
+# as tests/test_engine.py states it: float32 last bits, not a looser match
+PAGED_PROBS_RTOL = 1e-5
+
+
+@pytest.fixture(scope="module")
+def setup(tmp_path_factory):
+    data_dir = str(tmp_path_factory.mktemp("ancestry_corpus"))
+    write_corpus_dir(data_dir, n_commits=40, seed=29)
+    cfg = fira_tiny(batch_size=8, test_batch_size=6, decode_engine=True)
+    dataset = FiraDataset(data_dir, cfg)
+    cfg = dataset.cfg
+    batch = make_batch(dataset.splits["train"], np.arange(6), cfg)
+    params = init_state(FiraModel(cfg), cfg, batch).params
+    # moderate EOS bias: mixed settle depths, so slots are harvested and
+    # refilled mid-stream and freed blocks are re-granted dirty
+    return cfg, dataset, eos_biased_params(params, delta=4.0)
+
+
+def dense_view(state, s: int, name: str) -> np.ndarray:
+    """Slot ``s``'s per-beam cache (L, K, H, T, d_head) out of the paged
+    pool ``name``, through the block table and the ancestry table."""
+    pool = np.asarray(state[name])
+    _L, P, _K, _H, BS, _dh = pool.shape
+    tab = np.minimum(np.asarray(state["block_tab"])[s], P - 1)
+    anc = np.asarray(state["ancestry"])[s]                  # (K, T)
+    t = np.arange(anc.shape[1])[None, :]
+    blocks = pool[:, tab]                                   # (L,W,K,H,BS,dh)
+    view = blocks[:, t // BS, anc, :, t % BS, :]            # (K,T,L,H,dh)
+    return view.transpose(2, 0, 3, 1, 4)
+
+
+def stripe_view(state, s: int, name: str, K: int) -> np.ndarray:
+    return np.asarray(state[name])[:, s * K:(s + 1) * K]    # (L,K,H,T,dh)
+
+
+def _drain_recording(eng, dataset, cfg, view_of, order):
+    """Drain the train split; after EVERY step dispatch (cadence 1: one
+    position) record each seated request's written cache, keyed by
+    (request, depth). -> (outputs, {(pid, pos): (k, v)})."""
+    seen = {}
+    inner = eng.harvest
+
+    def harvest():
+        state = jax.device_get(eng._state)
+        for s, (pid, _host, _row) in eng._busy.items():
+            pos = int(state["pos"][s])
+            if (pid, pos) not in seen:
+                seen[(pid, pos)] = tuple(
+                    view_of(state, s, kv)[:, :, :, :pos] for kv in "kv")
+        return inner()
+
+    eng.harvest = harvest
+    tasks, _ = _decode_tasks(dataset.splits["train"], cfg)
+    out = {}
+    with Feeder(tasks, num_workers=0, depth=1) as feed:
+        for it in eng.run(feed, refill_order=order):
+            out[it.position] = (it.tokens, it.probs)
+    return out, seen
+
+
+def test_dense_view_through_ancestry_is_the_permuted_stripe_cache(setup):
+    cfg0, dataset, params = setup
+    cfg = dataclasses.replace(cfg0, engine_harvest_every=1, engine_slots=5)
+    model, K = FiraModel(cfg), cfg0.beam_size
+    paged = SlotEngine(model, params, cfg)
+    # lifo against fifo: the two arenas seat a request in different slots,
+    # and the pool's blocks go round the free list between requests
+    got, got_seen = _drain_recording(
+        paged, dataset, cfg,
+        lambda st, s, kv: dense_view(st, s, f"{kv}_pool"), "lifo")
+    ucfg = dataclasses.replace(cfg, engine_paged_kv=False)
+    unpaged = SlotEngine(model, params, ucfg)
+    want, want_seen = _drain_recording(
+        unpaged, dataset, ucfg,
+        lambda st, s, kv: stripe_view(st, s, f"{kv}_cache", K), "fifo")
+    assert "ancestry" in paged._state and "ancestry" not in unpaged._state
+    assert paged.smodel.beam_ancestry and not unpaged.smodel.beam_ancestry
+    assert paged._leaves["k_pool"].reorder is None      # never moved
+    assert unpaged._leaves["k_cache"].reorder == "stripe"
+    # blocks were re-granted: more grants than the pool has blocks
+    assert paged.stats.slots_refilled * paged._table_width \
+        > paged._pool_blocks
+    assert got_seen.keys() == want_seen.keys()
+    depths = {pos for _pid, pos in got_seen}
+    assert len(depths) > 3                              # mixed depths
+    for key in got_seen:
+        for a, b in zip(got_seen[key], want_seen[key]):
+            assert a.shape == b.shape and a.shape[3] == key[1]
+            # cached K/V are projections of O(1) activations: entries
+            # near zero carry the rounding of their O(1) terms
+            np.testing.assert_allclose(a, b, rtol=PAGED_PROBS_RTOL,
+                                       atol=1e-6)
+    # the beams really were re-sorted: some history lies outside its lane
+    anc = np.asarray(paged._state["ancestry"])
+    assert (anc != np.arange(K)[None, :, None]).any()
+    assert got.keys() == want.keys()
+    for pos in got:
+        np.testing.assert_array_equal(got[pos][0], want[pos][0])
+        np.testing.assert_allclose(got[pos][1], want[pos][1],
+                                   rtol=PAGED_PROBS_RTOL, atol=0)
+
+
+def _mid_drain_state(setup, slots=4):
+    """A paged engine's state between two dispatches, with at least two
+    live rows at depth >= 2, one of them with re-sorted beams (history
+    outside its own lane). -> (engine, host state, that row, live rows)."""
+    cfg0, dataset, params = setup
+    cfg = dataclasses.replace(cfg0, engine_harvest_every=1,
+                              engine_slots=slots)
+    eng = SlotEngine(FiraModel(cfg), params, cfg)
+    tasks, _ = _decode_tasks(dataset.splits["train"], cfg)
+    with Feeder(tasks, num_workers=0, depth=1) as feed:
+        run = eng.run(feed)
+        for _ in run:
+            st = jax.device_get(eng._state)
+            live = np.flatnonzero(st["live"] & ~st["done"] & (st["pos"] >= 2))
+            mixed = [s for s in live if (
+                st["ancestry"][s, :, :st["pos"][s]]
+                != np.arange(cfg.beam_size)[:, None]).any()]
+            if mixed and live.size >= 2:
+                break
+        run.close()
+    r = int(mixed[0])
+    return eng, st, r, np.array([r] + [s for s in live if s != r])
+
+
+def test_the_mask_selects_the_dense_view_bit_for_bit(setup):
+    """What the step attends for beam q of slot s — the entries of
+    ``gather_block_kv``'s key axis that ``lane_mask`` leaves — is, in
+    order, the written prefix of the dense per-beam cache; the drafter's
+    ``gather_block_kv_beam`` through the table is beam 0's."""
+    eng, st, _r, live = _mid_drain_state(setup)
+    K, T = eng.cfg.beam_size, eng.cfg.tar_len
+    BS, W = eng._block_size, eng._table_width
+    S = eng.slots
+    written = np.arange(T)[None, None, :] < st["pos"][:, None, None]
+    valid = np.broadcast_to(written, (S, K, T))
+    mask = np.asarray(layers.lane_mask(
+        jnp.asarray(st["ancestry"]), jnp.asarray(valid), BS))[:, 0]
+    assert mask.shape == (S, K, W * K * BS)
+    for name in ("k_pool", "v_pool"):
+        for l in range(st[name].shape[0]):
+            keys = np.asarray(layers.gather_block_kv(
+                jnp.asarray(st[name][l]), jnp.asarray(st["block_tab"])))
+            top = np.asarray(layers.gather_block_kv_beam(
+                jnp.asarray(st[name][l]), jnp.asarray(st["block_tab"]), 0,
+                jnp.asarray(st["ancestry"])))
+            for s in live:
+                p = int(st["pos"][s])
+                dense = dense_view(st, s, name)[l]          # (K,H,T,dh)
+                for q in range(K):
+                    assert mask[s, q].sum() == p
+                    # key order is (block, lane, offset): one entry a
+                    # position, so within a block positions stay in order
+                    # only lane by lane — sort the picks by position
+                    picks = np.flatnonzero(mask[s, q])
+                    w, rest = np.divmod(picks, K * BS)
+                    t = w * BS + rest % BS
+                    got = keys[s][:, picks[np.argsort(t)]]  # (H,p,dh)
+                    np.testing.assert_array_equal(
+                        got.view(np.int32),
+                        dense[q][:, :p].view(np.int32))
+                np.testing.assert_array_equal(
+                    top[s][:, :p].view(np.int32),
+                    dense[0][:, :p].view(np.int32))
+
+
+@pytest.mark.parametrize("kv,fac", [(True, False), (True, True),
+                                    (False, False), (False, True)])
+def test_served_beams_equal_the_unpaged_arena(setup, kv, fac):
+    """The production cadence (4 positions a dispatch, the scan form of
+    the step), slots reused: tokens bitwise; probs bitwise without a
+    paged self-KV and to float32 rounding with one."""
+    cfg0, dataset, params = setup
+    cfg = dataclasses.replace(cfg0, beam_kv_cache=kv, beam_factored_topk=fac,
+                              engine_harvest_every=4, engine_slots=4)
+    model = FiraModel(cfg)
+    outs = {}
+    for paged in (True, False):
+        c = dataclasses.replace(cfg, engine_paged_kv=paged)
+        eng = SlotEngine(model, params, c)
+        tasks, _ = _decode_tasks(dataset.splits["train"], c)
+        with Feeder(tasks, num_workers=0, depth=1) as feed:
+            outs[paged] = {it.position: (it.tokens, it.probs)
+                           for it in eng.run(feed)}
+        # the table exists exactly where a paged self-KV does
+        assert ("ancestry" in eng._state) == (paged and kv)
+        assert eng.smodel.beam_ancestry == (paged and kv)
+    assert outs[True].keys() == outs[False].keys()
+    assert len(outs[True]) == len(dataset.splits["train"])
+    for pos in outs[True]:
+        np.testing.assert_array_equal(outs[True][pos][0],
+                                      outs[False][pos][0])
+        if kv:
+            np.testing.assert_allclose(outs[True][pos][1],
+                                       outs[False][pos][1],
+                                       rtol=PAGED_PROBS_RTOL, atol=0)
+        else:
+            np.testing.assert_array_equal(outs[True][pos][1],
+                                          outs[False][pos][1])
+
+
+# --------------------------------------------------------------------------
+# no pool-sized op under kv_reorder in the lowered step
+# --------------------------------------------------------------------------
+
+_LOC_DEF = re.compile(r'^(#loc\d+) = loc\("([^"]*)"', re.M)
+_LOC_USE = re.compile(r"loc\((#loc\d+)\)\s*$")
+_TENSOR = re.compile(r"tensor<([0-9x]+)x[a-z][a-z0-9]*>")
+
+
+def _reorder_ops_at_least(text: str, numel: int):
+    """Lines of a lowered program (``as_text(debug_info=True)``) under the
+    ``kv_reorder`` scope that read or write a tensor of ``numel`` elements
+    or more."""
+    scoped = {loc for loc, name in _LOC_DEF.findall(text)
+              if "kv_reorder" in name}
+    assert scoped, "no kv_reorder scope in the lowered step"
+    hits = []
+    for line in text.splitlines():
+        use = _LOC_USE.search(line)
+        if not use or use.group(1) not in scoped:
+            continue
+        sizes = [int(np.prod([int(d) for d in t.split("x")]))
+                 for t in _TENSOR.findall(line)]
+        if any(n >= numel for n in sizes):
+            hits.append(line.strip()[:200])
+    return hits
+
+
+def _lowered_step(setup):
+    cfg0, dataset, params = setup
+    cfg = dataclasses.replace(cfg0, engine_slots=4)
+    eng = SlotEngine(FiraModel(cfg), params, cfg)
+    warm = make_batch(dataset.splits["train"], np.arange(0), cfg,
+                      batch_size=cfg.test_batch_size)
+    wire = {k: v for k, v in warm.items() if not k.startswith("_")}
+    eng._ensure_state(eng._prefill(eng.params, wire))
+    text = eng._step.lower(eng._decode_params, eng._state).as_text(
+        debug_info=True)
+    layer = int(np.prod(eng._state["k_pool"].shape[1:]))
+    return text, layer
+
+
+def test_step_lowers_without_a_pool_sized_reorder(setup, monkeypatch):
+    text, layer = _lowered_step(setup)
+    assert _reorder_ops_at_least(text, layer) == []
+    # what follows the beams is there, and it is S x K x T ints
+    assert re.search(r"kv_reorder/[^\"]*take_along_axis", text)
+    # the detector sees what it guards against: the same engine over a
+    # model that declares its pools reordered gathers and scatters them
+    leaves = slot_model.FiraSlotModel.leaves
+
+    def reordered(self, chunk):
+        out = leaves(self, chunk)
+        for name in ("k_pool", "v_pool"):
+            out[name] = dataclasses.replace(out[name], reorder="pool")
+        return out
+
+    monkeypatch.setattr(slot_model.FiraSlotModel, "leaves", reordered)
+    text, layer = _lowered_step(setup)
+    hits = _reorder_ops_at_least(text, layer)
+    # a pool: the gather of every slot's blocks, the beam axis taken by
+    # src_beam, and the scatter back (a region op: its types close it)
+    assert sum("stablehlo.gather" in h for h in hits) == 2
+    assert sum("call @take_along_axis" in h for h in hits) == 2
+    assert sum(h.startswith("})") for h in hits) == 2
+
+
+# --------------------------------------------------------------------------
+# a verify-frozen row resumes
+# --------------------------------------------------------------------------
+
+def test_gated_row_keeps_its_history_and_resumes(setup):
+    eng, st0, r, live = _mid_drain_state(setup)
+    step = jax.jit(lambda st, gate: eng._one_step(eng._decode_params, st,
+                                                  gate)[0])
+    everyone = jnp.ones((eng.slots,), bool)
+    frozen = everyone.at[r].set(False)
+
+    def row(st, upto):
+        return (st["tokens"][r], st["probs"][r], st["ancestry"][r],
+                int(st["pos"][r]),
+                dense_view(st, r, "k_pool")[:, :, :, :upto],
+                dense_view(st, r, "v_pool")[:, :, :, :upto])
+
+    def same(a, b):
+        for x, y in zip(a, b):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+    p0 = int(st0["pos"][r])
+    st1 = jax.device_get(step(st0, frozen))
+    assert int(st1["pos"][r]) == p0                      # it did not move
+    assert (st1["pos"][live[1:]] == st0["pos"][live[1:]] + 1).all()
+    same(row(st1, p0), row(st0, p0))                     # history intact
+    # resumed, it lands where the ungated step would have put it
+    st2 = jax.device_get(step(st1, everyone))
+    ref = jax.device_get(step(st0, everyone))
+    assert int(ref["pos"][r]) == p0 + 1
+    same(row(st2, p0 + 1), row(ref, p0 + 1))

@@ -2,10 +2,12 @@
 
 Pins the ISSUE-7 contract (docs/DECODE_ENGINE.md "Paged KV arena"):
 
-- the paged engine is per-sample BIT-EXACT (tokens AND probs) vs the
+- the paged engine's tokens are per-sample BIT-EXACT vs the
   whole-sequence unpaged arena in all four kv-cache x factored-topk
-  modes, and run_test file bytes are identical (single engine AND
-  2-replica fleet) with zero post-warmup compiles;
+  modes and its probs equal to float32 rounding (the self-attention
+  sums over all beam lanes under the ancestry mask — ISSUE 29), and
+  run_test file bytes are identical (single engine AND 2-replica fleet)
+  with zero post-warmup compiles;
 - scheduling stays deterministic when the pool is UNDERSIZED: admission
   is head-of-line on block reservations, so output bytes are a pure
   function of the stream, pool size included;
@@ -66,6 +68,9 @@ def _engine_outputs(model, params, dataset, cfg, **engine_kw):
     return out, eng
 
 
+# the bound tests/test_engine.py states, and why
+PAGED_PROBS_RTOL = 1e-5
+
 MODES = [
     # (kv_cache, factored_topk)
     (True, False),
@@ -78,8 +83,10 @@ MODES = [
 @pytest.mark.parametrize("kv,fac", MODES)
 def test_paged_bit_exact_vs_unpaged(setup, kv, fac):
     """Engine with the paged arena == engine with the whole-sequence
-    arena, per sample, bitwise (tokens AND probs) — the ROADMAP-4
-    regression contract, in every kv-cache x factored-topk mode."""
+    arena, per sample: tokens bitwise, probs to float32 rounding
+    (PAGED_PROBS_RTOL; bitwise until the pools stopped being reordered,
+    ISSUE 29) — the ROADMAP-4 regression contract, in every kv-cache x
+    factored-topk mode."""
     cfg0, dataset, _dir, eos_params = setup
     cfg = dataclasses.replace(cfg0, beam_kv_cache=kv, beam_factored_topk=fac)
     model = FiraModel(cfg)
@@ -117,7 +124,11 @@ def test_paged_bit_exact_vs_unpaged(setup, kv, fac):
     assert paged_out.keys() == unpaged_out.keys()
     for pos in paged_out:
         np.testing.assert_array_equal(paged_out[pos][0], unpaged_out[pos][0])
-        np.testing.assert_array_equal(paged_out[pos][1], unpaged_out[pos][1])
+        # the paged step attends a slot's beams over all lanes of its
+        # blocks under the ancestry mask: the same keys and values per
+        # beam, the exact zeros of the others summed in another order
+        np.testing.assert_allclose(paged_out[pos][1], unpaged_out[pos][1],
+                                   rtol=PAGED_PROBS_RTOL, atol=0)
 
 
 def test_paged_file_identical_zero_retraces_single_and_fleet(setup, tmp_path):
