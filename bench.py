@@ -677,8 +677,8 @@ def worker() -> None:
                                   "steps_per_commit", "dispatches",
                                   # paged-KV HBM accounting
                                   # (decode/paging.py): the machine-
-                                  # recorded side of any paged-vs-
-                                  # unpaged memory claim
+                                  # recorded side of any equal-
+                                  # memory claim
                                   "pool_blocks", "kv_block_size",
                                   "kv_bytes_per_slot", "peak_blocks",
                                   "pool_utilization")},

@@ -38,6 +38,9 @@ Phases, in order (PHASES below):
   train_pallas  cli train --copy-head pallas on a one-batch corpus
   sync          one warmed train dispatch timed twice: ended by
                 jax.block_until_ready and ended by float(loss)
+  adjacency     the encoder's dense adjacency as `--perf production` builds
+                it, at batch 170, 340 and 680: the cells it fills against a
+                plain float32 scatter of the same triplets on the host
   train_mesh, test_fleet   only with >= 4 devices: cli train --mesh 4x1 and
                 cli test --engine --engine-replicas 4, with every device's
                 peak memory checked so device 0 is not holding everything
@@ -75,7 +78,7 @@ WALL_LIMIT_S = 1150.0            # the driver allows 1200 s, compiles included
 EXIT_NO_DEVICE, EXIT_WONT_WIPE, EXIT_NO_REPO = 4, 5, 6
 
 PHASES = ("train", "test", "test_engine", "serve", "kernel", "train_pallas",
-          "sync", "train_mesh", "test_fleet")
+          "sync", "adjacency", "train_mesh", "test_fleet")
 MULTICHIP = ("train_mesh", "test_fleet")
 
 # sizes: the corpus must give the train split one fused K=8 dispatch plus a
@@ -85,12 +88,16 @@ MULTICHIP = ("train_mesh", "test_fleet")
 FULL = {"config": "fira-full", "batch": 170, "commits": 1800,
         "small_commits": 210, "pad_words": 24650, "pad_ast": 71,
         "serve_rate": 8.0,
+        # 170 alone is the one size at which a batched N-D scatter under the
+        # sorted-indices promise was right on the chip (PERF.md section 6)
+        "adjacency_batches": [170, 340, 680],
         # (name, B, T, S = sou + sub_token, D)
         "kernel_shapes": [("fira-full", 4, 30, 370, 256),
                           ("fira-large", 4, 30, 370, 512)]}
 TINY = {"config": "fira-tiny", "batch": 16, "commits": 200,
         "small_commits": 21, "pad_words": 0, "pad_ast": 0,
         "serve_rate": 8.0,
+        "adjacency_batches": [16, 32, 64],
         "kernel_shapes": [("fira-tiny", 2, 12, 56, 64),
                           ("fira-tiny-wide", 2, 12, 56, 128)]}
 
@@ -228,7 +235,57 @@ def child_sync(args) -> None:
     }))
 
 
-CHILDREN = {"probe": child_probe, "kernel": child_kernel, "sync": child_sync}
+def child_adjacency(args) -> None:
+    """The dense adjacency as FiraModel.encode builds it under the CLI's
+    flags (the sorted-indices promise with --perf production, straight into
+    the compute dtype), on real batches of each size, against np.add.at of
+    the same triplets in float32."""
+    from fira_tpu.utils import startup
+
+    startup.configure_compile_cache()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fira_tpu import cli
+    from fira_tpu.data.batching import make_batch
+    from fira_tpu.data.dataset import FiraDataset
+    from fira_tpu.model.model import dense_adjacency
+
+    cfg = cli._resolve_cfg(cli.build_parser().parse_args(
+        ["train", *json.loads(args.cli_flags)]))
+    dataset = FiraDataset(args.data_dir, cfg)
+    cfg = dataset.cfg
+    split = dataset.splits["train"]
+    dtype = jnp.dtype(cfg.compute_dtype)
+    build = jax.jit(lambda s, r, v: dense_adjacency(
+        s, r, v, cfg.graph_len, indices_sorted=cfg.sort_edges,
+        out_dtype=dtype))
+    rows = []
+    for B in json.loads(args.shapes):
+        batch = make_batch(split, np.arange(B) % len(split), cfg,
+                           batch_size=B)
+        s, r, v = (np.asarray(batch[k])
+                   for k in ("senders", "receivers", "values"))
+        got = np.asarray(build(s, r, v).astype(jnp.float32))
+        plain = np.zeros((B, cfg.graph_len, cfg.graph_len), np.float32)
+        np.add.at(plain, (np.arange(B)[:, None], s.astype(np.int64),
+                          r.astype(np.int64)), v.astype(np.float32))
+        # the program scatters in the compute dtype: one value a cell
+        # (graph_build's dedup), so rounding the plain scatter is exact
+        want = np.asarray(jnp.asarray(plain).astype(dtype)
+                          .astype(jnp.float32))
+        rows.append({"batch": B, "edges": int(np.count_nonzero(v)),
+                     "cells_program": int(np.count_nonzero(got)),
+                     "cells_plain": int(np.count_nonzero(plain)),
+                     "values_equal": bool(np.array_equal(got, want))})
+    print(json.dumps({"platform": jax.devices()[0].platform,
+                      "sort_edges": bool(cfg.sort_edges),
+                      "dtype": str(dtype), "rows": rows}))
+
+
+CHILDREN = {"probe": child_probe, "kernel": child_kernel, "sync": child_sync,
+            "adjacency": child_adjacency}
 
 
 # --------------------------------------------------------------------------
@@ -504,6 +561,22 @@ class Smoke:
                 "float_loss_s": {"median": med_f, "min": f[0], "max": f[-1]},
                 "agree_within_spread": abs(med_b - med_f) <= max(
                     spread, 0.02 * med_f)}
+
+    def adjacency(self) -> dict:
+        res = self.child("adjacency", "--data-dir", self.data, "--cli-flags",
+                         json.dumps(self.train_flags), "--shapes",
+                         json.dumps(self.size["adjacency_batches"]))
+        check(res["platform"] == self.want,
+              f"adjacency ran on {res['platform']!r}")
+        check(res["sort_edges"], "adjacency: --perf production did not set "
+                                 "the sorted-indices promise")
+        for row in res["rows"]:
+            check(row["cells_program"] == row["cells_plain"] > 0
+                  and row["values_equal"],
+                  f"adjacency at batch {row['batch']}: the program filled "
+                  f"{row['cells_program']} cells, a plain float32 scatter "
+                  f"of the same triplets {row['cells_plain']}: {row}")
+        return {"dtype": res["dtype"], "rows": res["rows"]}
 
     def _placement(self, info: dict) -> dict:
         """Every device holds its share: with memory stats, no device's peak

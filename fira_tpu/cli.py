@@ -141,15 +141,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "decode tar budget (validated at parse time, "
                         "exit 2). Output bytes are invariant to K "
                         "(pinned by tests)")
-    p.add_argument("--kv-paged", default=None, choices=["on", "off"],
-                   help="test: engine KV arena layout (docs/DECODE_ENGINE"
-                        ".md 'Paged KV arena'): 'on' (default) pages the "
-                        "per-slot self-attention caches into a fixed pool "
-                        "of KV blocks behind per-slot block tables — "
-                        "bit-exact per sample vs 'off' (the whole-"
-                        "sequence arena, kept as the equivalence "
-                        "comparator), while decoupling slot count from "
-                        "target length in HBM")
     p.add_argument("--kv-block-size", type=int, default=None, metavar="B",
                    help="test: paged-KV block size in cache positions; "
                         "must divide every declared decode tar budget "
@@ -162,14 +153,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "per replica >= slots x ceil(tar/block) on the "
                         "smallest decode tar and >= one largest-budget "
                         "sample (validated at parse time, exit 2). "
-                        "0/unset = auto: full residency, scheduling "
-                        "identical to the unpaged arena")
+                        "0/unset = auto: full residency, admission "
+                        "never waits for blocks")
     p.add_argument("--kv-dtype", default=None, choices=["f32", "bf16"],
                    help="test/serve: engine KV arena storage dtype (docs/"
                         "DECODE_ENGINE.md 'Low-precision tiers'): 'bf16' "
-                        "stores the slot arena (paged pool blocks and the "
-                        "unpaged comparator alike) in bfloat16 — half the "
-                        "kv_bytes_per_slot, machine-recorded in stats — "
+                        "stores the slot arena's pool blocks in bfloat16 "
+                        "— half the kv_bytes_per_slot, machine-recorded "
+                        "in stats — "
                         "while every read upcasts so attention math stays "
                         "f32. Output bytes within a tier stay a pure "
                         "function of the stream (pinned by tests); quality "
@@ -547,8 +538,6 @@ def _resolve_cfg(args):
         overrides["spec_decode"] = args.spec_decode
     if args.spec_k is not None:
         overrides["engine_spec_k"] = args.spec_k
-    if args.kv_paged is not None:
-        overrides["engine_paged_kv"] = args.kv_paged == "on"
     if args.kv_block_size is not None:
         overrides["kv_block_size"] = args.kv_block_size
     if args.kv_pool_blocks is not None:

@@ -124,7 +124,7 @@ class FiraConfig:
     # measures p100 < 6,000 edges (fullscale/FULLSCALE.json era builds), so
     # 6144 keeps headroom while cutting the per-step adjacency scatter
     # stream 25% vs the old 8192 (the scatter is the single biggest op in
-    # the round-4 step attribution, scripts/tpu_diag3.py ~22 ms of 86).
+    # the earlier machine's step attribution, docs/PERF.md: ~22 ms of 86).
     # make_batch raises loudly if a sample ever exceeds it.
     max_edges: int = 6144
     # "dense": scatter COO into a (B, graph_len^2) adjacency once per step and
@@ -137,12 +137,11 @@ class FiraConfig:
     # scatters without its sorting prologue). Semantically a no-op —
     # scatter-add order is irrelevant; equality is pinned by tests.
     sort_edges: bool = False
-    # Lower the dense-adjacency build as ONE linearized 1-D scatter
-    # (flat = (b*N+s)*N+r) instead of the batched 3-D scatter. With
-    # sort_edges the flat stream is fully ascending, the friendliest index
-    # pattern XLA can be promised. Bit-identical output (pinned by tests);
-    # a measured perf candidate, dense path only.
-    flat_scatter: bool = False
+    # NO READER: the dense adjacency is ONE linearized 1-D scatter whatever
+    # this says (model.dense_adjacency). Declared only because
+    # benchmark/configs/fira-*.json and tests/benchmark/tiny/configs/
+    # fira-tiny.json pass it by keyword; goes with them (ROADMAP D14).
+    flat_scatter: bool = True
     # "single": one persistent (B, graph_len, d) encoder node buffer; each
     #   round static-update-slices the Combination rows in place. "split":
     #   the diff rows and the [sub||ast] rows live as two tensors for the
@@ -180,7 +179,10 @@ class FiraConfig:
     # --- decode ---
     beam_compat_prob_space: bool = True  # reference prob-space accumulation
                                          # (run_model.py:271,305); False => log-space
-    beam_kv_cache: bool = True  # O(T) cached decode vs full-prefix re-decode
+    # O(T) cached decode vs full-prefix re-decode — the BATCHED beam's
+    # choice (decode/beam.make_beam_search); the slot engine does not read
+    # it: its arena is always the cached, paged one.
+    beam_kv_cache: bool = True
     # Beam candidate selection from the distribution FACTORS: per-side
     # top-k over the generation softmax (vocab) and the copy softmax
     # (sou+sub positions), gate-scaled and merged — 2k candidates per beam
@@ -189,7 +191,9 @@ class FiraConfig:
     # gate weights, so any global top-k entry is inside a side's top-k);
     # ties between exactly-equal probabilities may break differently than
     # the fused scan order, which is why this is a knob and the
-    # token-equality pins ride the test fixtures.
+    # token-equality pins ride the test fixtures. The BATCHED beam's
+    # choice; the slot engine does not read it and always selects from
+    # the factors.
     beam_factored_topk: bool = False
     # Stop the decode loop once every beam of every batch item has emitted
     # EOS (plus ONE settling step), instead of always scanning tar_len-1
@@ -236,21 +240,14 @@ class FiraConfig:
     # CPU length-mix bench (scripts/tpu_decode_bench.py engine_mixed row)
     # and the occupancy loss shows up honestly in slot_occupancy.
     engine_harvest_every: int = 4
-    # --- paged KV arena (decode/paging.py; docs/DECODE_ENGINE.md) ---
-    # True (default): the engine's per-slot self-attention K/V caches live
-    # in a FIXED POOL of KV blocks addressed through per-slot block tables
-    # (vLLM/PagedAttention under this stack's static shapes — gather/
-    # scatter by block id, fixed pool size, fixed table width). Slot
-    # residency decouples from sequence length: a slot holds only the
-    # blocks its decode bucket's tar budget reserves, so engine_slots can
-    # grow past what whole-sequence arenas allow at equal HBM and longer
-    # tar buckets become new bucket-table entries instead of a per-length
-    # arena blow-up. Per-sample BIT-exact (tokens AND probs) vs the
-    # unpaged arena at the base tar geometry in all four kv-cache x
-    # factored-topk modes (tests/test_paged_kv.py). False keeps the
-    # whole-sequence arena — the comparator the equivalence tests pin
-    # against. Only meaningful with beam_kv_cache (the non-cached engine
-    # path holds no K/V to page).
+    # --- paged KV arena (decode/paging.py; docs/DECODE_ENGINE.md): the
+    # engine's per-slot self-attention K/V caches live in a FIXED POOL of
+    # KV blocks addressed through per-slot block tables ---
+    # NO READER but the refusal of False (decode/paging.paging_errors: that
+    # value asked for the whole-sequence arena, which is gone). Declared
+    # only because benchmark/configs/fira-*.json and tests/benchmark/tiny/
+    # configs/fira-tiny.json pass it by keyword; goes with them (ROADMAP
+    # D14).
     engine_paged_kv: bool = True
     # KV block size (positions per block). Must divide EVERY declared
     # decode tar length (cfg.tar_len plus, under decode_tar_buckets, each
@@ -263,8 +260,8 @@ class FiraConfig:
     # per replica, pool >= slots x ceil(smallest decode tar / block) and
     # >= ceil(largest decode tar / block) (one worst-case sample must
     # always fit — the no-livelock floor). 0 = auto: full residency,
-    # slots x ceil(tar_len / block) per replica — byte-identical
-    # scheduling to the unpaged arena.
+    # slots x ceil(tar_len / block) per replica — admission never waits
+    # for blocks.
     kv_pool_blocks: int = 0
     # True: the decode bucket table keeps each declared bucket's OWN
     # tar_len instead of pinning tar full, and the engine caps each
@@ -334,9 +331,9 @@ class FiraConfig:
     # diverged), so ACCEPTED output is bit-exact vs the plain engine BY
     # CONSTRUCTION: every advanced position ran the identical step math,
     # and rejected tails simply were never advanced (tests/test_spec.py
-    # pins tokens+probs+file bytes across kv x factored x paged modes,
-    # k, replica count, and harvest cadence). Default off: the plain f32
-    # non-spec path stays the byte-identical contract path.
+    # pins tokens+probs+file bytes across k, replica count and harvest
+    # cadence). Default off: the plain f32 non-spec path stays the
+    # byte-identical contract path.
     spec_decode: str = "off"
     # Drafted tokens per slot per verify dispatch (the (S, k) geometry of
     # the engine_draft/engine_verify program family). Must be in
@@ -347,7 +344,7 @@ class FiraConfig:
     # --- low-precision serving tiers (decode/quant.py;
     # docs/DECODE_ENGINE.md "Low-precision tiers") ---
     # Storage dtype of the decode self-attention K/V arena — the paged
-    # pool's blocks AND the unpaged comparator stripes. "f32" (default)
+    # pool's blocks. "f32" (default)
     # is the byte-identical contract path; "bf16" stores the arena at
     # half the bytes (append casts on write, gathers upcast on read, so
     # attention math stays in the compute dtype) — kv_bytes_per_slot
@@ -560,7 +557,7 @@ class FiraConfig:
     # "threefry" (default): JAX's counter-based generator, reproducible
     # across backends. "rbg": hardware random-bit generator — faster random
     # bits on TPU (dropout costs ~10 ms of the measured 107 ms fira-full
-    # step, scripts/tpu_ablate.py det_nodropout). Param init is threefry
+    # step on the earlier machine, docs/PERF.md). Param init is threefry
     # either way (identical initial weights); checkpoints store the key, so
     # a resume must use the impl it was trained with.
     rng_impl: str = "threefry"
@@ -734,8 +731,7 @@ DECODE_PERF_KNOBS = {
 # paged pool for the generated positions, log-space beams (the head is a
 # log-softmax and has no copy side), bfloat16 weights and cache.
 _LM_ENGINE = dict(
-    arch="axk1", decode_engine=True, beam_kv_cache=True,
-    engine_paged_kv=True, beam_compat_prob_space=False,
+    arch="axk1", decode_engine=True, beam_compat_prob_space=False,
     compute_dtype="bfloat16", beam_size=3, tar_len=64,
 )
 
@@ -850,9 +846,6 @@ def arch_errors(cfg: FiraConfig, command: Optional[str] = None) -> list:
     if not cfg.decode_engine:
         no("the batched non-engine beam (decode_engine off); run it "
            "through the slot engine (--engine)")
-    if not (cfg.beam_kv_cache and cfg.engine_paged_kv):
-        no("an unpaged or uncached arena (beam_kv_cache and "
-           "engine_paged_kv must stay on)")
     if cfg.beam_compat_prob_space:
         no("probability-space beams (beam_compat_prob_space): its head "
            "is a log-softmax")

@@ -27,8 +27,8 @@ zero post-warmup retraces):
   ``sou_len``/``sub_token_len`` are pinned by the copy-label id space and
   decode pins ``tar_len`` full): advance every live slot's beam
   ``cfg.engine_harvest_every`` positions at the slot's own depth
-  (model.dist_parts_step_multi / fused_probs_step_multi; the per-row
-  ``s`` vector path of beam._selection_tail), with a per-slot
+  (model.dist_parts_step_paged; the per-row ``s`` vector path of
+  beam._selection_tail), with a per-slot
   finished/done mask instead of the batch path's global early-exit
   predicate. Idle/done slots compute garbage that is blended away — they
   are the occupancy loss the refill loop exists to keep near zero.
@@ -36,12 +36,11 @@ zero post-warmup retraces):
   (slot ids are data, not shapes: a (C,) vector with the out-of-range
   sentinel S marking rows not consumed this call, ``mode="drop"``).
 
-Equivalence contract (pinned by tests/test_engine.py in all four
-kv-cache x factored-topk modes): per sample, the engine's tokens are
-BIT-EXACT equal to the batched beam's, and so are its probs over the
-whole-sequence arena and without a cache; over the paged arena probs agree
-to float32 rounding (its self-attention sums the same terms among the
-exact zeros of the other beam lanes). The argument has three legs:
+Equivalence contract (pinned by tests/test_engine.py against the batched
+beam in all four of ITS kv-cache x factored-topk forms): per sample, the
+engine's tokens are BIT-EXACT equal to the batched beam's, and its probs
+agree to float32 rounding (the paged self-attention sums the same terms
+among the exact zeros of the other beam lanes). The argument has three legs:
 
 1. beam search is per-sample independent — every batched-beam op acts
    row-wise (embeds, per-row matmuls, attention over the row's own
@@ -58,11 +57,10 @@ exact zeros of the other beam lanes). The argument has three legs:
    tests/test_beam_early_exit.py already pins that stopping there equals
    running the full scan.
 
-Paged KV arena (``cfg.engine_paged_kv``, default on — decode/paging.py,
-docs/DECODE_ENGINE.md "Paged KV arena"): the per-slot self-attention
-caches live in a FIXED POOL of KV blocks — ``k_pool``/``v_pool``
-(L, P, beam, H, block, d_head) — addressed through a per-slot block
-table (S, W) instead of whole-sequence slot stripes. The step program
+The arena is paged (decode/paging.py, docs/DECODE_ENGINE.md "Paged KV
+arena"): the per-slot self-attention caches live in a FIXED POOL of KV
+blocks — ``k_pool``/``v_pool`` (L, P, beam, H, block, d_head) —
+addressed through a per-slot block table (S, W). The step program
 appends each beam's new K/V into ITS lane of the live slot's current tail
 block and that is the last time those bytes move: what follows the beams
 after a selection is the ``ancestry`` table (S, beam, tar_len) — the lane
@@ -73,11 +71,9 @@ fresh slot exactly the blocks its decode bucket's tar budget reserves;
 ``harvest`` returns a settled slot's blocks to the host free list WHOLE
 — freed blocks are unmapped, never zeroed (beam.step_valid_mask already
 multiplies unwritten positions by an exact 0.0). Everything stays
-static-shape (fixed P, fixed W), so the program family above is
-unchanged and per-sample tokens are BIT-exact, probs equal to float32
-rounding, vs the unpaged arena (tests/test_paged_kv.py). The point: slot residency
-decouples from sequence length — ``engine_slots`` grows past what
-whole-sequence arenas allow at equal HBM, and longer-tar decode buckets
+static-shape (fixed P, fixed W). The point: slot residency decouples
+from sequence length — ``engine_slots`` grows past what whole-sequence
+stripes would allow at equal HBM, and longer-tar decode buckets
 (``cfg.decode_tar_buckets``) become smaller/larger block RESERVATIONS
 against one pool instead of a per-length arena blow-up. The scheduler's
 admission becomes reservation-based when the pool is undersized: the
@@ -173,12 +169,11 @@ class EngineStats:
     occupied_slot_steps: int = 0  # exact count of (slot, micro-step) pairs
                                   # that did real beam work (device-counted)
     commits: int = 0             # samples harvested
-    # paged-KV HBM accounting (decode/paging.py; 0/defaults when the
-    # engine runs the unpaged arena or no KV cache at all) — stamped by
-    # every step dispatch so a stats reset between timed windows
-    # (bench.py / tpu_decode_bench.py do exactly that) re-learns them
-    pool_blocks: int = 0         # fixed pool size P (paged only)
-    kv_block_size: int = 0       # positions per block (paged only)
+    # paged-KV HBM accounting (decode/paging.py) — stamped by every step
+    # dispatch so a stats reset between timed windows (bench.py /
+    # tpu_decode_bench.py do exactly that) re-learns them
+    pool_blocks: int = 0         # fixed pool size P
+    kv_block_size: int = 0       # positions per block
     kv_bytes_per_slot: int = 0   # committed K+V cache HBM per slot
     block_steps: int = 0         # blocks in use, summed per step dispatch
     peak_blocks: int = 0         # high-water mark of blocks in use
@@ -266,13 +261,11 @@ class EngineStats:
     @property
     def pool_utilization(self) -> float:
         """Mean fraction of the KV pool mapped to live slots per step
-        dispatch. 1.0 for the unpaged arena (the whole-sequence stripes
-        are committed whether or not a slot is live — exactly the HBM
-        the paged pool stops paying); 0.0 with no KV cache at all."""
+        dispatch (0.0 before the first one)."""
         if self.pool_blocks and self.step_dispatches:
             return self.block_steps / (self.step_dispatches
                                        * self.pool_blocks)
-        return 1.0 if self.kv_bytes_per_slot else 0.0
+        return 0.0
 
     @property
     def dispatches(self) -> int:
@@ -373,6 +366,11 @@ class SlotEngine:
     compiles stay one-per-label under the guard.
     """
 
+    # read by tests/benchmark/test_benchmark_spans.py (a yardstick file no
+    # PR but a `benchmark` one may edit) and by nothing in fira_tpu/: the
+    # arena IS paged. Goes with that reader (ROADMAP D14).
+    _paged = True
+
     def __init__(self, model, params, cfg: FiraConfig, *,
                  slots: Optional[int] = None, guard=None,
                  device=None, tag: Optional[str] = None,
@@ -424,26 +422,23 @@ class SlotEngine:
         # paged KV arena geometry (decode/paging.py). ``pool_blocks`` is
         # THIS engine's pool (a fleet replica's per-chip share); None
         # falls back to cfg.kv_pool_blocks, 0 to the full-residency auto
-        # size (slots x table width — scheduling identical to unpaged).
-        self._paged = bool(cfg.beam_kv_cache and cfg.engine_paged_kv)
-        self._block_size = self._table_width = self._pool_blocks = 0
+        # size (slots x table width — admission never waits for blocks).
         self._kv_bytes_per_slot = 0
-        if self._paged:
-            self._block_size = paging.resolve_block_size(cfg)
-            if cfg.tar_len % self._block_size:
-                raise ValueError(
-                    f"kv_block_size {self._block_size} does not divide "
-                    f"tar_len {cfg.tar_len}; the block table must tile "
-                    f"the arena budget exactly (decode/paging.py)")
-            self._table_width = cfg.tar_len // self._block_size
-            self._pool_blocks = int(
-                pool_blocks if pool_blocks is not None
-                else cfg.kv_pool_blocks) or self.slots * self._table_width
-            if self._pool_blocks < self._table_width:
-                raise ValueError(
-                    f"kv_pool_blocks {self._pool_blocks} < table width "
-                    f"{self._table_width}: one full-tar sample must fit "
-                    f"an empty pool or admission livelocks")
+        self._block_size = paging.resolve_block_size(cfg)
+        if cfg.tar_len % self._block_size:
+            raise ValueError(
+                f"kv_block_size {self._block_size} does not divide "
+                f"tar_len {cfg.tar_len}; the block table must tile "
+                f"the arena budget exactly (decode/paging.py)")
+        self._table_width = cfg.tar_len // self._block_size
+        self._pool_blocks = int(
+            pool_blocks if pool_blocks is not None
+            else cfg.kv_pool_blocks) or self.slots * self._table_width
+        if self._pool_blocks < self._table_width:
+            raise ValueError(
+                f"kv_pool_blocks {self._pool_blocks} < table width "
+                f"{self._table_width}: one full-tar sample must fit "
+                f"an empty pool or admission livelocks")
         # cross-request prefill cache (decode/prefix_cache.py): one LRU
         # PER ENGINE — a fleet replica's cache is per-chip like its KV
         # arena (cached artifacts re-enter via device_put onto this
@@ -457,8 +452,7 @@ class SlotEngine:
         # leaves, what a step reads and writes, which arena leaves follow
         # the beams — everything below is the model's, nothing named here
         self.smodel = slot_model.for_config(
-            model, cfg, self.slots, self._paged, self._block_size,
-            self._pool_blocks)
+            model, cfg, self.slots, self._block_size, self._pool_blocks)
         self._leaves: Dict[str, slot_model.Leaf] = {}
         self._counters_seen = None
         self.stats = EngineStats(slots=self.slots)
@@ -498,8 +492,7 @@ class SlotEngine:
             # the drafter runs on the same decode-side weight tier as the
             # step it feeds: int8w leaves dequant at the trace top (a
             # no-op identity for f32/bf16 — scales is None)
-            base_draft = spec_lib.make_drafter(model, cfg, self.slots,
-                                               self._paged)
+            base_draft = spec_lib.make_drafter(model, cfg, self.slots)
             self._draft = jax.jit(lambda p, st: base_draft(
                 quant.dequant_tree(p, self._wq_scales), st))
             self._verify = jax.jit(self._verify_fn, donate_argnums=(1,))
@@ -610,9 +603,9 @@ class SlotEngine:
         (S,) bool the spec verify program (decode/spec.py) ANDs into the
         active mask, freezing rows whose drafts already diverged. A frozen
         row is handled by the inactive-row discipline that already exists
-        for idle/done slots — blended state, sentinel-masked paged table —
-        with ONE extra care: the unpaged cache permute below must not
-        scribble a row that will RESUME (see the gated identity blend)."""
+        for idle/done slots — blended state (its ancestry rows among it),
+        sentinel-masked block table — so it RESUMES with its history
+        intact."""
         cfg = self.cfg
         S, K, T = self.slots, cfg.beam_size, cfg.tar_len
         neg = (jnp.float32(-1.0) if cfg.beam_compat_prob_space
@@ -628,16 +621,14 @@ class SlotEngine:
         # garbage by construction and blended away below
         pos_c = jnp.minimum(pos, T - 2)
         all_fin_before = jnp.all(finished, axis=1)   # (S,)
-        # idle and done slots must neither write nor permute the paged
-        # pool: their table rows may still name blocks harvest already
-        # returned to the free list and insert re-granted to ANOTHER slot
-        # — the one aliasing hazard the whole-sequence arena never had.
-        # Masking their rows to the sentinel P turns every such gather
-        # into clamped (blended-away) garbage and every such scatter into
-        # a drop.
-        tab_step = (jnp.where(active[:, None], state["block_tab"],
-                              jnp.int32(self._pool_blocks))
-                    if self._paged else None)
+        # idle and done slots must neither write nor permute the pool:
+        # their table rows may still name blocks harvest already returned
+        # to the free list and insert re-granted to ANOTHER slot. Masking
+        # their rows to the sentinel P turns every such gather into
+        # clamped (blended-away) garbage and every such scatter into a
+        # drop.
+        tab_step = jnp.where(active[:, None], state["block_tab"],
+                             jnp.int32(self._pool_blocks))
         # beam ancestry (a model that declares it): this position goes
         # into each beam's OWN lane of the slot's blocks, so the table the
         # step reads through names lane k at ``pos`` for beam k
@@ -657,43 +648,20 @@ class SlotEngine:
                 self.smodel.select(parts, tokens, probs, finished, pos_c,
                                    state, neg)
         # cached histories follow their beams as the model DECLARED: by
-        # the ancestry table (slot_model ``beam_ancestry``: FIRA's paged
-        # pools are never moved — further down), or leaf by leaf
-        # (slot_model.Leaf.reorder) — pool leaves move block contents
-        # inside each active slot's own grant (A.X-K1's ``lat_pool``);
-        # whole-sequence stripes take exactly the batched beam's gather.
-        # Inactive stripe rows are NOT blended back: a done/idle slot's
-        # cache is never read again — it is not stepped, and a refill
-        # overwrites its rows wholesale — so letting the step scribble on
-        # it saves two full-cache select passes per micro-step.
-        # tokens/probs/finished/pos DO blend below: they must survive
-        # until harvest.
-        #
-        # GATED mode is the one exception: a verify-frozen row RESUMES —
-        # permuting its stripes by this frame's garbage src_beam would
-        # hand the resumed step a shuffled history. Frozen rows get the
-        # identity permutation instead; the plain trace (gate=None) keeps
-        # the cheaper scribble, byte-for-byte as before.
-        reordered = {n: leaf.reorder for n, leaf in self._leaves.items()
-                     if leaf.reorder and n in out_caches}
+        # the ancestry table (slot_model ``beam_ancestry``: FIRA's pools
+        # are never moved — further down), or leaf by leaf
+        # (slot_model.Leaf.reorder): a pool leaf moves block contents
+        # inside each active slot's own grant (A.X-K1's ``lat_pool``; the
+        # sentinel table rows of inactive slots drop).
+        # tokens/probs/finished/pos blend below: they must survive until
+        # harvest.
+        reordered = [n for n, leaf in self._leaves.items()
+                     if leaf.reorder and n in out_caches]
         if reordered:
-            stripe_beam = src_beam
-            if gate is not None and "stripe" in reordered.values():
-                stripe_beam = jnp.where(active[:, None], src_beam,
-                                        jnp.arange(K)[None, :])
-            idx = {}    # one index a (kind, rank), shared by its leaves
             with jax.named_scope("kv_reorder"):
-                for name, how in reordered.items():
-                    c = out_caches[name]
-                    key = (how, c.ndim)
-                    if key not in idx:
-                        idx[key] = slot_model.beam_index(
-                            src_beam if how == "pool" else stripe_beam,
-                            how, c.ndim)
-                    out_caches[name] = (
-                        slot_model.permute_pool(c, tab_step, idx[key])
-                        if how == "pool" else
-                        slot_model.permute_stripes(c, idx[key]))
+                for name in reordered:
+                    out_caches[name] = slot_model.permute_pool(
+                        out_caches[name], tab_step, src_beam)
 
         if ancestry is not None:
             # the pools stay where they were written: what follows the
@@ -729,21 +697,19 @@ class SlotEngine:
         """Scatter chunk rows into slots. ``slot_ids``: (C,) int32, row j
         goes to slot ``slot_ids[j]``; the out-of-range sentinel S marks
         rows NOT consumed by this call (their scatter drops). ``limits``:
-        (C,) int32 per-row tar budget. ``block_rows`` (paged arena only,
-        else None): (C, W) int32 block grants, sentinel-P-padded past the
-        row's reservation. ``fresh`` (None, or an int32 0/1 for a model
-        that counts on the device): 1 on a chunk's first insert.
+        (C,) int32 per-row tar budget. ``block_rows``: (C, W) int32 block
+        grants, sentinel-P-padded past the row's reservation. ``fresh``
+        (None, or an int32 0/1 for a model that counts on the device): 1
+        on a chunk's first insert.
 
-        INVARIANT — no cache zeroing, in EITHER arena. A fresh slot's
-        unwritten cache positions are exactly -1e9-masked by the step's
-        validity rule (beam.step_valid_mask) and exp(-1e9 - m) underflows
-        to 0.0 in the stable softmax dtype, so stale values multiply a
-        hard zero: the whole-sequence arena's old two full-arena zero
-        scatters per refill bought nothing, and the paged arena has
-        nothing to zero at all — freed blocks are simply UNMAPPED.
+        INVARIANT — no cache zeroing. A fresh slot's unwritten cache
+        positions are exactly -1e9-masked by the step's validity rule
+        (beam.step_valid_mask) and exp(-1e9 - m) underflows to 0.0 in the
+        stable softmax dtype, so stale values multiply a hard zero: the
+        arena has nothing to zero — freed blocks are simply UNMAPPED.
         tests/test_paged_kv.py pins this by object identity on the
         k/v buffers through an eager insert AND by bit-exact reuse of a
-        dirty arena, so the zeroing cannot silently reappear."""
+        dirty arena, so a zeroing cannot silently appear."""
         cfg = self.cfg
         K = cfg.beam_size
         C = slot_ids.shape[0]
@@ -765,12 +731,11 @@ class SlotEngine:
         new["limit"] = state["limit"].at[sid].set(
             limits.astype(jnp.int32), mode="drop")
         # the model's own leaves (slot_model): what prefill left for each
-        # seated row; pools and stripes are untouched (INVARIANT above)
+        # seated row; the pools are untouched (INVARIANT above)
         new.update(self.smodel.insert(state, chunk, sid, sid_bk, fresh))
-        if self._paged:
-            # hand the seated rows their block grants
-            new["block_tab"] = state["block_tab"].at[sid].set(
-                block_rows.astype(jnp.int32), mode="drop")
+        # hand the seated rows their block grants
+        new["block_tab"] = state["block_tab"].at[sid].set(
+            block_rows.astype(jnp.int32), mode="drop")
         if self.smodel.beam_ancestry:
             # a fresh slot's beams each start in their own lane
             new["ancestry"] = state["ancestry"].at[sid].set(
@@ -803,9 +768,8 @@ class SlotEngine:
         self._leaves = self.smodel.leaves(chunk)
         for name, leaf in self._leaves.items():
             z[name] = np.zeros(leaf.shape, leaf.dtype)
-        if self._paged:
-            z["block_tab"] = np.full((S, self._table_width),
-                                     self._pool_blocks, np.int32)  # unmapped
+        z["block_tab"] = np.full((S, self._table_width),
+                                 self._pool_blocks, np.int32)  # unmapped
         if self.smodel.beam_ancestry:
             z["ancestry"] = np.broadcast_to(
                 np.arange(K, dtype=np.int32)[None, :, None], (S, K, T)).copy()
@@ -887,8 +851,8 @@ class SlotEngine:
         C = self.smodel.chunk_rows(chunk)
         sentinel_ids = np.full((C,), self.slots, dtype=np.int32)  # all drop
         limits = np.full((C,), self.cfg.tar_len, dtype=np.int32)
-        block_rows = (np.full((C, self._table_width), self._pool_blocks,
-                              dtype=np.int32) if self._paged else None)
+        block_rows = np.full((C, self._table_width), self._pool_blocks,
+                             dtype=np.int32)
         with profiling.span("engine.prewarm.insert"):
             new_state = self._insert(self._state, chunk, sentinel_ids,
                                      limits, block_rows,
@@ -1015,11 +979,6 @@ class SlotEngine:
 
     # --- prefix-cache surface -------------------------------------------
 
-    def _artifact_fields(self) -> Tuple[str, ...]:
-        return ((prefix_cache_lib.ARTIFACT_FIELDS_KV + ("cache_seed",))
-                if self.cfg.beam_kv_cache
-                else prefix_cache_lib.ARTIFACT_FIELDS_NOKV)
-
     def _drain_pending_fills(self) -> None:
         """Materialize deferred miss-fills (the D2H was scheduled async
         at admit) and store each row by its content digest. Runs at the
@@ -1027,7 +986,7 @@ class SlotEngine:
         while self._pending_fills:
             fills, chunk = self._pending_fills.pop(0)
             chunk_host = {}
-            for f in self._artifact_fields():
+            for f in prefix_cache_lib.ARTIFACT_FIELDS:
                 chunk_host[f] = np.asarray(jax.device_get(chunk[f]))  # firacheck: allow[HOST-SYNC] deferred prefill-cache miss-fill draining at the harvest sync boundary; the D2H itself was scheduled async at admit (copy_to_host_async), so this materialization is the designated host copy, not a mid-admission stall
             entries = prefix_cache_lib.extract_payloads(
                 chunk_host, [r for r, _d in fills], self.cfg.beam_size)
@@ -1276,7 +1235,7 @@ class SlotEngine:
                 fills = [(r, digests[r]) for r, _pos in seat_rows
                          if digests[r] is not None]
                 if fills:
-                    for f in self._artifact_fields():
+                    for f in prefix_cache_lib.ARTIFACT_FIELDS:
                         a = chunk[f]
                         if hasattr(a, "copy_to_host_async"):
                             a.copy_to_host_async()
@@ -1327,7 +1286,7 @@ class SlotEngine:
     @profiling.span("engine.refill")
     def refill(self, refill_order: str = "fifo") -> None:
         """Insert staged rows into every free slot (one insert dispatch
-        per staged chunk touched). Paged arena: each seated row is granted
+        per staged chunk touched). Each seated row is granted
         its reservation — ceil(limit / block) blocks — from the free
         list; when the pool cannot cover the HEAD row's reservation the
         refill stops there and waits for harvests to return blocks
@@ -1341,32 +1300,27 @@ class SlotEngine:
             entry = self._staged[0]
 
             def need_of(row: int) -> int:
-                return (paging.blocks_per_seq(entry.limit_of(row),
-                                              self._block_size)
-                        if self._paged else 0)
-            if self._paged and len(self._free_blocks) < need_of(
-                    entry.rows[0][0]):
+                return paging.blocks_per_seq(entry.limit_of(row),
+                                             self._block_size)
+            if len(self._free_blocks) < need_of(entry.rows[0][0]):
                 break  # head-of-line: blocks return at the next harvest
             C = entry.host["valid"].shape[0]
             slot_ids = np.full((C,), self.slots, dtype=np.int32)  # S = drop
             limits = np.full((C,), entry.limit, dtype=np.int32)
-            block_rows = (np.full((C, self._table_width), self._pool_blocks,
-                                  dtype=np.int32)  # P = unmapped sentinel
-                          if self._paged else None)
+            block_rows = np.full((C, self._table_width), self._pool_blocks,
+                                 dtype=np.int32)  # P = unmapped sentinel
             n_ins = 0
-            while not self.retired and self._free and entry.rows and (
-                    not self._paged
-                    or len(self._free_blocks) >= need_of(entry.rows[0][0])):
+            while (not self.retired and self._free and entry.rows
+                   and len(self._free_blocks) >= need_of(entry.rows[0][0])):
                 r, pos_id = entry.rows.popleft()
                 slot = (self._free.popleft() if refill_order == "fifo"
                         else self._free.pop())
                 slot_ids[r] = slot
                 limits[r] = entry.limit_of(r)
-                if self._paged:
-                    need = need_of(r)
-                    grant = self._acquire_blocks(need)
-                    block_rows[r, :need] = grant
-                    self._slot_blocks[slot] = grant
+                need = need_of(r)
+                grant = self._acquire_blocks(need)
+                block_rows[r, :need] = grant
+                self._slot_blocks[slot] = grant
                 self._busy[slot] = (pos_id, entry.host, r)
                 n_ins += 1
             new_state = self._insert(self._state, entry.chunk, slot_ids,
@@ -1443,21 +1397,20 @@ class SlotEngine:
         st.kv_bytes_per_slot = self._kv_bytes_per_slot
         st.kv_dtype = self.cfg.kv_dtype
         st.serve_precision = self.cfg.serve_precision
-        if self._paged:
-            used = self._pool_blocks - len(self._free_blocks)
-            st.block_steps += used
-            st.peak_blocks = max(st.peak_blocks, used)
-            if self._followers or self.shared_positions:
-                # shared blocks: grants whose seat is serving a coalesced
-                # fan-out group — one block set, N requests' worth of
-                # decode (the dedup half of the HBM-reuse story; groups
-                # coalesced by the serve loop arrive via shared_positions)
-                fan = self.shared_positions
-                shared = sum(
-                    len(self._slot_blocks.get(s, ()))
-                    for s, (pid, _h, _r) in self._busy.items()
-                    if pid in self._followers or pid in fan)
-                st.shared_block_peak = max(st.shared_block_peak, shared)
+        used = self._pool_blocks - len(self._free_blocks)
+        st.block_steps += used
+        st.peak_blocks = max(st.peak_blocks, used)
+        if self._followers or self.shared_positions:
+            # shared blocks: grants whose seat is serving a coalesced
+            # fan-out group — one block set, N requests' worth of
+            # decode (the dedup half of the HBM-reuse story; groups
+            # coalesced by the serve loop arrive via shared_positions)
+            fan = self.shared_positions
+            shared = sum(
+                len(self._slot_blocks.get(s, ()))
+                for s, (pid, _h, _r) in self._busy.items()
+                if pid in self._followers or pid in fan)
+            st.shared_block_peak = max(st.shared_block_peak, shared)
 
     @profiling.span("engine.harvest")
     def harvest(self) -> List[EngineItem]:

@@ -1,10 +1,10 @@
 """Paged KV arena: host-side geometry, validation, and byte accounting.
 
-The slot engine's self-attention caches used to be whole-sequence slot
-stripes — every slot owned ``tar_len`` cache positions for its K beams,
-so slot count and target length were coupled through HBM. Under
-``cfg.engine_paged_kv`` (default) the caches live in a FIXED POOL of KV
-blocks addressed through per-slot block tables (vLLM's PagedAttention,
+Whole-sequence slot stripes — every slot owning ``tar_len`` cache
+positions for its K beams — would couple slot count and target length
+through HBM. The slot engine's self-attention caches live in a FIXED
+POOL of KV blocks addressed through per-slot block tables instead
+(vLLM's PagedAttention,
 SOSP '23 — PAPERS.md "Continuous batching / inference serving" — under
 this stack's static-shape discipline: fixed pool size P, fixed table
 width W, gather/scatter by block id). A slot is handed exactly the
@@ -87,8 +87,7 @@ def resolved_slots(cfg: FiraConfig) -> Tuple[int, int]:
 
 def auto_pool_blocks(cfg: FiraConfig, slots: int) -> int:
     """Full-residency default: every slot can hold a full ``tar_len``
-    sequence concurrently — admission never blocks on blocks, so the
-    paged scheduler is step-for-step identical to the unpaged arena."""
+    sequence concurrently — admission never blocks on blocks."""
     return int(slots) * blocks_per_seq(cfg.tar_len, resolve_block_size(cfg))
 
 
@@ -97,6 +96,8 @@ def paging_errors(cfg: FiraConfig) -> List[str]:
     parallel.mesh.divisibility_errors / fleet_divisibility_errors): one
     named-knob message per violation, CLI exit 2. Checks:
 
+    - the whole-sequence arena is not asked for: it is gone, the engine's
+      arena IS the paged pool;
     - ``kv_block_size`` divides every declared decode tar budget (table
       width x block must tile each budget exactly);
     - ``kv_pool_blocks`` splits evenly across ``engine_replicas`` (it is
@@ -106,7 +107,11 @@ def paging_errors(cfg: FiraConfig) -> List[str]:
       pool >= ceil(largest tar / block) — one worst-case sample must
       always fit when the pool is empty, the no-livelock floor.
     """
-    if not (cfg.decode_engine and cfg.beam_kv_cache and cfg.engine_paged_kv):
+    if not cfg.engine_paged_kv:
+        return ["engine_paged_kv False asks for the whole-sequence K/V "
+                "arena, which no longer exists: the slot engine's arena is "
+                "the paged pool (leave the knob at its default, True)"]
+    if not cfg.decode_engine:
         return []
     errs: List[str] = []
     tars = declared_decode_tars(cfg)
@@ -194,25 +199,22 @@ def block_bytes(cfg: FiraConfig, block_size: int, itemsize: int) -> int:
             * int(block_size) * d_head * int(itemsize))
 
 
-def kv_bytes_per_slot(cfg: FiraConfig, *, paged: bool, block_size: int,
+def kv_bytes_per_slot(cfg: FiraConfig, *, block_size: int,
                       pool_blocks: int, slots: int, itemsize: int) -> int:
     """The machine-recorded HBM claim: committed K+V self-attention cache
-    bytes per engine slot. Unpaged: each slot owns a whole-sequence
-    stripe. Paged: the pool is the commitment — its bytes amortize over
-    the slots it serves, which is exactly where the equal-memory
-    slot-count gain (or the longer-tar headroom) shows up."""
-    if paged:
-        return block_bytes(cfg, block_size, itemsize) * int(pool_blocks) \
-            // max(1, int(slots))
-    return block_bytes(cfg, 1, itemsize) * int(cfg.tar_len)
+    bytes per engine slot. The pool is the commitment — its bytes
+    amortize over the slots it serves, which is exactly where the
+    equal-memory slot-count gain (or the longer-tar headroom) shows up."""
+    return block_bytes(cfg, block_size, itemsize) * int(pool_blocks) \
+        // max(1, int(slots))
 
 
 def leaves_kv_bytes_per_slot(leaves, slots: int) -> int:
     """:func:`kv_bytes_per_slot` from the arena's DECLARED leaves
     (decode/slot_model.Leaf): the bytes of every leaf a model marks ``kv``
-    — pools, stripes, a latent cache of whatever width — amortized over
-    the slots they serve. For FIRA's K/V pools and stripes this is the
-    number the per-head formula above gives."""
+    — pools, a latent cache of whatever width — amortized over the slots
+    they serve. For FIRA's K/V pools this is the number the per-head
+    formula above gives."""
     import numpy as np
 
     total = sum(int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize

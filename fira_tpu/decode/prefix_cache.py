@@ -40,8 +40,7 @@ that lever under this stack's architecture (docs/DECODE_ENGINE.md
 Equivalence contract: a cache-hit seat decodes from BIT-identical
 artifact values (``device_put(device_get(x))`` round-trips exactly), so
 its (tokens, probs) — hence its output bytes — equal the cold run's
-(tests/test_prefix_cache.py, all four kv-cache x factored-topk modes,
-paged and unpaged).
+(tests/test_prefix_cache.py).
 """
 
 from __future__ import annotations
@@ -57,11 +56,10 @@ import numpy as np
 # (salted per process), always a keyed blake2b over explicit bytes
 _DIGEST_KEY = b"fira-prefix-cache-v1"
 
-# the per-row prefill artifact fields, by engine mode (the chunk keys of
-# decode/engine.SlotEngine._prefill_fn minus the scalar dtype marker)
-ARTIFACT_FIELDS_KV = ("src_mask", "diff", "sub_token",
-                      "cross_k", "cross_v", "src_proj")
-ARTIFACT_FIELDS_NOKV = ("src_mask", "diff", "sub_token", "states")
+# the prefill artifact fields: the chunk keys of FIRA's prefill
+# (decode/slot_model.FiraSlotModel.prefill), the scalar dtype marker last
+ARTIFACT_FIELDS = ("src_mask", "diff", "sub_token",
+                   "cross_k", "cross_v", "src_proj", "cache_seed")
 
 
 def _digest_arrays(items: Iterable[Tuple[str, np.ndarray]],
@@ -134,33 +132,27 @@ def extract_payloads(chunk_host: Dict[str, np.ndarray], rows: List[int],
                      beam: int) -> Dict[int, Dict[str, np.ndarray]]:
     """Slice one prefilled chunk's HOST copy into per-row cache payloads.
     Row r owns beam lanes ``r*K..(r+1)*K`` of the K-repeated arrays
-    (cross_k/cross_v on axis 1, src_proj/states on axis 0) — and those K
+    (cross_k/cross_v on axis 1, src_proj on axis 0) — and those K
     lanes are byte-identical by construction (the prefill's
     ``jnp.repeat``), so the payload stores ONE lane and :func:`build_chunk`
     re-repeats it: 1/K the host RAM, hashing, and byte-budget charge for
     a bit-identical rebuild. ``seed`` records the cache-seed dtype so a
     rebuilt chunk reproduces the prefill pytree exactly."""
     K = int(beam)
-    kv = "cross_k" in chunk_host
     out: Dict[int, Dict[str, np.ndarray]] = {}
     for r in rows:
-        p: Dict[str, np.ndarray] = {
+        out[r] = {
             "src_mask": np.ascontiguousarray(chunk_host["src_mask"][r]),
             "diff": np.ascontiguousarray(chunk_host["diff"][r]),
             "sub_token": np.ascontiguousarray(chunk_host["sub_token"][r]),
+            "cross_k": np.ascontiguousarray(
+                chunk_host["cross_k"][:, r * K:r * K + 1]),
+            "cross_v": np.ascontiguousarray(
+                chunk_host["cross_v"][:, r * K:r * K + 1]),
+            "src_proj": np.ascontiguousarray(
+                chunk_host["src_proj"][r * K:r * K + 1]),
+            "seed": np.zeros((), chunk_host["cache_seed"].dtype),
         }
-        if kv:
-            p["cross_k"] = np.ascontiguousarray(
-                chunk_host["cross_k"][:, r * K:r * K + 1])
-            p["cross_v"] = np.ascontiguousarray(
-                chunk_host["cross_v"][:, r * K:r * K + 1])
-            p["src_proj"] = np.ascontiguousarray(
-                chunk_host["src_proj"][r * K:r * K + 1])
-            p["seed"] = np.zeros((), chunk_host["cache_seed"].dtype)
-        else:
-            p["states"] = np.ascontiguousarray(
-                chunk_host["states"][r * K:r * K + 1])
-        out[r] = p
     return out
 
 
@@ -174,37 +166,28 @@ def build_chunk(payloads: Dict[int, Dict[str, np.ndarray]], batch_rows: int,
     drops them via the sentinel slot id, so their values are never read."""
     C, K = int(batch_rows), int(beam)
     any_p = next(iter(payloads.values()))
-    kv = "cross_k" in any_p
     out: Dict[str, np.ndarray] = {}
     for f in ("src_mask", "diff", "sub_token"):
         a = any_p[f]
         out[f] = np.zeros((C,) + a.shape, a.dtype)
-    if kv:
-        ck = any_p["cross_k"]          # (L, 1, ...) — one stored lane
-        L = ck.shape[0]
-        for f in ("cross_k", "cross_v"):
-            out[f] = np.zeros((L, C * K) + ck.shape[2:], ck.dtype)
-        sp = any_p["src_proj"]         # (1, ...)
-        out["src_proj"] = np.zeros((C * K,) + sp.shape[1:], sp.dtype)
-        out["cache_seed"] = np.zeros((), any_p["seed"].dtype)
-    else:
-        st = any_p["states"]           # (1, ...)
-        out["states"] = np.zeros((C * K,) + st.shape[1:], st.dtype)
+    ck = any_p["cross_k"]          # (L, 1, ...) — one stored lane
+    L = ck.shape[0]
+    for f in ("cross_k", "cross_v"):
+        out[f] = np.zeros((L, C * K) + ck.shape[2:], ck.dtype)
+    sp = any_p["src_proj"]         # (1, ...)
+    out["src_proj"] = np.zeros((C * K,) + sp.shape[1:], sp.dtype)
+    out["cache_seed"] = np.zeros((), any_p["seed"].dtype)
     for r, p in payloads.items():
         for f in ("src_mask", "diff", "sub_token"):
             out[f][r] = p[f]
         # re-repeat the single stored lane across the K beam slots —
         # bitwise what the prefill's jnp.repeat produced
-        if kv:
-            out["cross_k"][:, r * K:(r + 1) * K] = np.repeat(
-                p["cross_k"], K, axis=1)
-            out["cross_v"][:, r * K:(r + 1) * K] = np.repeat(
-                p["cross_v"], K, axis=1)
-            out["src_proj"][r * K:(r + 1) * K] = np.repeat(
-                p["src_proj"], K, axis=0)
-        else:
-            out["states"][r * K:(r + 1) * K] = np.repeat(
-                p["states"], K, axis=0)
+        out["cross_k"][:, r * K:(r + 1) * K] = np.repeat(
+            p["cross_k"], K, axis=1)
+        out["cross_v"][:, r * K:(r + 1) * K] = np.repeat(
+            p["cross_v"], K, axis=1)
+        out["src_proj"][r * K:(r + 1) * K] = np.repeat(
+            p["src_proj"], K, axis=0)
     return out
 
 

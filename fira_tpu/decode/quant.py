@@ -5,12 +5,12 @@ Two independent knobs, f32 staying the default CONTRACT path (labels,
 digests, and output bytes unchanged when both are "f32"):
 
 - ``cfg.kv_dtype`` ("f32" | "bf16") — storage dtype of the decode
-  self-attention K/V arena: the paged pool's blocks AND the unpaged
-  comparator stripes. The prefill program emits a ``cache_seed`` of this
-  dtype (:func:`kv_seed_dtype`), so the engine's arena allocation and its
-  ``kv_bytes_per_slot`` accounting follow automatically; writes cast on
-  append (model/layers.append_block_kv, the dense ``.at[].set`` sites) and
-  reads upcast on gather, so the attention math itself stays in the
+  self-attention K/V arena, the paged pool's blocks. The prefill
+  program emits a ``cache_seed`` of this dtype (:func:`kv_seed_dtype`),
+  so the engine's arena allocation and its ``kv_bytes_per_slot``
+  accounting follow automatically; writes cast on append
+  (model/layers.append_block_kv) and reads upcast on gather
+  (``gather_block_kv``), so the attention math itself stays in the
   compute dtype. Cross-attention K/V and the copy-head source projection
   are request-lifetime activations, not the per-step arena — they stay
   f32.

@@ -5,14 +5,17 @@ one prediction per line to OUTPUT/output_fira (ablations write their own
 suffixed files, matching OUTPUT/output_fira_{no_edit,no_subtoken,nothing}).
 
 Two decode paths, selected by ``cfg.decode_engine`` (CLI ``--engine``;
-bit-exact per sample — docs/DECODE_ENGINE.md):
+token-exact per sample — docs/DECODE_ENGINE.md):
 
-- **batched beam** (default): one beam program dispatch per packed batch;
-  with ``beam_early_exit`` the dispatch still runs until the batch's
-  LONGEST message settles.
+- **batched beam** (default): one beam program dispatch per packed batch,
+  in the form ``beam_kv_cache`` / ``beam_factored_topk`` choose; with
+  ``beam_early_exit`` the dispatch still runs until the batch's LONGEST
+  message settles.
 - **slot-refill engine** (decode/engine.py): S static slots advanced one
   token per step, settled slots harvested and refilled mid-flight from
   the same packer stream — wall clock scales with total tokens emitted.
+  ONE form (cached, paged, selecting from the factors), whatever the
+  batched beam's knobs say.
   With ``cfg.engine_replicas > 1`` the engine becomes a replicated FLEET
   (parallel/fleet.py): N engines on N devices pull from one shared
   admission queue; decoded file bytes are invariant to the replica count.

@@ -9,12 +9,12 @@ bound by name in the engine any more:
   requests leaves behind for its slots (a dict of device arrays, rows along
   each leaf's request axis). Traced inside the engine's ``_prefill_fn``.
 - ``leaves(chunk) -> {name: Leaf}``: the arena's model-owned leaves, each
-  with shape, dtype and whether it is PER BEAM and reordered with
-  ``src_beam`` after every selection (``"pool"`` for a paged block pool,
-  ``"stripe"`` for whole-sequence rows) or not (``reorder=None``: shared
-  by a slot's beams, or per beam LANE and read through the ancestry
-  table). ``kv`` marks what ``kv_bytes_per_slot`` counts
-  (decode/paging.py follows these declarations).
+  with shape, dtype and whether it is a per-beam block pool whose
+  contents the engine moves with ``src_beam`` after every selection
+  (``reorder="pool"``) or not (``reorder=None``: shared by a slot's
+  beams, or per beam LANE and read through the ancestry table). ``kv``
+  marks what ``kv_bytes_per_slot`` counts (decode/paging.py follows
+  these declarations).
 - ``insert(state, chunk, sid, sid_bk, fresh) -> {name: leaf}``:
   scatter chunk rows into slots ``sid`` (sentinel = dropped).
 - ``step(params, state, view) -> (parts, writes)``: one position for every
@@ -36,11 +36,11 @@ bound by name in the engine any more:
   (``beam._select_factored``); a model with one log-softmax goes through
   ``beam._select``.
 
-:class:`FiraSlotModel` is the first implementation and wraps the calls the
-engine made before the seam AS THEY WERE (the three ``*_step{,_multi,
-_paged}`` variants stay; collapsing them is D3's own PR): the lowered
-programs of the FIRA configurations are unchanged. :class:`LMSlotModel`
-(``arch="axk1"``, model/axk1.py) is the second.
+:class:`FiraSlotModel` is the first implementation: ONE arena (the paged
+pools, written once and followed by ancestry), one step
+(``FiraModel.dist_parts_step_paged``) and one selection
+(``beam._select_factored``), whatever the batched beam's knobs say.
+:class:`LMSlotModel` (``arch="axk1"``, model/axk1.py) is the second.
 """
 
 from __future__ import annotations
@@ -62,9 +62,9 @@ class Leaf:
 
     shape: Tuple[int, ...]
     dtype: np.dtype
-    reorder: Optional[str] = None   # "pool" | "stripe": per beam, moved by
-    #                                 src_beam; None: never moved (shared
-    #                                 by the beams, or per beam lane)
+    reorder: Optional[str] = None   # "pool": per beam, block contents
+    #                                 moved by src_beam; None: never moved
+    #                                 (shared by the beams, or per lane)
     kv: bool = False                # counted by kv_bytes_per_slot
 
 
@@ -75,28 +75,19 @@ class StepView(NamedTuple):
     pos_c: jnp.ndarray      # (S,) position of each slot, clamped legal
     pos_bk: jnp.ndarray     # (S*K,) the same, a row
     active: jnp.ndarray     # (S,) live and not done (and not gated off)
-    tab_step: Optional[jnp.ndarray]  # (S, W) block table, the sentinel in
-    #                                  rows that must not read or write
+    tab_step: jnp.ndarray   # (S, W) block table, the sentinel in rows
+    #                         that must not read or write
     ancestry: Optional[jnp.ndarray] = None  # (S, K, T) beam lane holding
     #                                  position t of beam k's history, this
     #                                  position already each beam's own lane
     #                                  (a model with ``beam_ancestry`` only)
 
 
-def beam_index(src_beam, reorder: str, ndim: int):
-    """``src_beam`` (S, K) shaped to gather the beam axis of a leaf of
-    rank ``ndim``: (1, S, 1, K, 1...) over a pool's gathered blocks
-    (L, S, W, K, ...), (1, S, K, 1...) over stripes seen as (L, S, K, ...)."""
-    lead = ((None, slice(None), None, slice(None)) if reorder == "pool"
-            else (None, slice(None), slice(None)))
-    return src_beam[lead + (None,) * (ndim + 1 - len(lead))]
-
-
-def permute_pool(pool, tab_step, idx):
+def permute_pool(pool, tab_step, src_beam):
     """Move block CONTENTS within each active slot's own block set so that
     cached histories follow their beams (table entries stay put: a slot's
     grant is host-owned from insert to harvest). pool: (L, P, K, ...);
-    ``idx``: :func:`beam_index`. Scatter targets are disjoint across slots
+    ``src_beam``: (S, K). Scatter targets are disjoint across slots
     because grants never overlap; sentinel rows (idle/done) drop.
 
     Three passes over every block of every active slot, every position:
@@ -104,47 +95,39 @@ def permute_pool(pool, tab_step, idx):
     ``lat_pool`` (0.10 GB, no such op among its step's longest). FIRA's
     pools are written once and never moved: its beams follow by the
     engine's ancestry table (``beam_ancestry``)."""
+    # (1, S, 1, K, 1...): the beam axis of the gathered blocks
+    idx = src_beam[(None, slice(None), None, slice(None))
+                   + (None,) * (pool.ndim - 3)]
     blocks = pool[:, tab_step]           # (L, S, W, K, ...)
     blocks = jnp.take_along_axis(blocks, idx, axis=3)
     return pool.at[:, tab_step].set(blocks, mode="drop")
 
 
-def permute_stripes(cache, idx):
-    """The unpaged twin: (L, S*K, ...) whole-sequence rows gathered by
-    ``src_beam`` (exactly the batched beam's gather)."""
-    S, K = idx.shape[1:3]
-    L, rest = cache.shape[0], cache.shape[2:]
-    c = cache.reshape((L, S, K) + rest)
-    c = jnp.take_along_axis(c, idx, axis=2)
-    return c.reshape((L, S * K) + rest)
-
-
 class FiraSlotModel:
     """FIRA behind the seam: encoder prefill, per-beam cross K/V and copy
-    projection, the decoder step in its three arena forms."""
+    projection, the decoder step over the paged pools, the selection from
+    the distribution's factors."""
 
     insert_by_geometry = False
     arena_counters: Tuple[str, ...] = ()
     prefill_budget = 0      # a prefill is cheap beside a step: unpaced
+    # the pools are written once, each beam into its own lane, and never
+    # moved: the engine keeps which lane holds each position of each
+    # beam's history and the step reads through that table
+    beam_ancestry = True
 
-    def __init__(self, model, cfg: FiraConfig, slots: int, paged: bool,
+    def __init__(self, model, cfg: FiraConfig, slots: int,
                  block_size: int, pool_blocks: int):
         self.model, self.cfg, self.slots = model, cfg, slots
-        self.paged = paged
-        # the paged pools are written once, each beam into its own lane,
-        # and never moved: the engine keeps which lane holds each position
-        # of each beam's history and the step reads through that table
-        self.beam_ancestry = paged
         self.block_size, self.pool_blocks = block_size, pool_blocks
 
     def chunk_rows(self, chunk) -> int:
         return int(chunk["diff"].shape[0])
 
     def prefill(self, params, batch):
-        """Per-batch preamble of the batched beam, verbatim: encode once,
-        then (kv mode) per-layer cross K/V + copy-head source projection
-        replicated per beam, or (full-redecode mode) the per-beam encoder
-        states themselves. Identical program prefix => identical values."""
+        """Per-batch preamble of the cached batched beam, verbatim: encode
+        once, then per-layer cross K/V + copy-head source projection
+        replicated per beam. Identical program prefix => identical values."""
         from fira_tpu.model.model import FiraModel
 
         cfg, model = self.cfg, self.model
@@ -153,141 +136,86 @@ class FiraSlotModel:
                                    method=FiraModel.encode)
         out = {"src_mask": mask, "diff": batch["diff"],
                "sub_token": batch["sub_token"]}
-        if cfg.beam_kv_cache:
-            cross_k, cross_v, src_proj = model.apply(
-                {"params": params}, states, method=FiraModel.decode_init)
-            out["cross_k"] = jnp.repeat(cross_k, K, axis=1)
-            out["cross_v"] = jnp.repeat(cross_v, K, axis=1)
-            out["src_proj"] = jnp.repeat(src_proj, K, axis=0)
-            # dtype marker only: fresh slots seed their self-attention
-            # cache at zeros of the ENCODER STATE dtype, exactly like the
-            # batched beam's cache0 (which may be wider than the compute
-            # dtype under stable_residual) — unless the low-precision KV
-            # tier pins the arena narrower (cfg.kv_dtype="bf16",
-            # decode/quant.py): the arena allocates the pools/stripes
-            # at this dtype and the HBM accounting follows it
-            out["cache_seed"] = jnp.zeros(
-                (), quant.kv_seed_dtype(cfg, states.dtype))
-        else:
-            out["states"] = jnp.repeat(states, K, axis=0)
+        cross_k, cross_v, src_proj = model.apply(
+            {"params": params}, states, method=FiraModel.decode_init)
+        out["cross_k"] = jnp.repeat(cross_k, K, axis=1)
+        out["cross_v"] = jnp.repeat(cross_v, K, axis=1)
+        out["src_proj"] = jnp.repeat(src_proj, K, axis=0)
+        # dtype marker only: fresh slots seed their self-attention cache
+        # at the ENCODER STATE dtype, exactly like the batched beam's
+        # cache0 (which may be wider than the compute dtype under
+        # stable_residual) — unless the low-precision KV tier pins the
+        # arena narrower (cfg.kv_dtype="bf16", decode/quant.py): the
+        # arena allocates the pools at this dtype and the HBM accounting
+        # follows it
+        out["cache_seed"] = jnp.zeros(
+            (), quant.kv_seed_dtype(cfg, states.dtype))
         return out
 
     def leaves(self, chunk) -> Dict[str, Leaf]:
         cfg = self.cfg
-        S, K, T = self.slots, cfg.beam_size, cfg.tar_len
+        S, K = self.slots, cfg.beam_size
         L, H = cfg.num_layers, cfg.num_head
         d_head = cfg.embedding_dim // H
-        out = {
+        ck, sp = chunk["cross_k"], chunk["src_proj"]
+        cd = chunk["cache_seed"].dtype
+        P, BS = self.pool_blocks, self.block_size
+        return {
             "diff": Leaf((S,) + chunk["diff"].shape[1:],
                          chunk["diff"].dtype),
             "sub_token": Leaf((S,) + chunk["sub_token"].shape[1:],
                               chunk["sub_token"].dtype),
             "src_mask": Leaf((S,) + chunk["src_mask"].shape[1:],
                              np.dtype(bool)),
+            "cross_k": Leaf((L, S * K) + ck.shape[2:], ck.dtype),
+            "cross_v": Leaf((L, S * K) + ck.shape[2:], ck.dtype),
+            "src_proj": Leaf((S * K,) + sp.shape[1:], sp.dtype),
+            # per beam LANE, not per beam: no reorder (beam_ancestry)
+            "k_pool": Leaf((L, P, K, H, BS, d_head), cd, kv=True),
+            "v_pool": Leaf((L, P, K, H, BS, d_head), cd, kv=True),
         }
-        if cfg.beam_kv_cache:
-            ck = chunk["cross_k"]
-            out["cross_k"] = Leaf((L, S * K) + ck.shape[2:], ck.dtype)
-            out["cross_v"] = Leaf((L, S * K) + ck.shape[2:], ck.dtype)
-            sp = chunk["src_proj"]
-            out["src_proj"] = Leaf((S * K,) + sp.shape[1:], sp.dtype)
-            cd = chunk["cache_seed"].dtype
-            if self.paged:
-                P, BS = self.pool_blocks, self.block_size
-                # per beam LANE, not per beam: no reorder (beam_ancestry)
-                out["k_pool"] = Leaf((L, P, K, H, BS, d_head), cd, kv=True)
-                out["v_pool"] = Leaf((L, P, K, H, BS, d_head), cd, kv=True)
-            else:
-                out["k_cache"] = Leaf((L, S * K, H, T, d_head), cd,
-                                      reorder="stripe", kv=True)
-                out["v_cache"] = Leaf((L, S * K, H, T, d_head), cd,
-                                      reorder="stripe", kv=True)
-        else:
-            st = chunk["states"]
-            out["states"] = Leaf((S * K,) + st.shape[1:], st.dtype)
-        return out
 
     def insert(self, state, chunk, sid, sid_bk, fresh) -> Dict:
-        """No cache zeroing, in either arena (the engine's INVARIANT):
-        k/v pools and stripes are untouched here."""
+        """No cache zeroing (the engine's INVARIANT): the pools are
+        untouched here."""
         new = {}
         for f in ("diff", "sub_token", "src_mask"):
             new[f] = state[f].at[sid].set(chunk[f], mode="drop")
-        if self.cfg.beam_kv_cache:
-            for f in ("cross_k", "cross_v"):
-                new[f] = state[f].at[:, sid_bk].set(chunk[f], mode="drop")
-            new["src_proj"] = state["src_proj"].at[sid_bk].set(
-                chunk["src_proj"], mode="drop")
-        else:
-            new["states"] = state["states"].at[sid_bk].set(
-                chunk["states"], mode="drop")
+        for f in ("cross_k", "cross_v"):
+            new[f] = state[f].at[:, sid_bk].set(chunk[f], mode="drop")
+        new["src_proj"] = state["src_proj"].at[sid_bk].set(
+            chunk["src_proj"], mode="drop")
         return new
 
     def step(self, params, state, view: StepView):
         from fira_tpu.model.model import FiraModel
 
-        cfg, model = self.cfg, self.model
-        T = cfg.tar_len
+        cfg = self.cfg
         flat, pos_bk = view.flat, view.pos_bk
         mask_k = jnp.repeat(state["src_mask"], cfg.beam_size, axis=0)
-        if not cfg.beam_kv_cache:
-            tar_mask = (flat != 0).at[:, 0].set(True)
-
-            def at_pos(a):  # row b's own position out of the full-prefix decode
-                return jnp.take_along_axis(
-                    a, pos_bk[:, None, None], axis=1)[:, 0, :]
-
-            if cfg.beam_factored_topk:
-                gen, copy, gate = model.apply(
-                    {"params": params}, state["states"], mask_k, flat,
-                    tar_mask, method=FiraModel.dist_parts)
-                return (at_pos(gen), at_pos(copy), at_pos(gate)), {}
-            fused = model.apply(
-                {"params": params}, state["states"], mask_k, flat,
-                tar_mask, method=FiraModel.fused_probs)
-            return (at_pos(fused),), {}
         # same per-row validity rule as beam_search_cached, at the
         # per-slot position vector (beam.step_valid_mask) — this mask
         # is also what makes unwritten/stale POOL blocks read as an
         # exact 0.0 contribution, so fresh slots need no zeroed cache
-        valid = step_valid_mask(flat, pos_bk, T)
+        valid = step_valid_mask(flat, pos_bk, cfg.tar_len)
         tok_in = jnp.take_along_axis(flat, pos_bk[:, None], axis=1)
-        if self.paged:
-            caches = ("k_pool", "v_pool")
-            args = (state["k_pool"], state["v_pool"], view.tab_step,
-                    view.ancestry)
-            methods = (FiraModel.dist_parts_step_paged,
-                       FiraModel.fused_probs_step_paged)
-        else:
-            caches = ("k_cache", "v_cache")
-            args = (state["k_cache"], state["v_cache"])
-            methods = (FiraModel.dist_parts_step_multi,
-                       FiraModel.fused_probs_step_multi)
-        if cfg.beam_factored_topk:
-            gen, copy, gate, k_new, v_new = model.apply(
-                {"params": params}, mask_k, tok_in, pos_bk, *args,
-                state["cross_k"], state["cross_v"], state["src_proj"],
-                valid[:, None, None, :], method=methods[0])
-            parts = (gen[:, 0, :], copy[:, 0, :], gate[:, 0, :])
-        else:
-            fused, k_new, v_new = model.apply(
-                {"params": params}, mask_k, tok_in, pos_bk, *args,
-                state["cross_k"], state["cross_v"], state["src_proj"],
-                valid[:, None, None, :], method=methods[1])
-            parts = (fused[:, 0, :],)
-        return parts, {caches[0]: k_new, caches[1]: v_new}
+        gen, copy, gate, k_new, v_new = self.model.apply(
+            {"params": params}, mask_k, tok_in, pos_bk, state["k_pool"],
+            state["v_pool"], view.tab_step, view.ancestry,
+            state["cross_k"], state["cross_v"], state["src_proj"],
+            valid[:, None, None, :],
+            method=FiraModel.dist_parts_step_paged)
+        parts = (gen[:, 0, :], copy[:, 0, :], gate[:, 0, :])
+        return parts, {"k_pool": k_new, "v_pool": v_new}
 
     def select(self, parts, tokens, probs, finished, pos_c, state, neg):
         S, K = probs.shape
-        slot_src = {"diff": state["diff"], "sub_token": state["sub_token"]}
-        if self.cfg.beam_factored_topk:
-            gen, copy, gate = parts
-            return _select_factored(
-                gen.reshape(S, K, -1), copy.reshape(S, K, -1),
-                gate.reshape(S, K, 2), tokens, probs, finished, pos_c,
-                slot_src, self.cfg, neg)
-        return _select(parts[0].reshape(S, K, -1), tokens, probs, finished,
-                       pos_c, slot_src, self.cfg, neg)
+        gen, copy, gate = parts
+        return _select_factored(
+            gen.reshape(S, K, -1), copy.reshape(S, K, -1),
+            gate.reshape(S, K, 2), tokens, probs, finished, pos_c,
+            {"diff": state["diff"], "sub_token": state["sub_token"]},
+            self.cfg, neg)
 
 
 class LMSlotModel:
@@ -369,8 +297,8 @@ class LMSlotModel:
                        self.cfg, neg, log_input=True)
 
 
-def for_config(model, cfg: FiraConfig, slots: int, paged: bool,
-               block_size: int, pool_blocks: int):
+def for_config(model, cfg: FiraConfig, slots: int, block_size: int,
+               pool_blocks: int):
     if cfg.arch == "axk1":
         return LMSlotModel(cfg, slots, block_size, pool_blocks)
-    return FiraSlotModel(model, cfg, slots, paged, block_size, pool_blocks)
+    return FiraSlotModel(model, cfg, slots, block_size, pool_blocks)

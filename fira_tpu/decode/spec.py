@@ -22,14 +22,11 @@ early-exit predicate). Every position the verify advances therefore ran the
 exact step math the plain engine would have run, and every position it did
 NOT advance is simply run later by a subsequent dispatch — so tokens, probs,
 and file bytes are invariant to ``k``, the acceptance pattern, the harvest
-cadence, and the replica count (tests/test_spec.py pins all of it, in all
-four kv x factored modes, paged and unpaged). "Rollback" of rejected tails
-is free: a frozen row's state is blended to its old values (the plain
-step's own inactive-row discipline), its paged block table is
-sentinel-masked (no append, no permute), and its unpaged cache rows are
-identity-permuted (see the gated branch in engine._one_step) — the one
-place the plain step's scribble-on-inactive-rows shortcut would corrupt a
-row that RESUMES.
+cadence, and the replica count (tests/test_spec.py pins all of it).
+"Rollback" of rejected tails is free: a frozen row's state — its ancestry
+rows among it — is blended to its old values (the plain step's own
+inactive-row discipline) and its block table is sentinel-masked (no
+append), so the row RESUMES with its history intact.
 
 Drafter tiers (cfg.spec_decode):
 
@@ -40,10 +37,11 @@ Drafter tiers (cfg.spec_decode):
   dispatch. Rides FIRA's measured verbatim-copy fraction.
 - ``draft``: a greedy argmax roll of the existing cached step program on
   each slot's TOP BEAM only — 1/beam of the step's decoder rows, against
-  scratch copies of the beam-0 caches (paged mode gathers beam 0's history
-  dense via layers.gather_block_kv_beam, lane by lane through the engine's
-  ancestry table; the real pool/arena is never written by a drafter).
-  Costlier, higher acceptance on generated spans.
+  a dense scratch view of beam 0's history (layers.gather_block_kv_beam,
+  lane by lane through the engine's ancestry table; the real pool is
+  never written by a drafter), stepped by the dense per-row
+  ``FiraModel.fused_probs_step_multi``. Costlier, higher acceptance on
+  generated spans.
 
 Both tiers emit RESOLVED vocab ids (beam._resolve_copy — the same id space
 the beam stores at extension time), so drafted-vs-emitted comparison is a
@@ -56,9 +54,8 @@ step/insert/harvest programs (replica tags compose: ``engine_verify[k4.r1]``)
 — zero post-warmup retraces with spec armed.
 
 Low-precision serving tiers (decode/quant.py) compose with NO code here:
-the drafter's scratch caches inherit the arena's storage dtype (the unpaged
-beam-0 slice stays bf16 and decode_step_multi's read-upcast rule handles
-it; the paged gather_block_kv_beam upcasts at the gather), and the engine
+the drafter's scratch caches come off the pool through
+gather_block_kv_beam, which upcasts at the gather, and the engine
 wraps the drafter so the int8w weight tier dequantizes at the draft trace
 top exactly like the step/verify programs. Draft math under a tier is
 acceptance-only — the verify body is still the engine's own step program on
@@ -157,15 +154,14 @@ def copy_biased_params(params, delta: float = 6.0,
     return {**params, "copy_net": new_cn}
 
 
-def make_drafter(model: FiraModel, cfg: FiraConfig, slots: int, paged: bool):
+def make_drafter(model: FiraModel, cfg: FiraConfig, slots: int):
     """Build the (params, state) -> (S, k) int32 drafter for this engine's
     tier/geometry. Pure function of the engine state — drafters never write
     real state (the scratch caches of the ``draft`` tier live and die in
     the scan carry), so the engine jits the result WITHOUT donation and the
     verify that follows donates the untouched arena as usual."""
     K, T = cfg.beam_size, cfg.tar_len
-    L, H = cfg.num_layers, cfg.num_head
-    d_head = cfg.embedding_dim // H
+    L = cfg.num_layers
     V = cfg.vocab_size
     k = int(cfg.engine_spec_k)
     tier = cfg.spec_decode
@@ -187,15 +183,7 @@ def make_drafter(model: FiraModel, cfg: FiraConfig, slots: int, paged: bool):
     if tier == "copy":
 
         def drafter(params, state):
-            if cfg.beam_kv_cache:
-                src_proj0 = state["src_proj"][0::K]  # beam-0 cached rows
-            else:
-                # the no-KV arena holds raw encoder states, not decode_init
-                # artifacts: project the beam-0 rows here (one matmul —
-                # still no decoder stack)
-                src_proj0 = model.apply(
-                    {"params": params}, state["states"][0::K],
-                    method=lambda m, s: m.copy_net.project_src(s))
+            src_proj0 = state["src_proj"][0::K]  # beam-0 cached rows
             mask = state["src_mask"]
 
             def body(flat0, tok0, pos0):
@@ -220,48 +208,21 @@ def make_drafter(model: FiraModel, cfg: FiraConfig, slots: int, paged: bool):
 
     def drafter(params, state):
         mask = state["src_mask"]
-        if not cfg.beam_kv_cache:
-            states0 = state["states"][0::K]
-
-            def body(flat0, tok0, pos0):
-                def step(carry, _):
-                    flat, p = carry
-                    tar_mask = (flat != 0).at[:, 0].set(True)
-                    fused = model.apply(
-                        {"params": params}, states0, mask, flat, tar_mask,
-                        method=FiraModel.fused_probs)
-                    at_p = jnp.take_along_axis(
-                        fused, p[:, None, None], axis=1)[:, 0, :]
-                    nxt = resolve(
-                        jnp.argmax(at_p, axis=-1).astype(jnp.int32), state)
-                    p2 = jnp.minimum(p + 1, T - 2)
-                    return (scatter_token(flat, p2, nxt), p2), nxt
-
-                _, drafts = jax.lax.scan(
-                    step, (flat0, pos0), None, length=k)
-                return drafts.T
-
-            return roll(state, body)
-
         cross_k0 = state["cross_k"][:, 0::K]
         cross_v0 = state["cross_v"][:, 0::K]
         src_proj0 = state["src_proj"][0::K]
-        if paged:
-            # dense SCRATCH view of each slot's top beam: the pool is read
-            # once per draft and never written (sentinel table rows of
-            # idle/done slots clamp to garbage the validity mask zeroes).
-            # Beam 0's history does not lie in lane 0: it is followed
-            # through the engine's ancestry table, position by position
-            tab, anc = state["block_tab"], state["ancestry"]
-            k_sc = jnp.stack([
-                gather_block_kv_beam(state["k_pool"][l], tab, 0, anc)
-                for l in range(L)])
-            v_sc = jnp.stack([
-                gather_block_kv_beam(state["v_pool"][l], tab, 0, anc)
-                for l in range(L)])
-        else:
-            k_sc = state["k_cache"].reshape(L, -1, K, H, T, d_head)[:, :, 0]
-            v_sc = state["v_cache"].reshape(L, -1, K, H, T, d_head)[:, :, 0]
+        # dense SCRATCH view of each slot's top beam: the pool is read
+        # once per draft and never written (sentinel table rows of
+        # idle/done slots clamp to garbage the validity mask zeroes).
+        # Beam 0's history does not lie in lane 0: it is followed
+        # through the engine's ancestry table, position by position
+        tab, anc = state["block_tab"], state["ancestry"]
+        k_sc = jnp.stack([
+            gather_block_kv_beam(state["k_pool"][l], tab, 0, anc)
+            for l in range(L)])
+        v_sc = jnp.stack([
+            gather_block_kv_beam(state["v_pool"][l], tab, 0, anc)
+            for l in range(L)])
 
         def body(flat0, tok0, pos0):
             def step(carry, _):

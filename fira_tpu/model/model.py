@@ -45,19 +45,27 @@ from fira_tpu.ops import copy_score
 
 def dense_adjacency(senders, receivers, values, graph_len: int,
                     indices_sorted: bool = False,
-                    out_dtype=None, flat: bool = False) -> jnp.ndarray:
+                    out_dtype=None) -> jnp.ndarray:
     """Scatter padded COO triplets into a dense batched adjacency.
 
     Pad entries are (0, 0, 0.0); scatter-ADD of zero is a no-op, so no
     masking is needed. Replaces the reference's host-side per-sample densify
-    (Dataset.py:336-343) with one on-device scatter per step.
+    (Dataset.py:336-343) with one on-device scatter per step: ONE
+    linearized 1-D scatter, flat = (b*N + s)*N + r. (A batched N-D scatter
+    ``adj.at[b, s, r]`` fills the same cells on the CPU but under
+    ``indices_are_sorted`` dropped most edges on the TPU at batch 340 and
+    680 — PERF.md section 7; chip_smoke.py's ``adjacency`` phase holds
+    this one to a plain float32 scatter there.)
     ``out_dtype``: scatter directly in the compute dtype instead of f32 —
     bit-identical to scattering f32 then casting, because graph_build's
     dedup guarantees each cell receives exactly one value (plus exact zero
     pads), so no cross-edge accumulation happens in the narrow dtype; the
     (B, N, N) buffer is built at half the bytes with no cast pass.
     ``indices_sorted``: promise that the (batch-major, cell-ascending) index
-    stream is sorted — so XLA can skip its scatter sorting prologue.
+    stream is sorted — so XLA can skip its scatter sorting prologue. Under
+    sort_edges the flat stream is FULLY ascending (pads (0,0) sort first
+    within each row and rows ascend), so the promise covers the whole
+    stream.
 
     CALLER CONTRACT: pass ``indices_sorted=True`` ONLY for batches built by
     ``data.batching.make_batch`` under ``cfg.sort_edges=True`` (it performs
@@ -69,23 +77,12 @@ def dense_adjacency(senders, receivers, values, graph_len: int,
     dt = values.dtype if out_dtype is None else out_dtype
     b_idx = jnp.arange(B, dtype=jnp.int32)[:, None]
     # indices travel int16 to halve H2D traffic; scatter wants int32
-    if flat:
-        # linearized 1-D scatter: flat = (b*N + s)*N + r. Under sort_edges
-        # the stream is FULLY ascending (pads (0,0) sort first within each
-        # row and rows ascend), so indices_are_sorted covers the whole
-        # stream — the flattest index pattern XLA can be promised.
-        # Bit-identical to the N-D scatter (same cells, same adds) — pinned
-        # by tests.
-        idx = ((b_idx * graph_len + senders.astype(jnp.int32)) * graph_len
-               + receivers.astype(jnp.int32))
-        out = jnp.zeros((B * graph_len * graph_len,), dtype=dt)
-        out = out.at[idx.reshape(-1)].add(
-            values.astype(dt).reshape(-1), indices_are_sorted=indices_sorted)
-        return out.reshape(B, graph_len, graph_len)
-    adj = jnp.zeros((B, graph_len, graph_len), dtype=dt)
-    return adj.at[b_idx, senders.astype(jnp.int32),
-                  receivers.astype(jnp.int32)].add(
-        values.astype(dt), indices_are_sorted=indices_sorted)
+    idx = ((b_idx * graph_len + senders.astype(jnp.int32)) * graph_len
+           + receivers.astype(jnp.int32))
+    out = jnp.zeros((B * graph_len * graph_len,), dtype=dt)
+    out = out.at[idx.reshape(-1)].add(
+        values.astype(dt).reshape(-1), indices_are_sorted=indices_sorted)
+    return out.reshape(B, graph_len, graph_len)
 
 
 def coo_matvec(senders, receivers, values, x,
@@ -338,14 +335,16 @@ class Decoder(nn.Module):
 
     def decode_step_multi(self, tok, pos_idx, k_cache, v_cache, cross_k,
                           cross_v, sou_mask, self_mask):
-        """One cached decode position PER ROW: like :meth:`decode_step` but
-        ``pos_idx`` is a (B,) vector — row b advances its own position
-        ``pos_idx[b]``. The slot-refill engine (decode/engine.py) holds
-        samples at mixed decode depths in one fixed-shape program, so the
-        shared-scalar position of the batch beam does not apply. Per row
-        the math is identical to :meth:`decode_step` at that row's scalar
-        position: the position-table row is gathered per row instead of
-        sliced once, and the cache write scatters per-row columns."""
+        """One cached decode position PER ROW over a dense whole-sequence
+        cache: like :meth:`decode_step` but ``pos_idx`` is a (B,) vector —
+        row b advances its own position ``pos_idx[b]``. ONE caller: the
+        speculative ``draft`` tier's scratch roll (decode/spec.py, through
+        FiraModel's dense fused step), which steps a dense
+        view of each slot's top beam gathered off the paged pool; the
+        engine's own step is :meth:`decode_step_paged`. Per row the math
+        is identical to :meth:`decode_step` at that row's scalar position:
+        the position-table row is gathered per row instead of sliced
+        once, and the cache write scatters per-row columns."""
         B = tok.shape[0]
         pos = pos_idx.astype(jnp.int32)
         b_idx = jnp.arange(B)
@@ -368,10 +367,12 @@ class Decoder(nn.Module):
 
     def decode_step_paged(self, tok, pos_idx, k_pool, v_pool, block_tab,
                           ancestry, cross_k, cross_v, sou_mask, self_mask):
-        """:meth:`decode_step_multi` with the self-attention cache behind
-        BLOCK-TABLE INDIRECTION (the slot engine's paged KV arena,
-        decode/engine.py): instead of each row owning a whole-sequence
-        (tar_len) cache stripe, the cache lives in a fixed pool of KV
+        """The slot engine's step (decode/engine.py): one cached decode
+        position per row at the row's OWN position (``pos_idx`` a vector:
+        slots hold samples at mixed depths), with the self-attention
+        cache behind BLOCK-TABLE INDIRECTION: instead of each row owning
+        a whole-sequence (tar_len) cache stripe as in the batched beam's
+        :meth:`decode_step`, the cache lives in a fixed pool of KV
         blocks — k_pool/v_pool: (L, P, K, H, block, d_head) — and
         ``block_tab`` (S, W) maps slot s's position range
         [w*block, (w+1)*block) to a pool block (sentinel id P = unmapped:
@@ -388,12 +389,13 @@ class Decoder(nn.Module):
         its own mask ``valid[t] & (ancestry[s, k, t] == lane)``
         (layers.lane_mask). A masked entry gets the -1e9 of an unwritten
         position — softmax weight an exact 0.0 — so per beam this is the
-        attention of :meth:`decode_step_multi` over the same keys and
-        values at the same precision; what differs is the order in which
-        the softmax and the value product sum their exact zeros, i.e. the
+        attention of :meth:`decode_step` over the same keys and values at
+        the same precision; what differs is the order in which the
+        softmax and the value product sum their exact zeros, i.e. the
         last bits (tests/test_paged_kv.py pins tokens bitwise and probs to
-        float32 rounding; tests/test_beam_ancestry.py that the entries
-        selected ARE the reordered whole-sequence cache, bit for bit).
+        float32 rounding against the batched beam; tests/
+        test_beam_ancestry.py that the entries selected ARE the reordered
+        whole-sequence cache, bit for bit).
 
         tok: (S*K, 1) token ids; pos_idx: (S*K,) per-row positions (rows
         of one slot share theirs); self_mask: (S*K, 1, 1, tar_len) per-row
@@ -560,10 +562,6 @@ class FiraModel(nn.Module):
         # the op metadata only (same HLO, same compile-cache key) — the
         # parts PERF.md section 5 otherwise knows by fusion number
         if cfg.adjacency_impl == "segment":
-            if cfg.flat_scatter:
-                raise ValueError(
-                    "flat_scatter applies to the dense adjacency build; "
-                    "use adjacency_impl='dense'")
             adj = functools.partial(
                 coo_matvec, batch["senders"], batch["receivers"],
                 batch["values"], indices_sorted=cfg.sort_edges,
@@ -577,7 +575,7 @@ class FiraModel(nn.Module):
                 adj = dense_adjacency(
                     batch["senders"], batch["receivers"], batch["values"],
                     graph_len, indices_sorted=cfg.sort_edges,
-                    out_dtype=self.dtype, flat=cfg.flat_scatter,
+                    out_dtype=self.dtype,
                 )
         else:
             raise ValueError(
@@ -664,8 +662,9 @@ class FiraModel(nn.Module):
 
     def _step_heads(self, mask, src_proj, tar_emb):
         """Shared generation/copy/gate head of the cached one-position
-        decode paths (scalar-position :meth:`dist_parts_step` and the
-        engine's per-row :meth:`dist_parts_step_multi`)."""
+        decode paths (the batched beam's :meth:`dist_parts_step`, the
+        engine's :meth:`dist_parts_step_paged`, the drafter's dense
+        fused step)."""
         with jax.named_scope("output_head"):
             gen = jax.nn.softmax(
                 self.out_fc(tar_emb).astype(stable_dtype(self.dtype)),
@@ -694,29 +693,15 @@ class FiraModel(nn.Module):
         gen, copy, gate = self._step_heads(mask, src_proj, tar_emb)
         return gen, copy, gate, k_cache, v_cache
 
-    def dist_parts_step_multi(self, mask, tok, pos_idx, k_cache, v_cache,
-                              cross_k, cross_v, src_proj, self_mask):
-        """Per-ROW-position twin of :meth:`dist_parts_step` (``pos_idx`` is
-        a (B,) vector): the slot-refill engine's step program advances every
-        slot at its own depth in one dispatch (decode/engine.py). Row-wise
-        identical math — Decoder.decode_step_multi plus the same heads."""
-        with jax.named_scope("decoder"):
-            tar_emb, k_cache, v_cache = self.decoder.decode_step_multi(
-                tok, pos_idx, k_cache, v_cache, cross_k, cross_v, mask,
-                self_mask,
-            )
-        gen, copy, gate = self._step_heads(mask, src_proj, tar_emb)
-        return gen, copy, gate, k_cache, v_cache
-
     def dist_parts_step_paged(self, mask, tok, pos_idx, k_pool, v_pool,
                               block_tab, ancestry, cross_k, cross_v,
                               src_proj, self_mask):
-        """Paged-arena twin of :meth:`dist_parts_step_multi`: the self-
-        attention cache is read and written through block-table
-        indirection and followed by beam ancestry
-        (Decoder.decode_step_paged) instead of whole-sequence stripes;
-        heads are the shared :meth:`_step_heads`, so per row the
-        distribution factors are bit-identical to the unpaged step."""
+        """THE slot engine's step (decode/slot_model.FiraSlotModel):
+        :meth:`dist_parts_step` at a per-row position vector, the self-
+        attention cache read and written through block-table indirection
+        and followed by beam ancestry (Decoder.decode_step_paged) instead
+        of whole-sequence stripes; heads are the shared
+        :meth:`_step_heads`."""
         with jax.named_scope("decoder"):
             tar_emb, k_pool, v_pool = self.decoder.decode_step_paged(
                 tok, pos_idx, k_pool, v_pool, block_tab, ancestry, cross_k,
@@ -725,26 +710,19 @@ class FiraModel(nn.Module):
         gen, copy, gate = self._step_heads(mask, src_proj, tar_emb)
         return gen, copy, gate, k_pool, v_pool
 
-    def fused_probs_step_paged(self, mask, tok, pos_idx, k_pool, v_pool,
-                               block_tab, ancestry, cross_k, cross_v,
-                               src_proj, self_mask):
-        """Paged-arena twin of :meth:`fused_probs_step_multi` — the
-        engine's non-factored step head over the block pool."""
-        gen, copy, gate, k_pool, v_pool = self.dist_parts_step_paged(
-            mask, tok, pos_idx, k_pool, v_pool, block_tab, ancestry,
-            cross_k, cross_v, src_proj, self_mask)
-        fused = jnp.concatenate(
-            [gate[:, :, 0:1] * gen, gate[:, :, 1:2] * copy], axis=-1
-        )
-        return fused, k_pool, v_pool
-
     def fused_probs_step_multi(self, mask, tok, pos_idx, k_cache, v_cache,
                                cross_k, cross_v, src_proj, self_mask):
-        """Per-ROW-position twin of :meth:`fused_probs_step` — the engine's
-        non-factored step head. Returns (fused (B, 1, V_out), caches)."""
-        gen, copy, gate, k_cache, v_cache = self.dist_parts_step_multi(
-            mask, tok, pos_idx, k_cache, v_cache, cross_k, cross_v,
-            src_proj, self_mask)
+        """Per-ROW-position twin of :meth:`fused_probs_step` over a dense
+        whole-sequence cache, for ONE caller: the speculative ``draft``
+        tier's scratch roll (decode/spec.py), whose caches are a dense
+        view of each slot's top beam that lives and dies in its scan
+        carry. Returns (fused (B, 1, V_out), caches)."""
+        with jax.named_scope("decoder"):
+            tar_emb, k_cache, v_cache = self.decoder.decode_step_multi(
+                tok, pos_idx, k_cache, v_cache, cross_k, cross_v, mask,
+                self_mask,
+            )
+        gen, copy, gate = self._step_heads(mask, src_proj, tar_emb)
         fused = jnp.concatenate(
             [gate[:, :, 0:1] * gen, gate[:, :, 1:2] * copy], axis=-1
         )

@@ -122,11 +122,8 @@ class FleetStats:
         # replica's pool by its own dispatch count
         pool_capacity = sum(r.step_dispatches * r.pool_blocks
                             for r in self.replicas)
-        if pool_capacity:
-            pool_util = round(tot("block_steps") / pool_capacity, 4)
-        else:
-            pool_util = (1.0 if any(r.kv_bytes_per_slot
-                                    for r in self.replicas) else 0.0)
+        pool_util = (round(tot("block_steps") / pool_capacity, 4)
+                     if pool_capacity else 0.0)
         return {
             "pool_blocks": tot("pool_blocks"),
             "kv_block_size": max((r.kv_block_size for r in self.replicas),

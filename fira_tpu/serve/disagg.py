@@ -189,7 +189,7 @@ def _worker_main(conn, init: Dict) -> None:
                 batch[k][j] = rh[k][0]
         chunk = eng._prefill(eng.params, jax.device_put(batch))
         chunk_host = {f: np.asarray(jax.device_get(chunk[f]))  # firacheck: allow[HOST-SYNC] the worker child's whole job is materializing prefill artifacts on host for transport; this D2H is the product, not a stall
-                      for f in eng._artifact_fields()}
+                      for f in prefix_cache_lib.ARTIFACT_FIELDS}
         entries = prefix_cache_lib.extract_payloads(
             chunk_host, list(range(len(rows))), cfg.beam_size)
         return [(rows[j][0], prefix_cache_lib.payload_checksum(entries[j]),
@@ -203,7 +203,7 @@ def _worker_main(conn, init: Dict) -> None:
                 if not k.startswith("_")}
         chunk = eng._prefill(eng.params, jax.device_put(wire))
         chunk_host = {f: np.asarray(jax.device_get(chunk[f]))  # firacheck: allow[HOST-SYNC] prewarm-time artifact sizing for the ready handshake (once per bucket, before any request exists)
-                      for f in eng._artifact_fields()}
+                      for f in prefix_cache_lib.ARTIFACT_FIELDS}
         entry = prefix_cache_lib.extract_payloads(
             chunk_host, [0], cfg.beam_size)[0]
         est[b] = prefix_cache_lib.payload_nbytes(entry)

@@ -92,7 +92,7 @@ Modes:
               nonzero on any violation.
   --quant     the LOW-PRECISION-TIER leg (docs/QUANT_BENCH_r01.jsonl;
               docs/DECODE_ENGINE.md "Low-precision tiers"): the equal-
-              HBM slot sweep (unpaged f32 vs the paged bf16 KV arena at
+              HBM slot sweep (the f32 pool at full residency vs the bf16 pool at
               4x the slots against the same pool bytes — the
               paged_equal_hbm_slot_gain row records the machine-
               measured >= 4.0), per-tier serve rows (rps + p50/p99 e2e
@@ -1259,8 +1259,8 @@ def quant_measure(out_path: str) -> int:
     """The LOW-PRECISION-TIER record (docs/QUANT_BENCH_r01.jsonl;
     docs/DECODE_ENGINE.md "Low-precision tiers"). Three legs:
 
-    - ``equal_hbm_sweep`` — the HBM claim, machine-recorded: an unpaged
-      f32 arena at a long tar budget vs the paged bf16 arena serving 4x
+    - ``equal_hbm_sweep`` — the HBM claim, machine-recorded: the f32 pool
+      at full residency at a long tar budget vs the bf16 pool serving 4x
       the slots against the SAME pool bytes (bf16 halves the per-
       position bytes, paging's own equal-HBM doubling stacks on top) —
       ``kv_bytes_per_slot`` quarters, the ``paged_equal_hbm_slot_gain``
@@ -1338,27 +1338,28 @@ def quant_measure(out_path: str) -> int:
         emit({"mode": "equal_hbm_sweep", "tag": tag,
               "commits_per_sec": round(st["commits"] / dt, 2),
               "slots": st["slots"], "tar_len": cfg_row.tar_len,
-              "paged": eng._paged, "pool_blocks": st["pool_blocks"],
+              "pool_blocks": st["pool_blocks"],
               "kv_block_size": st["kv_block_size"],
               "kv_bytes_per_slot": st["kv_bytes_per_slot"],
               "kv_dtype": st["kv_dtype"],
               "serve_precision": st["serve_precision"]})
         return st
 
-    st_unpaged = sweep_row(
-        "unpaged_f32_tar64", cfg_s.replace(engine_paged_kv=False))
+    # full residency: a slot's share of the pool is what a whole-sequence
+    # f32 stripe would cost
+    st_full = sweep_row("paged_f32_tar64", cfg_s)
     st_bf4x = sweep_row(
         "paged_bf16kv_tar64_4xslots", cfg_s.replace(kv_dtype="bf16"),
         slots=4 * sbatch, pool_blocks=2 * sbatch * w_long)
-    gain = st_bf4x["slots"] / st_unpaged["slots"]
+    gain = st_bf4x["slots"] / st_full["slots"]
     emit({"mode": "equal_hbm_sweep", "tag": "paged_equal_hbm_slot_gain",
           "kv_dtype": "bf16",
-          "slots": f"{st_unpaged['slots']} -> {st_bf4x['slots']}",
-          "kv_bytes_per_slot": f"{st_unpaged['kv_bytes_per_slot']} -> "
+          "slots": f"{st_full['slots']} -> {st_bf4x['slots']}",
+          "kv_bytes_per_slot": f"{st_full['kv_bytes_per_slot']} -> "
                                f"{st_bf4x['kv_bytes_per_slot']}",
           "value": round(gain, 2)})
     ok = gain >= 4.0 and st_bf4x["kv_bytes_per_slot"] * 4 \
-        == st_unpaged["kv_bytes_per_slot"]
+        == st_full["kv_bytes_per_slot"]
 
     # --- legs 2+3: per-tier serve at the knee + measured quality -----------
     n_commits = int(os.environ.get("FIRA_QUANT_COMMITS", "120"))

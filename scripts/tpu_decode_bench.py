@@ -174,7 +174,7 @@ def engine_row(tag, ps, *, model=None, cfg=None, driver=None, slots=None,
     """One timed engine drain. ``driver``/``cfg``/``model`` default to the
     flat mixed stream above; the paged rows pass their own bucketed
     stream. kv_* / pool_* fields are the machine-recorded HBM accounting
-    (decode/paging.py) every paged-vs-unpaged claim rides on."""
+    (decode/paging.py) every equal-memory claim rides on."""
     cfg = cfg or cfg_eng
     eng = engine_lib.SlotEngine(model or model_eng, ps, cfg, slots=slots,
                                 pool_blocks=pool_blocks)
@@ -199,7 +199,6 @@ def engine_row(tag, ps, *, model=None, cfg=None, driver=None, slots=None,
         "steps_run": st["steps_run"], "refills": st["refills"],
         "steps_per_commit": st["steps_per_commit"],
         "dispatches": st["dispatches"],
-        "paged": eng._paged,
         "pool_blocks": st["pool_blocks"],
         "kv_block_size": st["kv_block_size"],
         "kv_bytes_per_slot": st["kv_bytes_per_slot"],
@@ -379,22 +378,21 @@ if os.environ.get("DECODE_SPEC", "1") == "1":
 
 
 # --------------------------------------------------------------------------
-# Paged KV arena rows (cfg.engine_paged_kv; decode/paging.py +
+# Paged KV arena rows (decode/paging.py +
 # docs/DECODE_ENGINE.md "Paged KV arena"): the longer-target-geometry
 # door. Raise tar_len to DECODE_PAGED_TAR (the PR-description budget the
 # 30-position arena could never host) and declare the common case —
 # DECODE_PAGED_TAR_SHORT — as a decode tar bucket: short messages reserve
 # ceil(short/block) pool blocks, long ones the full budget, ONE step
-# program serves both. Three rows make the HBM claim machine-recorded:
+# program serves both. Two rows make the HBM claim machine-recorded:
 #
-#   unpaged_tar<T>            whole-sequence arena at the long budget —
-#                             every slot commits the full T-position
-#                             stripe (kv_bytes_per_slot is the price);
-#   paged_tar<T>              same slots, full-residency pool — equal
-#                             bytes, pool_utilization shows the share
-#                             mixed reservations actually map;
+#   paged_tar<T>              full-residency pool: every slot can hold the
+#                             full T-position budget (kv_bytes_per_slot
+#                             is what a whole-sequence stripe would cost);
+#                             pool_utilization shows the share mixed
+#                             reservations actually map;
 #   paged_tar<T>_2xslots      TWICE the slots against the SAME pool bytes
-#                             as the unpaged row (kv_bytes_per_slot
+#                             (kv_bytes_per_slot
 #                             halves) — the equal-memory slot-count gain,
 #                             servable because the short bucket dominates
 #                             real streams.
@@ -443,23 +441,19 @@ if os.environ.get("DECODE_PAGED", "1") == "1":
         "n_commits": len(split_p), "n_short_bucket": n_short,
         "n_batches": len(plan_p),
     }), flush=True)
-    _, st_unpaged = engine_row(
-        f"unpaged_tar{PAGED_TAR}", params_p,
-        model=model_p, cfg=cfg_p.replace(engine_paged_kv=False),
-        driver=drive_paged)
-    engine_row(f"paged_tar{PAGED_TAR}", params_p, model=model_p, cfg=cfg_p,
-               driver=drive_paged)
-    # SAME pool bytes as the unpaged row's arena (BATCH x W_long blocks),
+    _, st_full = engine_row(f"paged_tar{PAGED_TAR}", params_p,
+                            model=model_p, cfg=cfg_p, driver=drive_paged)
+    # SAME pool bytes as the full-residency row (BATCH x W_long blocks),
     # twice the slots: kv_bytes_per_slot halves at equal total HBM
     _, st_2x = engine_row(
         f"paged_tar{PAGED_TAR}_2xslots", params_p, model=model_p, cfg=cfg_p,
         driver=drive_paged, slots=2 * BATCH, pool_blocks=BATCH * w_long)
     print(json.dumps({
         "tag": "paged_equal_hbm_slot_gain",
-        "slots": f"{st_unpaged['slots']} -> {st_2x['slots']}",
-        "kv_bytes_per_slot": f"{st_unpaged['kv_bytes_per_slot']} -> "
+        "slots": f"{st_full['slots']} -> {st_2x['slots']}",
+        "kv_bytes_per_slot": f"{st_full['kv_bytes_per_slot']} -> "
                              f"{st_2x['kv_bytes_per_slot']}",
-        "value": round(st_2x["slots"] / st_unpaged["slots"], 2),
+        "value": round(st_2x["slots"] / st_full["slots"], 2),
     }), flush=True)
 
     # ----------------------------------------------------------------------
@@ -467,9 +461,9 @@ if os.environ.get("DECODE_PAGED", "1") == "1":
     # decode/quant.py + docs/DECODE_ENGINE.md "Low-precision tiers"),
     # riding the paged stream above. The bf16 arena halves the per-
     # position KV bytes, so the equal-HBM slot-count gain DOUBLES: the
-    # 4xslots row serves four times the unpaged-f32 slots against the
-    # SAME pool bytes (2 x BATCH x W_long bf16 blocks == BATCH unpaged
-    # f32 stripes). The int8w row keeps the f32 arena and swaps the
+    # 4xslots row serves four times the full-residency f32 slots against
+    # the SAME pool bytes (2 x BATCH x W_long bf16 blocks == BATCH x W_long
+    # f32 blocks). The int8w row keeps the f32 arena and swaps the
     # decode weight tier — throughput at unchanged KV accounting.
     # Quality vs f32 is measured by serve_bench.py --quant
     # (docs/QUANT_BENCH_r01.jsonl), not here. DECODE_QUANT=0 skips.
@@ -485,10 +479,10 @@ if os.environ.get("DECODE_PAGED", "1") == "1":
         print(json.dumps({
             "tag": "paged_equal_hbm_slot_gain",
             "kv_dtype": "bf16",
-            "slots": f"{st_unpaged['slots']} -> {st_bf4x['slots']}",
-            "kv_bytes_per_slot": f"{st_unpaged['kv_bytes_per_slot']} -> "
+            "slots": f"{st_full['slots']} -> {st_bf4x['slots']}",
+            "kv_bytes_per_slot": f"{st_full['kv_bytes_per_slot']} -> "
                                  f"{st_bf4x['kv_bytes_per_slot']}",
-            "value": round(st_bf4x["slots"] / st_unpaged["slots"], 2),
+            "value": round(st_bf4x["slots"] / st_full["slots"], 2),
         }), flush=True)
         engine_row(f"int8w_tar{PAGED_TAR}", params_p, model=model_p,
                    cfg=cfg_p.replace(serve_precision="int8w"),

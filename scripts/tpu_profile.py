@@ -2,8 +2,7 @@
 chip and print the top ops by self time (parsed offline with
 tensorboard_plugin_profile — no TensorBoard UI needed).
 
-The result attributes the train step op by op; ablation
-(scripts/tpu_ablate.py) only narrows it to whole sub-stacks.
+The result attributes the train step op by op.
 """
 
 import glob

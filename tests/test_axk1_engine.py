@@ -167,16 +167,16 @@ def test_lat_pool_follows_src_beam_through_permute_pool_not_ancestry(
     moved = []
     inner = slot_model.permute_pool
 
-    def spy(pool, tab_step, idx):
-        moved.append((pool.shape, idx.shape))
-        return inner(pool, tab_step, idx)
+    def spy(pool, tab_step, src_beam):
+        moved.append((pool.shape, src_beam.shape))
+        return inner(pool, tab_step, src_beam)
 
     monkeypatch.setattr(slot_model, "permute_pool", spy)
     # a fresh function: jit would serve the prewarmed step's trace
     text = jax.jit(lambda p, st: eng._step_fn(p, st)).lower(
         eng._decode_params, eng._state).as_text(debug_info=True)
     K = cfg.beam_size
-    assert moved == [(eng._state["lat_pool"].shape, (1, 2, 1, K, 1, 1))]
+    assert moved == [(eng._state["lat_pool"].shape, (2, K))]
     names = set(re.findall(r'loc\("(kv_reorder/[^"]*)"', text))
     assert any("gather" in n for n in names)
     assert any("scatter" in n for n in names)
@@ -228,7 +228,7 @@ REFUSED = {
     "engine_replicas": dict(engine_replicas=2, engine_slots=4),
     "serve/disagg.py": dict(serve_tiers="prefill-pool"),
     "non-engine beam": dict(decode_engine=False),
-    "unpaged": dict(engine_paged_kv=False),
+    "graph buckets": dict(buckets=((16, 400, 12),)),
     "beam_compat_prob_space": dict(beam_compat_prob_space=True),
     "buckets": dict(decode_tar_buckets=True),
 }
@@ -240,8 +240,9 @@ def test_unsupported_combinations_are_refused_by_name(what):
     errs = config_errors(cfg)
     assert errs and all("axk1" in e for e in errs), errs
     word = {"int8w": "int8w", "bf16-weight-tier": "serve_precision",
-            "non-engine beam": "non-engine", "unpaged": "unpaged",
+            "non-engine beam": "non-engine",
             "serve/disagg.py": "serve/disagg.py",
+            "graph buckets": "buckets / decode_tar_buckets",
             "buckets": "decode_tar_buckets"}.get(what, what)
     assert any(word in e for e in errs), errs
     if what != "non-engine beam":

@@ -9,14 +9,14 @@ position t of beam k's history. Pinned here, at fira-tiny on the CPU:
 - the entries the step's mask selects out of a slot's blocks ARE the dense
   per-beam cache rebuilt from (pool, ``block_tab``, ``ancestry``), bit for
   bit (a pure re-indexing), and so is the drafter's top-beam scratch;
-- that dense cache is the unpaged stripe cache after ``permute_stripes``,
-  position by position, over a drain with mixed settle depths and dirty
-  re-granted blocks — to float32 rounding, because the attention that
-  produced the cached values sums a beam's keys among the exact zeros of
-  the other lanes (another order, the same terms);
-- served tokens equal the unpaged arena's bitwise and probs to the same
-  rounding, in every kv-cache x factored-topk mode at the production
-  harvest cadence;
+- that dense cache is a whole-sequence stripe cache reordered by
+  ``src_beam`` after every selection (the batched beam's discipline, kept
+  in numpy beside the engine), position by position, over a drain with
+  mixed settle depths and dirty re-granted blocks;
+- served tokens equal the batched beam's bitwise and probs to float32
+  rounding (the attention sums a beam's keys among the exact zeros of the
+  other lanes: another order, the same terms), at beam 1 and 3, in
+  probability and in log space, at the production harvest cadence;
 - nothing as large as a pool layer is touched under the ``kv_reorder``
   scope of the lowered step (and the detector sees the reorder when a
   model declares one);
@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from beam_util import beam_outputs
 from fira_tpu.config import fira_tiny
 from fira_tpu.data.batching import make_batch
 from fira_tpu.data.dataset import FiraDataset
@@ -75,70 +76,84 @@ def dense_view(state, s: int, name: str) -> np.ndarray:
     return view.transpose(2, 0, 3, 1, 4)
 
 
-def stripe_view(state, s: int, name: str, K: int) -> np.ndarray:
-    return np.asarray(state[name])[:, s * K:(s + 1) * K]    # (L,K,H,T,dh)
-
-
-def _drain_recording(eng, dataset, cfg, view_of, order):
-    """Drain the train split; after EVERY step dispatch (cadence 1: one
-    position) record each seated request's written cache, keyed by
-    (request, depth). -> (outputs, {(pid, pos): (k, v)})."""
-    seen = {}
-    inner = eng.harvest
-
-    def harvest():
-        state = jax.device_get(eng._state)
-        for s, (pid, _host, _row) in eng._busy.items():
-            pos = int(state["pos"][s])
-            if (pid, pos) not in seen:
-                seen[(pid, pos)] = tuple(
-                    view_of(state, s, kv)[:, :, :, :pos] for kv in "kv")
-        return inner()
-
-    eng.harvest = harvest
-    tasks, _ = _decode_tasks(dataset.splits["train"], cfg)
-    out = {}
-    with Feeder(tasks, num_workers=0, depth=1) as feed:
-        for it in eng.run(feed, refill_order=order):
-            out[it.position] = (it.tokens, it.probs)
-    return out, seen
-
-
 def test_dense_view_through_ancestry_is_the_permuted_stripe_cache(setup):
+    """Beside the engine, in numpy, each request keeps a whole-sequence
+    stripe cache (L, K, H, T, d_head) the way the batched beam does: a
+    step's new K/V goes into row k at its position, then the rows are
+    gathered by that step's ``src_beam`` — the SELECTION's own, taken from
+    what ``smodel.select`` returned in that dispatch, not read back out of
+    the table under test. What the engine reads through (pool,
+    ``block_tab``, ``ancestry``) is that cache at every depth of every
+    request, bit for bit — the pool itself never moved."""
     cfg0, dataset, params = setup
     cfg = dataclasses.replace(cfg0, engine_harvest_every=1, engine_slots=5)
     model, K = FiraModel(cfg), cfg0.beam_size
-    paged = SlotEngine(model, params, cfg)
-    # lifo against fifo: the two arenas seat a request in different slots,
-    # and the pool's blocks go round the free list between requests
-    got, got_seen = _drain_recording(
-        paged, dataset, cfg,
-        lambda st, s, kv: dense_view(st, s, f"{kv}_pool"), "lifo")
-    ucfg = dataclasses.replace(cfg, engine_paged_kv=False)
-    unpaged = SlotEngine(model, params, ucfg)
-    want, want_seen = _drain_recording(
-        unpaged, dataset, ucfg,
-        lambda st, s, kv: stripe_view(st, s, f"{kv}_cache", K), "fifo")
-    assert "ancestry" in paged._state and "ancestry" not in unpaged._state
-    assert paged.smodel.beam_ancestry and not unpaged.smodel.beam_ancestry
-    assert paged._leaves["k_pool"].reorder is None      # never moved
-    assert unpaged._leaves["k_cache"].reorder == "stripe"
+    eng = SlotEngine(model, params, cfg)
+    stripes, depth_of, checked = {}, {}, set()
+    inner = eng.harvest
+    # the step program is traced at its first dispatch, through this
+    # wrapper: every dispatch then hands its (S, K) src_beam to the host
+    selected = []
+    select = eng.smodel.select
+
+    def spying_select(*args):
+        out = select(*args)
+        jax.debug.callback(lambda sb: selected.append(np.array(sb)), out[3])
+        return out
+
+    eng.smodel.select = spying_select
+
+    def harvest():
+        # after EVERY step dispatch (cadence 1: one position)
+        state = jax.device_get(eng._state)
+        jax.effects_barrier()
+        P, BS = eng._pool_blocks, eng._block_size
+        for s, (pid, _host, _row) in eng._busy.items():
+            pos = int(state["pos"][s])
+            if pos == depth_of.get(pid, 0):
+                continue                # settled: it did not step
+            assert pos == depth_of.get(pid, 0) + 1
+            depth_of[pid] = pos
+            p = pos - 1                 # the position this step wrote
+            tab = np.minimum(state["block_tab"][s], P - 1)
+            src_beam = selected[-1][s]              # of the last dispatch
+            # lane k held beam k at p, so the table's new column is it
+            np.testing.assert_array_equal(state["ancestry"][s, :, p],
+                                          src_beam)
+            for kv in "kv":
+                pool = state[f"{kv}_pool"]          # (L,P,K,H,BS,dh)
+                L, _P, _K, H, _BS, dh = pool.shape
+                c = stripes.setdefault(
+                    (pid, kv), np.zeros((L, K, H, cfg.tar_len, dh),
+                                        pool.dtype))
+                c[:, :, :, p] = pool[:, tab[p // BS], :, :, p % BS]
+                stripes[(pid, kv)] = c = c[:, src_beam]     # the permute
+                got = dense_view(state, s, f"{kv}_pool")
+                np.testing.assert_array_equal(
+                    got[:, :, :, :pos].view(np.int32),
+                    c[:, :, :, :pos].view(np.int32))
+            checked.add((pid, pos))
+        return inner()
+
+    eng.harvest = harvest
+    # lifo: a request lands in the slot freed last, and the pool's blocks
+    # go round the free list between requests
+    tasks, _ = _decode_tasks(dataset.splits["train"], cfg)
+    got = {}
+    with Feeder(tasks, num_workers=0, depth=1) as feed:
+        for it in eng.run(feed, refill_order="lifo"):
+            got[it.position] = (it.tokens, it.probs)
+    assert "ancestry" in eng._state and eng.smodel.beam_ancestry
+    assert eng._leaves["k_pool"].reorder is None        # never moved
     # blocks were re-granted: more grants than the pool has blocks
-    assert paged.stats.slots_refilled * paged._table_width \
-        > paged._pool_blocks
-    assert got_seen.keys() == want_seen.keys()
-    depths = {pos for _pid, pos in got_seen}
-    assert len(depths) > 3                              # mixed depths
-    for key in got_seen:
-        for a, b in zip(got_seen[key], want_seen[key]):
-            assert a.shape == b.shape and a.shape[3] == key[1]
-            # cached K/V are projections of O(1) activations: entries
-            # near zero carry the rounding of their O(1) terms
-            np.testing.assert_allclose(a, b, rtol=PAGED_PROBS_RTOL,
-                                       atol=1e-6)
+    assert eng.stats.slots_refilled * eng._table_width > eng._pool_blocks
+    assert {pid for pid, _pos in checked} == set(got)
+    assert len(selected) == eng.stats.step_dispatches
+    assert len({pos for _pid, pos in checked}) > 3      # mixed depths
     # the beams really were re-sorted: some history lies outside its lane
-    anc = np.asarray(paged._state["ancestry"])
+    anc = np.asarray(eng._state["ancestry"])
     assert (anc != np.arange(K)[None, :, None]).any()
+    want = beam_outputs(model, params, dataset.splits["train"], cfg)
     assert got.keys() == want.keys()
     for pos in got:
         np.testing.assert_array_equal(got[pos][0], want[pos][0])
@@ -211,39 +226,30 @@ def test_the_mask_selects_the_dense_view_bit_for_bit(setup):
                     dense[0][:, :p].view(np.int32))
 
 
-@pytest.mark.parametrize("kv,fac", [(True, False), (True, True),
-                                    (False, False), (False, True)])
-def test_served_beams_equal_the_unpaged_arena(setup, kv, fac):
+@pytest.mark.parametrize("log_space", (False, True), ids=("prob", "log"))
+@pytest.mark.parametrize("beam", (1, 3))
+def test_served_beams_equal_the_batched_beam(setup, beam, log_space):
     """The production cadence (4 positions a dispatch, the scan form of
-    the step), slots reused: tokens bitwise; probs bitwise without a
-    paged self-KV and to float32 rounding with one."""
+    the step), slots reused, one beam (no lane to follow) and three, both
+    score spaces: tokens bitwise, probs to float32 rounding."""
     cfg0, dataset, params = setup
-    cfg = dataclasses.replace(cfg0, beam_kv_cache=kv, beam_factored_topk=fac,
+    cfg = dataclasses.replace(cfg0, beam_size=beam,
+                              beam_compat_prob_space=not log_space,
                               engine_harvest_every=4, engine_slots=4)
     model = FiraModel(cfg)
-    outs = {}
-    for paged in (True, False):
-        c = dataclasses.replace(cfg, engine_paged_kv=paged)
-        eng = SlotEngine(model, params, c)
-        tasks, _ = _decode_tasks(dataset.splits["train"], c)
-        with Feeder(tasks, num_workers=0, depth=1) as feed:
-            outs[paged] = {it.position: (it.tokens, it.probs)
-                           for it in eng.run(feed)}
-        # the table exists exactly where a paged self-KV does
-        assert ("ancestry" in eng._state) == (paged and kv)
-        assert eng.smodel.beam_ancestry == (paged and kv)
-    assert outs[True].keys() == outs[False].keys()
-    assert len(outs[True]) == len(dataset.splits["train"])
-    for pos in outs[True]:
-        np.testing.assert_array_equal(outs[True][pos][0],
-                                      outs[False][pos][0])
-        if kv:
-            np.testing.assert_allclose(outs[True][pos][1],
-                                       outs[False][pos][1],
-                                       rtol=PAGED_PROBS_RTOL, atol=0)
-        else:
-            np.testing.assert_array_equal(outs[True][pos][1],
-                                          outs[False][pos][1])
+    eng = SlotEngine(model, params, cfg)
+    tasks, _ = _decode_tasks(dataset.splits["train"], cfg)
+    with Feeder(tasks, num_workers=0, depth=1) as feed:
+        got = {it.position: (it.tokens, it.probs) for it in eng.run(feed)}
+    assert eng._state["ancestry"].shape == (4, beam, cfg.tar_len)
+    want = beam_outputs(model, params, dataset.splits["train"], cfg)
+    assert got.keys() == want.keys()
+    assert len(got) == len(dataset.splits["train"])
+    for pos in got:
+        assert got[pos][0].shape == (beam, cfg.tar_len)
+        np.testing.assert_array_equal(got[pos][0], want[pos][0])
+        np.testing.assert_allclose(got[pos][1], want[pos][1],
+                                   rtol=PAGED_PROBS_RTOL, atol=0)
 
 
 # --------------------------------------------------------------------------

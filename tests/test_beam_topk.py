@@ -171,16 +171,16 @@ def test_selection_lowers_without_a_vocabulary_sort(tiny, lower,
     assert _wide_ranking_ops(lower(cfg), cfg.vocab_size)
 
 
-@pytest.mark.parametrize("kv,paged,factored", [
-    (True, True, True),      # the production step (DECODE_PERF_KNOBS)
-    (True, False, False),    # whole-sequence arena, unfactored fused row
-    (False, False, True),    # full-prefix re-decode
-], ids=("paged_factored", "unpaged_fused", "nocache_factored"))
-def test_engine_step_lowers_without_a_vocabulary_sort(tiny, kv, paged,
-                                                      factored):
+@pytest.mark.parametrize("harvest_every,beam", [
+    (4, 3),     # the production step (a scan of 4 positions)
+    (1, 3),     # the plain one-position form (the spec verify's body)
+    (4, 1),     # a single beam: K = 1 pass a side
+], ids=("scan4_beam3", "plain_beam3", "scan4_beam1"))
+def test_engine_step_lowers_without_a_vocabulary_sort(tiny, harvest_every,
+                                                      beam):
     cfg0, split, params = tiny
-    cfg = dataclasses.replace(cfg0, beam_kv_cache=kv, engine_paged_kv=paged,
-                              beam_factored_topk=factored)
+    cfg = dataclasses.replace(cfg0, engine_harvest_every=harvest_every,
+                              beam_size=beam)
     eng = SlotEngine(FiraModel(cfg), params, cfg, slots=cfg.engine_slots)
     warm = make_batch(split, np.arange(0), cfg,
                       batch_size=cfg.test_batch_size)

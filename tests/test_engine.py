@@ -2,9 +2,10 @@
 
 Pins the engine's whole contract:
 
-- per-sample BIT-EXACTNESS (tokens AND probs) vs the batched beam in all
-  four kv-cache x factored-topk modes — the equivalence the production
-  DECODE_PERF_KNOBS preset rides on;
+- per-sample equality with the batched beam in all four of ITS kv-cache x
+  factored-topk forms (tokens bitwise, probs to float32 rounding), at the
+  engine shapes production varies: slots, harvest cadence, KV block size,
+  score space — the equivalence the DECODE_PERF_KNOBS preset rides on;
 - scheduler determinism: identical output file bytes for any prefill-queue
   depth, feeder worker count, and refill order;
 - the ordered streaming writer (decode/stream.py): contiguous-prefix
@@ -20,13 +21,14 @@ import os
 import numpy as np
 import pytest
 
+from beam_util import beam_outputs
 from fira_tpu.analysis import sanitizer
 from fira_tpu.config import fira_tiny
 from fira_tpu.data.dataset import FiraDataset
 from fira_tpu.data.feeder import Feeder
 from fira_tpu.data.synthetic import write_corpus_dir
 from fira_tpu.decode import engine as engine_lib
-from fira_tpu.decode.beam import eos_biased_params, make_beam_search
+from fira_tpu.decode.beam import eos_biased_params
 from fira_tpu.decode.runner import _decode_tasks, run_test
 from fira_tpu.decode.stream import OrderedStreamWriter
 from fira_tpu.model.model import FiraModel
@@ -51,48 +53,49 @@ def setup(tmp_path_factory):
 
 
 MODES = [
-    # (kv_cache, factored_topk)
+    # the BATCHED beam's (kv_cache, factored_topk): the oracle's four
+    # forms. The engine reads neither knob — it has one form.
     (True, False),
     (True, True),
     (False, False),
     (False, True),
 ]
 
+# the engine's shape, as production varies it
+SHAPES = {
+    # the defaults: slots = the batch, harvest every 4, automatic KV block
+    # size, probability-space scores
+    "as-batch": dict(),
+    # what the benchmark's cells differ in: slots != batch, harvest every
+    # position, one KV block a sequence, log-space scores
+    "own-shape": dict(engine_slots=4, engine_harvest_every=1,
+                      kv_block_size=12, beam_compat_prob_space=False),
+}
+
 
 # float32 rounding, for the one place the engine's arithmetic is not the
-# batched beam's sum for sum: the paged arena's self-attention runs a
-# slot's K beams over all K lanes of its blocks under the ancestry mask
+# batched beam's sum for sum: the arena's self-attention runs a slot's K
+# beams over all K lanes of its blocks under the ancestry mask
 # (model.Decoder.decode_step_paged), so its softmax and value product add
 # their exact zeros in another order. Observed 7e-8 relative at fira-tiny;
 # the bound leaves room for 30 positions x 8 layers of such last bits.
 PAGED_PROBS_RTOL = 1e-5
 
 
-@pytest.mark.parametrize("paged", (True, False), ids=("paged", "unpaged"))
+@pytest.mark.parametrize("shape", sorted(SHAPES))
 @pytest.mark.parametrize("kv,fac", MODES)
-def test_engine_bit_exact_per_sample(setup, kv, fac, paged):
+def test_engine_bit_exact_per_sample(setup, kv, fac, shape):
     """Engine (tokens, probs) == batched beam (tokens, probs), per sample,
-    in every kv-cache x factored-topk mode: bitwise over the whole-sequence
-    arena and without a cache; over the paged arena tokens bitwise and
-    probs to float32 rounding (PAGED_PROBS_RTOL)."""
+    against every kv-cache x factored-topk form of the batched beam:
+    tokens bitwise, probs to float32 rounding (PAGED_PROBS_RTOL)."""
     cfg0, dataset, _params, eos_params = setup
     cfg = dataclasses.replace(cfg0, beam_kv_cache=kv, beam_factored_topk=fac,
-                              engine_paged_kv=paged)
+                              **SHAPES[shape])
     model = FiraModel(cfg)
     data = dataset.splits["train"]  # the big split: several batches, real refill pressure
 
     # batched-beam reference, keyed by split position
-    beam = make_beam_search(model, cfg)
-    expected = {}
-    tasks, _ = _decode_tasks(data, cfg)
-    with Feeder(tasks, num_workers=0, depth=1) as feed:
-        for item in feed:
-            toks, probs = beam(eos_params, item.device)
-            toks, probs = np.asarray(toks), np.asarray(probs)
-            C = item.host["valid"].shape[0]
-            for i in range(C):
-                if item.host["valid"][i]:
-                    expected[item.index * C + i] = (toks[i], probs[i])
+    expected = beam_outputs(model, eos_params, data, cfg)
 
     eng = engine_lib.SlotEngine(model, eos_params, cfg)
     tasks2, _ = _decode_tasks(data, cfg)
@@ -103,13 +106,11 @@ def test_engine_bit_exact_per_sample(setup, kv, fac, paged):
             seen.add(it.position)
             ref_toks, ref_probs = expected[it.position]
             np.testing.assert_array_equal(it.tokens, ref_toks)
-            if eng._paged:
-                np.testing.assert_allclose(it.probs, ref_probs,
-                                           rtol=PAGED_PROBS_RTOL, atol=0)
-            else:
-                np.testing.assert_array_equal(it.probs, ref_probs)
+            np.testing.assert_allclose(it.probs, ref_probs,
+                                       rtol=PAGED_PROBS_RTOL, atol=0)
     assert seen == set(expected)
-    assert eng._paged == (paged and kv)
+    assert eng.slots == (cfg.engine_slots or cfg.test_batch_size)
+    assert eng.stats.kv_block_size == (cfg.kv_block_size or 6)
     assert eng.stats.commits == len(data)
     # the engine must actually retire+refill mid-flight, not run one
     # monolithic pass: with mixed settle depths there are more refill

@@ -187,40 +187,44 @@ class TestSplitEncoderBuffer:
             FiraModel(cfg_bad).apply(params, jbatch, deterministic=True)
 
 
-def test_flat_scatter_is_bit_identical(tiny):
-    """cfg.flat_scatter lowers the dense adjacency as one linearized 1-D
-    scatter — same cells, same adds, bitwise-equal output (sorted and
-    unsorted streams, f32 and bf16 targets)."""
+@pytest.mark.parametrize("promised", (False, True),
+                         ids=("unsorted", "sorted"))
+@pytest.mark.parametrize("batch", (1, 16, 170, 340))
+def test_dense_adjacency_equals_a_numpy_scatter(batch, promised):
+    """The one adjacency scatter against ``np.add.at`` of the same padded
+    triplets, at the batch sizes the reference and the cells use (170 a
+    chip, 340 and up where the deleted N-D form went wrong on the TPU),
+    in raw order and host-sorted under the ``indices_sorted`` promise,
+    float32 and bfloat16 targets: the same cells, the same values."""
     from fira_tpu.data.batching import sort_edge_rows
 
-    cfg, _model, _params, jbatch = tiny
-    s_np = np.asarray(jbatch["senders"])
-    r_np = np.asarray(jbatch["receivers"])
-    v_np = np.asarray(jbatch["values"])
-    ss, rs, vs, _ = sort_edge_rows(s_np, r_np, v_np, None, cfg.graph_len)
-    streams = [(s_np, r_np, v_np, False),  # raw order, no sorted promise
-               (ss, rs, vs, True)]         # host-sorted, promise honored
+    N, E = 40, 96
+    rng = np.random.default_rng(batch)
+    s = np.zeros((batch, E), np.int16)
+    r = np.zeros((batch, E), np.int16)
+    v = np.zeros((batch, E), np.float32)
+    for b in range(batch):
+        # distinct cells a row, as graph_build's dedup guarantees; the
+        # rest of the row is (0, 0, 0.0) padding
+        n = int(rng.integers(0, E + 1))
+        cells = rng.choice(N * N, size=n, replace=False)
+        s[b, :n], r[b, :n] = cells // N, cells % N
+        v[b, :n] = rng.uniform(0.05, 1.0, size=n)
+    if promised:
+        s, r, v, _ = sort_edge_rows(s, r, v, None, N)
+    want = np.zeros((batch, N, N), np.float32)
+    np.add.at(want, (np.arange(batch)[:, None], s.astype(np.int64),
+                     r.astype(np.int64)), v)
+    assert np.count_nonzero(want) == np.count_nonzero(v)
     for out_dtype in (jnp.float32, jnp.bfloat16):
-        for s, r, v, sorted_flag in streams:
-            a = dense_adjacency(jnp.asarray(s), jnp.asarray(r),
-                                jnp.asarray(v), cfg.graph_len,
-                                indices_sorted=sorted_flag,
-                                out_dtype=out_dtype)
-            b = dense_adjacency(jnp.asarray(s), jnp.asarray(r),
-                                jnp.asarray(v), cfg.graph_len,
-                                indices_sorted=sorted_flag,
-                                out_dtype=out_dtype, flat=True)
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_flat_scatter_segment_path_is_rejected(tiny):
-    import dataclasses
-
-    cfg, _model, params, jbatch = tiny
-    cfg_bad = dataclasses.replace(cfg, adjacency_impl="segment",
-                                  flat_scatter=True)
-    with pytest.raises(ValueError, match="dense"):
-        FiraModel(cfg_bad).apply(params, jbatch, deterministic=True)
+        got = dense_adjacency(jnp.asarray(s), jnp.asarray(r), jnp.asarray(v),
+                              N, indices_sorted=promised,
+                              out_dtype=out_dtype)
+        assert got.shape == (batch, N, N) and got.dtype == out_dtype
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(jnp.float32)),
+            np.asarray(jnp.asarray(want).astype(out_dtype)
+                       .astype(jnp.float32)))
 
 
 def test_init_state_params_do_not_depend_on_batch_rows(tiny):

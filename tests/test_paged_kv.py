@@ -2,20 +2,19 @@
 
 Pins the ISSUE-7 contract (docs/DECODE_ENGINE.md "Paged KV arena"):
 
-- the paged engine's tokens are per-sample BIT-EXACT vs the
-  whole-sequence unpaged arena in all four kv-cache x factored-topk
-  modes and its probs equal to float32 rounding (the self-attention
+- the engine's tokens are per-sample BIT-EXACT vs the batched beam's
+  whole-sequence cache at every KV block size and under an undersized
+  pool, and its probs equal to float32 rounding (the self-attention
   sums over all beam lanes under the ancestry mask — ISSUE 29), and
   run_test file bytes are identical (single engine AND 2-replica fleet)
   with zero post-warmup compiles;
 - scheduling stays deterministic when the pool is UNDERSIZED: admission
   is head-of-line on block reservations, so output bytes are a pure
   function of the stream, pool size included;
-- the no-zeroing INVARIANT: insert touches neither the paged pools nor
-  the unpaged cache stripes (freed blocks are unmapped, never zeroed —
-  beam.step_valid_mask makes unwritten positions an exact 0.0), and a
-  dirty arena reused across streams stays bit-exact, so the old
-  two-full-arena-scatters-per-refill zeroing cannot silently reappear;
+- the no-zeroing INVARIANT: insert does not touch the pools (freed
+  blocks are unmapped, never zeroed — beam.step_valid_mask makes
+  unwritten positions an exact 0.0), and a dirty arena reused across
+  streams stays bit-exact, so a zeroing scatter cannot silently appear;
 - parse-time paging-knob validation (decode/paging.paging_errors): named
   -knob messages, CLI exit 2, and the fleet's per-replica pool split.
 """
@@ -25,6 +24,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+from beam_util import beam_outputs
 from fira_tpu.analysis import sanitizer
 from fira_tpu.config import fira_tiny
 from fira_tpu.data.dataset import FiraDataset
@@ -71,78 +71,71 @@ def _engine_outputs(model, params, dataset, cfg, **engine_kw):
 # the bound tests/test_engine.py states, and why
 PAGED_PROBS_RTOL = 1e-5
 
-MODES = [
-    # (kv_cache, factored_topk)
-    (True, False),
-    (True, True),
-    (False, False),
-    (False, True),
-]
+
+# (kv_block_size, pool blocks as a share of full residency); tar_len 12
+GEOMETRIES = {
+    "auto": (0, 1.0),            # 6 positions a block, 2 blocks a sequence
+    "block3": (3, 1.0),          # 4 blocks a sequence
+    "block-tar": (12, 1.0),      # one block a sequence
+    "block3-half-pool": (3, 0.5),  # admission waits for harvested blocks
+}
 
 
-@pytest.mark.parametrize("kv,fac", MODES)
-def test_paged_bit_exact_vs_unpaged(setup, kv, fac):
-    """Engine with the paged arena == engine with the whole-sequence
-    arena, per sample: tokens bitwise, probs to float32 rounding
-    (PAGED_PROBS_RTOL; bitwise until the pools stopped being reordered,
-    ISSUE 29) — the ROADMAP-4 regression contract, in every kv-cache x
-    factored-topk mode."""
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_paged_bit_exact_vs_whole_sequence_cache(setup, geometry):
+    """The engine over its block pool == the batched beam over whole-
+    sequence cache stripes, per sample, at every block size and under an
+    undersized pool: tokens bitwise, probs to float32 rounding
+    (PAGED_PROBS_RTOL) — the ROADMAP-4 regression contract."""
     cfg0, dataset, _dir, eos_params = setup
-    cfg = dataclasses.replace(cfg0, beam_kv_cache=kv, beam_factored_topk=fac)
+    block, share = GEOMETRIES[geometry]
+    bs = block or paging.resolve_block_size(cfg0)
+    W = paging.blocks_per_seq(cfg0.tar_len, bs)
+    slots = cfg0.test_batch_size
+    cfg = dataclasses.replace(
+        cfg0, kv_block_size=block,
+        kv_pool_blocks=0 if share == 1.0 else int(slots * W * share))
     model = FiraModel(cfg)
 
-    paged_out, paged_eng = _engine_outputs(
-        model, eos_params, dataset,
-        dataclasses.replace(cfg, engine_paged_kv=True))
-    if not kv:
-        # no K/V cache => nothing to page: the knob must be inert, and the
-        # stats must carry no phantom pool (pool_utilization 0.0 = no
-        # cache HBM committed at all)
-        assert paged_eng._paged is False
-        assert paged_eng.stats.pool_blocks == 0
-        assert paged_eng.stats.pool_utilization == 0.0
-        return
-    assert paged_eng._paged is True
-    st = paged_eng.stats.summary()
-    # full-residency auto pool: every slot holds a whole-tar reservation
-    assert st["pool_blocks"] == paged_eng.slots * paged_eng._table_width
-    assert st["kv_block_size"] == paging.resolve_block_size(cfg)
-    assert st["kv_bytes_per_slot"] > 0
+    paged_out, eng = _engine_outputs(model, eos_params, dataset, cfg)
+    st = eng.stats.summary()
+    assert st["kv_block_size"] == bs and eng._table_width == W
+    assert st["pool_blocks"] == int(slots * W * share)
+    # the machine-recorded HBM claim follows the pool, not the slots
+    assert st["kv_bytes_per_slot"] == paging.kv_bytes_per_slot(
+        cfg, block_size=bs, pool_blocks=st["pool_blocks"], slots=slots,
+        itemsize=paging.kv_itemsize(cfg))
     assert 0.0 < st["pool_utilization"] <= 1.0
     assert 0 < st["peak_blocks"] <= st["pool_blocks"]
+    if share < 1.0:
+        assert st["peak_blocks"] > st["pool_blocks"] - W   # the cap binds
+    # every grant came back: nothing leaked, nothing doubled
+    assert eng.allocator_invariants() == []
+    assert len(eng._free_blocks) == eng._pool_blocks
 
-    unpaged_out, unpaged_eng = _engine_outputs(
-        model, eos_params, dataset,
-        dataclasses.replace(cfg, engine_paged_kv=False))
-    assert unpaged_eng._paged is False
-    # the unpaged arena commits its whole-sequence stripes whether or not
-    # a slot is live: utilization pinned 1.0, the HBM the pool stops paying
-    assert unpaged_eng.stats.pool_utilization == 1.0
-    assert (unpaged_eng.stats.kv_bytes_per_slot
-            == paged_eng.stats.kv_bytes_per_slot)  # full residency: equal HBM
-
-    assert paged_out.keys() == unpaged_out.keys()
+    want = beam_outputs(model, eos_params, dataset.splits["train"], cfg)
+    assert paged_out.keys() == want.keys()
     for pos in paged_out:
-        np.testing.assert_array_equal(paged_out[pos][0], unpaged_out[pos][0])
+        np.testing.assert_array_equal(paged_out[pos][0], want[pos][0])
         # the paged step attends a slot's beams over all lanes of its
         # blocks under the ancestry mask: the same keys and values per
         # beam, the exact zeros of the others summed in another order
-        np.testing.assert_allclose(paged_out[pos][1], unpaged_out[pos][1],
+        np.testing.assert_allclose(paged_out[pos][1], want[pos][1],
                                    rtol=PAGED_PROBS_RTOL, atol=0)
 
 
 def test_paged_file_identical_zero_retraces_single_and_fleet(setup, tmp_path):
-    """run_test bytes + BLEU: paged == unpaged on a BUCKETED stream, with
-    zero post-warmup compiles under the armed sanitizer for the paged
-    single engine AND the paged 2-replica fleet (the paged step/insert
-    programs live under the SAME declared label family)."""
+    """run_test bytes + BLEU: engine == batched beam on a BUCKETED stream,
+    with zero post-warmup compiles under the armed sanitizer for the
+    single engine AND the 2-replica fleet (the step/insert programs live
+    under the SAME declared label family)."""
     cfg0, dataset, _dir, eos_params = setup
     cfg = dataclasses.replace(cfg0, buckets=((16, 400, 12),),
                               decode_engine=True)
     model = FiraModel(cfg)
     ref = run_test(model, eos_params, dataset,
-                   dataclasses.replace(cfg, engine_paged_kv=False),
-                   out_dir=str(tmp_path / "unpaged"), split="train")
+                   dataclasses.replace(cfg, decode_engine=False),
+                   out_dir=str(tmp_path / "batched"), split="train")
     ref_bytes = open(ref["output_path"], "rb").read()
 
     with sanitizer.sanitize(nans=False, infs=False) as guard:
@@ -197,43 +190,37 @@ def test_undersized_pool_head_of_line_deterministic(setup, tmp_path):
 
 def test_insert_never_zeroes_cache_and_dirty_arena_reuse(setup, tmp_path):
     """The comment-backed INVARIANT of engine._insert_fn: insert must not
-    touch the K/V buffers in EITHER arena — paged pools get new block
-    GRANTS (table rows), unpaged stripes get nothing (stale positions are
-    -1e9-masked to an exact 0.0 by beam.step_valid_mask). Pinned by
-    object identity through an EAGER insert, so a reintroduced zeroing
-    scatter fails here even before any output diverges — plus bit-exact
-    file bytes from a deliberately DIRTY arena reused across streams."""
+    touch the K/V pools — a seated slot gets new block GRANTS (table
+    rows), nothing else (stale positions are -1e9-masked to an exact 0.0
+    by beam.step_valid_mask). Pinned by object identity through an EAGER
+    insert, so a zeroing scatter fails here even before any output
+    diverges — plus bit-exact file bytes from a deliberately DIRTY arena
+    reused across streams."""
     cfg0, dataset, _dir, eos_params = setup
     data = dataset.splits["train"]
 
-    for paged, fields in ((True, ("k_pool", "v_pool")),
-                          (False, ("k_cache", "v_cache"))):
-        cfg = dataclasses.replace(cfg0, beam_kv_cache=True,
-                                  engine_paged_kv=paged)
-        model = FiraModel(cfg)
-        eng = engine_lib.SlotEngine(model, eos_params, cfg)
-        tasks, _ = _decode_tasks(data, cfg)
-        with Feeder(tasks, num_workers=0, depth=1) as feed:
-            for _ in eng.run(feed):
-                pass
-        state = eng._state  # dirty: every slot has decoded real samples
-        from fira_tpu.data.batching import make_batch
+    model = FiraModel(cfg0)
+    eng = engine_lib.SlotEngine(model, eos_params, cfg0)
+    tasks, _ = _decode_tasks(data, cfg0)
+    with Feeder(tasks, num_workers=0, depth=1) as feed:
+        for _ in eng.run(feed):
+            pass
+    state = eng._state  # dirty: every slot has decoded real samples
+    from fira_tpu.data.batching import make_batch
 
-        host = make_batch(data, np.arange(cfg.test_batch_size), cfg,
-                          batch_size=cfg.test_batch_size)
-        chunk = eng._prefill(eng.params, host)
-        C = host["valid"].shape[0]
-        slot_ids = np.arange(C, dtype=np.int32)
-        limits = np.full((C,), cfg.tar_len, np.int32)
-        block_rows = None
-        if paged:
-            W = eng._table_width
-            block_rows = np.arange(C * W, dtype=np.int32).reshape(C, W)
-        new = eng._insert_fn(state, chunk, slot_ids, limits, block_rows)
-        for f in fields:
-            assert new[f] is state[f], (
-                f"insert touched {f}: the no-zeroing invariant broke — "
-                f"freed blocks/stripes must be unmapped, never zeroed")
+    host = make_batch(data, np.arange(cfg0.test_batch_size), cfg0,
+                      batch_size=cfg0.test_batch_size)
+    chunk = eng._prefill(eng.params, host)
+    C = host["valid"].shape[0]
+    slot_ids = np.arange(C, dtype=np.int32)
+    limits = np.full((C,), cfg0.tar_len, np.int32)
+    W = eng._table_width
+    block_rows = np.arange(C * W, dtype=np.int32).reshape(C, W)
+    new = eng._insert_fn(state, chunk, slot_ids, limits, block_rows)
+    for f in ("k_pool", "v_pool"):
+        assert new[f] is state[f], (
+            f"insert touched {f}: the no-zeroing invariant broke — "
+            f"freed blocks must be unmapped, never zeroed")
 
     # dirty-arena reuse: second drain of the SAME engine starts from pools
     # full of the first drain's values; bytes must not change
@@ -272,29 +259,37 @@ def test_auto_block_size_and_byte_accounting():
     isz = paging.kv_itemsize(cfg)
     assert isz == 4  # fira_tiny defaults kv_dtype="f32"
     assert paging.kv_itemsize(cfg.replace(kv_dtype="bf16")) == 2
-    # full residency: the paged pool commits exactly the unpaged bytes
+    # full residency: the pool commits exactly a whole-sequence stripe's
+    # bytes a slot — K and V, every layer, beam, head and position
+    stripe = (2 * cfg.num_layers * cfg.beam_size * cfg.embedding_dim
+              * cfg.tar_len * isz)
     assert paging.kv_bytes_per_slot(
-        cfg, paged=True, block_size=bs, pool_blocks=slots * W, slots=slots,
-        itemsize=isz) == paging.kv_bytes_per_slot(
-        cfg, paged=False, block_size=0, pool_blocks=0, slots=slots,
-        itemsize=isz)
+        cfg, block_size=bs, pool_blocks=slots * W, slots=slots,
+        itemsize=isz) == stripe
     # half the pool: half the committed HBM per slot
     assert paging.kv_bytes_per_slot(
-        cfg, paged=True, block_size=bs, pool_blocks=slots * W // 2,
-        slots=slots, itemsize=isz) == paging.kv_bytes_per_slot(
-        cfg, paged=False, block_size=0, pool_blocks=0, slots=slots,
-        itemsize=isz) // 2
+        cfg, block_size=bs, pool_blocks=slots * W // 2,
+        slots=slots, itemsize=isz) == stripe // 2
 
 
 def test_paging_errors_named_knob_messages():
-    base = fira_tiny().replace(decode_engine=True)  # beam_kv_cache defaults on
+    base = fira_tiny().replace(decode_engine=True)
 
     assert paging.paging_errors(base) == []  # auto knobs always admissible
-    # paging disabled (either knob) => nothing to validate
-    assert paging.paging_errors(base.replace(engine_paged_kv=False,
-                                             kv_block_size=5)) == []
+    # no engine => nothing to validate
     assert paging.paging_errors(base.replace(decode_engine=False,
                                              kv_block_size=5)) == []
+    # the whole-sequence arena is gone: asking for it is refused by name,
+    # with or without the engine; the batched beam's knobs do not reach
+    # the engine's arena at all
+    for asked in (base, base.replace(decode_engine=False)):
+        errs = paging.paging_errors(asked.replace(engine_paged_kv=False))
+        assert len(errs) == 1 and errs[0].startswith("engine_paged_kv False")
+    assert paging.paging_errors(base.replace(beam_kv_cache=False,
+                                             beam_factored_topk=True)) == []
+    errs = paging.paging_errors(base.replace(beam_kv_cache=False,
+                                             kv_block_size=5))
+    assert len(errs) == 1 and "kv_block_size 5" in errs[0]
 
     errs = paging.paging_errors(base.replace(kv_block_size=5))
     assert len(errs) == 1 and "does not divide decode tar budget 12" in errs[0]
@@ -323,10 +318,10 @@ def test_paging_errors_named_knob_messages():
                                              kv_pool_blocks=16)) == []
 
 
-def test_cli_exits_2_on_paging_knobs(setup, tmp_path):
+def test_cli_exits_2_on_paging_knobs(setup, tmp_path, monkeypatch, capsys):
     """Parse-time rejection with named-knob messages — not a mid-run
     shape error (the exit-2 contract of parallel.mesh/fleet)."""
-    from fira_tpu import cli
+    from fira_tpu import cli, config
 
     _cfg, _dataset, data_dir, _params = setup
     base = ["test", "--data-dir", data_dir, "--config", "fira-tiny",
@@ -335,11 +330,23 @@ def test_cli_exits_2_on_paging_knobs(setup, tmp_path):
     assert cli.main(base + ["--kv-pool-blocks", "10"]) == 2
     assert cli.main(base + ["--engine-replicas", "2",
                             "--kv-pool-blocks", "7"]) == 2
-    # --kv-paged off makes the same knobs inert: an invalid block size
-    # must NOT exit 2 (nothing is paged) — the run then fails on the
-    # missing checkpoint (rc 1), i.e. it got PAST parse-time validation
-    rc = cli.main(base + ["--kv-paged", "off", "--kv-block-size", "5"])
-    assert rc == 1
+    # the CLI has no flag for the arena any more (argparse's own exit 2)
+    with pytest.raises(SystemExit) as e:
+        cli.main(base + ["--kv-paged", "off"])
+    assert e.value.code == 2
+    # a configuration that still asks for the whole-sequence arena is
+    # refused at parse time, by the knob's name
+    monkeypatch.setitem(
+        config.NAMED_CONFIGS, "fira-tiny-unpaged",
+        lambda **kw: fira_tiny(engine_paged_kv=False, **kw))
+    capsys.readouterr()
+    base[base.index("fira-tiny")] = "fira-tiny-unpaged"
+    assert cli.main(base) == 2
+    assert "engine_paged_kv False" in capsys.readouterr().err
+    # valid knobs get PAST parse-time validation: the run then fails on
+    # the missing checkpoint (rc 1)
+    base[base.index("fira-tiny-unpaged")] = "fira-tiny"
+    assert cli.main(base + ["--kv-block-size", "3"]) == 1
 
 
 def test_fleet_pool_split_per_replica(setup):

@@ -4,8 +4,8 @@ docs/DECODE_ENGINE.md "Prefix cache & dedup").
 Pins the ISSUE-11 contract:
 
 - BIT-EXACTNESS: a cache-hit or deduped response equals its cold run —
-  tokens AND probs, in all four kv-cache x factored-topk modes, paged
-  and unpaged; serve/drain output bytes are identical cache-on vs
+  tokens AND probs, at every engine shape (slots, cadence, beams,
+  block size, pool size); serve/drain output bytes are identical cache-on vs
   cache-off (the ``--prefix-cache off`` equivalence comparator), and
   cache-off itself is byte-identical to pre-PR behavior (zero cache
   counters, no digests computed);
@@ -75,26 +75,26 @@ def _drain(model, params, dataset, cfg):
     return out, eng
 
 
-MODES = [
-    # (kv_cache, factored_topk, paged)
-    (True, False, True),
-    (True, False, False),
-    (True, True, True),
-    (True, True, False),
-    (False, False, False),
-    (False, True, False),
-]
+# the engine shapes a cached seat has to fit, as production varies them
+SHAPES = {
+    "defaults": dict(),
+    "r8": dict(engine_harvest_every=8),
+    "slots3": dict(engine_slots=3),              # != the chunks' 4 rows
+    "beam1-log": dict(beam_size=1, beam_compat_prob_space=False),
+    "block-tar-slots6": dict(kv_block_size=12, engine_slots=6),
+    "block3-half-pool": dict(kv_block_size=3, kv_pool_blocks=8),
+}
 
 
-@pytest.mark.parametrize("kv,fac,paged", MODES)
-def test_cache_hit_bit_exact_vs_cold(setup, kv, fac, paged):
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_cache_hit_bit_exact_vs_cold(setup, shape):
     """The regression contract: cache-on output (tokens AND probs) is
-    bitwise equal to cache-off on a repeated stream, in every kv-cache x
-    factored-topk mode, paged and unpaged — and the reuse actually
-    happened (hits + coalesced deliveries + saved dispatches metered)."""
+    bitwise equal to cache-off on a repeated stream, at every engine shape
+    (slots, harvest cadence, beams and score space, KV block size, an
+    undersized pool) — and the reuse actually happened (hits + coalesced
+    deliveries + saved dispatches metered)."""
     cfg0, dataset, _dir, params = setup
-    cfg = dataclasses.replace(cfg0, beam_kv_cache=kv, beam_factored_topk=fac,
-                              engine_paged_kv=paged)
+    cfg = dataclasses.replace(cfg0, **SHAPES[shape])
     model = FiraModel(cfg)
     cold, cold_eng = _drain(model, params, dataset, cfg)
     warm, warm_eng = _drain(model, params, dataset,
@@ -112,11 +112,10 @@ def test_cache_hit_bit_exact_vs_cold(setup, kv, fac, paged):
     assert cold_eng.stats.cache_hits == cold_eng.stats.cache_misses == 0
     assert cold_eng.stats.dedup_fanout == 0
     assert cold_eng._cache is None
-    if paged and kv:
-        # allocator drained back to baseline, no grant leaked or doubled
-        assert warm_eng.allocator_invariants() == []
-        assert len(warm_eng._free_blocks) == warm_eng._pool_blocks
-        assert warm_eng._block_refs == {}
+    # allocator drained back to baseline, no grant leaked or doubled
+    assert warm_eng.allocator_invariants() == []
+    assert len(warm_eng._free_blocks) == warm_eng._pool_blocks
+    assert warm_eng._block_refs == {}
 
 
 def test_serve_dedup_fanout_records_one_seat(setup, tmp_path):

@@ -14,7 +14,7 @@ Pins the tier contract (docs/DECODE_ENGINE.md "Low-precision tiers"):
 - ``kv_bytes_per_slot`` derives from the arena's ACTUAL dtype (stats
   stamp ``kv_dtype``/``serve_precision``), halving under the bf16 arena;
 - within a tier, output bytes stay a pure function of the stream —
-  repeat runs, paged vs unpaged, harvest cadence, replica count — and
+  repeat runs, slot count, harvest cadence, replica count — and
   a fleet respawn re-quantizes by construction.
 
 Engine-driving legs are slow-marked per the PR-15 rig note (tier-1 wall
@@ -269,12 +269,12 @@ def test_bf16_arena_halves_kv_bytes_and_stamps_stats(engine_setup):
     # f32 figure
     bs = paging.resolve_block_size(cfg)
     expect = paging.kv_bytes_per_slot(
-        cfg, paged=True, block_size=bs,
+        cfg, block_size=bs,
         pool_blocks=paging.auto_pool_blocks(cfg, eng.slots),
         slots=eng.slots, itemsize=paging.kv_itemsize(cfg))
     assert s["kv_bytes_per_slot"] == expect
     assert expect * 2 == paging.kv_bytes_per_slot(
-        cfg, paged=True, block_size=bs,
+        cfg, block_size=bs,
         pool_blocks=paging.auto_pool_blocks(cfg, eng.slots),
         slots=eng.slots, itemsize=4)
     # labels carry the tier suffix (new program family, compile-guarded)
@@ -285,7 +285,7 @@ def test_bf16_arena_halves_kv_bytes_and_stamps_stats(engine_setup):
 @pytest.mark.slow
 def test_within_tier_byte_stability(engine_setup):
     """Within a tier, (tokens, probs) are a pure function of the stream:
-    repeat runs and paged-vs-unpaged agree bitwise. (Cross-tier drift is
+    repeat runs and another slot count agree bitwise. (Cross-tier drift is
     allowed — and MEASURED, by the bench's bleu_delta_vs_f32.)"""
     cfg0, dataset, _dir, params = engine_setup
     tier = dataclasses.replace(cfg0, decode_engine=True, kv_dtype="bf16",
@@ -293,7 +293,7 @@ def test_within_tier_byte_stability(engine_setup):
     a, _ = _engine_outputs(params, tier, dataset)
     b, _ = _engine_outputs(params, tier, dataset)
     c, _ = _engine_outputs(
-        params, dataclasses.replace(tier, engine_paged_kv=False), dataset)
+        params, dataclasses.replace(tier, engine_slots=4), dataset)
     assert set(a) == set(b) == set(c)
     for p in a:
         np.testing.assert_array_equal(a[p][0], b[p][0])

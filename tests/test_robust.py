@@ -371,19 +371,30 @@ def test_serve_watchdog_retires_hung_replica(setup, trace, drain_lines,
                                              tmp_path):
     """An injected hang past the dispatch watchdog: the hung dispatch is
     abandoned, the replica retired, and the run still completes with
-    no-fault bytes — bounded wall clock, never a wedge."""
+    no-fault bytes — never a wedge.
+
+    Both real-clock limits are set against the HANG, not against the
+    host's speed (the test used to hold a 0.25 s watchdog and a 60 s wall
+    limit, and a loaded host — six test workers beside it — ran the 7 s
+    of compiles past the 60): the hang outlasts any run of this test, so
+    finishing before it ends IS the proof that the dispatch was abandoned
+    rather than waited out, and the watchdog leaves a healthy dispatch
+    (6 ms alone, 0.09 s at a twentieth of the speed) a second. The
+    abandoned daemon thread sleeps on and bails on ``retired`` when it
+    wakes."""
     cfg, dataset, params = setup
     c = dataclasses.replace(cfg, engine_replicas=2,
                             inject_faults="engine.step:hang:0.02:18",
-                            fault_hang_s=1.5, dispatch_watchdog_s=0.25)
+                            fault_hang_s=600.0, dispatch_watchdog_s=1.0)
     t0 = time.perf_counter()
     m = serve_split(FiraModel(cfg), params, dataset, c, arrival_times=trace,
                     out_dir=str(tmp_path / "hang"), split="train",
                     clock="virtual")
-    assert time.perf_counter() - t0 < 60
+    assert time.perf_counter() - t0 < c.fault_hang_s     # abandoned
     sv = m["serve"]
     assert m["faults"]["engine.step"] >= 1
     assert sv["replica_retirements"] >= 1
+    assert sv["requeued_requests"] >= 1   # what it owed went to a survivor
     assert sv["completed"] == sv["offered"]
     assert sv["retired_replicas"]  # the abandoned replica is named
     assert open(m["output_path"]).read() == "\n".join(drain_lines)

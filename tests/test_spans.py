@@ -6,6 +6,7 @@ host plane, the drain generator's explicit root. CPU, fira-tiny."""
 import glob
 import json
 import os
+import statistics
 import threading
 import time
 
@@ -407,7 +408,14 @@ def test_records_link_requests_to_rounds(served):
 def test_spans_are_on_the_profilers_host_plane(served):
     """Every serve.* / engine.* name of the window is an event on /host:CPU
     of the .xplane.pb — the device trace's clock — with the ring's
-    duration."""
+    duration. The annotation is entered before the ring's clock is read
+    and left after it (profiling._Span), so it is never the shorter of the
+    two; the two gaps are microseconds unless the host takes the thread
+    away inside one (seen: 3.6 ms on one span of a loaded run). So every
+    pair is bounded — the event encloses its span and is longer by at
+    most a scheduler quantum — and every name's median pair agrees within
+    0.2 ms: a span annotated wrongly fails on its own name however few
+    events it has, one preemption does not."""
     from jax.profiler import ProfileData
 
     (path,) = glob.glob(os.path.join(served["trace_dir"], "**",
@@ -428,8 +436,11 @@ def test_spans_are_on_the_profilers_host_plane(served):
                       if e.name == name)
         traced = sorted(host.get(name, []))
         assert len(traced) == len(ring), name
-        for (_t, want), (_s, got_ns) in zip(ring, traced):
-            assert got_ns / 1e9 == pytest.approx(want, abs=2e-4), name
+        longer = [got_ns / 1e9 - want
+                  for (_t, want), (_s, got_ns) in zip(ring, traced)]
+        assert min(longer) >= -2e-4, name               # it encloses
+        assert max(longer) <= 2e-2, name                # one quantum
+        assert abs(statistics.median(longer)) <= 2e-4, name
 
 
 def test_drain_generator_root_and_feeder_spans(setup):

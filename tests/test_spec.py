@@ -3,8 +3,8 @@
 Pins the spec contract (docs/DECODE_ENGINE.md "Speculative drafting"):
 
 - accepted output BIT-EXACT (tokens AND probs, file bytes) vs plain
-  engine decode — in the kv-cache x factored-topk modes, paged and
-  unpaged, for both drafter tiers;
+  engine decode — for both drafter tiers, at the engine's default shape
+  and at one of its own (slots, cadence, block size, score space);
 - file bytes invariant to the draft length k, the harvest cadence, and
   the replica count — the acceptance pattern is scheduling, never output;
 - real work: acceptances > 0 on draftable streams (the copy tier
@@ -64,25 +64,21 @@ def _engine_outputs(model, params, cfg, dataset):
     return out, eng.stats
 
 
-# every kv x factored mode, both tiers covered across the matrix (the
-# k/cadence file-bytes test and the check.sh spec smoke cover the
-# transposed tier assignments)
-MODES_TIERS = [
-    # (kv_cache, factored_topk, tier)
-    (True, False, "draft"),
-    (True, True, "copy"),
-    (False, False, "copy"),
-    (False, True, "draft"),
-]
+# the engine's shape under the drafter, as production varies it
+SHAPES = {
+    "defaults": dict(),
+    "own-shape": dict(engine_slots=4, engine_harvest_every=1,
+                      kv_block_size=3, beam_compat_prob_space=False),
+}
 
 
-@pytest.mark.parametrize("kv,fac,tier", MODES_TIERS)
-def test_spec_bit_exact_per_sample(setup, kv, fac, tier):
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+@pytest.mark.parametrize("tier", ("copy", "draft"))
+def test_spec_bit_exact_per_sample(setup, tier, shape):
     """Spec-on (tokens, probs) == spec-off (tokens, probs), per sample,
     bitwise — acceptance moves scheduling only, never output."""
     cfg0, dataset, _dir, _params, eos_params = setup
-    base = dataclasses.replace(cfg0, beam_kv_cache=kv,
-                               beam_factored_topk=fac, decode_engine=True)
+    base = dataclasses.replace(cfg0, decode_engine=True, **SHAPES[shape])
     model = FiraModel(base)
     ref, ref_stats = _engine_outputs(model, eos_params, base, dataset)
     got, stats = _engine_outputs(
@@ -110,7 +106,7 @@ def test_spec_bit_exact_per_sample(setup, kv, fac, tier):
 
 def test_spec_file_bytes_invariant_to_k_cadence_and_paging(setup, tmp_path):
     """run_test file bytes: plain engine == spec for k in {2, 4, 8}, any
-    harvest cadence, paged and unpaged — under the armed sanitizer with
+    harvest cadence and any KV block size — under the armed sanitizer with
     the draft/verify programs declared in the guard family (zero
     post-warmup compiles)."""
     cfg0, dataset, _dir, _params, eos_params = setup
@@ -125,7 +121,7 @@ def test_spec_file_bytes_invariant_to_k_cadence_and_paging(setup, tmp_path):
     variants = [
         dict(spec_decode="draft", engine_spec_k=2, engine_harvest_every=1),
         dict(spec_decode="draft", engine_spec_k=8, engine_harvest_every=3),
-        dict(spec_decode="copy", engine_spec_k=4, engine_paged_kv=False),
+        dict(spec_decode="copy", engine_spec_k=4, kv_block_size=3),
     ]
     for i, v in enumerate(variants):
         c = dataclasses.replace(cfg, **v)

@@ -94,11 +94,11 @@ def test_mesh_train_step_and_tp_shardings(tiny_setup):
     # production-config encoder: split buffer + sorted scatter — guards the
     # column-slab einsums' sharding propagation under the Megatron TP rules
     {"encoder_buffer": "split", "sort_edges": True},
-    # flat 1-D adjacency scatter: the lowering flattens the batch axis into
-    # B*N*N, so its GSPMD propagation deserves its own mesh pin before the
-    # unattended TPU ablation runs it
-    {"flat_scatter": True, "sort_edges": True},
-], ids=["parity", "split_buffer", "flat_scatter"])
+    # the adjacency is one flat 1-D scatter: the lowering flattens the
+    # batch axis into B*N*N, so its GSPMD propagation under the sorted
+    # promise (the train cell's knobs) deserves its own mesh pin
+    {"sort_edges": True},
+], ids=["parity", "split_buffer", "sorted_scatter"])
 def test_mesh_matches_single_device_loss(tiny_setup, overrides):
     """DP+TP sharded step computes the same loss as the unsharded step."""
     dataset = tiny_setup
