@@ -15,8 +15,9 @@ with per-batch max length.
 
 Program family (all fixed-shape, labelled for the compile guard —
 ``engine_prefill[<geom>]`` x the decode bucket table, ``engine_step``,
-``engine_insert``, ``engine_harvest`` (the sliced-readback row gather);
-zero post-warmup retraces):
+``engine_insert``, ``engine_harvest`` (the batched row gather of a
+harvest's readback: one program for any number of settled rows); zero
+post-warmup retraces):
 
 - **prefill** (one per decode bucket geometry): encoder forward + per-beam
   cross-attention K/V + copy-head source projection for ONE packed batch
@@ -177,12 +178,14 @@ class EngineStats:
     kv_bytes_per_slot: int = 0   # committed K+V cache HBM per slot
     block_steps: int = 0         # blocks in use, summed per step dispatch
     peak_blocks: int = 0         # high-water mark of blocks in use
-    # sliced-harvest readback accounting: harvest copies ONLY the settled
-    # slots' token/prob rows D2H (one jitted dynamic-index gather per
-    # row) instead of the full (S, K, T) / (S, K) arenas per harvest
-    harvest_row_reads: int = 0   # settled-slot rows read back individually
-    harvest_bytes_read: int = 0  # token/prob bytes actually copied D2H
-    harvest_bytes_saved: int = 0  # vs the historical full-arena readback
+    # harvest readback accounting: a harvest that settles rows gathers
+    # ALL of them with one program dispatch and reads the result with one
+    # blocking transfer (rows a read = harvest_row_reads / harvest_reads)
+    harvest_reads: int = 0       # batched readbacks done (one a harvest
+    #                              that settled rows)
+    harvest_row_reads: int = 0   # settled-slot rows those reads delivered
+    harvest_bytes_read: int = 0  # token/prob bytes that crossed D2H (the
+    #                              gather's whole padded result)
     # cross-request reuse accounting (decode/prefix_cache.py; all zero
     # when cfg.prefix_cache is off — the byte-identical comparator)
     cache_hits: int = 0          # seated rows served from the prefill cache
@@ -288,9 +291,9 @@ class EngineStats:
             "kv_bytes_per_slot": self.kv_bytes_per_slot,
             "peak_blocks": self.peak_blocks,
             "pool_utilization": round(self.pool_utilization, 4),
+            "harvest_reads": self.harvest_reads,
             "harvest_row_reads": self.harvest_row_reads,
             "harvest_bytes_read": self.harvest_bytes_read,
-            "harvest_bytes_saved": self.harvest_bytes_saved,
             "cache_hits": self.cache_hits,
             "cache_misses": self.cache_misses,
             "cache_hit_rate": round(self.cache_hit_rate, 4),
@@ -462,14 +465,13 @@ class SlotEngine:
         # holds exactly one live state, rebound on every dispatch
         self._step = jax.jit(self._step_fn, donate_argnums=(1,))
         self._insert = jax.jit(self._insert_fn, donate_argnums=(0,))
-        # sliced harvest readback: one tiny program gathers a SINGLE
-        # settled slot's (tokens, probs) rows so the D2H copy is the
-        # slot's own bytes, not the whole (S, K, T) arena. dynamic_index
-        # keeps the slot id a runtime value — one compile for any slot,
-        # not one per slot constant.
-        self._take_rows = jax.jit(lambda tokens, probs, slot: (
-            jax.lax.dynamic_index_in_dim(tokens, slot, 0, keepdims=False),
-            jax.lax.dynamic_index_in_dim(probs, slot, 0, keepdims=False)))
+        # harvest readback: one tiny program gathers EVERY settled slot's
+        # (tokens, probs) rows of a harvest into a buffer of its own (not
+        # a view of the arena, which the next dispatch donates). The slot
+        # ids are data — an int32 vector as long as the arena, padded by
+        # harvest() — so it is one compile a configuration whatever the
+        # number of rows that settle.
+        self._take_rows = jax.jit(self._take_rows_fn)
         self._pending_occ = None
         # speculative draft-and-verify (decode/spec.py; cfg.spec_decode):
         # the drafter reads the arena (never donated — the verify right
@@ -511,7 +513,7 @@ class SlotEngine:
     def labels(self, table=None) -> List[str]:
         """This engine's full declared program family: one prefill label
         per decode bucket geometry (or the untagged prefill when no table)
-        plus step + insert + the sliced-harvest row gather."""
+        plus step + insert + the harvest's batched row gather."""
         from fira_tpu.data.buckets import geom_tag
 
         prefills = ([self.label(PREFILL_KIND, geom_tag(g)) for g in table]
@@ -778,6 +780,13 @@ class SlotEngine:
         # firacheck: allow[RETIRED-RECHECK] arena-state write: retire() deliberately leaves the arena in place ("the arena and stats stay") and a dead engine's _state is never read again — only scheduling/guard state needs the post-dispatch re-check
         self._state = jax.device_put(z, self.device)
 
+    @staticmethod
+    def _take_rows_fn(tokens, probs, idx):
+        """Rows ``idx`` (int32, one entry a slot of the arena) of the two
+        output leaves, as buffers of their own."""
+        return (jnp.take(tokens, idx, axis=0, mode="clip"),
+                jnp.take(probs, idx, axis=0, mode="clip"))
+
     # --- host scheduler --------------------------------------------------
 
     def _guard_step(self, label: str) -> None:
@@ -791,11 +800,12 @@ class SlotEngine:
         per decode bucket geometry (the prefill compile keys), then one
         no-op insert (every slot id the drop sentinel), one step over the
         all-dead arena (no slot active — the state is untouched), and one
-        harvest row gather. Outputs are unchanged by construction (pinned
-        by the byte-equality tests); the point is that NO dispatch after
-        prewarm pays a compile — which the per-dispatch wall-clock
-        watchdog (docs/FAULTS.md) depends on: a first-use XLA compile
-        inside a watchdogged dispatch would read as a hung replica."""
+        harvest row gather at its one index length. Outputs are unchanged
+        by construction (pinned by the byte-equality tests); the point is
+        that NO dispatch after prewarm pays a compile — which the
+        per-dispatch wall-clock watchdog (docs/FAULTS.md) depends on: a
+        first-use XLA compile inside a watchdogged dispatch would read as
+        a hung replica."""
         # one span a program: what a span holds is that program's trace,
         # compile (or cache load) and dispatch — the host's share — and the
         # compile listener's xla.compile events land under the program
@@ -828,7 +838,7 @@ class SlotEngine:
             self._pending_occ = occ  # zero: no slot was active
         with profiling.span("engine.prewarm.take_rows"):
             self._take_rows(self._state["tokens"], self._state["probs"],
-                            jnp.int32(0))
+                            np.zeros((self.slots,), dtype=np.int32))
         self._guard_step(self.label(HARVEST_LABEL))
         if self._spec_tier is not None:
             # compile the (S, k) draft/verify pair over the all-dead arena:
@@ -1415,16 +1425,19 @@ class SlotEngine:
     @profiling.span("engine.harvest")
     def harvest(self) -> List[EngineItem]:
         """Read back the dispatched step's done mask and return every
-        newly settled slot's sample. The readback is SLICED: one jitted
-        dynamic-index gather per settled slot copies only that slot's
-        (tokens, probs) rows D2H instead of the whole arena per harvest —
-        the saved bytes are metered (``harvest_bytes_saved``). COPIES,
-        not views: the next dispatch DONATES the arena buffers, and on
-        the CPU backend a zero-copy device_get view into a donated buffer
-        dangles. Items are materialized EAGERLY (a plain list, not a lazy
-        generator) for the same reason: a caller interleaving refill()
-        between items would donate the arena out from under a pending
-        row gather."""
+        newly settled slot's sample. The readback is BATCHED: however
+        many slots settled, ONE ``_take_rows`` dispatch gathers their
+        (tokens, probs) rows — the slot ids go in as a host index vector
+        as long as the arena, padded with the first settled slot, so the
+        program never recompiles — and ONE blocking ``device_get`` of the
+        pair brings them to the host, where they are sliced per slot (a
+        round trip to the chip costs ~1.5 ms whatever it carries; the
+        bytes never were the cost: PERF.md, PR 31). COPIES, not views:
+        the gather's result is a device buffer of its own, which the next
+        dispatch's donation of the arena cannot touch, and ``np.array``
+        makes the host side writable and independent of it. Items are
+        materialized EAGERLY (a plain list, not a lazy generator): the
+        bookkeeping below must be whole before a caller's refill()."""
         if self._faults is not None:
             self._faults.check("engine.harvest")
         if self.retired:
@@ -1439,7 +1452,7 @@ class SlotEngine:
         # engine.harvest.wait: the first blocking reads — the HOST waiting
         # for the dispatched step (device busy); everything after it in
         # this method is engine.harvest.read — the DEVICE waiting for the
-        # host (one gather dispatch + D2H per settled row)
+        # host (one gather dispatch + one D2H read for all settled rows)
         with profiling.span("engine.harvest.wait"):
             occ_now = int(np.array(jax.device_get(self._pending_occ)))
             stats.occupied_slot_steps += occ_now
@@ -1484,34 +1497,32 @@ class SlotEngine:
         items: List[EngineItem] = []
         if newly:   # a harvest that settles nothing records no read
             with profiling.span("engine.harvest.read", rows=len(newly)):
-                tokens, probs = self._state["tokens"], self._state["probs"]
-                full_bytes = tokens.nbytes + probs.nbytes
-                row_bytes = full_bytes // self.slots
-                # PHASE 1 — readbacks only, no bookkeeping: a watchdog expiry
-                # mid-device_get abandons this thread with every settled slot
-                # still in _busy, so retire() requeues ALL of them (popping
-                # as we read would strand the already-popped, never-delivered
-                # requests). Phase 2 is pure host dict work — microseconds,
+                # PHASE 1 — the readback only, no bookkeeping: a watchdog
+                # expiry mid-device_get abandons this thread with every
+                # settled slot still in _busy, so retire() requeues ALL of
+                # them. Phase 2 is pure host dict work — microseconds,
                 # nothing left to hang on.
-                reads = []
-                for s in newly:
-                    if self.retired:
-                        return []  # abandoned by a watchdog mid-harvest
-                    toks_s, probs_s = self._take_rows(tokens, probs,
-                                                      jnp.int32(s))
-                    toks_np = np.array(jax.device_get(toks_s))  # firacheck: allow[HOST-SYNC] harvest IS the engine's designated output boundary: settled beams must reach the host to be cooked into text, and the sliced row gather is exactly the copy this readback exists to make
-                    probs_np = np.array(jax.device_get(probs_s))  # firacheck: allow[HOST-SYNC] same harvest output boundary as the line above
-                    if self.retired:
-                        # the gather/readback above is exactly the window a
-                        # watchdog expiry abandons this thread inside: the
-                        # live loop owns the shared compile guard now
-                        return []
-                    self._guard_step(self.label(HARVEST_LABEL))
-                    reads.append((s, toks_np, probs_np))
                 if self.retired:
+                    return []  # abandoned by a watchdog mid-harvest
+                idx = np.full((self.slots,), newly[0], dtype=np.int32)
+                idx[:len(newly)] = newly
+                rows = self._take_rows(self._state["tokens"],
+                                       self._state["probs"], idx)
+                # the ONE blocking read: harvest is the engine's designated
+                # output boundary (settled beams must reach the host to be
+                # cooked into text); both copies are in flight together
+                toks_np, probs_np = (np.array(a)
+                                     for a in jax.device_get(rows))
+                if self.retired:
+                    # the gather/readback above is exactly the window a
+                    # watchdog expiry abandons this thread inside: the
+                    # live loop owns the shared compile guard now
                     return []
-                # PHASE 2 — every readback landed: retire the bookkeeping
-                for s, toks_np, probs_np in reads:
+                self._guard_step(self.label(HARVEST_LABEL))
+                stats.harvest_reads += 1
+                stats.harvest_bytes_read += toks_np.nbytes + probs_np.nbytes
+                # PHASE 2 — the readback landed: retire the bookkeeping
+                for i, s in enumerate(newly):
                     pos_id, host, r = self._busy.pop(s)
                     self._free.append(s)
                     # the slot's block grant is RELEASED through the
@@ -1522,9 +1533,9 @@ class SlotEngine:
                     self._release_blocks(self._slot_blocks.pop(s, ()))
                     stats.commits += 1
                     stats.harvest_row_reads += 1
-                    stats.harvest_bytes_read += row_bytes
+                    toks_s, probs_s = toks_np[i], probs_np[i]
                     items.append(EngineItem(position=pos_id, host=host, row=r,
-                                            tokens=toks_np, probs=probs_np))
+                                            tokens=toks_s, probs=probs_s))
                     # dedup fan-out delivery: every follower coalesced onto
                     # this seat gets the leader's settled beams at its OWN
                     # output position (one decode, N commits — byte-identical
@@ -1535,10 +1546,8 @@ class SlotEngine:
                     for fpos, fhost, frow in self._followers.pop(pos_id, ()):
                         stats.commits += 1
                         items.append(EngineItem(position=fpos, host=fhost,
-                                                row=frow, tokens=toks_np,
-                                                probs=probs_np))
-                stats.harvest_bytes_saved += (full_bytes
-                                              - row_bytes * len(reads))
+                                                row=frow, tokens=toks_s,
+                                                probs=probs_s))
         return items
 
     def run(self, feed, *, refill_order: str = "fifo"

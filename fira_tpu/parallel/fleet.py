@@ -149,11 +149,12 @@ class FleetStats:
             "step_dispatches": tot("step_dispatches"),
             "commits": self.commits,
             "dispatches": sum(r.dispatches for r in self.replicas),
-            # sliced-harvest readback accounting (decode/engine.py):
-            # per-replica D2H bytes total across the fleet
+            # harvest readback accounting (decode/engine.py): batched
+            # reads, the rows they delivered and the per-replica D2H
+            # bytes, totalled across the fleet
+            "harvest_reads": tot("harvest_reads"),
             "harvest_row_reads": tot("harvest_row_reads"),
             "harvest_bytes_read": tot("harvest_bytes_read"),
-            "harvest_bytes_saved": tot("harvest_bytes_saved"),
             # cross-request reuse accounting (decode/prefix_cache.py):
             # caches are per-chip, so counts total across replicas and
             # the hit rate is the fleet-wide served-from-cache fraction

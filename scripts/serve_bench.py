@@ -241,7 +241,7 @@ def _serve_row(model, params, dataset, cfg, times, out_dir, **kw):
     sv = metrics["serve"]
     sv["wall_s"] = round(time.perf_counter() - t0, 3)
     sv["slot_occupancy"] = metrics["engine"]["slot_occupancy"]
-    sv["harvest_bytes_saved"] = metrics["engine"]["harvest_bytes_saved"]
+    sv["harvest_reads"] = metrics["engine"]["harvest_reads"]
     return sv, metrics
 
 

@@ -12,12 +12,15 @@ Pins the engine's whole contract:
   flushing, atomic completion, and the crash contract (a kill mid-run
   leaves a parseable plain prefix of the final file);
 - the compile-guard story: the (geometry x {prefill, step, insert})
-  program family warms once, then zero post-warmup compiles.
+  program family warms once, then zero post-warmup compiles;
+- the harvest's readback: one ``_take_rows`` dispatch and one transfer a
+  harvest, however many rows settled, for both model families.
 """
 
 import dataclasses
 import os
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -117,6 +120,182 @@ def test_engine_bit_exact_per_sample(setup, kv, fac, shape):
     # dispatches than the initial fill alone
     assert eng.stats.slots_refilled == len(data)
     assert 0.0 < eng.stats.slot_occupancy <= 1.0
+
+
+# --------------------------------------------------------------------------
+# the harvest's readback: one gather and one transfer, whatever settled
+# --------------------------------------------------------------------------
+
+# a FIRA drain stream with repeats, so in-flight duplicates coalesce onto
+# a leader's seat as followers (decode/prefix_cache.py)
+REPEAT_CHUNKS = [np.array([0, 1, 2, 3]), np.array([0, 1, 2, 3]),
+                 np.array([4, 5, 0, 1]), np.array([2, 3, 4, 5])]
+
+
+class HarvestProbe:
+    """Wraps ONE engine's ``harvest`` and ``_take_rows``: chooses which
+    seated slots a harvest sees as settled (``mode``), copies the arena
+    before the harvest, counts the gather's dispatches and holds every
+    item against the arena rows it must equal.
+
+    ``one``: a harvest sees at most one of the slots that settled (the
+    others stay seated and done, and settle at later harvests);
+    ``every``: a harvest over a full arena sees every slot settled;
+    ``several``: the schedule's own rows. The mask the device keeps is
+    put back after the harvest, with the harvested slots done."""
+
+    def __init__(self, eng, mode):
+        self.eng, self.mode = eng, mode
+        self.takes = 0
+        self.rows_a_read = []
+        self.held = []          # (item, its arena tokens, its arena probs)
+        self.followers = 0
+        self._take, self._harvest = eng._take_rows, eng.harvest
+        eng._take_rows, eng.harvest = self.take_rows, self.harvest
+
+    def take_rows(self, tokens, probs, idx):
+        self.takes += 1
+        assert isinstance(idx, np.ndarray) and idx.dtype == np.int32
+        assert idx.shape == (self.eng.slots,)      # ONE program a config
+        return self._take(tokens, probs, idx)
+
+    def harvest(self):
+        eng = self.eng
+        done = np.array(eng._state["done"])
+        busy = sorted(eng._busy)
+        settled = [s for s in busy if done[s]]
+        if self.mode == "one":
+            settled = settled[:1]
+        elif self.mode == "every" and len(busy) == eng.slots:
+            settled = busy
+        seen = np.zeros_like(done)
+        seen[settled] = True
+        eng._state = dict(eng._state, done=jnp.asarray(seen))
+        arena_t = np.array(eng._state["tokens"])
+        arena_p = np.array(eng._state["probs"])
+        slot_of = {pid: s for s, (pid, _h, _r) in eng._busy.items()}
+        owed = {pid for pid, s in slot_of.items() if seen[s]}
+        for leader, fl in eng._followers.items():
+            if leader in owed:
+                for fpos, _fh, _fr in fl:
+                    slot_of[fpos] = slot_of[leader]
+                    owed.add(fpos)
+                    self.followers += 1
+        before = self.takes
+        items = self._harvest()
+        eng._state = dict(eng._state, done=jnp.asarray(done | seen))
+        # one dispatch where rows settled, none where none did
+        assert self.takes - before == (1 if settled else 0)
+        if settled:
+            self.rows_a_read.append(len(settled))
+        assert sorted(it.position for it in items) == sorted(owed)
+        first = {}
+        for it in items:
+            s = slot_of[it.position]
+            assert it.tokens.dtype == np.int32 and it.probs.dtype == np.float32
+            assert it.tokens.tobytes() == arena_t[s].tobytes()
+            assert it.probs.tobytes() == arena_p[s].tobytes()
+            lead = first.setdefault(s, it)   # a leader comes before its followers
+            assert it.tokens is lead.tokens and it.probs is lead.probs
+            self.held.append((it, arena_t[s].copy(), arena_p[s].copy()))
+        return items
+
+    def close(self):
+        self.eng._take_rows = self._take
+        del self.eng.harvest
+
+
+@pytest.fixture(scope="module")
+def harvest_engines(setup):
+    """One engine a model and arena, shared by the cases below (a run
+    starts from ``begin_stream`` and a fresh ``EngineStats``)."""
+    cfg0, dataset, _params, eos_params = setup
+    made = {}
+
+    def get(name):
+        if name in made:
+            return made[name]
+        if name == "axk1":
+            from fira_tpu.config import get_config
+            from fira_tpu.model import axk1
+
+            cfg = get_config("axk1-tiny", engine_slots=4)
+            params = axk1.init_params(cfg.lm, 5, jnp.dtype(cfg.compute_dtype))
+            eng = engine_lib.SlotEngine(None, params, cfg)
+        else:
+            cfg = dataclasses.replace(cfg0, engine_slots=4,
+                                      prefix_cache=name == "fira-followers")
+            eng = engine_lib.SlotEngine(FiraModel(cfg), eos_params, cfg)
+        made[name] = eng
+        return eng
+    return get
+
+
+def _harvest_feed(name, eng, dataset):
+    cfg = eng.cfg
+    if name == "axk1":
+        from fira_tpu.data import buckets
+        from fira_tpu.data.synthetic import make_prompt_requests
+
+        prompts, limits = make_prompt_requests(
+            14, vocab_size=cfg.lm.vocab_size, seed=2, min_len=8, max_len=64,
+            limits=(3, 3, 7, 11))
+        return buckets.prompt_tasks(cfg.lm, (
+            (i, p, int(m)) for i, (p, m) in enumerate(zip(prompts, limits))))
+    data = dataset.splits["train"]
+    if name == "fira-followers":
+        from fira_tpu.data.feeder import assembly_tasks
+
+        return assembly_tasks(data, REPEAT_CHUNKS, cfg, batch_size=4)
+    return _decode_tasks(data, cfg)[0]
+
+
+@pytest.mark.parametrize("name,mode", [
+    ("fira", "one"), ("fira", "several"), ("fira", "every"),
+    ("fira-followers", "several"),
+    ("axk1", "one"), ("axk1", "several"), ("axk1", "every")])
+def test_harvest_reads_all_settled_rows_at_once(setup, harvest_engines,
+                                                name, mode):
+    """A harvest that settles rows dispatches ``_take_rows`` ONCE, with an
+    index vector as long as the arena, and none where nothing settled;
+    every item's tokens and probs are bitwise the arena's rows of its slot
+    as they stood before the harvest, followers hold their leader's very
+    arrays, and all of them outlive every later dispatch (which donates
+    the arena): copies, not views of a donated buffer."""
+    _cfg, dataset, _params, _eos = setup
+    eng = harvest_engines(name)
+    eng.stats = engine_lib.EngineStats(slots=eng.slots)
+    probe = HarvestProbe(eng, mode)
+    try:
+        with Feeder(_harvest_feed(name, eng, dataset), num_workers=0,
+                    depth=1) as feed:
+            positions = [it.position for it in eng.run(feed)]
+    finally:
+        probe.close()
+    st = eng.stats
+    assert len(positions) == len(set(positions)) == st.commits > eng.slots
+    # the items survived every dispatch since their harvest
+    assert len(probe.held) == st.commits
+    for it, toks, probs in probe.held:
+        assert it.tokens.tobytes() == toks.tobytes()
+        assert it.probs.tobytes() == probs.tobytes()
+    reads = probe.rows_a_read
+    assert st.harvest_reads == probe.takes == len(reads) > 0
+    assert st.harvest_reads <= st.harvest_row_reads == sum(reads)
+    assert st.harvest_row_reads == st.commits - st.dedup_fanout
+    assert st.dedup_fanout == probe.followers
+    assert st.harvest_bytes_read == st.harvest_reads * (
+        eng._state["tokens"].nbytes + eng._state["probs"].nbytes)
+    s = st.summary()
+    assert s["harvest_reads"] == st.harvest_reads
+    if mode == "one":
+        assert set(reads) == {1}
+    elif mode == "every":
+        assert max(reads) == eng.slots
+    else:
+        assert 2 <= max(reads)
+    if name == "fira-followers":
+        assert probe.followers > 0
 
 
 def test_engine_run_test_file_identical_and_zero_retraces(setup, tmp_path):

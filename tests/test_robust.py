@@ -400,6 +400,71 @@ def test_serve_watchdog_retires_hung_replica(setup, trace, drain_lines,
     assert open(m["output_path"]).read() == "\n".join(drain_lines)
 
 
+def test_watchdog_inside_the_harvest_read_requeues_every_settled_slot(setup):
+    """A hang injected INSIDE the harvest's single batched readback: the
+    watchdog abandons the harvest with every settled slot still seated
+    (the readback touches no bookkeeping), ``retire()`` requeues each owed
+    request exactly once — the settled ones among them — and the abandoned
+    thread, when it wakes, delivers nothing and counts nothing."""
+    import threading
+
+    from fira_tpu.decode import engine as engine_lib
+    from fira_tpu.decode.runner import _decode_tasks
+
+    cfg, dataset, params = setup
+    eng = engine_lib.SlotEngine(FiraModel(cfg), params, cfg)
+    tasks, _ = _decode_tasks(dataset.splits["train"], cfg)
+    with Feeder(tasks, num_workers=0, depth=1, put=False) as feed:
+        it = iter(feed)
+        eng.begin_stream()
+        while eng.wants_input():
+            item = next(it)
+            eng.admit(item.host, item.index, None)
+        eng.refill()
+        while True:
+            eng.step_dispatch()
+            done = np.array(eng._state["done"])
+            settled = [s for s in eng._busy if done[s]]
+            if len(settled) > 1:
+                break
+            assert len(eng.harvest()) == len(settled)   # none or one: on
+            eng.refill()
+    reads, rows, commits = (eng.stats.harvest_reads,
+                            eng.stats.harvest_row_reads, eng.stats.commits)
+    owed = sorted(eng.pending_positions())
+    settled_pos = {eng._busy[s][0] for s in settled}
+    entered, release, woke = (threading.Event() for _ in range(3))
+    out = {}
+    take = eng._take_rows
+
+    def hung_take(*args):
+        entered.set()
+        release.wait(120.0)
+        return take(*args)
+
+    def abandoned_harvest():
+        out["items"] = eng.harvest()
+        woke.set()
+
+    eng._take_rows = hung_take
+    with pytest.raises(WatchdogTimeout):
+        run_with_watchdog(abandoned_harvest, 1.0, label="harvest")
+    assert entered.wait(60.0)           # the thread hangs in the ONE read
+    assert all(s in eng._busy for s in settled)
+    payloads = eng.retire()
+    requeued = []
+    for p in payloads:
+        v = np.asarray(p["valid"], dtype=bool)
+        requeued += [int(x) for x in np.asarray(p["_positions"])[v]]
+    assert sorted(requeued) == owed     # every owed request, exactly once
+    assert settled_pos <= set(requeued)
+    release.set()
+    assert woke.wait(60.0) and out["items"] == []
+    assert (eng.stats.harvest_reads, eng.stats.harvest_row_reads,
+            eng.stats.commits) == (reads, rows, commits)
+    assert eng.allocator_invariants() == []
+
+
 def test_serve_all_replicas_lost_sheds_with_reason(setup, trace, tmp_path):
     """Single replica, step fault at rate 1: the only replica retires on
     its first dispatch and everything still in flight is recorded-shed —

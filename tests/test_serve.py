@@ -19,7 +19,7 @@ Pins the serving layer's whole contract:
 - the latency-aware prefill budget: admissions between step dispatches
   never exceed it;
 - parse-time knob validation with named messages and CLI exit 2;
-- the sliced harvest readback metering (decode/engine.py satellite).
+- the batched harvest readback metering (decode/engine.py satellite).
 """
 
 import dataclasses
@@ -381,14 +381,13 @@ def test_cli_serve_end_to_end(tmp_path):
 
 
 # --------------------------------------------------------------------------
-# sliced harvest readback (decode/engine.py satellite)
+# batched harvest readback (decode/engine.py satellite)
 # --------------------------------------------------------------------------
 
 def test_harvest_sliced_readback_metered(setup):
-    """Harvest copies only settled slots' rows D2H: one row read per
-    commit, and the metered savings vs the historical full-arena
-    readback are positive whenever a harvest retires fewer than all
-    slots."""
+    """Harvest reads every settled slot's rows of a dispatch with one
+    gather and one transfer: one row delivered per commit, no more reads
+    than rows, and the metered bytes are what really crossed."""
     from fira_tpu.data.feeder import Feeder
     from fira_tpu.decode import engine as engine_lib
     from fira_tpu.decode.runner import _decode_tasks
@@ -402,10 +401,15 @@ def test_harvest_sliced_readback_metered(setup):
             pass
     st = eng.stats
     assert st.harvest_row_reads == st.commits == len(data)
-    assert st.harvest_bytes_read > 0
-    assert st.harvest_bytes_saved > 0
+    # one batched read a harvest that settled rows, and the bytes that
+    # really crossed: the gather's whole padded result, every read
+    assert 0 < st.harvest_reads <= st.harvest_row_reads
+    state = eng._state
+    assert st.harvest_bytes_read == st.harvest_reads * (
+        state["tokens"].nbytes + state["probs"].nbytes)
     s = st.summary()
-    assert s["harvest_bytes_saved"] == st.harvest_bytes_saved
+    assert s["harvest_reads"] == st.harvest_reads
+    assert s["harvest_bytes_read"] == st.harvest_bytes_read
 
 
 def test_serve_stats_serialize_completion_order_and_stable_heartbeats():

@@ -308,6 +308,7 @@ def test_prewarm_spans_hold_the_compiles(served):
     assert under.get("engine.prewarm.step") == "jit(_step_fn)"
     assert under.get("engine.prewarm.prefill") == "jit(_prefill_fn)"
     assert under.get("engine.prewarm.insert") == "jit(_insert_fn)"
+    assert under.get("engine.prewarm.take_rows") == "jit(_take_rows_fn)"
 
 
 def test_window_after_the_burst_compiles_nothing(served):
@@ -319,6 +320,30 @@ def test_window_after_the_burst_compiles_nothing(served):
     assert in_window == [], [e.ids for e in in_window]
     assert served["window"]["serve"]["phases"]["compiles"] == 0
     assert served["window"]["engine"]["phases"]["compiles"] == 0
+
+
+def test_one_read_a_harvest_and_one_harvest_program(served):
+    """A harvest that settled rows holds ONE ``engine.harvest.read`` (the
+    batched readback), the reads are the engine's ``harvest_reads`` and
+    their ``rows`` its ``harvest_row_reads``; the harvest program was
+    compiled once, under ``engine.prewarm.take_rows``: the index length
+    prewarm warms is the one every harvest of the burst and the window
+    dispatched."""
+    events = served["events"]
+    window_run = _roots(events, "serve.run")[-1]
+    mine = [e for e in events if inside(e, window_run)]
+    reads = [e for e in mine if e.name == "engine.harvest.read"]
+    stats = served["eng"].stats
+    assert len(reads) == stats.harvest_reads > 0
+    assert len({e.parent_id for e in reads}) == len(reads)
+    assert sum(e.ids["rows"] for e in reads) == stats.harvest_row_reads \
+        == stats.commits
+    assert max(e.ids["rows"] for e in reads) > 1     # rows shared a read
+    by_id = {e.span_id: e for e in events}
+    harvest_compiles = [e for e in events if e.name == profiling.COMPILE_EVENT
+                        and e.ids["program"] == "jit(_take_rows_fn)"]
+    assert [by_id[e.parent_id].name for e in harvest_compiles] == [
+        "engine.prewarm.take_rows"]
 
 
 def test_serve_round_structure_and_harvest_split(served):
