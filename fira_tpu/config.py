@@ -13,10 +13,40 @@ import dataclasses
 from typing import Optional
 
 
+class _PromptGeometry:
+    """What data/buckets.py asks of any token model's key block: the
+    prompt-length buckets (the engine's geometry tags), the padded tokens
+    one prefill dispatch may hold, and the longest prompt a slot keeps."""
+
+    @property
+    def prompt_len_max(self) -> int:
+        return int(self.prompt_buckets[-1])
+
+    def bucket_rows(self, bucket: int) -> int:
+        """Requests one prefill dispatch of ``bucket``-long prompts holds."""
+        return max(1, self.prefill_token_budget // int(bucket))
+
+    def share_errors(self) -> list:
+        """The checks every key block shares: the experts held lie inside
+        the router's width, the buckets ascend."""
+        errs = []
+        if not 0 <= self.expert_offset \
+                <= self.n_routed_experts - self.experts_held:
+            errs.append(
+                f"lm.expert_offset {self.expert_offset} + lm.experts_held "
+                f"{self.experts_held} must lie within lm.n_routed_experts "
+                f"{self.n_routed_experts}")
+        if list(self.prompt_buckets) != sorted(set(self.prompt_buckets)):
+            errs.append(f"lm.prompt_buckets {self.prompt_buckets} must "
+                        f"ascend")
+        return errs
+
+
 @dataclasses.dataclass(frozen=True)
-class LMConfig:
-    """A decoder-only language model's published keys (``arch="axk1"``), by
-    the names its ``config.json`` gives them; the defaults are A.X-K1's
+class LMConfig(_PromptGeometry):
+    """A.X-K1's key block (``arch="axk1"``): the published keys of that
+    latent-attention, routed-expert decoder by the names its
+    ``config.json`` gives them, with A.X-K1's values as the defaults
     (https://huggingface.co/skt/A.X-K1/blob/main/config.json). What this
     engine holds of a deployment comes after them: the experts held here
     of ``n_routed_experts`` (the router keeps its full width), the rows of
@@ -59,27 +89,100 @@ class LMConfig:
     prefill_token_budget: int = 8192
 
     @property
-    def prompt_len_max(self) -> int:
-        return int(self.prompt_buckets[-1])
-
-    @property
     def latent_dim(self) -> int:
         """What one token caches a layer: [c_kv | rotated k_rope]."""
         return self.kv_lora_rank + self.qk_rope_head_dim
 
-    def bucket_rows(self, bucket: int) -> int:
-        """Requests one prefill dispatch of ``bucket``-long prompts holds."""
-        return max(1, self.prefill_token_budget // int(bucket))
+    def errors(self) -> list:
+        errs = self.share_errors()
+        if self.n_routed_experts % self.n_group:
+            errs.append(f"lm.n_group {self.n_group} does not divide "
+                        f"lm.n_routed_experts {self.n_routed_experts}")
+        return errs
+
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class AfmoeConfig(_PromptGeometry):
+    """Trinity-Mini's key block (``arch="afmoe"``): the published keys of
+    that window-and-full-attention, routed-expert decoder by the names its
+    ``config.json`` gives them (``model_type: afmoe``), Trinity-Mini's
+    values as the defaults
+    (https://huggingface.co/arcee-ai/Trinity-Mini/blob/main/config.json).
+    After them, as in :class:`LMConfig`: the experts held here of
+    ``num_experts`` and the prefill geometry."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 6144        # the leading dense layers' SwiGLU
+    moe_intermediate_size: int = 1024    # each routed / shared expert
+    num_hidden_layers: int = 32
+    num_dense_layers: int = 2
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    sliding_window: int = 2048
+    # one entry a layer: every 4th attends over the whole context, without
+    # rotary positions; the others over a window, with them
+    layer_types: tuple = ((SLIDING,) * 3 + (FULL,)) * 8
+    num_experts: int = 128               # the router's outputs
+    num_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    route_norm: bool = True
+    route_scale: float = 2.826
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    mup_enabled: bool = True             # embedding times sqrt(hidden_size)
+    vocab_size: int = 200192
+    # this chip's share: experts [expert_offset, expert_offset +
+    # experts_held) of every expert layer (all of them at the defaults)
+    experts_held: int = 128
+    expert_offset: int = 0
+    prompt_buckets: tuple = (1024, 2048, 4096, 8192, 16384)
+    prefill_token_budget: int = 16384
+
+    @property
+    def n_routed_experts(self) -> int:
+        """``num_experts`` under the name the shared grouped products
+        (model/axk1.routed_experts) know the router's width by."""
+        return self.num_experts
+
+    @property
+    def kv_dim(self) -> int:
+        """What one token caches a layer: [k | v], every key/value head."""
+        return 2 * self.num_key_value_heads * self.head_dim
+
+    def layers_of(self, kind: str) -> tuple:
+        return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
+
+    def errors(self) -> list:
+        errs = self.share_errors()
+        if len(self.layer_types) != self.num_hidden_layers or any(
+                t not in (SLIDING, FULL) for t in self.layer_types):
+            errs.append(
+                f"lm.layer_types {self.layer_types} must name "
+                f"{SLIDING!r} or {FULL!r} for each of lm.num_hidden_layers "
+                f"{self.num_hidden_layers}")
+        if self.num_attention_heads % self.num_key_value_heads:
+            errs.append(
+                f"lm.num_key_value_heads {self.num_key_value_heads} does "
+                f"not divide lm.num_attention_heads "
+                f"{self.num_attention_heads}")
+        return errs
 
 
 @dataclasses.dataclass(frozen=True)
 class FiraConfig:
-    # --- architecture: "fira" (the paper's encoder-decoder, every field
-    # below) or "axk1" (a latent-attention, routed-expert decoder: ``lm``
-    # holds its published keys; of the fields below it reads beam_size,
-    # tar_len, the engine/paging knobs and seed) ---
+    # --- architecture (ARCH_TABLE below): "fira" (the paper's
+    # encoder-decoder, every field below) or a decoder-only token model —
+    # "axk1" (A.X-K1: latent attention, group-limited routed experts) or
+    # "afmoe" (Trinity-Mini: window and full attention layers, 128 small
+    # experts) — whose published keys ``lm`` holds in its own key block; of
+    # the fields below such a model reads beam_size, tar_len, the
+    # engine/paging knobs and seed ---
     arch: str = "fira"
-    lm: Optional[LMConfig] = None
+    lm: Optional[object] = None         # the arch's key block (ARCH_TABLE)
 
     # --- sequence geometry (reference run_model.py:31-35) ---
     sou_len: int = 210          # diff tokens incl. <start>/<eos>
@@ -727,24 +830,24 @@ DECODE_PERF_KNOBS = {
 }
 
 
-# What every "axk1" preset fixes outside ``lm``: the slot engine with a
-# paged pool for the generated positions, log-space beams (the head is a
-# log-softmax and has no copy side), bfloat16 weights and cache.
+# What every token model's preset fixes outside ``lm``: the slot engine
+# with a paged pool for the generated positions, log-space beams (the head
+# is a log-softmax and has no copy side), bfloat16 weights and cache.
 _LM_ENGINE = dict(
-    arch="axk1", decode_engine=True, beam_compat_prob_space=False,
+    decode_engine=True, beam_compat_prob_space=False,
     compute_dtype="bfloat16", beam_size=3, tar_len=64,
 )
 
 
-def _lm_preset(lm: LMConfig, kw: dict) -> FiraConfig:
-    """``lm=`` in ``kw`` may be an LMConfig or a dict of its keys to
+def _lm_preset(arch: str, lm, kw: dict) -> FiraConfig:
+    """``lm=`` in ``kw`` may be a key block or a dict of its keys to
     replace; ``vocab_size`` always follows the block's."""
     over = kw.pop("lm", None)
-    if isinstance(over, LMConfig):
-        lm = over
-    elif over:
+    if isinstance(over, dict):
         lm = dataclasses.replace(lm, **over)
-    base = dict(_LM_ENGINE, lm=lm)
+    elif over is not None:
+        lm = over
+    base = dict(_LM_ENGINE, arch=arch, lm=lm)
     base.update(kw)
     base["vocab_size"] = lm.vocab_size
     return FiraConfig(**base)
@@ -757,8 +860,8 @@ def axk1_ep16(**kw) -> FiraConfig:
     experts, an eighth of the vocabulary."""
     base = dict(engine_slots=64, test_batch_size=16)
     base.update(kw)
-    return _lm_preset(LMConfig(num_hidden_layers=7, experts_held=12,
-                               vocab_size=20480), base)
+    return _lm_preset("axk1", LMConfig(num_hidden_layers=7, experts_held=12,
+                                       vocab_size=20480), base)
 
 
 def axk1_tiny(**kw) -> FiraConfig:
@@ -767,7 +870,7 @@ def axk1_tiny(**kw) -> FiraConfig:
     base = dict(engine_slots=4, test_batch_size=4, tar_len=16,
                 compute_dtype="float32")
     base.update(kw)
-    return _lm_preset(LMConfig(
+    return _lm_preset("axk1", LMConfig(
         hidden_size=64, intermediate_size=160, moe_intermediate_size=32,
         num_hidden_layers=3, num_attention_heads=4, q_lora_rank=48,
         kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
@@ -777,12 +880,47 @@ def axk1_tiny(**kw) -> FiraConfig:
         prompt_buckets=(16, 32, 64), prefill_token_budget=64), base)
 
 
+# published layers 1-5 of Trinity-Mini's 32: the second dense layer, then
+# one whole period of expert layers, three window to one full
+_AFMOE_STAGE = (SLIDING, SLIDING, FULL, SLIDING, SLIDING)
+
+
+def trinity_mini_l5(**kw) -> FiraConfig:
+    """Trinity-Mini at its published widths, one pipeline stage on one
+    chip (benchmark/configs/trinity-mini-l5.json says how it was cut):
+    published layers 1-5 with all 128 experts of every expert layer and
+    the whole vocabulary."""
+    base = dict(engine_slots=48, test_batch_size=16)
+    base.update(kw)
+    return _lm_preset("afmoe", AfmoeConfig(
+        num_hidden_layers=5, num_dense_layers=1, layer_types=_AFMOE_STAGE),
+        base)
+
+
+def afmoe_tiny(**kw) -> FiraConfig:
+    """Every mechanism of Trinity-Mini at CPU-test widths: d 64, 4 query
+    over 2 key/value heads of 16, a window of 8, the stage's five layer
+    types (1 dense + 4 expert), 8 experts of width 32, top-2."""
+    base = dict(engine_slots=4, test_batch_size=4, tar_len=16,
+                compute_dtype="float32")
+    base.update(kw)
+    return _lm_preset("afmoe", AfmoeConfig(
+        hidden_size=64, intermediate_size=96, moe_intermediate_size=32,
+        num_hidden_layers=5, num_dense_layers=1, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, sliding_window=8,
+        layer_types=_AFMOE_STAGE, num_experts=8, num_experts_per_tok=2,
+        vocab_size=64, experts_held=8, prompt_buckets=(16, 32, 64),
+        prefill_token_budget=64), base)
+
+
 NAMED_CONFIGS = {
     "fira-tiny": fira_tiny,
     "fira-full": fira_full,
     "fira-large": fira_large,
     "axk1-ep16": axk1_ep16,
     "axk1-tiny": axk1_tiny,
+    "trinity-mini-l5": trinity_mini_l5,
+    "afmoe-tiny": afmoe_tiny,
 }
 
 
@@ -823,7 +961,25 @@ def config_errors(cfg: FiraConfig) -> list:
     return errs + arch_errors(cfg)
 
 
-ARCHS = ("fira", "axk1")
+@dataclasses.dataclass(frozen=True)
+class Arch:
+    """What an ``arch`` name stands for, in ONE place: the key block
+    ``cfg.lm`` must be (None: the model reads FiraConfig's own fields),
+    the module that holds the model (a token model's has ``init_params``,
+    ``prefill``, ``decode_step``, ``COUNTERS``), and the class of
+    decode/slot_model.py that puts it behind the engine's seam."""
+
+    lm_block: Optional[type]
+    model: str
+    slot_model: str
+
+
+ARCH_TABLE = {
+    "fira": Arch(None, "fira_tpu.model.model", "FiraSlotModel"),
+    "axk1": Arch(LMConfig, "fira_tpu.model.axk1", "LMSlotModel"),
+    "afmoe": Arch(AfmoeConfig, "fira_tpu.model.afmoe", "AfmoeSlotModel"),
+}
+ARCHS = tuple(ARCH_TABLE)
 
 
 def arch_errors(cfg: FiraConfig, command: Optional[str] = None) -> list:
@@ -832,15 +988,17 @@ def arch_errors(cfg: FiraConfig, command: Optional[str] = None) -> list:
     ``test`` / ``serve`` / ``message``), where there is one."""
     if cfg.arch not in ARCHS:
         return [f"arch {cfg.arch!r} not in {list(ARCHS)}"]
-    if cfg.arch == "fira":
-        return ([f"arch 'fira' takes no lm block (got {cfg.lm!r})"]
+    block = ARCH_TABLE[cfg.arch].lm_block
+    if block is None:
+        return ([f"arch {cfg.arch!r} takes no lm block (got {cfg.lm!r})"]
                 if cfg.lm is not None else [])
-    lm, errs = cfg.lm, []
-    if lm is None:
-        return ["arch 'axk1' needs an lm block (config.LMConfig)"]
+    arch, lm, errs = cfg.arch, cfg.lm, []
+    if not isinstance(lm, block):
+        return [f"arch {arch!r} needs an lm block "
+                f"(config.{block.__name__})"]
 
     def no(what: str) -> None:
-        errs.append(f"arch 'axk1' does not support {what} yet")
+        errs.append(f"arch {arch!r} does not support {what} yet")
     if command in ("train", "serve", "message"):
         no(f"cli {command} (cli test --engine runs it)")
     if not cfg.decode_engine:
@@ -864,17 +1022,7 @@ def arch_errors(cfg: FiraConfig, command: Optional[str] = None) -> list:
     if cfg.buckets or cfg.decode_tar_buckets:
         no("graph bucket tables (buckets / decode_tar_buckets): its "
            "prefill buckets are lm.prompt_buckets")
-    if not 0 <= lm.expert_offset <= lm.n_routed_experts - lm.experts_held:
-        errs.append(
-            f"lm.expert_offset {lm.expert_offset} + lm.experts_held "
-            f"{lm.experts_held} must lie within lm.n_routed_experts "
-            f"{lm.n_routed_experts}")
-    if lm.n_routed_experts % lm.n_group:
-        errs.append(f"lm.n_group {lm.n_group} does not divide "
-                    f"lm.n_routed_experts {lm.n_routed_experts}")
-    if list(lm.prompt_buckets) != sorted(set(lm.prompt_buckets)):
-        errs.append(f"lm.prompt_buckets {lm.prompt_buckets} must ascend")
-    return errs
+    return errs + lm.errors()
 
 
 def apply_ablation(cfg: FiraConfig, ablation: Optional[str]) -> FiraConfig:

@@ -450,7 +450,7 @@ def padding_report(split: ProcessedSplit, cfg: FiraConfig,
     }
 
 
-# --- prompt-length buckets (arch "axk1": cfg.lm.prompt_buckets) -------------
+# --- prompt-length buckets (a token model's key block: cfg.lm.prompt_buckets) -
 
 def prompt_tag(bucket: int) -> str:
     """Geometry tag of a prompt-length bucket in the engine's program

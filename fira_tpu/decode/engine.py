@@ -176,6 +176,11 @@ class EngineStats:
     pool_blocks: int = 0         # fixed pool size P
     kv_block_size: int = 0       # positions per block
     kv_bytes_per_slot: int = 0   # committed K+V cache HBM per slot
+    # ... of them, what the model DECLARES by layer type (slot_model
+    # Leaf.kv_kind): prompts kept whole | rings of a window's length; zero
+    # for a model whose layers are all of one kind
+    kv_bytes_per_slot_full: int = 0
+    kv_bytes_per_slot_window: int = 0
     block_steps: int = 0         # blocks in use, summed per step dispatch
     peak_blocks: int = 0         # high-water mark of blocks in use
     # harvest readback accounting: a harvest that settles rows gathers
@@ -233,6 +238,12 @@ class EngineStats:
     moe_assignments_held: int = 0  # ... of them, to experts held here
     moe_held_load_max: int = 0    # the busiest held expert's load, summed
     #                               over expert layers and dispatches
+    # window-attention accounting (model/afmoe.COUNTERS, the same leaf —
+    # zero for a model without window layers)
+    attn_keys_read: int = 0       # keys the steps' attention was ASKED to
+    #                               cover: occupied slots x layers x the
+    #                               keys inside window or context
+    attn_keys_context: int = 0    # the same with every layer full
     # per span name count/total_s/max_s and the compile counters, over
     # the spans that closed while THIS stats object lived (utils/
     # profiling.Phases) — a stats reset between timed windows resets the
@@ -289,6 +300,8 @@ class EngineStats:
             "pool_blocks": self.pool_blocks,
             "kv_block_size": self.kv_block_size,
             "kv_bytes_per_slot": self.kv_bytes_per_slot,
+            "kv_bytes_per_slot_full": self.kv_bytes_per_slot_full,
+            "kv_bytes_per_slot_window": self.kv_bytes_per_slot_window,
             "peak_blocks": self.peak_blocks,
             "pool_utilization": round(self.pool_utilization, 4),
             "harvest_reads": self.harvest_reads,
@@ -316,6 +329,8 @@ class EngineStats:
             "moe_assignments": self.moe_assignments,
             "moe_assignments_held": self.moe_assignments_held,
             "moe_held_load_max": self.moe_held_load_max,
+            "attn_keys_read": self.attn_keys_read,
+            "attn_keys_context": self.attn_keys_context,
             "phases": self.phases.summary(),
         }
 
@@ -426,7 +441,7 @@ class SlotEngine:
         # THIS engine's pool (a fleet replica's per-chip share); None
         # falls back to cfg.kv_pool_blocks, 0 to the full-residency auto
         # size (slots x table width — admission never waits for blocks).
-        self._kv_bytes_per_slot = 0
+        self._kv_bytes_by_kind: Dict[str, int] = {}
         self._block_size = paging.resolve_block_size(cfg)
         if cfg.tar_len % self._block_size:
             raise ValueError(
@@ -775,7 +790,7 @@ class SlotEngine:
         if self.smodel.beam_ancestry:
             z["ancestry"] = np.broadcast_to(
                 np.arange(K, dtype=np.int32)[None, :, None], (S, K, T)).copy()
-        self._kv_bytes_per_slot = paging.leaves_kv_bytes_per_slot(
+        self._kv_bytes_by_kind = paging.leaves_kv_bytes_by_kind(
             self._leaves, S)
         # firacheck: allow[RETIRED-RECHECK] arena-state write: retire() deliberately leaves the arena in place ("the arena and stats stay") and a dead engine's _state is never read again — only scheduling/guard state needs the post-dispatch re-check
         self._state = jax.device_put(z, self.device)
@@ -1404,7 +1419,10 @@ class SlotEngine:
         # resets between timed windows keep the HBM fields populated
         st.pool_blocks = self._pool_blocks
         st.kv_block_size = self._block_size
-        st.kv_bytes_per_slot = self._kv_bytes_per_slot
+        by_kind = self._kv_bytes_by_kind
+        st.kv_bytes_per_slot = sum(by_kind.values())
+        st.kv_bytes_per_slot_full = by_kind.get("full", 0)
+        st.kv_bytes_per_slot_window = by_kind.get("window", 0)
         st.kv_dtype = self.cfg.kv_dtype
         st.serve_precision = self.cfg.serve_precision
         used = self._pool_blocks - len(self._free_blocks)

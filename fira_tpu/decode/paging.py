@@ -29,7 +29,7 @@ engine's scheduler (decode/engine.py).
 from __future__ import annotations
 
 import math
-from typing import List, Tuple
+from typing import Dict, List, Tuple
 
 from fira_tpu.config import FiraConfig
 
@@ -215,8 +215,20 @@ def leaves_kv_bytes_per_slot(leaves, slots: int) -> int:
     — pools, a latent cache of whatever width — amortized over the slots
     they serve. For FIRA's K/V pools this is the number the per-head
     formula above gives."""
+    return sum(leaves_kv_bytes_by_kind(leaves, slots).values())
+
+
+def leaves_kv_bytes_by_kind(leaves, slots: int) -> Dict[str, int]:
+    """Per-slot bytes of the ``kv`` leaves, added up by what they DECLARE
+    to hold (``Leaf.kv_kind``): ``"full"`` (prompts kept whole),
+    ``"window"`` (rings of a prompt's last positions), ``""`` (the pools
+    of generated positions, and any cache a model does not tell apart by
+    layer type). A model whose layers are of one kind has one entry."""
     import numpy as np
 
-    total = sum(int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-                for leaf in leaves.values() if leaf.kv)
-    return total // max(1, int(slots))
+    total: Dict[str, int] = {}
+    for leaf in leaves.values():
+        if leaf.kv:
+            total[leaf.kv_kind] = total.get(leaf.kv_kind, 0) + int(
+                np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+    return {kind: n // max(1, int(slots)) for kind, n in total.items()}

@@ -275,21 +275,24 @@ def run_test(model: FiraModel, params, dataset: FiraDataset,
 def run_lm_test(cfg: FiraConfig, *, out_dir: str = "OUTPUT",
                 n_requests: int = 64, guard=None, params=None,
                 requests=None) -> Dict:
-    """``cli test --engine`` for a decoder-only architecture
-    (``cfg.arch == "axk1"``): the SAME slot engine, over token-id prompts
-    from data/synthetic.py (no tokenizer ships, so the output file holds
+    """``cli test --engine`` for a decoder-only architecture (any ``arch``
+    of config.ARCH_TABLE with a key block): the SAME slot engine, over
+    token-id prompts from data/synthetic.py (no tokenizer ships, so the output file holds
     ids: one line a request, its most probable beam after <start>) and
     weights drawn from ``cfg.seed`` unless ``params`` are given.
     ``requests``: (prompts, max_new) to use instead of the synthetic draw."""
+    import importlib
+
     import jax.numpy as jnp
 
+    from fira_tpu.config import ARCH_TABLE
     from fira_tpu.data.synthetic import make_prompt_requests
-    from fira_tpu.model import axk1
 
     lm = cfg.lm
     if params is None:
-        params = axk1.init_params(lm, cfg.seed,
-                                  jnp.dtype(cfg.compute_dtype))
+        model = importlib.import_module(ARCH_TABLE[cfg.arch].model)
+        params = model.init_params(lm, cfg.seed,
+                                   jnp.dtype(cfg.compute_dtype))
     if requests is None:
         T = cfg.tar_len
         requests = make_prompt_requests(

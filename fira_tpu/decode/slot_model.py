@@ -40,7 +40,11 @@ bound by name in the engine any more:
 pools, written once and followed by ancestry), one step
 (``FiraModel.dist_parts_step_paged``) and one selection
 (``beam._select_factored``), whatever the batched beam's knobs say.
-:class:`LMSlotModel` (``arch="axk1"``, model/axk1.py) is the second.
+:class:`LMSlotModel` (``arch="axk1"``, model/axk1.py) is the second;
+:class:`AfmoeSlotModel` (``arch="afmoe"``, model/afmoe.py) the third, and
+the first whose prompt leaves differ BY LAYER TYPE (``Leaf.kv_kind``: a
+whole prompt a full layer, a ring a window layer, a leaf a layer):
+config.ARCH_TABLE says which class an ``arch`` gets.
 """
 
 from __future__ import annotations
@@ -51,7 +55,7 @@ from typing import Dict, NamedTuple, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from fira_tpu.config import FiraConfig
+from fira_tpu.config import ARCH_TABLE, FULL, SLIDING, FiraConfig
 from fira_tpu.decode import quant
 from fira_tpu.decode.beam import _select, _select_factored, step_valid_mask
 
@@ -66,6 +70,10 @@ class Leaf:
     #                                 moved by src_beam; None: never moved
     #                                 (shared by the beams, or per lane)
     kv: bool = False                # counted by kv_bytes_per_slot
+    kv_kind: str = ""               # of a ``kv`` leaf that holds PROMPTS:
+    #                                 "full" (a prompt kept whole) or
+    #                                 "window" (a ring of its last
+    #                                 positions); paging adds them up by it
 
 
 class StepView(NamedTuple):
@@ -234,8 +242,9 @@ class LMSlotModel:
     # waves in lockstep, so the engine alternates one prefill with one step
     prefill_budget = 1
 
-    def __init__(self, cfg: FiraConfig, slots: int, block_size: int,
-                 pool_blocks: int):
+    def __init__(self, model, cfg: FiraConfig, slots: int,
+                 block_size: int, pool_blocks: int):
+        # ``model``: None (plain functions over the parameter tree)
         from fira_tpu.model import axk1
 
         self.cfg, self.lm, self.slots = cfg, cfg.lm, slots
@@ -297,8 +306,99 @@ class LMSlotModel:
                        self.cfg, neg, log_input=True)
 
 
+class AfmoeSlotModel(LMSlotModel):
+    """Trinity-Mini behind the seam (model/afmoe.py). Per slot, BY LAYER
+    TYPE: a full layer keeps the prompt's keys and values whole
+    (``prompt_k_full<j>`` / ``prompt_v_full<j>``, as long as the longest
+    bucket), a window layer a ring of its last ``sliding_window`` positions
+    (``prompt_k_win<j>`` / ``prompt_v_win<j>``: position p at entry ``p mod
+    sliding_window``, the order prefill hands them over in); both shared by
+    the slot's beams, positions last, a leaf a layer and side (a step then
+    reads each as it lies; of a leaf that stacked them the chip's compiler
+    copied every slice it took, the whole arena a dispatch). Per beam:
+    every layer's generated positions in the engine's paged pool, reordered
+    like A.X-K1's (``reorder="pool"``: 3 beams x 64 positions x 5 layers
+    are 2 MB a slot, so the three passes of ``permute_pool`` are 0.3 GB a
+    position beside 7.7 GB of weights; an ancestry table would save them
+    and cost the step a gather through it). What is inherited is what does
+    not differ: the pacing, the chunk's rows, the selection."""
+
+    def __init__(self, model, cfg: FiraConfig, slots: int,
+                 block_size: int, pool_blocks: int):
+        from fira_tpu.model import afmoe
+
+        super().__init__(model, cfg, slots, block_size, pool_blocks)
+        self.arena_counters = afmoe.COUNTERS
+        lm = self.lm
+
+        def pairs(stem: str, kind: str):
+            return [(f"prompt_k_{stem}{j}", f"prompt_v_{stem}{j}")
+                    for j in range(len(lm.layers_of(kind)))]
+        # what the chunk calls a kind of prompt cache -> (Leaf.kv_kind, its
+        # length, a (keys' leaf, values' leaf) pair a layer of that kind)
+        self._prompt_leaves = {
+            "kv_full": ("full", lm.prompt_len_max, pairs("full", FULL)),
+            "kv_ring": ("window", lm.sliding_window, pairs("win", SLIDING)),
+        }
+
+    def prefill(self, params, batch):
+        from fira_tpu.model import afmoe
+
+        full, rings, counters = afmoe.prefill(
+            params, self.lm, batch["tokens"], batch["lengths"], self.dtype)
+        return {"kv_full": full, "kv_ring": rings,
+                "lengths": batch["lengths"], "counters": counters}
+
+    def leaves(self, chunk) -> Dict[str, Leaf]:
+        lm, S, K = self.lm, self.slots, self.cfg.beam_size
+        dt, c = chunk["kv_full"][0][0].dtype, lm.kv_dim
+        out = {name: Leaf((S, c // 2, length), dt, kv=True, kv_kind=kind)
+               for kind, length, pairs in self._prompt_leaves.values()
+               for pair in pairs for name in pair}
+        out.update({
+            "prompt_len": Leaf((S,), np.dtype(np.int32)),
+            "kv_pool": Leaf((lm.num_hidden_layers, self.pool_blocks, K,
+                             self.block_size, c), dt, reorder="pool",
+                            kv=True),
+            "counters": Leaf((len(self.arena_counters),),
+                             np.dtype(np.int32)),
+        })
+        return out
+
+    def insert(self, state, chunk, sid, sid_bk, fresh) -> Dict:
+        # a chunk's prompts are as long as their bucket; its rings came in
+        # ring order (entry r holds position p, p mod window == r), a
+        # bucket under the window filling the first entries only
+        new = {
+            "prompt_len": state["prompt_len"].at[sid].set(
+                chunk["lengths"].astype(jnp.int32), mode="drop"),
+            "counters": state["counters"] + chunk["counters"] * fresh,
+        }
+        for key, (_kind, _length, pairs) in self._prompt_leaves.items():
+            for pair, sides in zip(pairs, chunk[key]):
+                for name, x in zip(pair, sides):
+                    new[name] = state[name].at[sid, :, :x.shape[-1]].set(
+                        x, mode="drop")
+        return new
+
+    def step(self, params, state, view: StepView):
+        from fira_tpu.model import afmoe
+
+        S, K = self.slots, self.cfg.beam_size
+        tok = jnp.take_along_axis(view.flat, view.pos_bk[:, None], axis=1)
+        full, win = ([(state[k], state[v]) for k, v in pairs]
+                     for _kind, _length, pairs
+                     in self._prompt_leaves.values())
+        logp, pool, counters = afmoe.decode_step(
+            params, self.lm, tok.reshape(S, K), view.pos_c, full, win,
+            state["prompt_len"], state["kv_pool"], view.tab_step,
+            view.active, self.dtype)
+        return (logp,), {"kv_pool": pool,
+                         "counters": state["counters"] + counters}
+
+
 def for_config(model, cfg: FiraConfig, slots: int, block_size: int,
                pool_blocks: int):
-    if cfg.arch == "axk1":
-        return LMSlotModel(cfg, slots, block_size, pool_blocks)
-    return FiraSlotModel(model, cfg, slots, block_size, pool_blocks)
+    """The slot model config.ARCH_TABLE names for ``cfg.arch``."""
+    return globals()[ARCH_TABLE[cfg.arch].slot_model](
+        model, cfg, slots, block_size, pool_blocks)

@@ -335,19 +335,31 @@ def route(scores, lm: LMConfig):
     return ids, w * lm.routed_scaling_factor
 
 
-def expert_chunk_rows(lm: LMConfig, n_tokens: int) -> int:
+# rows one pass of the grouped product holds at the most: a prefill
+# dispatch that holds every expert (16,384 tokens x top-8 = 131,072
+# assignments) takes several passes of this many rows, in expert order,
+# instead of one whose float32 result alone is 1.3 GB
+EXPERT_CHUNK_ROWS_MAX = 32768
+
+
+def expert_chunk_rows(lm, n_tokens: int) -> int:
     """Rows one pass of the grouped product holds: a quarter over the
     expected assignments to held experts, a multiple of 128 (of 512 from
-    2,048 up), never more than every token choosing only held experts."""
+    2,048 up), never more than every token choosing only held experts nor
+    than EXPERT_CHUNK_ROWS_MAX. ``lm``: any key block with
+    ``num_experts_per_tok``, ``experts_held``, ``n_routed_experts``."""
     k = lm.num_experts_per_tok
     expect = n_tokens * k * lm.experts_held / lm.n_routed_experts
     unit = 512 if expect >= 2048 else 128
-    rows = int(math.ceil(1.25 * expect / unit)) * unit
+    rows = min(int(math.ceil(1.25 * expect / unit)) * unit,
+               EXPERT_CHUNK_ROWS_MAX)
     return max(8, min(rows, n_tokens * min(k, lm.experts_held)))
 
 
-def routed_experts(p, x, ids, weights, valid, lm: LMConfig, dtype):
-    """The held experts' part of the routed sum. x (N, d) normed; ids /
+def routed_experts(p, x, ids, weights, valid, lm, dtype):
+    """The held experts' part of the routed sum (shared with
+    model/afmoe.py: ``lm`` is any key block with ``num_experts_per_tok``,
+    ``experts_held``, ``expert_offset``). x (N, d) normed; ids /
     weights (N, k); valid (N,) bool — padding takes no expert's time.
     Assignments to held experts are sorted by expert and computed as
     grouped products (``jax.lax.ragged_dot``: one matmul over rows in
