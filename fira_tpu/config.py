@@ -381,7 +381,7 @@ class FiraConfig:
     # .py; docs/DECODE_ENGINE.md "Prefix cache & dedup") ---
     # True arms BOTH reuse mechanisms on the engine path: (a) the
     # content-addressed prefill-result cache — each request's prefill
-    # artifacts (encoder output / per-beam cross K/V / copy-head src
+    # artifacts (encoder output / per-layer cross K/V / copy-head src
     # projections) are keyed by a keyed-blake2b digest of its packed
     # payload, and a repeat request seats from the cached artifacts
     # WITHOUT dispatching prefill — and (b) in-flight dedup: a request
@@ -402,7 +402,7 @@ class FiraConfig:
     # time, exit 2 — decode/paging.prefix_cache_errors).
     prefix_cache_entries: int = 256
     # Optional HOST-memory budget for the cache in bytes, per replica
-    # (entry payloads are per-beam cross K/V + src projections — MBs per
+    # (entry payloads are per-layer cross K/V + src projections — MBs per
     # entry at production geometry, so an entry-count bound alone can
     # pin gigabytes of host RAM). 0 = unbounded (the entry cap is the
     # only bound); otherwise LRU entries evict until total payload bytes

@@ -19,9 +19,10 @@ Program family (all fixed-shape, labelled for the compile guard —
 harvest's readback: one program for any number of settled rows); zero
 post-warmup retraces):
 
-- **prefill** (one per decode bucket geometry): encoder forward + per-beam
-  cross-attention K/V + copy-head source projection for ONE packed batch
-  of new requests — exactly the per-batch preamble of the batched beam, on
+- **prefill** (one per decode bucket geometry): encoder forward +
+  cross-attention K/V + copy-head source projection, once a request (a
+  slot's beams share them), for ONE packed batch of new requests —
+  the per-batch preamble of the batched beam up to its K-fold repeat, on
   exactly the batches the existing bucketed/sorted packer emits (the
   feeder assembles and ships them asynchronously, as for every driver).
 - **step** (single geometry — the bucketable axes never reach the decoder:
@@ -732,7 +733,6 @@ class SlotEngine:
         C = slot_ids.shape[0]
         tokens0, probs0, finished0, _neg = _init_beam(C, cfg)
         sid = slot_ids.astype(jnp.int32)
-        sid_bk = jnp.repeat(sid, K) * K + jnp.tile(jnp.arange(K), C)
 
         new = dict(state)
 
@@ -749,7 +749,7 @@ class SlotEngine:
             limits.astype(jnp.int32), mode="drop")
         # the model's own leaves (slot_model): what prefill left for each
         # seated row; the pools are untouched (INVARIANT above)
-        new.update(self.smodel.insert(state, chunk, sid, sid_bk, fresh))
+        new.update(self.smodel.insert(state, chunk, sid, fresh))
         # hand the seated rows their block grants
         new["block_tab"] = state["block_tab"].at[sid].set(
             block_rows.astype(jnp.int32), mode="drop")
@@ -1014,7 +1014,7 @@ class SlotEngine:
             for f in prefix_cache_lib.ARTIFACT_FIELDS:
                 chunk_host[f] = np.asarray(jax.device_get(chunk[f]))  # firacheck: allow[HOST-SYNC] deferred prefill-cache miss-fill draining at the harvest sync boundary; the D2H itself was scheduled async at admit (copy_to_host_async), so this materialization is the designated host copy, not a mid-admission stall
             entries = prefix_cache_lib.extract_payloads(
-                chunk_host, [r for r, _d in fills], self.cfg.beam_size)
+                chunk_host, [r for r, _d in fills])
             for r, d in fills:
                 self.stats.cache_evictions += self._cache.put(d, entries[r])
 
@@ -1219,9 +1219,7 @@ class SlotEngine:
                 prefix_cache_lib.payload_nbytes(p) for p in payloads.values())
             st.prefills_saved += 1
             chunk = jax.device_put(
-                prefix_cache_lib.build_chunk(payloads, C,
-                                             self.cfg.beam_size),
-                self.device)
+                prefix_cache_lib.build_chunk(payloads, C), self.device)
             self._ensure_state(chunk)
         elif seat_rows:
             if device_batch is None or self.device is not None:

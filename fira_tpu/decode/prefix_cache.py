@@ -2,7 +2,7 @@
 
 At serving scale traffic REPEATS — CI re-runs, monorepo bots, and client
 retries send byte-identical diffs — yet every request pays a full
-prefill: the encoder pass, the per-beam cross K/V, and the copy-head
+prefill: the encoder pass, the per-layer cross K/V, and the copy-head
 source projections (the static, read-only-during-decode half of a seat's
 state). vLLM's block-sharing design (PAPERS.md "Continuous batching /
 inference serving", SOSP '23) showed content-addressed read-only reuse is
@@ -128,17 +128,15 @@ def payload_checksum(payload: Dict[str, np.ndarray]) -> str:
     return _digest_arrays(sorted(payload.items()))
 
 
-def extract_payloads(chunk_host: Dict[str, np.ndarray], rows: List[int],
-                     beam: int) -> Dict[int, Dict[str, np.ndarray]]:
+def extract_payloads(chunk_host: Dict[str, np.ndarray], rows: List[int]
+                     ) -> Dict[int, Dict[str, np.ndarray]]:
     """Slice one prefilled chunk's HOST copy into per-row cache payloads.
-    Row r owns beam lanes ``r*K..(r+1)*K`` of the K-repeated arrays
-    (cross_k/cross_v on axis 1, src_proj on axis 0) — and those K
-    lanes are byte-identical by construction (the prefill's
-    ``jnp.repeat``), so the payload stores ONE lane and :func:`build_chunk`
-    re-repeats it: 1/K the host RAM, hashing, and byte-budget charge for
-    a bit-identical rebuild. ``seed`` records the cache-seed dtype so a
-    rebuilt chunk reproduces the prefill pytree exactly."""
-    K = int(beam)
+    A chunk holds the source side once a request (cross_k/cross_v rows on
+    axis 1, src_proj on axis 0: the arena stores them once a slot and the
+    slot's beams share them), so a payload is row r of every field, the
+    row axis kept at length 1, and :func:`build_chunk` writes it back to
+    row r: a bit-identical rebuild. ``seed`` records the cache-seed dtype
+    so a rebuilt chunk reproduces the prefill pytree exactly."""
     out: Dict[int, Dict[str, np.ndarray]] = {}
     for r in rows:
         out[r] = {
@@ -146,48 +144,42 @@ def extract_payloads(chunk_host: Dict[str, np.ndarray], rows: List[int],
             "diff": np.ascontiguousarray(chunk_host["diff"][r]),
             "sub_token": np.ascontiguousarray(chunk_host["sub_token"][r]),
             "cross_k": np.ascontiguousarray(
-                chunk_host["cross_k"][:, r * K:r * K + 1]),
+                chunk_host["cross_k"][:, r:r + 1]),
             "cross_v": np.ascontiguousarray(
-                chunk_host["cross_v"][:, r * K:r * K + 1]),
+                chunk_host["cross_v"][:, r:r + 1]),
             "src_proj": np.ascontiguousarray(
-                chunk_host["src_proj"][r * K:r * K + 1]),
+                chunk_host["src_proj"][r:r + 1]),
             "seed": np.zeros((), chunk_host["cache_seed"].dtype),
         }
     return out
 
 
-def build_chunk(payloads: Dict[int, Dict[str, np.ndarray]], batch_rows: int,
-                beam: int) -> Dict[str, np.ndarray]:
+def build_chunk(payloads: Dict[int, Dict[str, np.ndarray]], batch_rows: int
+                ) -> Dict[str, np.ndarray]:
     """Assemble a staged-chunk pytree from cached per-row payloads: the
     EXACT key set, shapes, and dtypes of the prefill program's output for
     this geometry (so the insert program sees the same pytree structure
     it was traced with — a cache hit can never retrace). Rows without a
     payload (pad rows, coalesced rows) stay zero; the insert scatter
     drops them via the sentinel slot id, so their values are never read."""
-    C, K = int(batch_rows), int(beam)
+    C = int(batch_rows)
     any_p = next(iter(payloads.values()))
     out: Dict[str, np.ndarray] = {}
     for f in ("src_mask", "diff", "sub_token"):
         a = any_p[f]
         out[f] = np.zeros((C,) + a.shape, a.dtype)
-    ck = any_p["cross_k"]          # (L, 1, ...) — one stored lane
-    L = ck.shape[0]
+    ck = any_p["cross_k"]          # (L, 1, ...): the request's one row
     for f in ("cross_k", "cross_v"):
-        out[f] = np.zeros((L, C * K) + ck.shape[2:], ck.dtype)
+        out[f] = np.zeros((ck.shape[0], C) + ck.shape[2:], ck.dtype)
     sp = any_p["src_proj"]         # (1, ...)
-    out["src_proj"] = np.zeros((C * K,) + sp.shape[1:], sp.dtype)
+    out["src_proj"] = np.zeros((C,) + sp.shape[1:], sp.dtype)
     out["cache_seed"] = np.zeros((), any_p["seed"].dtype)
     for r, p in payloads.items():
         for f in ("src_mask", "diff", "sub_token"):
             out[f][r] = p[f]
-        # re-repeat the single stored lane across the K beam slots —
-        # bitwise what the prefill's jnp.repeat produced
-        out["cross_k"][:, r * K:(r + 1) * K] = np.repeat(
-            p["cross_k"], K, axis=1)
-        out["cross_v"][:, r * K:(r + 1) * K] = np.repeat(
-            p["cross_v"], K, axis=1)
-        out["src_proj"][r * K:(r + 1) * K] = np.repeat(
-            p["src_proj"], K, axis=0)
+        for f in ("cross_k", "cross_v"):
+            out[f][:, r] = p[f][:, 0]
+        out["src_proj"][r] = p["src_proj"][0]
     return out
 
 

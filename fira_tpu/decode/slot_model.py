@@ -15,7 +15,7 @@ bound by name in the engine any more:
   beams, or per beam LANE and read through the ancestry table). ``kv``
   marks what ``kv_bytes_per_slot`` counts (decode/paging.py follows
   these declarations).
-- ``insert(state, chunk, sid, sid_bk, fresh) -> {name: leaf}``:
+- ``insert(state, chunk, sid, fresh) -> {name: leaf}``:
   scatter chunk rows into slots ``sid`` (sentinel = dropped).
 - ``step(params, state, view) -> (parts, writes)``: one position for every
   beam of every slot, at each slot's own depth; ``view`` carries the
@@ -36,9 +36,11 @@ bound by name in the engine any more:
   (``beam._select_factored``); a model with one log-softmax goes through
   ``beam._select``.
 
-:class:`FiraSlotModel` is the first implementation: ONE arena (the paged
-pools, written once and followed by ancestry), one step
-(``FiraModel.dist_parts_step_paged``) and one selection
+:class:`FiraSlotModel` is the first implementation: ONE arena (the source
+side — cross K/V, copy projection, mask — once a slot, shared by its
+beams; the paged pools, written once and followed by ancestry), one step
+(``FiraModel.dist_parts_step_paged``: a slot's K beams are K queries
+against what the slot holds) and one selection
 (``beam._select_factored``), whatever the batched beam's knobs say.
 :class:`LMSlotModel` (``arch="axk1"``, model/axk1.py) is the second;
 :class:`AfmoeSlotModel` (``arch="afmoe"``, model/afmoe.py) the third, and
@@ -112,9 +114,11 @@ def permute_pool(pool, tab_step, src_beam):
 
 
 class FiraSlotModel:
-    """FIRA behind the seam: encoder prefill, per-beam cross K/V and copy
-    projection, the decoder step over the paged pools, the selection from
-    the distribution's factors."""
+    """FIRA behind the seam: encoder prefill, cross K/V and copy
+    projection ONCE A SLOT (the slot's K beams read them as K queries; the
+    batched beam repeats them K-fold, the arena never does), the decoder
+    step over the paged pools, the selection from the distribution's
+    factors."""
 
     insert_by_geometry = False
     arena_counters: Tuple[str, ...] = ()
@@ -133,22 +137,19 @@ class FiraSlotModel:
         return int(chunk["diff"].shape[0])
 
     def prefill(self, params, batch):
-        """Per-batch preamble of the cached batched beam, verbatim: encode
-        once, then per-layer cross K/V + copy-head source projection
-        replicated per beam. Identical program prefix => identical values."""
+        """Per-batch preamble of the cached batched beam up to its
+        per-beam replication: encode once, then per-layer cross K/V +
+        copy-head source projection, a row a request. Identical program
+        prefix => identical values."""
         from fira_tpu.model.model import FiraModel
 
         cfg, model = self.cfg, self.model
-        K = cfg.beam_size
         states, mask = model.apply({"params": params}, batch,
                                    method=FiraModel.encode)
         out = {"src_mask": mask, "diff": batch["diff"],
                "sub_token": batch["sub_token"]}
-        cross_k, cross_v, src_proj = model.apply(
+        out["cross_k"], out["cross_v"], out["src_proj"] = model.apply(
             {"params": params}, states, method=FiraModel.decode_init)
-        out["cross_k"] = jnp.repeat(cross_k, K, axis=1)
-        out["cross_v"] = jnp.repeat(cross_v, K, axis=1)
-        out["src_proj"] = jnp.repeat(src_proj, K, axis=0)
         # dtype marker only: fresh slots seed their self-attention cache
         # at the ENCODER STATE dtype, exactly like the batched beam's
         # cache0 (which may be wider than the compute dtype under
@@ -175,24 +176,23 @@ class FiraSlotModel:
                               chunk["sub_token"].dtype),
             "src_mask": Leaf((S,) + chunk["src_mask"].shape[1:],
                              np.dtype(bool)),
-            "cross_k": Leaf((L, S * K) + ck.shape[2:], ck.dtype),
-            "cross_v": Leaf((L, S * K) + ck.shape[2:], ck.dtype),
-            "src_proj": Leaf((S * K,) + sp.shape[1:], sp.dtype),
+            # the source side, once a slot: its beams are K queries
+            "cross_k": Leaf((L, S) + ck.shape[2:], ck.dtype),
+            "cross_v": Leaf((L, S) + ck.shape[2:], ck.dtype),
+            "src_proj": Leaf((S,) + sp.shape[1:], sp.dtype),
             # per beam LANE, not per beam: no reorder (beam_ancestry)
             "k_pool": Leaf((L, P, K, H, BS, d_head), cd, kv=True),
             "v_pool": Leaf((L, P, K, H, BS, d_head), cd, kv=True),
         }
 
-    def insert(self, state, chunk, sid, sid_bk, fresh) -> Dict:
+    def insert(self, state, chunk, sid, fresh) -> Dict:
         """No cache zeroing (the engine's INVARIANT): the pools are
         untouched here."""
         new = {}
-        for f in ("diff", "sub_token", "src_mask"):
+        for f in ("diff", "sub_token", "src_mask", "src_proj"):
             new[f] = state[f].at[sid].set(chunk[f], mode="drop")
         for f in ("cross_k", "cross_v"):
-            new[f] = state[f].at[:, sid_bk].set(chunk[f], mode="drop")
-        new["src_proj"] = state["src_proj"].at[sid_bk].set(
-            chunk["src_proj"], mode="drop")
+            new[f] = state[f].at[:, sid].set(chunk[f], mode="drop")
         return new
 
     def step(self, params, state, view: StepView):
@@ -200,28 +200,26 @@ class FiraSlotModel:
 
         cfg = self.cfg
         flat, pos_bk = view.flat, view.pos_bk
-        mask_k = jnp.repeat(state["src_mask"], cfg.beam_size, axis=0)
         # same per-row validity rule as beam_search_cached, at the
         # per-slot position vector (beam.step_valid_mask) — this mask
         # is also what makes unwritten/stale POOL blocks read as an
         # exact 0.0 contribution, so fresh slots need no zeroed cache
         valid = step_valid_mask(flat, pos_bk, cfg.tar_len)
         tok_in = jnp.take_along_axis(flat, pos_bk[:, None], axis=1)
+        # the factors come back a slot a row, its beams second: what the
+        # selection takes
         gen, copy, gate, k_new, v_new = self.model.apply(
-            {"params": params}, mask_k, tok_in, pos_bk, state["k_pool"],
-            state["v_pool"], view.tab_step, view.ancestry,
+            {"params": params}, state["src_mask"], tok_in, pos_bk,
+            state["k_pool"], state["v_pool"], view.tab_step, view.ancestry,
             state["cross_k"], state["cross_v"], state["src_proj"],
             valid[:, None, None, :],
             method=FiraModel.dist_parts_step_paged)
-        parts = (gen[:, 0, :], copy[:, 0, :], gate[:, 0, :])
-        return parts, {"k_pool": k_new, "v_pool": v_new}
+        return (gen, copy, gate), {"k_pool": k_new, "v_pool": v_new}
 
     def select(self, parts, tokens, probs, finished, pos_c, state, neg):
-        S, K = probs.shape
         gen, copy, gate = parts
         return _select_factored(
-            gen.reshape(S, K, -1), copy.reshape(S, K, -1),
-            gate.reshape(S, K, 2), tokens, probs, finished, pos_c,
+            gen, copy, gate, tokens, probs, finished, pos_c,
             {"diff": state["diff"], "sub_token": state["sub_token"]},
             self.cfg, neg)
 
@@ -276,7 +274,7 @@ class LMSlotModel:
                              np.dtype(np.int32)),
         }
 
-    def insert(self, state, chunk, sid, sid_bk, fresh) -> Dict:
+    def insert(self, state, chunk, sid, fresh) -> Dict:
         P = chunk["lat"].shape[2]
         return {
             "prompt_lat": state["prompt_lat"].at[:, sid, :P].set(
@@ -365,7 +363,7 @@ class AfmoeSlotModel(LMSlotModel):
         })
         return out
 
-    def insert(self, state, chunk, sid, sid_bk, fresh) -> Dict:
+    def insert(self, state, chunk, sid, fresh) -> Dict:
         # a chunk's prompts are as long as their bucket; its rings came in
         # ring order (entry r holds position p, p mod window == r), a
         # bucket under the window filling the first entries only

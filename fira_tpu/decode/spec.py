@@ -31,10 +31,11 @@ append), so the row RESUMES with its history intact.
 Drafter tiers (cfg.spec_decode):
 
 - ``copy``: the copy-head distribution ALONE — pointer scores from the
-  cached source projections (state["src_proj"], computed once at prefill)
-  against the raw target embedding proxy (model.copy_draft_scores: embed +
-  position row, NO decoder layer). Near-free: k tiny matvec/tanh passes per
-  dispatch. Rides FIRA's measured verbatim-copy fraction.
+  cached source projections (state["src_proj"], computed once at prefill
+  and held a row a slot) against the raw target embedding proxy
+  (model.copy_draft_scores: embed + position row, NO decoder layer).
+  Near-free: k tiny matvec/tanh passes per dispatch. Rides FIRA's measured
+  verbatim-copy fraction.
 - ``draft``: a greedy argmax roll of the existing cached step program on
   each slot's TOP BEAM only — 1/beam of the step's decoder rows, against
   a dense scratch view of beam 0's history (layers.gather_block_kv_beam,
@@ -160,7 +161,7 @@ def make_drafter(model: FiraModel, cfg: FiraConfig, slots: int):
     real state (the scratch caches of the ``draft`` tier live and die in
     the scan carry), so the engine jits the result WITHOUT donation and the
     verify that follows donates the untouched arena as usual."""
-    K, T = cfg.beam_size, cfg.tar_len
+    T = cfg.tar_len
     L = cfg.num_layers
     V = cfg.vocab_size
     k = int(cfg.engine_spec_k)
@@ -183,15 +184,14 @@ def make_drafter(model: FiraModel, cfg: FiraConfig, slots: int):
     if tier == "copy":
 
         def drafter(params, state):
-            src_proj0 = state["src_proj"][0::K]  # beam-0 cached rows
-            mask = state["src_mask"]
-
             def body(flat0, tok0, pos0):
                 def step(carry, _):
                     tok, p = carry
+                    # the source side is a row a slot, as the arena holds it
                     scores = model.apply(
-                        {"params": params}, mask, src_proj0, tok[:, None],
-                        p, method=FiraModel.copy_draft_scores)
+                        {"params": params}, state["src_mask"],
+                        state["src_proj"], tok[:, None], p,
+                        method=FiraModel.copy_draft_scores)
                     choice = V + jnp.argmax(
                         scores[:, 0, :], axis=-1).astype(jnp.int32)
                     nxt = resolve(choice, state)
@@ -207,10 +207,6 @@ def make_drafter(model: FiraModel, cfg: FiraConfig, slots: int):
     assert tier == "draft", tier
 
     def drafter(params, state):
-        mask = state["src_mask"]
-        cross_k0 = state["cross_k"][:, 0::K]
-        cross_v0 = state["cross_v"][:, 0::K]
-        src_proj0 = state["src_proj"][0::K]
         # dense SCRATCH view of each slot's top beam: the pool is read
         # once per draft and never written (sentinel table rows of
         # idle/done slots clamp to garbage the validity mask zeroes).
@@ -229,10 +225,12 @@ def make_drafter(model: FiraModel, cfg: FiraConfig, slots: int):
                 flat, p, kc, vc = carry
                 valid = step_valid_mask(flat, p, T)
                 tok_in = jnp.take_along_axis(flat, p[:, None], axis=1)
+                # the source side is a row a slot in the arena: the top
+                # beam's dense step reads the leaves themselves
                 fused, kc, vc = model.apply(
-                    {"params": params}, mask, tok_in, p, kc, vc,
-                    cross_k0, cross_v0, src_proj0,
-                    valid[:, None, None, :],
+                    {"params": params}, state["src_mask"], tok_in, p, kc,
+                    vc, state["cross_k"], state["cross_v"],
+                    state["src_proj"], valid[:, None, None, :],
                     method=FiraModel.fused_probs_step_multi)
                 nxt = resolve(
                     jnp.argmax(fused[:, 0, :], axis=-1).astype(jnp.int32),

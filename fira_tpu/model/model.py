@@ -397,9 +397,16 @@ class Decoder(nn.Module):
         test_beam_ancestry.py that the entries selected ARE the reordered
         whole-sequence cache, bit for bit).
 
+        The source side is held ONCE A SLOT: cross_k/cross_v
+        (L, S, H, src_len, d_head) and ``sou_mask`` (S, src_len). A slot's
+        K beams read them as they read its blocks, as the query axis of one
+        attention a slot — per head one (K x d_head) x (d_head x src_len)
+        product where K rows of one query each would read K copies.
+
         tok: (S*K, 1) token ids; pos_idx: (S*K,) per-row positions (rows
         of one slot share theirs); self_mask: (S*K, 1, 1, tar_len) per-row
-        validity; W*block must equal tar_len."""
+        validity; W*block must equal tar_len. Returns x (S, K, D) and the
+        pools."""
         _L, _P, K, _H, BS, _dh = k_pool.shape
         B = tok.shape[0]
         S, W = block_tab.shape
@@ -414,19 +421,21 @@ class Decoder(nn.Module):
         blk = block_tab[slot, pos // BS]             # (B,) current tail block
         off = pos % BS
         mask = lane_mask(ancestry, self_mask.reshape(S, K, W * BS), BS)
-        x = self.embed(tok) + self._pos_table()[pos][:, None, :]
+        # a slot's K beams share its blocks and its source: they are the
+        # query axis of both attentions, so x is (S, K, D) throughout
+        x = (self.embed(tok) + self._pos_table()[pos][:, None, :]
+             ).reshape(S, K, -1)
         for i in range(self.cfg.num_layers):
             sa = getattr(self, f"self_attn_{i}")
-            k_new, v_new = sa.project_kv(x, x)       # (B, H, 1, d_head)
+            rows = x.reshape(B, 1, -1)
+            k_new, v_new = sa.project_kv(rows, rows)  # (B, H, 1, d_head)
             k_pool = append_block_kv(k_pool, i, blk, krow, off,
                                      k_new[:, :, 0, :])
             v_pool = append_block_kv(v_pool, i, blk, krow, off,
                                      v_new[:, :, 0, :])
-            # a slot's K beams share its blocks: they are the query axis
-            x = sa.attend(x.reshape(S, K, -1),
-                          gather_block_kv(k_pool[i], block_tab),
+            x = sa.attend(x, gather_block_kv(k_pool[i], block_tab),
                           gather_block_kv(v_pool[i], block_tab),
-                          mask, deterministic=True).reshape(B, 1, -1)
+                          mask, deterministic=True)
             x = getattr(self, f"cross_attn_{i}").attend(
                 x, cross_k[i], cross_v[i], sou_mask, deterministic=True)
             x = getattr(self, f"ffn_{i}")(x, deterministic=True)
@@ -664,7 +673,10 @@ class FiraModel(nn.Module):
         """Shared generation/copy/gate head of the cached one-position
         decode paths (the batched beam's :meth:`dist_parts_step`, the
         engine's :meth:`dist_parts_step_paged`, the drafter's dense
-        fused step)."""
+        fused step). ``tar_emb`` (B, n, D) scores n targets against row
+        b's source: n = 1 for a row a beam, and the engine's n = K beams
+        of a slot against the ONE ``src_proj`` (S, src_len, D) and
+        ``mask`` (S, src_len) the slot holds."""
         with jax.named_scope("output_head"):
             gen = jax.nn.softmax(
                 self.out_fc(tar_emb).astype(stable_dtype(self.dtype)),
@@ -701,7 +713,11 @@ class FiraModel(nn.Module):
         attention cache read and written through block-table indirection
         and followed by beam ancestry (Decoder.decode_step_paged) instead
         of whole-sequence stripes; heads are the shared
-        :meth:`_step_heads`."""
+        :meth:`_step_heads`. ``mask`` (S, src_len), ``cross_k``/
+        ``cross_v`` (L, S, ...) and ``src_proj`` (S, src_len, D) are a
+        SLOT's, read by its K beams as K queries; the factors come back
+        a slot a row: gen (S, K, V), copy (S, K, src_len), gate
+        (S, K, 2)."""
         with jax.named_scope("decoder"):
             tar_emb, k_pool, v_pool = self.decoder.decode_step_paged(
                 tok, pos_idx, k_pool, v_pool, block_tab, ancestry, cross_k,

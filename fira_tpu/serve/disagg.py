@@ -191,7 +191,7 @@ def _worker_main(conn, init: Dict) -> None:
         chunk_host = {f: np.asarray(jax.device_get(chunk[f]))  # firacheck: allow[HOST-SYNC] the worker child's whole job is materializing prefill artifacts on host for transport; this D2H is the product, not a stall
                       for f in prefix_cache_lib.ARTIFACT_FIELDS}
         entries = prefix_cache_lib.extract_payloads(
-            chunk_host, list(range(len(rows))), cfg.beam_size)
+            chunk_host, list(range(len(rows))))
         return [(rows[j][0], prefix_cache_lib.payload_checksum(entries[j]),
                  entries[j]) for j in range(len(rows))]
 
@@ -204,8 +204,7 @@ def _worker_main(conn, init: Dict) -> None:
         chunk = eng._prefill(eng.params, jax.device_put(wire))
         chunk_host = {f: np.asarray(jax.device_get(chunk[f]))  # firacheck: allow[HOST-SYNC] prewarm-time artifact sizing for the ready handshake (once per bucket, before any request exists)
                       for f in prefix_cache_lib.ARTIFACT_FIELDS}
-        entry = prefix_cache_lib.extract_payloads(
-            chunk_host, [0], cfg.beam_size)[0]
+        entry = prefix_cache_lib.extract_payloads(chunk_host, [0])[0]
         est[b] = prefix_cache_lib.payload_nbytes(entry)
     conn.send(("ready", wid, est))
 

@@ -71,7 +71,7 @@ def _through_the_cache(lm, params, tok, plen, n_gen):
     # dirty caches: a stale entry that a mask lets through would show
     state = {n: x + 3.0 if n.startswith("prompt_k_") else x
              for n, x in state.items()}
-    state.update(sm.insert(state, chunk, jnp.arange(S), None, 1))
+    state.update(sm.insert(state, chunk, jnp.arange(S), 1))
     tab = jnp.arange(S * T // BS).reshape(S, T // BS)
     step = jax.jit(lambda st, view: sm.step(params, st, view))
     out = []

@@ -264,6 +264,13 @@ def served(setup, tmp_path_factory):
     every program, then the window — the window under a profiler session."""
     cfg, dataset, model, params = setup
     split = dataset.splits["train"]
+    # jax keeps one in-process cache a jitted FUNCTION, and the harvest
+    # gather is a staticmethod every engine shares: an engine of the same
+    # (slots, beam, tar_len) built earlier on this worker (which files
+    # share a worker differs from run to run) would leave prewarm nothing
+    # to compile, and the tests below assert that compile. Start as a
+    # fresh process does.
+    jax.clear_caches()
     eng = SlotEngine(model, params, cfg, slots=cfg.engine_slots)
     warm = make_batch(split, np.arange(0), cfg, batch_size=cfg.test_batch_size)
     mark = len(profiling.events())
