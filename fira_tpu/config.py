@@ -26,9 +26,14 @@ class _PromptGeometry:
         """Requests one prefill dispatch of ``bucket``-long prompts holds."""
         return max(1, self.prefill_token_budget // int(bucket))
 
+    def bucket_errors(self) -> list:
+        if list(self.prompt_buckets) != sorted(set(self.prompt_buckets)):
+            return [f"lm.prompt_buckets {self.prompt_buckets} must ascend"]
+        return []
+
     def share_errors(self) -> list:
-        """The checks every key block shares: the experts held lie inside
-        the router's width, the buckets ascend."""
+        """The checks every key block with routed experts shares: the
+        experts held lie inside the router's width, the buckets ascend."""
         errs = []
         if not 0 <= self.expert_offset \
                 <= self.n_routed_experts - self.experts_held:
@@ -36,10 +41,7 @@ class _PromptGeometry:
                 f"lm.expert_offset {self.expert_offset} + lm.experts_held "
                 f"{self.experts_held} must lie within lm.n_routed_experts "
                 f"{self.n_routed_experts}")
-        if list(self.prompt_buckets) != sorted(set(self.prompt_buckets)):
-            errs.append(f"lm.prompt_buckets {self.prompt_buckets} must "
-                        f"ascend")
-        return errs
+        return errs + self.bucket_errors()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -173,12 +175,95 @@ class AfmoeConfig(_PromptGeometry):
 
 
 @dataclasses.dataclass(frozen=True)
+class JambaConfig(_PromptGeometry):
+    """Jamba2-3B's key block (``arch="jamba"``): the published keys of that
+    state-space-and-attention decoder by the names its ``config.json`` gives
+    them (``model_type: jamba``), Jamba2-3B's values as the defaults
+    (https://huggingface.co/ai21labs/AI21-Jamba2-3B/blob/main/config.json).
+    Layer i is an attention layer iff ``i % attn_layer_period ==
+    attn_layer_offset``; every other layer is a Mamba layer. After the
+    published keys: the prefill geometry."""
+
+    hidden_size: int = 2560
+    intermediate_size: int = 8192        # every layer's dense SwiGLU
+    num_hidden_layers: int = 28
+    attn_layer_period: int = 14
+    attn_layer_offset: int = 7
+    num_attention_heads: int = 20
+    num_key_value_heads: int = 1
+    mamba_expand: int = 2                # d_inner = mamba_expand x hidden
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_dt_rank: int = 160
+    mamba_proj_bias: bool = False
+    mamba_conv_bias: bool = True
+    num_experts: int = 1                 # 1: no layer routes
+    tie_word_embeddings: bool = True
+    rms_norm_eps: float = 1e-6
+    vocab_size: int = 65536
+    prompt_buckets: tuple = (256, 512, 1024, 2048, 4096)
+    prefill_token_budget: int = 4096
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def kv_dim(self) -> int:
+        """What one token caches an ATTENTION layer: [k | v]."""
+        return 2 * self.num_key_value_heads * self.head_dim
+
+    def layer_is_attention(self, layer: int) -> bool:
+        return layer % self.attn_layer_period == self.attn_layer_offset
+
+    @property
+    def attention_layers(self) -> tuple:
+        return tuple(i for i in range(self.num_hidden_layers)
+                     if self.layer_is_attention(i))
+
+    @property
+    def mamba_layers(self) -> tuple:
+        return tuple(i for i in range(self.num_hidden_layers)
+                     if not self.layer_is_attention(i))
+
+    def errors(self) -> list:
+        errs = self.bucket_errors()
+        if self.num_experts != 1:
+            errs.append(f"lm.num_experts {self.num_experts}: only the dense "
+                        f"feed-forward (num_experts 1) is implemented")
+        if self.mamba_proj_bias or not self.mamba_conv_bias \
+                or not self.tie_word_embeddings:
+            errs.append("lm.mamba_proj_bias / lm.mamba_conv_bias / "
+                        "lm.tie_word_embeddings other than false / true / "
+                        "true are not implemented")
+        if self.hidden_size % self.num_attention_heads \
+                or self.num_attention_heads % self.num_key_value_heads:
+            errs.append(
+                f"lm.num_attention_heads {self.num_attention_heads} must "
+                f"divide lm.hidden_size {self.hidden_size} and be a "
+                f"multiple of lm.num_key_value_heads "
+                f"{self.num_key_value_heads}")
+        if not self.attention_layers or not self.mamba_layers:
+            errs.append(
+                f"lm.attn_layer_period {self.attn_layer_period} / "
+                f"lm.attn_layer_offset {self.attn_layer_offset} must leave "
+                f"lm.num_hidden_layers {self.num_hidden_layers} with layers "
+                f"of both kinds")
+        return errs
+
+
+@dataclasses.dataclass(frozen=True)
 class FiraConfig:
     # --- architecture (ARCH_TABLE below): "fira" (the paper's
     # encoder-decoder, every field below) or a decoder-only token model —
     # "axk1" (A.X-K1: latent attention, group-limited routed experts) or
     # "afmoe" (Trinity-Mini: window and full attention layers, 128 small
-    # experts) — whose published keys ``lm`` holds in its own key block; of
+    # experts) or "jamba" (Jamba2-3B: state-space layers with a recurrent
+    # state a beam, two attention layers) — whose published keys ``lm`` holds in its own key block; of
     # the fields below such a model reads beam_size, tar_len, the
     # engine/paging knobs and seed ---
     arch: str = "fira"
@@ -913,6 +998,29 @@ def afmoe_tiny(**kw) -> FiraConfig:
         prefill_token_budget=64), base)
 
 
+def jamba2_3b(**kw) -> FiraConfig:
+    """Jamba2-3B as published, whole on one chip
+    (benchmark/configs/jamba2-3b.json): 26 Mamba layers and 2 attention
+    layers, the whole vocabulary, 6.06 GB of bfloat16 weights."""
+    base = dict(engine_slots=64, test_batch_size=16)
+    base.update(kw)
+    return _lm_preset("jamba", JambaConfig(), base)
+
+
+def jamba_tiny(**kw) -> FiraConfig:
+    """Every mechanism of Jamba2-3B at CPU-test widths: d 64, 4 layers
+    (layer 1 attention: 4 query heads over 1 key/value head of 16; layers
+    0, 2, 3 Mamba: d_inner 128, d_state 16, d_conv 4, dt_rank 4)."""
+    base = dict(engine_slots=4, test_batch_size=4, tar_len=16,
+                compute_dtype="float32")
+    base.update(kw)
+    return _lm_preset("jamba", JambaConfig(
+        hidden_size=64, intermediate_size=96, num_hidden_layers=4,
+        attn_layer_period=4, attn_layer_offset=1, num_attention_heads=4,
+        mamba_dt_rank=4, vocab_size=64, prompt_buckets=(16, 32, 64),
+        prefill_token_budget=64), base)
+
+
 NAMED_CONFIGS = {
     "fira-tiny": fira_tiny,
     "fira-full": fira_full,
@@ -921,6 +1029,8 @@ NAMED_CONFIGS = {
     "axk1-tiny": axk1_tiny,
     "trinity-mini-l5": trinity_mini_l5,
     "afmoe-tiny": afmoe_tiny,
+    "jamba2-3b": jamba2_3b,
+    "jamba-tiny": jamba_tiny,
 }
 
 
@@ -978,13 +1088,15 @@ ARCH_TABLE = {
     "fira": Arch(None, "fira_tpu.model.model", "FiraSlotModel"),
     "axk1": Arch(LMConfig, "fira_tpu.model.axk1", "LMSlotModel"),
     "afmoe": Arch(AfmoeConfig, "fira_tpu.model.afmoe", "AfmoeSlotModel"),
+    "jamba": Arch(JambaConfig, "fira_tpu.model.jamba", "JambaSlotModel"),
 }
 ARCHS = tuple(ARCH_TABLE)
 
 
 def arch_errors(cfg: FiraConfig, command: Optional[str] = None) -> list:
     """What an architecture does not run yet is refused by name, never
-    run silently as something else. ``command``: the CLI's (``train`` /
+    run silently as something else: every token model (``axk1``, ``afmoe``,
+    ``jamba``) goes through the same lines below. ``command``: the CLI's (``train`` /
     ``test`` / ``serve`` / ``message``), where there is one."""
     if cfg.arch not in ARCHS:
         return [f"arch {cfg.arch!r} not in {list(ARCHS)}"]

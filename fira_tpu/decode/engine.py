@@ -182,6 +182,9 @@ class EngineStats:
     # for a model whose layers are all of one kind
     kv_bytes_per_slot_full: int = 0
     kv_bytes_per_slot_window: int = 0
+    # ... and recurrent state (``"state"``): bytes a slot that do not grow
+    # with the prompt
+    kv_bytes_per_slot_state: int = 0
     block_steps: int = 0         # blocks in use, summed per step dispatch
     peak_blocks: int = 0         # high-water mark of blocks in use
     # harvest readback accounting: a harvest that settles rows gathers
@@ -245,6 +248,10 @@ class EngineStats:
     #                               cover: occupied slots x layers x the
     #                               keys inside window or context
     attn_keys_context: int = 0    # the same with every layer full
+    # recurrent-state accounting (model/jamba.COUNTERS, the same leaf —
+    # zero for a model without state-space layers)
+    state_rows: int = 0           # slot-beams whose state a position
+    #                               updated: occupied slots x beams
     # per span name count/total_s/max_s and the compile counters, over
     # the spans that closed while THIS stats object lived (utils/
     # profiling.Phases) — a stats reset between timed windows resets the
@@ -303,6 +310,7 @@ class EngineStats:
             "kv_bytes_per_slot": self.kv_bytes_per_slot,
             "kv_bytes_per_slot_full": self.kv_bytes_per_slot_full,
             "kv_bytes_per_slot_window": self.kv_bytes_per_slot_window,
+            "kv_bytes_per_slot_state": self.kv_bytes_per_slot_state,
             "peak_blocks": self.peak_blocks,
             "pool_utilization": round(self.pool_utilization, 4),
             "harvest_reads": self.harvest_reads,
@@ -332,6 +340,7 @@ class EngineStats:
             "moe_held_load_max": self.moe_held_load_max,
             "attn_keys_read": self.attn_keys_read,
             "attn_keys_context": self.attn_keys_context,
+            "state_rows": self.state_rows,
             "phases": self.phases.summary(),
         }
 
@@ -656,10 +665,14 @@ class SlotEngine:
                 jnp.arange(T)[None, None, :] == pos_c[:, None, None],
                 jnp.arange(K, dtype=jnp.int32)[None, :, None],
                 state["ancestry"])
+        # beam parents (a model that declares recurrent state): the lane
+        # whose state each beam continues from — the last selection's
+        # source beam; the step reads through it as it writes
+        parent = state["parent"] if self.smodel.beam_parent else None
         view = slot_model.StepView(
             flat=tokens.reshape(S * K, T), pos_c=pos_c,
             pos_bk=jnp.repeat(pos_c, K), active=active, tab_step=tab_step,
-            ancestry=ancestry)
+            ancestry=ancestry, parent=parent)
         parts, out_caches = self.smodel.step(params, state, view)
         with jax.named_scope("topk"):
             new_tokens, new_probs, new_finished, src_beam = \
@@ -692,6 +705,13 @@ class SlotEngine:
                     ancestry, src_beam[:, :, None], axis=1)
             out_caches["ancestry"] = jnp.where(
                 active[:, None, None], followed, state["ancestry"])
+
+        if parent is not None:
+            # recurrent state is not moved either: the NEXT position reads
+            # each beam's state from the lane of the beam it came from.
+            # Inactive rows keep their parents with their state.
+            out_caches["parent"] = jnp.where(active[:, None], src_beam,
+                                             parent)
 
         tokens = jnp.where(active[:, None, None], new_tokens, tokens)
         probs = jnp.where(active[:, None], new_probs, probs)
@@ -757,6 +777,10 @@ class SlotEngine:
             # a fresh slot's beams each start in their own lane
             new["ancestry"] = state["ancestry"].at[sid].set(
                 jnp.arange(K, dtype=jnp.int32)[None, :, None], mode="drop")
+        if self.smodel.beam_parent:
+            # a fresh slot's beams share one history: all continue from
+            # the state the model's insert put in lane 0
+            new["parent"] = state["parent"].at[sid].set(0, mode="drop")
         return new
 
     # --- state ----------------------------------------------------------
@@ -790,6 +814,8 @@ class SlotEngine:
         if self.smodel.beam_ancestry:
             z["ancestry"] = np.broadcast_to(
                 np.arange(K, dtype=np.int32)[None, :, None], (S, K, T)).copy()
+        if self.smodel.beam_parent:
+            z["parent"] = np.zeros((S, K), np.int32)
         self._kv_bytes_by_kind = paging.leaves_kv_bytes_by_kind(
             self._leaves, S)
         # firacheck: allow[RETIRED-RECHECK] arena-state write: retire() deliberately leaves the arena in place ("the arena and stats stay") and a dead engine's _state is never read again — only scheduling/guard state needs the post-dispatch re-check
@@ -1421,6 +1447,7 @@ class SlotEngine:
         st.kv_bytes_per_slot = sum(by_kind.values())
         st.kv_bytes_per_slot_full = by_kind.get("full", 0)
         st.kv_bytes_per_slot_window = by_kind.get("window", 0)
+        st.kv_bytes_per_slot_state = by_kind.get("state", 0)
         st.kv_dtype = self.cfg.kv_dtype
         st.serve_precision = self.cfg.serve_precision
         used = self._pool_blocks - len(self._free_blocks)

@@ -30,6 +30,14 @@ bound by name in the engine any more:
   k's history — follows the beams in IT after every selection, and hands
   it to ``step`` in the view. False: per-beam leaves are moved as their
   ``reorder`` says, and no table is allocated or carried.
+- ``beam_parent``: True for a model with per-beam RECURRENT STATE
+  (``Leaf.kv_kind="state"``): a beam's state IS its history, so after a
+  selection beam k must continue from its source beam's state. The engine
+  then keeps ``parent`` (S, K) — the last selection's ``src_beam``, all 0
+  on a fresh slot (its beams share the one state prefill left in lane 0)
+  — and hands it to ``step``, which reads lane ``parent[s, k]`` as it
+  computes what it writes to lane k: the state follows the beams inside
+  the one read and one write a position needs anyway.
 - ``select(parts, tokens, probs, finished, pos, state, neg)``: the beam
   selection for this model's distribution -> (tokens, probs, finished,
   src_beam). FIRA's copy head selects from the factors
@@ -45,7 +53,10 @@ against what the slot holds) and one selection
 :class:`LMSlotModel` (``arch="axk1"``, model/axk1.py) is the second;
 :class:`AfmoeSlotModel` (``arch="afmoe"``, model/afmoe.py) the third, and
 the first whose prompt leaves differ BY LAYER TYPE (``Leaf.kv_kind``: a
-whole prompt a full layer, a ring a window layer, a leaf a layer):
+whole prompt a full layer, a ring a window layer, a leaf a layer);
+:class:`JambaSlotModel` (``arch="jamba"``, model/jamba.py) the fourth, and
+the first with leaves that are not keys and values at all: a recurrent
+state a beam lane beside two attention layers' K/V (``beam_parent``).
 config.ARCH_TABLE says which class an ``arch`` gets.
 """
 
@@ -75,7 +86,11 @@ class Leaf:
     kv_kind: str = ""               # of a ``kv`` leaf that holds PROMPTS:
     #                                 "full" (a prompt kept whole) or
     #                                 "window" (a ring of its last
-    #                                 positions); paging adds them up by it
+    #                                 positions); or "state": a recurrent
+    #                                 state of fixed size a beam lane,
+    #                                 rewritten whole at every position
+    #                                 (``beam_parent``); paging adds them
+    #                                 up by it
 
 
 class StepView(NamedTuple):
@@ -91,6 +106,10 @@ class StepView(NamedTuple):
     #                                  position t of beam k's history, this
     #                                  position already each beam's own lane
     #                                  (a model with ``beam_ancestry`` only)
+    parent: Optional[jnp.ndarray] = None    # (S, K) the lane whose state
+    #                                  beam k continues from: the last
+    #                                  selection's source beam, 0 on a fresh
+    #                                  slot (a model with ``beam_parent``)
 
 
 def permute_pool(pool, tab_step, src_beam):
@@ -127,6 +146,7 @@ class FiraSlotModel:
     # moved: the engine keeps which lane holds each position of each
     # beam's history and the step reads through that table
     beam_ancestry = True
+    beam_parent = False     # no recurrent state
 
     def __init__(self, model, cfg: FiraConfig, slots: int,
                  block_size: int, pool_blocks: int):
@@ -234,6 +254,7 @@ class LMSlotModel:
 
     insert_by_geometry = True      # a chunk is as long as its bucket
     beam_ancestry = False          # lat_pool is reordered (permute_pool)
+    beam_parent = False            # no recurrent state
     # one prefill dispatch of prompts (8,192 padded tokens at the published
     # widths) outweighs a step dispatch three times: refilling every free
     # slot first would stall the seated slots for seconds and seat whole
@@ -393,6 +414,108 @@ class AfmoeSlotModel(LMSlotModel):
             view.active, self.dtype)
         return (logp,), {"kv_pool": pool,
                          "counters": state["counters"] + counters}
+
+
+class JambaSlotModel(LMSlotModel):
+    """Jamba2-3B behind the seam (model/jamba.py). Per beam LANE, a leaf a
+    Mamba layer: the recurrent state ``ssm_state<j>`` (S * K, d_state,
+    d_inner) float32 and the convolution's tail ``conv_state<j>`` (taps -
+    1, S * K, d_inner), row ``s * K + k`` lane k of slot s —
+    ``kv_kind="state"``: of fixed size whatever the prompt's length, not
+    paged, rewritten whole at every position. d_inner lies last and the
+    lanes are folded into the rows because the chip tiles an array's last
+    two axes by (8, 128) (bfloat16: 16): a trailing d_state of 16 would be
+    padded eightfold, a second-to-last axis of 3 taps or 3 beams fivefold.
+    A leaf a layer, so that a step REPLACES each whole (the lane a beam
+    reads is its parent's, the lane it writes its own: no update in place
+    could be right, and a stacked leaf would be copied for it).
+
+    ``insert`` writes a request's ONE state into lane 0 of its slot, once:
+    the engine seats a slot with ``parent`` all 0, so its K beams — which
+    share one history until the first selection — all continue from that
+    lane (writing all K lanes would triple the insert's 28 MB a slot for
+    nothing). Per slot, shared by the beams: the two attention layers'
+    prompt keys and values whole (``prompt_k_full<j>`` / ``prompt_v_full<j>``,
+    ``"full"``); per beam, paged and reordered as Trinity-Mini's: the
+    attention layers' generated positions (``kv_pool``, whose layer axis
+    counts ATTENTION layers)."""
+
+    beam_parent = True
+
+    def __init__(self, model, cfg: FiraConfig, slots: int,
+                 block_size: int, pool_blocks: int):
+        from fira_tpu.model import jamba
+
+        super().__init__(model, cfg, slots, block_size, pool_blocks)
+        self.arena_counters = jamba.COUNTERS
+        n_ssm, n_attn = (len(self.lm.mamba_layers),
+                         len(self.lm.attention_layers))
+        self._ssm = [f"ssm_state{j}" for j in range(n_ssm)]
+        self._conv = [f"conv_state{j}" for j in range(n_ssm)]
+        self._prompt = [(f"prompt_k_full{j}", f"prompt_v_full{j}")
+                        for j in range(n_attn)]
+
+    def prefill(self, params, batch):
+        from fira_tpu.model import jamba
+
+        states, tails, kvs, counters = jamba.prefill(
+            params, self.lm, batch["tokens"], batch["lengths"], self.dtype)
+        return {"ssm": states, "conv": tails, "kv_full": kvs,
+                "lengths": batch["lengths"], "counters": counters}
+
+    def leaves(self, chunk) -> Dict[str, Leaf]:
+        lm, S, K = self.lm, self.slots, self.cfg.beam_size
+        dt, c = chunk["conv"][0].dtype, lm.kv_dim
+        out = {n: Leaf((S * K, lm.mamba_d_state, lm.d_inner),
+                       np.dtype(np.float32), kv=True, kv_kind="state")
+               for n in self._ssm}
+        out.update({n: Leaf((lm.mamba_d_conv - 1, S * K, lm.d_inner), dt,
+                            kv=True, kv_kind="state") for n in self._conv})
+        out.update({n: Leaf((S, c // 2, lm.prompt_len_max), dt, kv=True,
+                            kv_kind="full")
+                    for pair in self._prompt for n in pair})
+        out.update({
+            "prompt_len": Leaf((S,), np.dtype(np.int32)),
+            "kv_pool": Leaf((len(self._prompt), self.pool_blocks, K,
+                             self.block_size, c), dt, reorder="pool",
+                            kv=True),
+            "counters": Leaf((len(self.arena_counters),),
+                             np.dtype(np.int32)),
+        })
+        return out
+
+    def insert(self, state, chunk, sid, fresh) -> Dict:
+        lane0 = sid * self.cfg.beam_size    # the sentinel stays out of range
+        new = {
+            "prompt_len": state["prompt_len"].at[sid].set(
+                chunk["lengths"].astype(jnp.int32), mode="drop"),
+            "counters": state["counters"] + chunk["counters"] * fresh,
+        }
+        for name, H in zip(self._ssm, chunk["ssm"]):
+            new[name] = state[name].at[lane0].set(H, mode="drop")
+        for name, tail in zip(self._conv, chunk["conv"]):
+            new[name] = state[name].at[:, lane0].set(tail, mode="drop")
+        for pair, sides in zip(self._prompt, chunk["kv_full"]):
+            for name, x in zip(pair, sides):
+                new[name] = state[name].at[sid, :, :x.shape[-1]].set(
+                    x, mode="drop")
+        return new
+
+    def step(self, params, state, view: StepView):
+        from fira_tpu.model import jamba
+
+        S, K = self.slots, self.cfg.beam_size
+        tok = jnp.take_along_axis(view.flat, view.pos_bk[:, None], axis=1)
+        logp, ssm, conv, pool, counters = jamba.decode_step(
+            params, self.lm, tok.reshape(S, K), view.pos_c,
+            [state[n] for n in self._ssm], [state[n] for n in self._conv],
+            view.parent, [(state[k], state[v]) for k, v in self._prompt],
+            state["prompt_len"], state["kv_pool"], view.tab_step,
+            view.active, self.dtype)
+        writes = {"kv_pool": pool, "counters": state["counters"] + counters}
+        writes.update(zip(self._ssm, ssm))
+        writes.update(zip(self._conv, conv))
+        return (logp,), writes
 
 
 def for_config(model, cfg: FiraConfig, slots: int, block_size: int,

@@ -200,16 +200,16 @@ def _gate_and_project(p, o, g, dtype):
     return mm(o, p["w_o"], dtype)
 
 
-def attention_prefill(p, h, cos, sin, window, lm: AfmoeConfig, dtype):
-    """Causal attention over a batch of prompts, a block of queries at a
-    time. h (B, P, d) normed; ``window`` None on a full layer; ``cos`` /
-    ``sin`` None on a layer that rotates nothing. -> (attention output (B, P, d) float32, what is
-    cached (B, P, kv_dim)). Keys past a prompt's end lie after every real
-    query, so the causal mask alone keeps them out of real rows."""
-    B, P, _ = h.shape
-    H, hd = lm.num_attention_heads, lm.head_dim
-    q, kv, g = _projections(p, h, cos, sin, lm, dtype)
-    k, v = _keys_values(kv, lm)
+def attend_prefill(q, k, v, window, dtype):
+    """Causal attention of a batch of prompts, a block of queries at a
+    time: q (B, P, KV, G, hd), k and v (B, P, KV, hd) -> the heads' output
+    (B, P, KV * G * hd) in ``dtype``. ``window`` None: every j <= i (up to
+    four spans of query blocks, each with its own key extent); else a band
+    of ``window + block`` keys a block, never P x P. Keys past a prompt's
+    end lie after every real query, so the causal mask alone keeps them
+    out of real rows. Shared with model/jamba.py (one key/value head, no
+    window)."""
+    B, P, KV, G, hd = q.shape
     Qb = min(ATTN_Q_BLOCK, P)
     if P % Qb:
         raise ValueError(f"a prompt bucket of {P} tokens is not a whole "
@@ -242,25 +242,34 @@ def attention_prefill(p, h, cos, sin, window, lm: AfmoeConfig, dtype):
             return (o / jnp.transpose(total, (0, 3, 1, 2))[..., None]
                     ).astype(dtype)
         o = jax.lax.map(block, first + jnp.arange(blocks))
-        return jnp.moveaxis(o, 0, 1).reshape(B, blocks * Qb, H * hd)
+        return jnp.moveaxis(o, 0, 1).reshape(B, blocks * Qb, KV * G * hd)
 
     n_blocks = P // Qb
     if window is not None:
-        with jax.named_scope("attn.window.prefill"):
-            # a band: a block's last query sees back to its first key, its
-            # first query as far as window - 1 before itself
-            keys = min(P, window + Qb)
-            o = span(0, n_blocks, keys, lambda i: jnp.clip(
-                (i + 1) * Qb - keys, 0, P - keys))
-            return _gate_and_project(p, o, g, dtype), kv
-    with jax.named_scope("attn.full.prefill"):
-        # up to four spans of query blocks, each with its own key extent:
-        # the last scores every key, the first a quarter of them
-        per = -(-n_blocks // min(4, n_blocks))
-        o = jnp.concatenate(
-            [span(at, min(per, n_blocks - at),
-                  (at + min(per, n_blocks - at)) * Qb, lambda i: 0)
-             for at in range(0, n_blocks, per)], axis=1)
+        # a band: a block's last query sees back to its first key, its
+        # first query as far as window - 1 before itself
+        keys = min(P, window + Qb)
+        return span(0, n_blocks, keys, lambda i: jnp.clip(
+            (i + 1) * Qb - keys, 0, P - keys))
+    # up to four spans of query blocks, each with its own key extent:
+    # the last scores every key, the first a quarter of them
+    per = -(-n_blocks // min(4, n_blocks))
+    return jnp.concatenate(
+        [span(at, min(per, n_blocks - at),
+              (at + min(per, n_blocks - at)) * Qb, lambda i: 0)
+         for at in range(0, n_blocks, per)], axis=1)
+
+
+def attention_prefill(p, h, cos, sin, window, lm: AfmoeConfig, dtype):
+    """Causal attention over a batch of prompts (:func:`attend_prefill`).
+    h (B, P, d) normed; ``window`` None on a full layer; ``cos`` / ``sin``
+    None on a layer that rotates nothing. -> (attention output (B, P, d)
+    float32, what is cached (B, P, kv_dim))."""
+    q, kv, g = _projections(p, h, cos, sin, lm, dtype)
+    k, v = _keys_values(kv, lm)
+    with jax.named_scope("attn.full.prefill" if window is None
+                         else "attn.window.prefill"):
+        o = attend_prefill(q, k, v, window, dtype)
         return _gate_and_project(p, o, g, dtype), kv
 
 
@@ -292,17 +301,19 @@ def prompt_layout(kv):
     return kv[0], kv[1]
 
 
-def attention_decode(p, q, g, prompt_kv, prompt_seen, gen_kv, gen_seen,
-                     lm: AfmoeConfig, dtype):
+def attend_decode(q, prompt_kv, prompt_seen, gen_kv, gen_seen, dtype):
     """One position of every beam of every slot. q (S, K, KV, G, hd);
-    prompt_kv: keys and values (S, kv_dim / 2, P) each
-    (:func:`prompt_layout`), shared by a slot's beams, entry j seen where
-    prompt_seen (S, P); gen_kv (S, K, T, kv_dim) with this position's in;
-    gen_seen (S, T). -> attention output (S, K, d) float32."""
-    S, K, KV = q.shape[:3]
-    k_p, v_p = (x.reshape(S, KV, lm.head_dim, -1) for x in prompt_kv)
-    k_g, v_g = _keys_values(gen_kv, lm)
-    scale = lm.head_dim ** -0.5
+    prompt_kv: keys and values (S, KV * hd, P) each (:func:`prompt_layout`),
+    shared by a slot's beams, entry j seen where prompt_seen (S, P);
+    gen_kv (S, K, T, 2 * KV * hd) with this position's in; gen_seen (S, T).
+    -> the heads' output (S, K, KV * G * hd) float32. Shared with
+    model/jamba.py."""
+    S, K, KV, _G, hd = q.shape
+    k_p, v_p = (x.reshape(S, KV, hd, -1) for x in prompt_kv)
+    lead = gen_kv.shape[:-1]
+    k_g = gen_kv[..., :KV * hd].reshape(lead + (KV, hd))
+    v_g = gen_kv[..., KV * hd:].reshape(lead + (KV, hd))
+    scale = hd ** -0.5
     s_p = jnp.einsum("skngd,sndp->skngp", q, k_p,
                      preferred_element_type=jnp.float32)
     s_g = jnp.einsum("skngd,sktnd->skngt", q, k_g,
@@ -319,7 +330,14 @@ def attention_decode(p, q, g, prompt_kv, prompt_seen, gen_kv, gen_seen,
                    preferred_element_type=jnp.float32)
     o = o + jnp.einsum("skngt,sktnd->skngd", e_g.astype(dtype), v_g,
                        preferred_element_type=jnp.float32)
-    o = (o / denom[..., None]).reshape(S, K, -1)
+    return (o / denom[..., None]).reshape(S, K, -1)
+
+
+def attention_decode(p, q, g, prompt_kv, prompt_seen, gen_kv, gen_seen,
+                     lm: AfmoeConfig, dtype):
+    """:func:`attend_decode`, then the output gate and ``W_o`` -> (S, K,
+    d) float32."""
+    o = attend_decode(q, prompt_kv, prompt_seen, gen_kv, gen_seen, dtype)
     return _gate_and_project(p, o, g, dtype)
 
 
