@@ -548,8 +548,7 @@ def worker() -> None:
                                     batch_size=batch_size, extents=ext)
         items = []
         for task in grouping.grouped_assembly_tasks(
-                split, plan, cfg_comp, batch_size=batch_size,
-                bucketed=True):
+                split, plan, cfg_comp, batch_size=batch_size):
             host = task()
             wire = {kk: vv for kk, vv in host.items()
                     if not kk.startswith("_")}

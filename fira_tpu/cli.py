@@ -462,8 +462,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "step window here (TensorBoard-loadable)")
     p.add_argument("--buckets", default=None, metavar="SPEC",
                    help="padding-bucket family (docs/BUCKETING.md): 'off' "
-                        "(default — single geometry, byte-identical "
-                        "batches), 'auto' (choose 3 buckets from the "
+                        "(default — no declared table: decode runs the "
+                        "single full geometry, a train dispatch pads its "
+                        "edge rows to the least rung of max_edges / 2^k "
+                        "that holds its commits), 'auto' (choose 3 buckets "
+                        "from the "
                         "split's length histograms), or an explicit table "
                         "'AST:EDGES:TAR[,AST:EDGES:TAR...]' of geometries "
                         "<= the config's full values. Each sample packs "

@@ -308,12 +308,15 @@ class FiraConfig:
     # NOT a dense graph_len^2 array (the reference densifies per sample,
     # Dataset.py:336-343 — its biggest throughput sin). Densification to a
     # batch of graph_len^2 happens once per step inside the jitted program.
-    # Padded COO length per sample. The full-scale 90,661-commit corpus
-    # measures p100 < 6,000 edges (fullscale/FULLSCALE.json era builds), so
-    # 6144 keeps headroom while cutting the per-step adjacency scatter
-    # stream 25% vs the old 8192 (the scatter is the single biggest op in
-    # the earlier machine's step attribution, docs/PERF.md: ~22 ms of 86).
-    # make_batch raises loudly if a sample ever exceeds it.
+    # The ADMISSION BOUND on a sample's edges — not the wire's width. The
+    # full-scale 90,661-commit corpus measures p100 < 6,000 edges
+    # (fullscale/FULLSCALE.json era builds), so 6144 keeps headroom;
+    # make_batch raises loudly if a sample ever exceeds it. What a batch's
+    # COO rows are PADDED to is a bucket geometry's max_edges: a train
+    # dispatch takes the least rung of the halving ladder max_edges / 2^k
+    # that holds its commits (data/buckets.edge_ladder — the adjacency
+    # scatter prices every slot alike, pad or real), a decode batch the
+    # bound itself unless cfg.buckets declares otherwise.
     max_edges: int = 6144
     # "dense": scatter COO into a (B, graph_len^2) adjacency once per step and
     #   run the GCN as a bmm (MXU-friendly at the reference's 650 nodes);
@@ -799,7 +802,9 @@ class FiraConfig:
     # same-bucket samples into batches, so XLA compiles |buckets|+1
     # programs per entry point — all pre-warmed at startup, zero
     # post-warmup retraces (the sanitizer learns the declared family).
-    # () = off: the single-geometry path, byte-identical batches.
+    # () = no declared table: decode/dev run the single full geometry
+    # (byte-identical batches), training the edge ladder (the COO pad
+    # alone follows the split, AST and target axes full; max_edges above).
     # sou_len/sub_token_len are NOT bucketable (the copy-label id space
     # and fused output width bake them in). Composes with the grouped
     # device programs: fused_steps/accum_steps > 1 makes the scheduler

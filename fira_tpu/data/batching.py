@@ -1,13 +1,15 @@
 """Fixed-shape batch assembly for jitted TPU programs.
 
 Every batch has a shape drawn from a SMALL FIXED FAMILY (XLA compiles once
-per family member): by default the single full config geometry; under
-``cfg.buckets`` (data/buckets.py, docs/BUCKETING.md) one of a declared set
-of smaller padding geometries via ``make_batch(..., geom=...)``. The final
-partial batch of an epoch is padded with zeroed samples whose labels are
-all <pad>, so they contribute nothing to the masked loss; a ``valid`` bool
-array marks real rows for eval bookkeeping. COO edges are padded per-sample
-to cfg.max_edges (pad entries scatter zero — a no-op on device).
+per family member): ``make_batch`` alone pads to the full config geometry;
+``make_batch(..., geom=...)`` to one of a set of smaller padding geometries
+(data/buckets.py, docs/BUCKETING.md) — the declared ``cfg.buckets``, or for
+a train dispatch by default a rung of the edge ladder. The final partial
+batch of an epoch is padded with zeroed samples whose labels are all <pad>,
+so they contribute nothing to the masked loss; a ``valid`` bool array marks
+real rows for eval bookkeeping. COO edges are padded per-sample to the
+geometry's max_edges (pad entries scatter zero: nothing to the result, a
+slot's full price to the device); ``cfg.max_edges`` is the admission bound.
 
 The reference instead ships a dense 650^2 float adjacency per sample through
 a torch DataLoader (Dataset.py:336-343) — the batching fix called out in

@@ -47,9 +47,25 @@ sample to its bucket's open chunk, and emits a chunk the moment it fills
 (tails flush in table order). With ``shuffle=False`` (dev/decode) packing
 is a stable partition by bucket — sort-by-length packing that preserves
 in-bucket corpus order; drivers restore output order from the
-``_positions`` host-only field each batch carries. ``cfg.buckets = ()``
-bypasses this module entirely: the single-geometry path is byte-identical
-to before.
+``_positions`` host-only field each batch carries.
+
+What ``cfg.buckets = ()`` means (the default of every configuration)
+--------------------------------------------------------------------
+NOT "pad every row to ``max_edges``". ``max_edges`` is the admission bound
+of ``make_batch`` (no commit of the 90,661-commit corpus has more edges),
+not the width of the wire. The two tables part at :func:`train_table` /
+:func:`decode_table`:
+
+- TRAINING takes the **edge ladder** (:func:`edge_ladder`): the COO pad of
+  a dispatch is ``max_edges / 2^k``, the least rung that holds its widest
+  commit; the AST tail and the target length stay FULL (they change the
+  products the step computes — those remain the user's table to cut).
+  The rung is read off the input: no field, flag or variable selects it,
+  and the train loop compiles only the rungs its split populates.
+- DECODE (and the dev gate) stays the full geometry alone: the engine
+  prewarms one prefill and one insert program a geometry at set-up, so a
+  rung there costs every start a compile; it waits on a table of prefill
+  batch sizes an edge rung can ride on (ROADMAP S11).
 
 Sanitizer / firacheck interplay: see docs/BUCKETING.md. Each bucket's
 programs get their own compile-guard label (``train_step[a16.e256.t8]``),
@@ -137,9 +153,10 @@ def _validated(cfg: FiraConfig, geom: BucketGeom) -> BucketGeom:
 
 
 def bucket_table(cfg: FiraConfig) -> Tuple[BucketGeom, ...]:
-    """The effective bucket family: cfg.buckets validated, sorted by FLOP
+    """The DECLARED bucket family: cfg.buckets validated, sorted by FLOP
     cost ascending, with the full geometry appended as the always-admissible
-    fallback. ``cfg.buckets = ()`` yields just the full geometry."""
+    fallback. ``cfg.buckets = ()`` yields just the full geometry — what
+    :func:`decode_table` builds on; training asks :func:`train_table`."""
     full = full_geom(cfg)
     geoms = []
     for entry in cfg.buckets:
@@ -148,6 +165,34 @@ def bucket_table(cfg: FiraConfig) -> Tuple[BucketGeom, ...]:
             geoms.append(g)
     geoms.sort(key=lambda g: geom_cost(cfg, g))
     return tuple(geoms) + (full,)
+
+
+def edge_ladder(cfg: FiraConfig) -> Tuple[BucketGeom, ...]:
+    """The halving ladder of the COO pad: ``max_edges // 2^k`` for k = 0,
+    1, 2, ... while the rung holds the geometry's self-loops
+    (:func:`_validated`'s floor), ascending, AST tail and target length
+    FULL on every rung — fira-full: 768, 1536, 3072, 6144 edge slots a
+    row. A pure function of the configuration; the last rung is the full
+    geometry, so the ladder is a bucket table like any declared one."""
+    full = full_geom(cfg)
+    rungs = [full]
+    while rungs[0].max_edges // 2 >= cfg.graph_len:   # a self-loop a node
+        rungs.insert(0, full._replace(max_edges=rungs[0].max_edges // 2))
+    return tuple(rungs)
+
+
+def train_table(cfg: FiraConfig) -> Tuple[BucketGeom, ...]:
+    """The TRAIN plan's table (data/grouping.grouped_plan, train/loop.py):
+    the user's declared table where there is one, else the edge ladder.
+
+    HERE the train and decode tables part, for one stated reason: a train
+    program a rung is compiled once and only for the rungs the split
+    populates, while the decode engine pays a prefill and an insert
+    program a geometry at every start (``setup_s``) — so
+    :func:`decode_table` keeps ``cfg.buckets = ()`` at the full geometry
+    alone until its prefill has a table of batch sizes for an edge rung
+    to ride on (ROADMAP S11)."""
+    return bucket_table(cfg) if cfg.buckets else edge_ladder(cfg)
 
 
 # --------------------------------------------------------------------------
@@ -187,6 +232,17 @@ def _last_nonzero_extent(a: np.ndarray) -> np.ndarray:
 
 
 def sample_extents(split: ProcessedSplit, cfg: FiraConfig) -> SampleExtents:
+    """The split's extents, measured on the first call and kept with the
+    split (``ProcessedSplit.derived``; its arrays are written once, when
+    it is built): the train plan asks once an epoch, on the thread that
+    feeds the dispatch loop, and must not walk every edge each time."""
+    key = ("extents", cfg.sou_len, cfg.sub_token_len, cfg.ast_change_len)
+    if key not in split.derived:
+        split.derived[key] = _measure_extents(split, cfg)
+    return split.derived[key]
+
+
+def _measure_extents(split: ProcessedSplit, cfg: FiraConfig) -> SampleExtents:
     from fira_tpu.data.graph_build import EDGE_KIND_SELF_LOOP
 
     arr = split.arrays
@@ -358,7 +414,9 @@ def bucketed_assembly_tasks(split: ProcessedSplit, plan: Plan,
 
 def decode_table(cfg: FiraConfig) -> Tuple[BucketGeom, ...]:
     """The decode-side bucket family, deduplicated, cost-sorted, full
-    fallback last.
+    fallback last. Built on the DECLARED table: ``cfg.buckets = ()`` is
+    the full geometry alone here, never the edge ladder — see
+    :func:`train_table` for why the two part.
 
     Default (``cfg.decode_tar_buckets = False``): tar_len pinned to the
     FULL value on every bucket — beam output length is model-decided and

@@ -137,6 +137,9 @@ class ProcessedSplit:
     def __init__(self, arrays: Dict[str, np.ndarray]):
         self.arrays = arrays
         self.n = arrays["diff"].shape[0]
+        # what callers measured of the arrays and keep with them
+        # (data/buckets.sample_extents); the arrays are not written again
+        self.derived: Dict = {}
 
     def __len__(self) -> int:
         return self.n

@@ -15,11 +15,11 @@ device loops; PAPERS.md).
 
 One plan shape subsumes every train epoch:
 
-- ``group_size == 1``: per-step dispatch. With a bucket table this is
-  EXACTLY ``buckets.packed_plan(shuffle=True)`` (same greedy walk, same
-  tail flush); with ``cfg.buckets = ()`` it degenerates to the sequential
-  ``epoch_index_chunks`` slicing — both byte-identical to the pre-grouping
-  paths (pinned by tests/test_grouping.py).
+- ``group_size == 1``: per-step dispatch — EXACTLY
+  ``buckets.packed_plan(shuffle=True)`` over the same table (same greedy
+  walk, same tail flush); over a one-geometry table it degenerates to the
+  sequential ``epoch_index_chunks`` slicing (pinned by
+  tests/test_grouping.py).
 - ``group_size > 1``, fused: each bucket's chunks collect until K are
   ready, then emit as ONE :class:`GroupEntry` the moment the K-th fills
   (deterministic in the walk); leftovers smaller than K fall back to
@@ -28,6 +28,19 @@ One plan shape subsumes every train epoch:
   (zero rows contribute nothing to the global (sum, count) — the same
   machinery as the pre-bucket accum tail), so accumulation is always ONE
   A-stacked dispatch and the per-step program is never needed.
+
+The table (``buckets.train_table``)
+-----------------------------------
+The user's declared ``cfg.buckets`` where there is one. With
+``cfg.buckets = ()`` — every configuration's default — the plan takes the
+EDGE LADDER (``buckets.edge_ladder``): a commit goes to the least rung of
+``max_edges / 2^k`` that holds its edges, K same-rung batches make a
+dispatch, ``make_batch(geom=)`` shortens the COO rows, and the AST tail and
+the target length stay full. So a dispatch carries the edge slots its
+commits fill, not the admission bound: on a corpus of small commits the
+adjacency scatter stops adding millions of zeros a step, and sample ORDER
+differs from plain chunking only where the split populates more than one
+rung.
 
 Determinism contract (extends the buckets/feeder contracts)
 -----------------------------------------------------------
@@ -59,8 +72,8 @@ from typing import Dict, Iterator, List, NamedTuple, Optional, Sequence
 import numpy as np
 
 from fira_tpu.config import FiraConfig
-from fira_tpu.data.buckets import (BucketGeom, assign_buckets, bucket_table,
-                                   geom_cost, geom_tag, sample_extents)
+from fira_tpu.data.buckets import (BucketGeom, assign_buckets, geom_cost,
+                                   geom_tag, sample_extents, train_table)
 from fira_tpu.data.dataset import ProcessedSplit
 
 
@@ -103,13 +116,18 @@ def grouped_plan(split: ProcessedSplit, cfg: FiraConfig, *,
     chunks, plus each bucket's partial chunk) emit per-step; with
     ``accum=True`` they emit as one short group the assembly pads to
     ``group_size`` with all-invalid micro-batches.
+
+    ``table``: default ``buckets.train_table(cfg)`` — the declared table,
+    or the edge ladder under ``cfg.buckets = ()``. The split's extents are
+    memoised (``buckets.sample_extents``), so a caller that plans every
+    epoch without passing ``extents`` / ``assignment`` measures them once.
     """
     from fira_tpu.data.batching import epoch_order
 
     if group_size < 1:
         raise ValueError(f"group_size must be >= 1, got {group_size}")
     bs = batch_size or cfg.batch_size
-    table = tuple(table) if table is not None else bucket_table(cfg)
+    table = tuple(table) if table is not None else train_table(cfg)
     if assignment is None:
         if len(table) == 1:  # single geometry: everything is the fallback
             assignment = np.zeros(len(split), dtype=np.int64)
@@ -163,43 +181,45 @@ def stack_group(batches: Sequence[Dict[str, np.ndarray]], *,
     return {k: np.stack([b[k] for b in group]) for k in group[0]}
 
 
+def plan_programs(plan: Plan) -> List[tuple]:
+    """The distinct ``(geometry, pad_to)`` of a plan's dispatches, sorted:
+    the programs an epoch runs. The same for every epoch of a split —
+    chunk formation counts a bucket's members, whatever their order — so
+    the train loop warms and declares this set and no more."""
+    return sorted({(e.geom, e.pad_to) for e in plan})
+
+
 def grouped_assembly_tasks(split: ProcessedSplit, plan: Plan,
                            cfg: FiraConfig, *,
-                           batch_size: Optional[int] = None,
-                           bucketed: bool = False) -> Iterator:
+                           batch_size: Optional[int] = None) -> Iterator:
     """One zero-arg assembly task per plan entry for the async Feeder
     (data/feeder.py): a per-step entry builds one ``make_batch`` batch; a
     stacked entry builds its member batches AND stacks them, so the worker
     ``device_put``s the whole K-group as ONE transfer.
 
-    ``bucketed=False`` (``cfg.buckets = ()``): batches build at the full
-    geometry with no host-only fields — byte-identical to the pre-grouping
-    stream. ``bucketed=True``: each batch builds at its entry's geometry
-    and carries the host-only ``_tag`` (geometry tag, for per-bucket guard
-    labels; per-step entries also carry ``_positions`` like
+    Each batch builds at ITS ENTRY'S geometry (at the full geometry that
+    is ``make_batch`` without ``geom``, byte for byte) and carries the
+    host-only ``_tag`` (geometry tag, for per-bucket guard labels;
+    per-step entries also carry ``_positions`` like
     ``buckets.bucketed_assembly_tasks``)."""
     from fira_tpu.data.batching import make_batch
 
     bs = batch_size or cfg.batch_size
 
     def task(entry: GroupEntry):
-        geom = entry.geom if bucketed else None
-
         def build():
-            group = [make_batch(split, c, cfg, batch_size=bs, geom=geom)
+            group = [make_batch(split, c, cfg, batch_size=bs,
+                                geom=entry.geom)
                      for c in entry.chunks]
             if entry.pad_to == 1:
                 batch = group[0]
-                if bucketed:
-                    chunk = entry.chunks[0]
-                    positions = np.full(bs, -1, dtype=np.int64)
-                    positions[: len(chunk)] = chunk
-                    batch["_positions"] = positions
-                    batch["_tag"] = geom_tag(entry.geom)
-                return batch
-            batch = stack_group(group, pad_to=entry.pad_to)
-            if bucketed:
-                batch["_tag"] = geom_tag(entry.geom)
+                chunk = entry.chunks[0]
+                positions = np.full(bs, -1, dtype=np.int64)
+                positions[: len(chunk)] = chunk
+                batch["_positions"] = positions
+            else:
+                batch = stack_group(group, pad_to=entry.pad_to)
+            batch["_tag"] = geom_tag(entry.geom)
             return batch
         return build
 

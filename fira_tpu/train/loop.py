@@ -3,8 +3,9 @@
 Rebuilds the reference's train/dev orchestration
 (/root/reference/run_model.py:83-184) TPU-first: a SMALL FIXED FAMILY of
 compiled programs runs for the whole session — per-step/grouped train
-steps x bucket geometries x dev (data/grouping.py, data/buckets.py), all
-pre-warmed at startup when bucketed; batches stream through fixed shapes;
+steps x bucket geometries x dev (data/grouping.py, data/buckets.py; by
+default the populated rungs of the edge ladder), the train programs
+pre-warmed at startup; batches stream through fixed shapes;
 throughput is reported as commits/sec/chip (the repo's metric of record,
 BASELINE.md).
 
@@ -225,9 +226,6 @@ def train(dataset: FiraDataset, cfg: Optional[FiraConfig] = None, *,
                         cfg, batch_size=cfg.batch_size)
     with profiling.span("train.init_state"):
         state = init_state(model, cfg, sample)
-    if mesh is not None:
-        state = state.replace(
-            params=pmesh.shard_params(state.params, mesh))
     train_step = step_lib.jit_train_step(model, cfg, mesh, state, sample)
     dev_step = jax.jit(step_lib.make_dev_step(model))
 
@@ -237,6 +235,13 @@ def train(dataset: FiraDataset, cfg: Optional[FiraConfig] = None, *,
         state, meta = ckpt.restore_latest(state, expect_rng_impl=cfg.rng_impl)
         best_bleu, start_epoch = meta["best_bleu"], meta["epoch"]
         log.console(f"resumed at epoch {start_epoch}, best dev bleu {best_bleu:.4f}")
+    if mesh is not None:
+        # the WHOLE state on the mesh — fresh or restored — placed as every
+        # dispatch hands it back and as the warm-up's throwaway copy is:
+        # the first real dispatch then runs the program the warm-up
+        # compiled (a state with only its params sharded is another
+        # signature, and compiled the step a second time)
+        state = jax.device_put(state, step_lib.state_shardings(state, mesh))
 
     n_epochs = epochs if epochs is not None else cfg.epochs
     n_chips = 1 if mesh is None else mesh.devices.size
@@ -327,22 +332,40 @@ def train(dataset: FiraDataset, cfg: Optional[FiraConfig] = None, *,
                  else step_lib.jit_accum_step)
         grouped_step = maker(model, cfg, mesh, state, stacked_sample)
 
-    # --- bucketed geometry family (data/buckets.py; docs/BUCKETING.md) ---
-    # Table + per-sample assignment computed ONCE for the train split; the
-    # whole (geometry x entrypoint x group-size) program family is
-    # pre-warmed here — each member compiles against a throwaway state copy
+    # --- the program family (data/buckets.py; docs/BUCKETING.md) ---
+    # Table + per-sample assignment computed ONCE for the train split. The
+    # table is the user's declared cfg.buckets, or — the default — the edge
+    # ladder: the COO pad of a dispatch is the least rung of max_edges / 2^k
+    # that holds its commits (buckets.train_table). The family's programs
+    # are pre-warmed here — each compiles against a throwaway state copy
     # and an all-pad batch (zero training effect), so the epoch loop never
     # compiles again. The guard then learns the closed family: every label
     # gets its one warmup dispatch, and any label outside the declared set
-    # raises. Under fused the per-step program is warmed too (epoch tails
-    # dispatch it); under accum it never runs (tails pad to the stacked
-    # shape), so only the grouped member is warmed per geometry.
-    bucket_table = bucket_assignment = dev_plan = None
+    # raises.
+    train_table = buckets_lib.train_table(cfg)
+    bucket_assignment = buckets_lib.assign_buckets(
+        buckets_lib.sample_extents(train_split, cfg), train_table)
+
+    def epoch_plan(epoch: int):
+        """ONE scheduler for every mode (data/grouping.py): per-step mode
+        is the greedy packer's walk, grouped mode packs bucket-homogeneous
+        K-stacks over the SAME permutation (fused tails per-step, accum
+        tails padded with all-invalid micro-batches)."""
+        return grouping.grouped_plan(
+            train_split, cfg, batch_size=cfg.batch_size,
+            group_size=group_size, accum=accum > 1, shuffle=True,
+            seed=cfg.seed, epoch=epoch, table=train_table,
+            assignment=bucket_assignment)
+
+    dev_geoms, dev_plan, dev_labels = (), None, ["dev_step"]
     if cfg.buckets:
-        bucket_table = buckets_lib.bucket_table(cfg)
-        bucket_assignment = buckets_lib.assign_buckets(
-            buckets_lib.sample_extents(train_split, cfg), bucket_table)
-        warm_per_step = group_size == 1 or fused > 1
+        # a declared table: every member x every entry point an epoch can
+        # dispatch. Under fused the per-step program is warmed too (epoch
+        # tails dispatch it); under accum it never runs (tails pad to the
+        # stacked shape), so only the grouped member is warmed per geometry.
+        sizes = ([1] if group_size == 1 or fused > 1 else []) \
+            + ([group_size] if group_size > 1 else [])
+        programs = [(g, k) for g in train_table for k in sizes]
         # dev packs with the decode table (tar pinned full — the gating
         # metric scores every tar position, see _eval_tasks, so the
         # engine-only cfg.decode_tar_buckets knob is forced off here);
@@ -354,37 +377,42 @@ def train(dataset: FiraDataset, cfg: Optional[FiraConfig] = None, *,
         dev_plan = buckets_lib.packed_plan(
             dataset.splits["valid"], cfg, batch_size=cfg.test_batch_size,
             table=dev_geoms, use_msg=False)
-        labels = [sanitizer_label("dev_step", buckets_lib.geom_tag(g))
-                  for g in dev_geoms]
-        for g in bucket_table:
-            tag = buckets_lib.geom_tag(g)
-            if warm_per_step:
-                labels.append(sanitizer_label("train_step", tag))
-            if group_size > 1:
-                labels.append(sanitizer_label("grouped_step", tag,
-                                              group_size))
-        if guard is not None:
-            guard.declare(labels)
+        dev_labels = [sanitizer_label("dev_step", buckets_lib.geom_tag(g))
+                      for g in dev_geoms]
+    else:
+        # the edge ladder: ONLY the rungs this split populates, with the
+        # entry points its plan dispatches (the same set every epoch) — a
+        # corpus of small commits compiles one rung, not four. The dev
+        # gate keeps its one full-geometry program (decode_table), warmed
+        # by its first dispatch as before.
+        programs = grouping.plan_programs(epoch_plan(start_epoch))
+
+    def program_label(geom, k: int) -> str:
+        return sanitizer_label("grouped_step" if k > 1 else "train_step",
+                               buckets_lib.geom_tag(geom), k)
+
+    if guard is not None:
+        guard.declare(dev_labels + [program_label(g, k) for g, k in programs])
+    if programs and start_epoch < n_epochs:
         # donation-safe throwaway copy: the real state (and its PRNG) is
         # untouched by warmup; host round-trip avoids compiling a copy op
         host_state = jax.device_get(state)
         warm_state = (jax.device_put(host_state,
                                      step_lib.state_shardings(state, mesh))
                       if mesh is not None else jax.device_put(host_state))
-        for g in bucket_table:
-            tag = buckets_lib.geom_tag(g)
+        for g, k in programs:
             wb = buckets_lib.warmup_batch(train_split, cfg, g,
                                           cfg.batch_size)
-            if warm_per_step:
-                warm_state, wm = train_step(warm_state, wb)
-                if guard is not None:
-                    guard.step(sanitizer_label("train_step", tag))
-            if group_size > 1:
-                swb = grouping.stack_group([wb] * group_size)
-                warm_state, wm = grouped_step(warm_state, swb)
-                if guard is not None:
-                    guard.step(sanitizer_label("grouped_step", tag,
-                                               group_size))
+            if k > 1:
+                wb = grouping.stack_group([wb] * k)
+            if batch_sharding is not None:
+                # placed as the feeder's workers ship the real ones: a
+                # host batch is another signature of the same program
+                wb = jax.device_put(wb, batch_sharding(wb))
+            warm_state, wm = (grouped_step if k > 1 else train_step)(
+                warm_state, wb)
+            if guard is not None:
+                guard.step(program_label(g, k))
         for g in dev_geoms:
             wb = buckets_lib.warmup_batch(train_split, cfg, g,
                                           cfg.test_batch_size)
@@ -396,34 +424,26 @@ def train(dataset: FiraDataset, cfg: Optional[FiraConfig] = None, *,
         del warm_state, host_state
         log.console(
             f"buckets: pre-warmed "
-            f"{len(bucket_table) * (1 if warm_per_step else 0)} train + "
-            f"{len(bucket_table) * (1 if group_size > 1 else 0)} grouped"
+            f"{sum(k == 1 for _, k in programs)} train + "
+            f"{sum(k > 1 for _, k in programs)} grouped"
             f"{f'(g{group_size})' if group_size > 1 else ''} + "
             f"{len(dev_geoms)} dev programs "
-            f"({', '.join(buckets_lib.geom_tag(g) for g in bucket_table)})")
+            f"({', '.join(sorted({buckets_lib.geom_tag(g) for g, _ in programs}))}"
+            f"{'' if cfg.buckets else ': the populated rungs of the edge ladder'})")
         meter.start()  # warmup/compile time is not train time
 
     def epoch_tasks(epoch: int):
         """Zero-arg assembly tasks in the exact deterministic (seed, epoch)
-        batch order — ONE scheduler for every mode (data/grouping.py):
-        per-step mode reproduces the legacy chunking/packing byte-for-byte,
-        grouped mode packs bucket-homogeneous K-stacks over the SAME
-        permutation (fused tails per-step, accum tails padded with
-        all-invalid micro-batches). Each task builds ONE dispatch item, so
-        independent items assemble in parallel on the feeder's workers."""
-        plan = grouping.grouped_plan(
-            train_split, cfg, batch_size=cfg.batch_size,
-            group_size=group_size, accum=accum > 1, shuffle=True,
-            seed=cfg.seed, epoch=epoch, table=bucket_table,
-            assignment=bucket_assignment)
+        batch order. Each task builds ONE dispatch item, so independent
+        items assemble in parallel on the feeder's workers."""
         return grouping.grouped_assembly_tasks(
-            train_split, plan, cfg, batch_size=cfg.batch_size,
-            bucketed=bucket_table is not None)
+            train_split, epoch_plan(epoch), cfg, batch_size=cfg.batch_size)
 
     # Aggregated feeder stats across epochs (each epoch gets a fresh
     # pipeline; sums/mins fold here for TrainResult)
     feed_totals = {"batches": 0.0, "feed_stall_s": 0.0,
-                   "queue_depth_sum": 0.0, "queue_depth_min": float("inf")}
+                   "queue_depth_sum": 0.0, "queue_depth_min": float("inf"),
+                   "edge_slots": 0.0, "edges": 0.0}
 
     for epoch in range(start_epoch, n_epochs):
         last_metrics = None
@@ -533,9 +553,9 @@ def train(dataset: FiraDataset, cfg: Optional[FiraConfig] = None, *,
             # clean pipeline shutdown on ANY exit (error, interrupt, normal
             # exhaustion): no worker threads survive the epoch
             s = epoch_feed.stats()
-            feed_totals["batches"] += s["batches"]
-            feed_totals["feed_stall_s"] += s["feed_stall_s"]
-            feed_totals["queue_depth_sum"] += s["queue_depth_sum"]
+            for key in ("batches", "feed_stall_s", "queue_depth_sum",
+                        "edge_slots", "edges"):
+                feed_totals[key] += s[key]
             feed_totals["queue_depth_min"] = min(
                 feed_totals["queue_depth_min"], s["queue_depth_min"])
             epoch_feed.close()
@@ -568,6 +588,11 @@ def train(dataset: FiraDataset, cfg: Optional[FiraConfig] = None, *,
                             if n_fed else 0.0),
         "num_workers": float(cfg.feeder_workers),
         "depth": float(cfg.feeder_depth),
+        # COO slots the dispatches shipped and the real edges among them:
+        # edges / edge_slots is the wire's fill share (the rest is pad the
+        # adjacency scatter adds as zeros)
+        "edge_slots": feed_totals["edge_slots"],
+        "edges": feed_totals["edges"],
     }
     if n_fed:
         log.console(
@@ -576,7 +601,9 @@ def train(dataset: FiraDataset, cfg: Optional[FiraConfig] = None, *,
             f"({msum['feed_stall_ms_per_step']:.1f} ms/step) | feeder "
             f"queue depth mean {feeder_stats['queue_depth_mean']:.1f} "
             f"min {feeder_stats['queue_depth_min']:.0f} "
-            f"(workers {cfg.feeder_workers}, depth {cfg.feeder_depth})")
+            f"(workers {cfg.feeder_workers}, depth {cfg.feeder_depth}) | "
+            f"edge fill "
+            f"{feed_totals['edges'] / max(feed_totals['edge_slots'], 1):.3f}")
     # epochs ACTUALLY executed this call (a resumed run skips start_epoch of
     # them; a checkpoint already past the target runs zero) — callers
     # validating resume legs depend on the distinction

@@ -154,6 +154,12 @@ class _Span(contextlib.ContextDecorator):
     def duration_s(self) -> float:
         return self.t_end - self.t_start
 
+    def note(self, **ids) -> None:
+        """Ids learned while the span is open (what it built, not what it
+        was asked for): on the ring's event, not on the trace annotation,
+        which took its ids when it opened."""
+        self.ids = {**self.ids, **ids}
+
 
 class _Root:
     """A span opened by :meth:`Recorder.begin`: recorded at :meth:`end`,
@@ -195,6 +201,9 @@ class _Stopwatch:
     @property
     def duration_s(self) -> float:
         return self.t_end - self.t_start
+
+    def note(self, **_ids) -> None:
+        pass
 
 
 def stopwatch(_name: str = "", **_ids) -> _Stopwatch:

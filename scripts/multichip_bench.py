@@ -118,8 +118,7 @@ def measure(n_devices: int) -> dict:
     def train_pass():
         nonlocal state
         feed = Feeder(grouping.grouped_assembly_tasks(
-                          split, plan, cfg, batch_size=cfg.batch_size,
-                          bucketed=True),
+                          split, plan, cfg, batch_size=cfg.batch_size),
                       num_workers=cfg.feeder_workers,
                       depth=cfg.feeder_depth,
                       sharding=pmesh.feed_shardings(mesh))

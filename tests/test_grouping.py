@@ -123,20 +123,40 @@ def test_group_size_one_reproduces_legacy_packers(corpus):
     for (c, g), e in zip(ref, new):
         assert e.geom == g and e.pad_to == 1 and len(e.chunks) == 1
         np.testing.assert_array_equal(e.chunks[0], c)
-    # unbucketed: the sequential epoch chunking, byte-identical batches
+    # ONE geometry (the full one, as a table of its own): the sequential
+    # epoch chunking, the wire fields byte-identical to plain make_batch
     chunks = epoch_index_chunks(len(split), cfg, batch_size=8, shuffle=True,
                                 seed=5, epoch=1)
     plan = G.grouped_plan(split, cfg, batch_size=8, group_size=1,
-                          shuffle=True, seed=5, epoch=1)
+                          shuffle=True, seed=5, epoch=1,
+                          table=(B.full_geom(cfg),))
     assert len(plan) == len(chunks)
-    tasks = list(G.grouped_assembly_tasks(split, plan, cfg, batch_size=8,
-                                          bucketed=False))
+    tasks = list(G.grouped_assembly_tasks(split, plan, cfg, batch_size=8))
     for task, c in zip(tasks, chunks):
         got = task()
         want = make_batch(split, c, cfg, batch_size=8)
-        assert set(got) == set(want)  # no host-only fields when unbucketed
+        # the host-only fields ride along (the feeder strips them)
+        assert set(got) - set(want) == {"_positions", "_tag"}
+        assert got["_tag"] == B.geom_tag(B.full_geom(cfg))
+        np.testing.assert_array_equal(got["_positions"], c)
         for k in want:
             np.testing.assert_array_equal(got[k], want[k])
+            assert got[k].dtype == want[k].dtype
+    # cfg.buckets = () WITHOUT a table is the edge ladder, not full pad:
+    # the same samples, every chunk on the least rung that holds its
+    # widest commit, AST and target axes full
+    ladder = G.grouped_plan(split, cfg, batch_size=8, group_size=1,
+                            shuffle=True, seed=5, epoch=1)
+    ext = B.sample_extents(split, cfg)
+    np.testing.assert_array_equal(
+        np.sort(np.concatenate([e.chunks[0] for e in ladder])),
+        np.arange(len(split)))
+    for e in ladder:
+        assert e.geom in B.edge_ladder(cfg)
+        assert (e.geom.ast_len, e.geom.tar_len) == (cfg.ast_change_len,
+                                                    cfg.tar_len)
+        widest = int(ext.edges[e.chunks[0]].max())
+        assert e.geom.max_edges // 2 < widest <= e.geom.max_edges
 
 
 def test_feeder_stream_identical_across_worker_counts(corpus):
@@ -146,8 +166,7 @@ def test_feeder_stream_identical_across_worker_counts(corpus):
                           shuffle=True, seed=3, epoch=0, table=table)
 
     def stream(workers):
-        tasks = G.grouped_assembly_tasks(split, plan, cfg, batch_size=8,
-                                         bucketed=True)
+        tasks = G.grouped_assembly_tasks(split, plan, cfg, batch_size=8)
         with Feeder(tasks, num_workers=workers, depth=3, put=False) as feed:
             return [item.host for item in feed]
 

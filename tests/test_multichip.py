@@ -109,8 +109,7 @@ def test_feeder_stream_byte_stable_across_workers_and_mesh_sizes(
     def stream(workers, n_data):
         mesh = (pmesh.make_mesh(n_data=n_data, n_model=1)
                 if n_data else None)
-        tasks = G.grouped_assembly_tasks(split, plan, cfg, batch_size=8,
-                                         bucketed=True)
+        tasks = G.grouped_assembly_tasks(split, plan, cfg, batch_size=8)
         with Feeder(tasks, num_workers=workers, depth=3,
                     sharding=pmesh.feed_shardings(mesh)) as feed:
             return [item.host for item in feed]
@@ -141,8 +140,7 @@ def test_feed_shardings_mixed_geometry_on_two_device_mesh(tiny_dataset):
     plan = G.grouped_plan(split, cfg, batch_size=8, group_size=2,
                           shuffle=True, seed=3, epoch=0, table=table)
     mesh = pmesh.make_mesh(n_data=2, n_model=1)
-    tasks = G.grouped_assembly_tasks(split, plan, cfg, batch_size=8,
-                                     bucketed=True)
+    tasks = G.grouped_assembly_tasks(split, plan, cfg, batch_size=8)
     geoms_seen = set()
     saw_stacked = saw_per_step = False
     with Feeder(tasks, num_workers=2, depth=3,

@@ -85,7 +85,10 @@ def tiny(tmp_path_factory):
 def test_guard_wiring_through_train_loop(tiny, tmp_path):
     """End-to-end: train() threads the guard through every dispatch site
     (train_step + dev_step labels) without tripping on a healthy run —
-    pins the label placement, not just CompileGuard mechanics."""
+    pins the label placement, not just CompileGuard mechanics. With no
+    declared bucket table the train program carries its edge rung's tag
+    (data/buckets.edge_ladder) and the dev gate the plain full-geometry
+    label."""
     from fira_tpu.train.loop import train
 
     dataset = tiny
@@ -96,7 +99,10 @@ def test_guard_wiring_through_train_loop(tiny, tmp_path):
                        epochs=1, resume=False, guard=guard)
     assert result.epochs_run == 1
     # both programs dispatched >1 time and the guard saw them
-    assert guard._seen.get("train_step", 0) >= 2
+    rungs = {lbl: n for lbl, n in guard._seen.items()
+             if lbl.startswith("train_step[")}
+    assert rungs and sum(rungs.values()) >= 2 + len(rungs)
+    assert "train_step" not in guard._seen
     assert guard._seen.get("dev_step", 0) >= 1
     assert guard.compiles_after_warmup() == 0
 
