@@ -109,10 +109,9 @@ class CompileWatcher(logging.Handler):
     This one GUARDS: it exists only under the sanitizer, where
     :class:`CompileGuard` turns its count into a raise. The one that
     COUNTS, in every run, is ``utils/profiling.py``'s ``jax.monitoring``
-    listener (``xla.compile`` events in the recorder's ring, with the
-    program's name and the span it ran under, and the ``compiles`` /
-    ``compile_s`` / ``cache_hits`` / ``cache_misses`` counters of every
-    ``phases`` block). The two are not fed from each other: the guard needs
+    listener (``jax.trace`` / ``jax.lower`` / ``xla.compile`` events in
+    the recorder's ring, with the program's name and the span it ran
+    under, and the build counters of every ``phases`` block). The two are not fed from each other: the guard needs
     jax's log record (its message names shapes the RetraceError quotes,
     and ``jax_log_compiles`` is its arming switch), the listener needs
     neither.

@@ -332,6 +332,7 @@ def make_memory_batch(cfg, n: int, seed: int = 0, pad_vocab_to: int = 0):
     return cfg, make_batch(split, np.arange(n), cfg), word_vocab
 
 
+@profiling.span("corpus.prompts")
 def make_prompt_requests(n: int, *, vocab_size: int, seed: int = 0,
                          min_len: int = 256, max_len: int = 4096,
                          round_size: int = 16,

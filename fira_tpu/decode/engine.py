@@ -399,6 +399,7 @@ class SlotEngine:
     # arena IS paged. Goes with that reader (ROADMAP D14).
     _paged = True
 
+    @profiling.span("engine.init")
     def __init__(self, model, params, cfg: FiraConfig, *,
                  slots: Optional[int] = None, guard=None,
                  device=None, tag: Optional[str] = None,
@@ -848,9 +849,10 @@ class SlotEngine:
         first-use XLA compile inside a watchdogged dispatch would read as
         a hung replica."""
         # one span a program: what a span holds is that program's trace,
-        # compile (or cache load) and dispatch — the host's share — and the
-        # compile listener's xla.compile events land under the program
-        # that paid them (utils/profiling.py). The spans do NOT wait for
+        # lowering, compile (or cache load) and dispatch — the host's
+        # share — and the build listener's jax.trace / jax.lower /
+        # xla.compile events land under the program that paid them
+        # (utils/profiling.py). The spans do NOT wait for
         # the device: closed on block_until_ready they read the arena's
         # upload (4-5 s at benchmark size, under `insert`) and each first
         # run, but the waiting cost every process 2-5 s of set-up that

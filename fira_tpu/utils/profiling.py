@@ -1,4 +1,4 @@
-"""The program's one recorder: spans, compile counters, traces, the meter.
+"""The program's one recorder: spans, build counters, traces, the meter.
 
 The reference has no profiling at all — an unused ``import time`` and
 step-rate prints (/root/reference/run_model.py:114-115,181-182). Here one
@@ -15,15 +15,31 @@ module owns every wall-clock read the layers take of themselves:
   ``RequestRecord``). ``begin(name)`` is the same for a root that outlives a
   ``yield`` (``SlotEngine.run``): ring only, and the thread's parent stack
   is held only inside ``with root:`` stretches, never across the yield.
-- the compile listener: one ``jax.monitoring`` registration records every
-  backend compile as an ``xla.compile`` event in the same ring (ids: the
-  program's name as jax gives it, ``jit(_step_fn)``, and whether the
-  persistent cache served it; parent: the span open on that thread) and as
-  the counters ``compiles`` / ``compile_s`` / ``cache_hits`` /
-  ``cache_misses``. It COUNTS, always; ``analysis.sanitizer.CompileWatcher``
-  is the one that GUARDS (it raises, and only under ``--sanitize``).
+- the build listener: one ``jax.monitoring`` registration records each of
+  jax's three build stages of a program as an event in the same ring —
+  ``jax.trace`` (Python tracing to a jaxpr), ``jax.lower`` (jaxpr to an MLIR
+  module) and ``xla.compile`` (the backend build: ``compile_or_get_cached``,
+  so a COMPILE OR a persistent-cache LOAD; ids ``cache: hit|miss`` where the
+  cache was asked and ``load_s``, the cache's read, where it served) — ids:
+  the program's name as jax gives it (``_step_fn``, ``jit__step_fn``,
+  ``jit(_step_fn)``), parent: the span open on that thread. jax reports a
+  stage once a build, never a call: a warm loop records none. Builds nest
+  (tracing ``jit_multi_step`` traces the jits inside it), so a time over
+  build events is a union of intervals, never a sum of durations. The
+  counters ``compiles`` / ``compile_s`` (every backend build, cache-served
+  or compiled: ``cache_misses`` is the count that compiled, ``cache_hits``
+  the count loaded, ``cache_load_s`` the loads' seconds), ``traces`` /
+  ``trace_s`` and ``lowers`` / ``lower_s`` (sums of durations: nested
+  traces count inside their parents too). It COUNTS, always;
+  ``analysis.sanitizer.CompileWatcher`` is the one that GUARDS (it raises,
+  and only under ``--sanitize``).
+- two clocks beside the ring's (``time.perf_counter``): the process's start
+  read once from the OS (:func:`process_start`), so a reader can tell how
+  much of start-up no event covers; and the offset to the profiler's clock
+  (:func:`profiler_offset_ns`), so a ring time lines up with an
+  ``.xplane.pb`` by arithmetic. Both are in ``dump``'s header.
 - :class:`Phases`: per span name ``count`` / ``total_s`` / ``max_s`` plus
-  the compile counters, over the spans that closed while the object lived
+  the build counters, over the spans that closed while the object lived
   — the ``phases`` block of ``EngineStats.summary()`` and
   ``ServeStats.summary()``, so a stats reset resets its phases with it.
 - ``events()`` / ``dump(path)``: a copy of the ring / the ring as JSON
@@ -41,9 +57,10 @@ One entry point a need: ``span`` for a block (``with span(...)``) or a whole
 function (``@span(...)``: a fresh span each call), ``begin`` for the root
 that outlives a ``yield``, ``stopwatch`` for a time that is no layer
 boundary. The module-level ``span`` / ``begin`` / ``events`` / ``dump`` /
-``collect`` / ``counters`` ARE the program's interface; they act on
-``RECORDER``, the process's one :class:`Recorder` (the class is where the
-state lives; only a test that must not see the process's ring makes another).
+``collect`` / ``counters`` / ``process_start`` / ``profiler_offset_ns`` ARE
+the program's interface; the ring's act on ``RECORDER``, the process's one
+:class:`Recorder` (the class is where the state lives; only a test that
+must not see the process's ring makes another).
 """
 
 from __future__ import annotations
@@ -54,22 +71,32 @@ import dataclasses
 import functools
 import itertools
 import json
+import os
 import threading
 import time
 import weakref
 from typing import Dict, Iterator, List, NamedTuple, Optional
 
 RING_EVENTS = 1 << 16   # ~1.5 h of serve rounds, ~40 min of drain dispatches
+TRACE_EVENT = "jax.trace"
+LOWER_EVENT = "jax.lower"
 COMPILE_EVENT = "xla.compile"
+BUILD_EVENTS = (TRACE_EVENT, LOWER_EVENT, COMPILE_EVENT)
 
-_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+# jax's monitoring names of the three build stages -> the ring's names
+_BUILD_STAGES = {"/jax/core/compile/jaxpr_trace_duration": TRACE_EVENT,
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration":
+                     LOWER_EVENT,
+                 "/jax/core/compile/backend_compile_duration": COMPILE_EVENT}
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 class Event(NamedTuple):
-    """One closed span (or one compile). Times are ``time.perf_counter``
-    seconds; ``parent_id`` 0 means no span was open on the thread."""
+    """One closed span (or one build stage of a program). Times are
+    ``time.perf_counter`` seconds; ``parent_id`` 0 means no span was open
+    on the thread."""
 
     span_id: int
     parent_id: int
@@ -85,8 +112,10 @@ class Event(NamedTuple):
 
 
 class Phases:
-    """Totals of the spans that closed, and the compiles that ran, while
-    this object lived (made by :meth:`Recorder.collect`)."""
+    """Totals of the spans that closed, and the builds that ran, while this
+    object lived (made by :meth:`Recorder.collect`). ``compiles`` counts
+    every backend build, the cache's loads among them (``cache_hits``);
+    ``cache_misses`` those that compiled."""
 
     def __init__(self) -> None:
         self.spans: Dict[str, List[float]] = {}   # name -> [count, total, max]
@@ -94,6 +123,11 @@ class Phases:
         self.compile_s = 0.0
         self.cache_hits = 0
         self.cache_misses = 0
+        self.cache_load_s = 0.0
+        self.traces = 0
+        self.trace_s = 0.0
+        self.lowers = 0
+        self.lower_s = 0.0
 
     def _add(self, name: str, seconds: float) -> None:
         row = self.spans.get(name)
@@ -105,11 +139,29 @@ class Phases:
             if seconds > row[2]:
                 row[2] = seconds
 
+    def _build(self, name: str, seconds: float,
+               load_s: Optional[float]) -> None:
+        if name == COMPILE_EVENT:
+            self.compiles += 1
+            self.compile_s += seconds
+            self.cache_load_s += load_s or 0.0
+        elif name == TRACE_EVENT:
+            self.traces += 1
+            self.trace_s += seconds
+        else:
+            self.lowers += 1
+            self.lower_s += seconds
+
     def counters(self) -> Dict[str, float]:
         return {"compiles": self.compiles,
                 "compile_s": round(self.compile_s, 6),
                 "cache_hits": self.cache_hits,
-                "cache_misses": self.cache_misses}
+                "cache_misses": self.cache_misses,
+                "cache_load_s": round(self.cache_load_s, 6),
+                "traces": self.traces,
+                "trace_s": round(self.trace_s, 6),
+                "lowers": self.lowers,
+                "lower_s": round(self.lower_s, 6)}
 
     def summary(self) -> Dict:
         """Sorted by name: thread timing decides which span closes first,
@@ -213,7 +265,7 @@ def stopwatch(_name: str = "", **_ids) -> _Stopwatch:
 
 class Recorder:
     """The ring, the thread-local parent stacks, the live :class:`Phases`
-    collectors and the compile listener's state."""
+    collectors and the build listener's state."""
 
     def __init__(self, maxlen: int = RING_EVENTS) -> None:
         self._ring: "collections.deque[Event]" = collections.deque(
@@ -222,6 +274,7 @@ class Recorder:
         self._local = threading.local()
         self._lock = threading.Lock()
         self._collectors: "weakref.WeakSet[Phases]" = weakref.WeakSet()
+        self.recorded = 0               # events appended, the dropped too
         self.total = self.collect()     # the process's own, never dropped
 
     # --- spans ---
@@ -248,10 +301,11 @@ class Recorder:
         self._ring.append(Event(span_id, parent_id, name, t_start, t_end,
                                 threading.get_ident(), ids or None))
         with self._lock:
+            self.recorded += 1
             for c in self._collectors:
                 c._add(name, t_end - t_start)
 
-    # --- compiles (fed by the module's one jax.monitoring registration) ---
+    # --- builds (fed by the module's one jax.monitoring registration) ---
 
     def _on_cache_event(self, hit: bool) -> None:
         self._local.cache = "hit" if hit else "miss"
@@ -262,21 +316,31 @@ class Recorder:
                 else:
                     c.cache_misses += 1
 
-    def _on_compile(self, seconds: float, fun_name: Optional[str]) -> None:
+    def _on_cache_load(self, seconds: float) -> None:
+        self._local.load_s = seconds
+
+    def _on_build(self, name: str, seconds: float,
+                  fun_name: Optional[str]) -> None:
+        """One build stage that just ended on this thread, ``seconds``
+        long. A backend build takes the cache's verdict and load time that
+        jax reported inside it, on the same thread, just before."""
         now = time.perf_counter()
         span_id, parent_id = self._new_id(self._stack())
         ids = {"program": fun_name}
-        cache = getattr(self._local, "cache", None)
-        if cache is not None:       # the cache event just before, same thread
-            del self._local.cache
-            ids["cache"] = cache
-        self._ring.append(Event(span_id, parent_id, COMPILE_EVENT,
-                                now - seconds, now, threading.get_ident(),
-                                ids))
+        load_s = None
+        if name == COMPILE_EVENT:
+            cache = self._local.__dict__.pop("cache", None)
+            load_s = self._local.__dict__.pop("load_s", None)
+            if cache is not None:
+                ids["cache"] = cache
+            if load_s is not None:
+                ids["load_s"] = load_s
+        self._ring.append(Event(span_id, parent_id, name, now - seconds, now,
+                                threading.get_ident(), ids))
         with self._lock:
+            self.recorded += 1
             for c in self._collectors:
-                c.compiles += 1
-                c.compile_s += seconds
+                c._build(name, seconds, load_s)
 
     # --- reading ---
 
@@ -289,22 +353,70 @@ class Recorder:
     def events(self) -> List[Event]:
         return list(self._ring)
 
+    def dropped(self) -> int:
+        """Events the bounded ring let go (0 until it has wrapped)."""
+        return max(0, self.recorded - len(self._ring))
+
     def counters(self) -> Dict[str, float]:
         return self.total.counters()
 
     def dump(self, path: str) -> str:
-        """The ring as JSON lines, oldest first; the first line says how
-        many events the ring holds of how many were recorded."""
+        """The ring as JSON lines, oldest first. The first line says how
+        many events the ring holds of how many were recorded, when the
+        process started on the ring's clock (``process_start``, seconds)
+        and the profiler's clock less the ring's (``profiler_offset_ns``):
+        an event at ring time ``t`` is at ``t * 1e9 + profiler_offset_ns``
+        on the profiler's clock, which an ``.xplane.pb`` gives relative to
+        its session's start (plane ``Task Environment``, stat
+        ``profile_start_time``)."""
         events = self.events()
-        recorded = sum(int(row[0]) for row in self.total.spans.values()) \
-            + self.total.compiles
         with open(path, "w") as f:
             f.write(json.dumps({"recorder": {
                 "clock": "perf_counter", "events": len(events),
-                "recorded": recorded, **self.counters()}}) + "\n")
+                "recorded": self.recorded,
+                "process_start": process_start(),
+                "profiler_offset_ns": profiler_offset_ns(),
+                **self.counters()}}) + "\n")
             for ev in events:
                 f.write(json.dumps(ev._asdict(), default=str) + "\n")
         return path
+
+
+def _offset_ns(other_ns) -> int:
+    """``other_ns()`` less the ring's clock, in ns, from the tightest of a
+    few bracketing reads: the ring's clock, the other, the ring's again."""
+    best = None
+    for _ in range(16):
+        a = time.perf_counter_ns()
+        o = other_ns()
+        b = time.perf_counter_ns()
+        if best is None or b - a < best[0]:
+            best = (b - a, o - (a + b) // 2)
+    return best[1]
+
+
+@functools.lru_cache(maxsize=None)
+def process_start() -> Optional[float]:
+    """When this process started, on the ring's clock (``perf_counter``
+    seconds), read once from the OS: ``/proc/self/stat``'s start time
+    (clock ticks after boot, so to 1/CLK_TCK, 10 ms) against
+    ``CLOCK_BOOTTIME``. None where the OS does not say (no ``/proc``)."""
+    try:
+        with open("/proc/self/stat") as f:
+            after_name = f.read().rsplit(")", 1)[1].split()
+        ticks = int(after_name[19])          # field 22, starttime
+        hz = os.sysconf("SC_CLK_TCK")
+        boot = _offset_ns(lambda: time.clock_gettime_ns(time.CLOCK_BOOTTIME))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return ticks / hz - boot / 1e9
+
+
+def profiler_offset_ns() -> int:
+    """The profiler's clock less the ring's, in ns, sampled now (the two
+    may slew apart over hours). The profiler stamps host and device events
+    with the wall clock (``absl::GetCurrentTimeNanos``)."""
+    return _offset_ns(time.time_ns)
 
 
 RECORDER = Recorder()
@@ -318,8 +430,9 @@ counters = RECORDER.counters
 
 @functools.lru_cache(maxsize=None)
 def _annotation_type():
-    """jax is imported, and the compile listener registered, at the first
-    span and not with this module (importing it starts nothing)."""
+    """jax is imported, and the build listener registered (if the entry
+    point's ``utils/startup`` call has not already), at the first span and
+    not with this module (importing it starts nothing)."""
     from jax.profiler import TraceAnnotation
 
     listen()
@@ -331,10 +444,11 @@ _listen_lock = threading.Lock()
 
 
 def listen() -> None:
-    """Register the one compile listener with ``jax.monitoring`` (idempotent;
-    the first span of the process calls it). jax hands the
-    cache's hit and miss to plain-event listeners and the compile's duration
-    and program name to duration listeners: both feed ``RECORDER``."""
+    """Register the one build listener with ``jax.monitoring`` (idempotent;
+    the entry points' ``utils/startup`` call makes it, else the first
+    span). jax hands the cache's hit and miss to plain-event listeners, and
+    each build stage's duration and program name, and the cache's load
+    time, to duration listeners: both feed ``RECORDER``."""
     global _listening
     with _listen_lock:
         if _listening:
@@ -348,8 +462,11 @@ def listen() -> None:
                 RECORDER._on_cache_event(False)
 
         def on_duration(event: str, seconds: float, **kw) -> None:
-            if event == _BACKEND_COMPILE:
-                RECORDER._on_compile(float(seconds), kw.get("fun_name"))
+            stage = _BUILD_STAGES.get(event)
+            if stage is not None:
+                RECORDER._on_build(stage, float(seconds), kw.get("fun_name"))
+            elif event == _CACHE_LOAD:
+                RECORDER._on_cache_load(float(seconds))
 
         jax.monitoring.register_event_listener(on_event)
         jax.monitoring.register_event_duration_secs_listener(on_duration)

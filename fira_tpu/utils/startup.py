@@ -5,7 +5,10 @@ Every entry point (``fira_tpu.cli``, ``bench.py``'s worker, ``chip_smoke.py``'s
 children, the ``scripts/tpu_*.py`` that time the chip) calls
 :func:`configure_compile_cache` first thing, so one rule decides where
 compiled programs are kept; CPU-only entry points (tests, the virtual-mesh
-scripts) call :func:`force_cpu_backend` before their first jax use.
+scripts) call :func:`force_cpu_backend` before their first jax use. Either
+registers the recorder's build listener (``profiling.listen``), so every
+program the process builds is in the ring, those built before its first
+span too.
 
 Nothing here falls back: a ``JAX_PLATFORMS`` that names a backend the machine
 lacks makes :func:`device_info` raise, and callers that need a TPU compare
@@ -18,6 +21,8 @@ import json
 import os
 import re
 from typing import Dict, List, Optional
+
+from fira_tpu.utils import profiling
 
 _DEVICE_COUNT_FLAG = "--xla_force_host_platform_device_count"
 
@@ -54,6 +59,7 @@ def force_cpu_backend(n_virtual_devices: Optional[int] = None) -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
+    profiling.listen()
 
 
 def compile_cache_dir() -> str:
@@ -71,6 +77,7 @@ def configure_compile_cache() -> str:
         import jax
 
         jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    profiling.listen()
     return compile_cache_dir()
 
 
@@ -79,8 +86,6 @@ def device_info() -> Dict:
     backend: raises here, before any work, when ``JAX_PLATFORMS`` names one
     the machine does not have."""
     import jax
-
-    from fira_tpu.utils import profiling
 
     with profiling.span("startup.backend"):
         devs = jax.devices()

@@ -14,6 +14,8 @@ a commit over ``max_edges`` still raises in ``make_batch``; the feeder's
 extents are measured once.
 """
 
+import time
+
 import numpy as np
 import pytest
 
@@ -231,13 +233,13 @@ def test_feeder_counts_the_edge_slots_it_ships(corpus):
     ext = B.sample_extents(split, cfg)
     plan = G.grouped_plan(split, cfg, batch_size=4, group_size=2,
                           shuffle=True, seed=3, epoch=0)
-    mark = len(profiling.events())
+    mark = time.perf_counter()     # by time: a full ring keeps its length
     with Feeder(G.grouped_assembly_tasks(split, plan, cfg, batch_size=4),
                 num_workers=2, depth=3, put=False) as feed:
         items = list(feed)
         stats = feed.stats()
-    spans = sorted((e for e in profiling.events()[mark:]
-                    if e.name == "feeder.assemble"),
+    spans = sorted((e for e in profiling.events()
+                    if e.name == "feeder.assemble" and e.t_start >= mark),
                    key=lambda e: e.t_start)
     assert len(spans) == len(items) == len(plan)
     want = {}

@@ -41,6 +41,12 @@ def inside(ev, outer):
     return ev.t_start >= outer.t_start and ev.t_end <= outer.t_end
 
 
+def _since(t0):
+    """The ring's events begun since ``t0``: by time, not by index, since
+    a full ring holds its length while it drops its oldest."""
+    return [e for e in profiling.events() if e.t_start >= t0]
+
+
 # --------------------------------------------------------------------------
 # the recorder on its own
 # --------------------------------------------------------------------------
@@ -273,7 +279,7 @@ def served(setup, tmp_path_factory):
     jax.clear_caches()
     eng = SlotEngine(model, params, cfg, slots=cfg.engine_slots)
     warm = make_batch(split, np.arange(0), cfg, batch_size=cfg.test_batch_size)
-    mark = len(profiling.events())
+    mark = time.perf_counter()
     eng.prewarm([(warm, None)])
     out = str(tmp_path_factory.mktemp("spans_serve"))
     n = len(split)
@@ -288,7 +294,7 @@ def served(setup, tmp_path_factory):
     trace_dir = os.path.join(out, "trace")
     with profiling.trace(trace_dir):
         window = serve(arrivals.poisson_times(n, rate=0.5, seed=3), "window")
-    events = profiling.events()[mark:]
+    events = _since(mark)
     return {"events": events, "burst": burst, "window": window, "eng": eng,
             "trace_dir": trace_dir}
 
@@ -480,7 +486,7 @@ def test_drain_generator_root_and_feeder_spans(setup):
     split = dataset.splits["train"]
     eng = SlotEngine(model, params, cfg, slots=cfg.engine_slots)
     chunks = [np.arange(i, i + 4) for i in range(0, 12, 4)]
-    mark = len(profiling.events())
+    mark = time.perf_counter()
     with Feeder(assembly_tasks(split, chunks, cfg, batch_size=4),
                 num_workers=1, depth=2) as feed:
         stall0 = feed.stats()["feed_stall_s"]
@@ -492,7 +498,7 @@ def test_drain_generator_root_and_feeder_spans(setup):
                     pass
         stalled = feed.stats()["feed_stall_s"] - stall0
     assert got == 12
-    events = profiling.events()[mark:]
+    events = _since(mark)
     (root,) = _roots(events, "engine.run")
     assert root.parent_id == consumer.span_id
     for e in events:
