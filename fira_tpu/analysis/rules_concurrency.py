@@ -63,7 +63,7 @@ _BOUNDARY_CALLS = {
     "jax.device_put", "jax.device_get", "device_put", "device_get",
     "jax.block_until_ready",
 }
-_BOUNDARY_SELF_ATTRS = {"_prefill", "_step", "_insert", "_take_rows"}
+_BOUNDARY_SELF_ATTRS = {"_prefill", "_step", "_insert"}
 _BOUNDARY_ATTRS = {"copy_to_host_async", "block_until_ready"}
 
 # container-mutating method names: a call self._x.append(...) mutates _x

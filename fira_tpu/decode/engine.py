@@ -15,9 +15,8 @@ with per-batch max length.
 
 Program family (all fixed-shape, labelled for the compile guard —
 ``engine_prefill[<geom>]`` x the decode bucket table, ``engine_step``,
-``engine_insert``, ``engine_harvest`` (the batched row gather of a
-harvest's readback: one program for any number of settled rows); zero
-post-warmup retraces):
+``engine_insert``; the harvest dispatches no program of its own: it reads
+the step's own outputs; zero post-warmup retraces):
 
 - **prefill** (one per decode bucket geometry): encoder forward +
   cross-attention K/V + copy-head source projection, once a request (a
@@ -84,13 +83,18 @@ deterministic), and parse-time floors (decode/paging.paging_errors)
 guarantee it can always eventually be seated.
 
 Host scheduler (:meth:`SlotEngine.run`): drains the packer stream via the
-async feeder, prefills ahead (``cfg.engine_prefill_depth`` chunks, at most
-the model's ``prefill_budget`` dispatches a pass where it declares one),
-refills every freed slot, steps, harvests settled slots, and yields one
-:class:`EngineItem` per sample AS IT SETTLES (out of split order — the
-ordered streaming writer, decode/stream.py, restores order on disk). The
-per-dispatch ``done`` readback is the engine's designated sync boundary:
-the refill decision is host-side by construction.
+async feeder; a pass refills every freed slot, dispatches the step, then
+prefills ahead while that step is in flight (``cfg.engine_prefill_depth``
+chunks, and rows for the slots the last harvest freed; at most the model's
+``prefill_budget`` dispatches between two steps where it declares one) —
+so the device has the next chunk's prefill queued when the step ends —
+and only then harvests settled slots, yielding one :class:`EngineItem`
+per sample AS IT SETTLES (out of split order — the ordered streaming
+writer, decode/stream.py, restores order on disk). The step writes out
+what the harvest reads (done mask, tokens, probs, counters) and its host
+copy starts at dispatch: the harvest's ONE transfer is the engine's
+designated sync boundary, and the refill decision is host-side by
+construction.
 
 The scheduler is exposed as STEPPABLE pieces — ``begin_stream`` /
 ``wants_input`` / ``admit`` / ``refill`` / ``step_dispatch`` / ``harvest``
@@ -155,7 +159,6 @@ from fira_tpu.utils import profiling
 PREFILL_KIND = "engine_prefill"
 STEP_LABEL = "engine_step"
 INSERT_LABEL = "engine_insert"
-HARVEST_LABEL = "engine_harvest"
 
 
 @dataclasses.dataclass
@@ -164,6 +167,12 @@ class EngineStats:
 
     slots: int
     prefills: int = 0            # prefill program dispatches (chunks)
+    # ... of them, as ``run`` placed them: queued while a step was in
+    # flight (they cover the host's harvest), and dispatched after a
+    # harvest because the rows staged ahead fell short of the free slots
+    # (on the critical path); the first fill of a stream is neither
+    prefills_ahead: int = 0
+    prefills_topup: int = 0
     refills: int = 0             # insert program dispatches
     slots_refilled: int = 0      # slot fills across all inserts
     steps: int = 0               # beam MICRO-steps run (cadence x dispatches)
@@ -187,14 +196,15 @@ class EngineStats:
     kv_bytes_per_slot_state: int = 0
     block_steps: int = 0         # blocks in use, summed per step dispatch
     peak_blocks: int = 0         # high-water mark of blocks in use
-    # harvest readback accounting: a harvest that settles rows gathers
-    # ALL of them with one program dispatch and reads the result with one
-    # blocking transfer (rows a read = harvest_row_reads / harvest_reads)
-    harvest_reads: int = 0       # batched readbacks done (one a harvest
-    #                              that settled rows)
+    # harvest readback accounting: every harvest brings the step's own
+    # outputs to the host in one transfer begun at dispatch, and a harvest
+    # that settles rows takes ALL of them from it (rows a read =
+    # harvest_row_reads / harvest_reads)
+    harvest_reads: int = 0       # harvests whose transfer delivered
+    #                              settled rows
     harvest_row_reads: int = 0   # settled-slot rows those reads delivered
-    harvest_bytes_read: int = 0  # token/prob bytes that crossed D2H (the
-    #                              gather's whole padded result)
+    harvest_bytes_read: int = 0  # token/prob bytes that crossed D2H (every
+    #                              slot's rows, every harvest)
     # cross-request reuse accounting (decode/prefix_cache.py; all zero
     # when cfg.prefix_cache is off — the byte-identical comparator)
     cache_hits: int = 0          # seated rows served from the prefill cache
@@ -297,6 +307,8 @@ class EngineStats:
         return {
             "slots": self.slots,
             "prefills": self.prefills,
+            "prefills_ahead": self.prefills_ahead,
+            "prefills_topup": self.prefills_topup,
             "refills": self.refills,
             "slots_refilled": self.slots_refilled,
             "steps_run": self.steps,
@@ -491,28 +503,23 @@ class SlotEngine:
         # holds exactly one live state, rebound on every dispatch
         self._step = jax.jit(self._step_fn, donate_argnums=(1,))
         self._insert = jax.jit(self._insert_fn, donate_argnums=(0,))
-        # harvest readback: one tiny program gathers EVERY settled slot's
-        # (tokens, probs) rows of a harvest into a buffer of its own (not
-        # a view of the arena, which the next dispatch donates). The slot
-        # ids are data — an int32 vector as long as the arena, padded by
-        # harvest() — so it is one compile a configuration whatever the
-        # number of rows that settle.
-        self._take_rows = jax.jit(self._take_rows_fn)
-        self._pending_occ = None
+        # what the last step dispatch wrote out for the harvest (_outputs:
+        # buffers of their own, not views of the arena the next dispatch
+        # donates), their host copy already started; None once harvested
+        self._pending_out = None
         # speculative draft-and-verify (decode/spec.py; cfg.spec_decode):
         # the drafter reads the arena (never donated — the verify right
         # behind it consumes the same state), the verify donates it like
-        # the plain step. _pending_spec carries the verify's device-side
-        # [tested, matched, iters] counters to the harvest sync boundary
-        # (the _pending_occ pattern: no new host syncs). _spec_cd is the
-        # stall cooldown — plain dispatches to run before re-arming after
-        # a verify whose drafts all missed (scheduling only; output bytes
-        # are invariant by the spec.py exactness argument).
+        # the plain step and writes its device-side [tested, matched,
+        # iters] counters out beside the step's (no new host syncs).
+        # _spec_cd is the stall cooldown — plain dispatches to run before
+        # re-arming after a verify whose drafts all missed (scheduling
+        # only; output bytes are invariant by the spec.py exactness
+        # argument).
         self._spec_tier = (cfg.spec_decode
                            if cfg.spec_decode not in (None, "off") else None)
         self._spec_k = int(cfg.engine_spec_k)
         self._spec_cd = 0
-        self._pending_spec = None
         if self._spec_tier is not None:
             errs = spec_lib.spec_errors(cfg)
             if errs:
@@ -539,27 +546,27 @@ class SlotEngine:
     def labels(self, table=None) -> List[str]:
         """This engine's full declared program family: one prefill label
         per decode bucket geometry (or the untagged prefill when no table)
-        plus step + insert + the harvest's batched row gather."""
+        plus step + insert."""
         from fira_tpu.data.buckets import geom_tag
 
         prefills = ([self.label(PREFILL_KIND, geom_tag(g)) for g in table]
                     if table is not None else [self.label(PREFILL_KIND)])
-        return prefills + [self.label(STEP_LABEL), self.label(INSERT_LABEL),
-                           self.label(HARVEST_LABEL)] + self._spec_labels()
+        return prefills + [self.label(STEP_LABEL),
+                           self.label(INSERT_LABEL)] + self._spec_labels()
 
     def labels_for_tags(self, geom_tags) -> List[str]:
         """The declared family from already-computed geometry tags (the
         respawn path holds the stored warm-batch tags, not the bucket
         table — parallel/fleet.py replace_slot): one prefill label per
-        tag (None = the untagged single-geometry prefill) plus the
-        step/insert/harvest trio."""
+        tag (None = the untagged single-geometry prefill) plus the step
+        and the insert(s)."""
         prefills = [self.label(PREFILL_KIND, t) for t in geom_tags] \
             or [self.label(PREFILL_KIND)]
         inserts = ([self.label(INSERT_LABEL, t) for t in geom_tags]
                    if self.smodel.insert_by_geometry
                    else [self.label(INSERT_LABEL)])
         return prefills + [self.label(STEP_LABEL)] + inserts \
-            + [self.label(HARVEST_LABEL)] + self._spec_labels()
+            + self._spec_labels()
 
     def _spec_labels(self) -> List[str]:
         """The (S, k) draft/verify pair when spec is armed (the ``k<k>``
@@ -586,9 +593,10 @@ class SlotEngine:
         beam positions at its own depth (a lax.scan of identical one-step
         bodies — slots that settle mid-scan self-mask out, so the cadence
         changes WHICH dispatch a harvest lands in, never the math);
-        everything else passes through unchanged. Returns (state,
-        occupied-slot-step count) — the occupancy numerator, counted
-        exactly, micro-step by micro-step.
+        everything else passes through unchanged. Returns (state, what
+        the harvest reads — :meth:`_outputs`, with the occupied-slot-step
+        count: the occupancy numerator, counted exactly, micro-step by
+        micro-step).
 
         ``params`` is the engine's DECODE-SIDE tree (self._decode_params):
         under serve_precision="int8w" the quantized leaves dequant ONCE
@@ -599,7 +607,8 @@ class SlotEngine:
         params = quant.dequant_tree(params, self._wq_scales)
         R = max(1, int(self.cfg.engine_harvest_every))
         if R == 1:
-            return self._one_step(params, state)
+            state, occ = self._one_step(params, state)
+            return state, self._outputs(state, occ)
 
         def body(carry, _):
             st, acc = carry
@@ -608,21 +617,37 @@ class SlotEngine:
 
         (state, occ), _ = jax.lax.scan(
             body, (state, jnp.int32(0)), None, length=R)
-        return state, occ
+        return state, self._outputs(state, occ)
 
     def _verify_fn(self, params, state, drafts):
         """The speculative verify program: up to ``engine_spec_k`` gated
         EXACT step frames in one dispatch (decode/spec.run_verify over
         this engine's own :meth:`_one_step` — the identical per-position
         HLO the plain step runs, which is the whole exactness argument).
-        Returns (state', occ_entry, [tested, matched, iters]); occ_entry
-        rides the _pending_occ slot, the counter vector _pending_spec."""
+        Returns (state', :meth:`_outputs` with occ_entry as the occupancy
+        and the [tested, matched, iters] counter vector as ``spec``)."""
         # same trace-top dequant as _step_fn: the while_loop frames reuse
         # one reconstructed tree (identity for f32/bf16 weight tiers)
         params = quant.dequant_tree(params, self._wq_scales)
         step = functools.partial(self._one_step, params)
-        return spec_lib.run_verify(step, state, drafts, self._spec_k,
-                                   self.cfg.tar_len)
+        state, occ, spec = spec_lib.run_verify(
+            step, state, drafts, self._spec_k, self.cfg.tar_len)
+        return state, self._outputs(state, occ, spec)
+
+    def _outputs(self, state, occ, spec=None) -> Dict:
+        """What a harvest reads of a step, as outputs of the program's own
+        (not the arena's leaves, which the next dispatch donates): the
+        occupancy count, the done mask, every slot's tokens and probs, the
+        model's device counters where it declares them and the verify's
+        where it ran. Copying all S rows costs less than the round trip a
+        gather of the settled ones would add after the step."""
+        out = {"occ": occ, "done": state["done"], "tokens": state["tokens"],
+               "probs": state["probs"]}
+        if self.smodel.arena_counters:
+            out["counters"] = state["counters"]
+        if spec is not None:
+            out["spec"] = spec
+        return out
 
     def _one_step(self, params, state, gate=None):
         """One beam position for every live, not-yet-done slot.
@@ -822,13 +847,6 @@ class SlotEngine:
         # firacheck: allow[RETIRED-RECHECK] arena-state write: retire() deliberately leaves the arena in place ("the arena and stats stay") and a dead engine's _state is never read again — only scheduling/guard state needs the post-dispatch re-check
         self._state = jax.device_put(z, self.device)
 
-    @staticmethod
-    def _take_rows_fn(tokens, probs, idx):
-        """Rows ``idx`` (int32, one entry a slot of the arena) of the two
-        output leaves, as buffers of their own."""
-        return (jnp.take(tokens, idx, axis=0, mode="clip"),
-                jnp.take(probs, idx, axis=0, mode="clip"))
-
     # --- host scheduler --------------------------------------------------
 
     def _guard_step(self, label: str) -> None:
@@ -840,9 +858,9 @@ class SlotEngine:
                 ) -> None:
         """Compile the WHOLE program family up front: one all-pad batch
         per decode bucket geometry (the prefill compile keys), then one
-        no-op insert (every slot id the drop sentinel), one step over the
-        all-dead arena (no slot active — the state is untouched), and one
-        harvest row gather at its one index length. Outputs are unchanged
+        no-op insert (every slot id the drop sentinel) and one step over
+        the all-dead arena (no slot active — the state is untouched; its
+        outputs are never harvested). Outputs are unchanged
         by construction (pinned by the byte-equality tests); the point is
         that NO dispatch after prewarm pays a compile — which the
         per-dispatch wall-clock watchdog (docs/FAULTS.md) depends on: a
@@ -875,14 +893,8 @@ class SlotEngine:
         if not self.smodel.insert_by_geometry:
             self._prewarm_insert(chunk, None)
         with profiling.span("engine.prewarm.step"):
-            self._state, occ = self._step(self._decode_params, self._state)
+            self._state, _out = self._step(self._decode_params, self._state)
         self._guard_step(self.label(STEP_LABEL))
-        if self._pending_occ is None:
-            self._pending_occ = occ  # zero: no slot was active
-        with profiling.span("engine.prewarm.take_rows"):
-            self._take_rows(self._state["tokens"], self._state["probs"],
-                            np.zeros((self.slots,), dtype=np.int32))
-        self._guard_step(self.label(HARVEST_LABEL))
         if self._spec_tier is not None:
             # compile the (S, k) draft/verify pair over the all-dead arena:
             # the verify's while_loop condition is false at frame 0 (no
@@ -892,11 +904,9 @@ class SlotEngine:
             with profiling.span("engine.prewarm.spec"):
                 drafts = self._draft(self._decode_params, self._state)
                 self._guard_step(self.label(spec_lib.DRAFT_LABEL, km))
-                self._state, occ, pend = self._verify(
+                self._state, _out = self._verify(
                     self._decode_params, self._state, drafts)
                 self._guard_step(self.label(spec_lib.VERIFY_LABEL, km))
-            self._pending_occ = occ      # zeros: no slot was active
-            self._pending_spec = pend
 
     def _prewarm_insert(self, chunk, tag: Optional[str]) -> None:
         """One no-op insert of ``chunk``'s geometry: every slot id the
@@ -931,6 +941,11 @@ class SlotEngine:
         self._free: "collections.deque[int]" = collections.deque(
             range(self.slots))
         self._busy: Dict[int, Tuple[int, Dict, int]] = {}
+        # the previous stream's step outputs name its seats, not this one's
+        self._pending_out = None
+        # slots the last harvest freed: what the step in flight is expected
+        # to free, which ``run`` stages rows for ahead of the harvest
+        self._settled_last = 0
         # paged-KV block allocator: the free list (a deque — O(1) grants)
         # and the per-slot grant map reset with the scheduler; the POOL
         # CONTENTS do not — stale block values are exactly masked, never
@@ -958,7 +973,12 @@ class SlotEngine:
         # Admission therefore never blocks on a prefill readback, and the
         # store-later window is covered by dedup (the rows' digests sit
         # in _inflight until the same harvest that drains their fill).
+        # A harvest drains only the fills admitted before the step it
+        # reads (``_fills_due``, counted at step_dispatch): a chunk
+        # prefilled behind that step is read at the next harvest, so the
+        # harvest never waits on a prefill queued after the step.
         self._pending_fills: List[Tuple[List[Tuple[int, str]], Dict]] = []
+        self._fills_due = 0
 
     # --- refcounted paged-block allocator -------------------------------
 
@@ -1032,11 +1052,11 @@ class SlotEngine:
 
     # --- prefix-cache surface -------------------------------------------
 
-    def _drain_pending_fills(self) -> None:
-        """Materialize deferred miss-fills (the D2H was scheduled async
-        at admit) and store each row by its content digest. Runs at the
-        harvest sync boundary only."""
-        while self._pending_fills:
+    def _drain_pending_fills(self, n: int) -> None:
+        """Materialize the first ``n`` deferred miss-fills (the D2H was
+        scheduled async at admit) and store each row by its content
+        digest. Runs at the harvest sync boundary only."""
+        for _ in range(min(n, len(self._pending_fills))):
             fills, chunk = self._pending_fills.pop(0)
             chunk_host = {}
             for f in prefix_cache_lib.ARTIFACT_FIELDS:
@@ -1070,12 +1090,14 @@ class SlotEngine:
     def cache_len(self) -> int:
         return len(self._cache) if self._cache is not None else 0
 
-    def wants_input(self) -> bool:
+    def wants_input(self, ahead: int = 0) -> bool:
         """Prefill-ahead policy: keep ``engine_prefill_depth`` chunks
-        staged, and at least enough rows to refill every free slot."""
+        staged, and at least enough rows to refill every free slot plus
+        ``ahead`` more — the slots a step still in flight is expected to
+        free (``run`` passes what the last harvest freed)."""
         depth = max(1, int(self.cfg.engine_prefill_depth))
         return (len(self._staged) < depth
-                or self._staged_rows < len(self._free))
+                or self._staged_rows < len(self._free) + ahead)
 
     def in_flight(self) -> int:
         return len(self._busy)
@@ -1399,7 +1421,8 @@ class SlotEngine:
     def step_dispatch(self) -> None:
         """Dispatch one step program (async — the fleet dispatches every
         replica's step before any harvest readback, so replica compute
-        overlaps across chips)."""
+        overlaps across chips) and start the host copy of what its
+        harvest will read."""
         if self._faults is not None:
             self._faults.check("engine.step")
         if self.retired:
@@ -1413,18 +1436,21 @@ class SlotEngine:
         spec_now = self._spec_tier is not None and self._spec_cd == 0
         if spec_now:
             drafts = self._draft(self._decode_params, self._state)
-            new_state, new_occ, new_spec = self._verify(
+            new_state, out = self._verify(
                 self._decode_params, self._state, drafts)
         else:
-            new_state, new_occ = self._step(self._decode_params, self._state)
-            new_spec = None
+            new_state, out = self._step(self._decode_params, self._state)
+        # the harvest's transfer starts now: it lands as the step ends,
+        # whatever the host queues behind the step meanwhile
+        for leaf in out.values():
+            leaf.copy_to_host_async()
         if self.retired:
             # the watchdog expired while the dispatch call was in flight:
             # do NOT touch the shared compile guard or stats from this
             # abandoned thread — the live loop owns them now
             return
-        self._state, self._pending_occ = new_state, new_occ
-        self._pending_spec = new_spec
+        self._state, self._pending_out = new_state, out
+        self._fills_due = len(self._pending_fills)
         if self._spec_cd > 0:
             self._spec_cd -= 1
         st = self.stats
@@ -1469,105 +1495,86 @@ class SlotEngine:
 
     @profiling.span("engine.harvest")
     def harvest(self) -> List[EngineItem]:
-        """Read back the dispatched step's done mask and return every
-        newly settled slot's sample. The readback is BATCHED: however
-        many slots settled, ONE ``_take_rows`` dispatch gathers their
-        (tokens, probs) rows — the slot ids go in as a host index vector
-        as long as the arena, padded with the first settled slot, so the
-        program never recompiles — and ONE blocking ``device_get`` of the
-        pair brings them to the host, where they are sliced per slot (a
-        round trip to the chip costs ~1.5 ms whatever it carries; the
-        bytes never were the cost: PERF.md, PR 31). COPIES, not views:
-        the gather's result is a device buffer of its own, which the next
-        dispatch's donation of the arena cannot touch, and ``np.array``
-        makes the host side writable and independent of it. Items are
-        materialized EAGERLY (a plain list, not a lazy generator): the
-        bookkeeping below must be whole before a caller's refill()."""
+        """Read back what the dispatched step wrote out for it and return
+        every newly settled slot's sample. ONE blocking ``device_get``
+        brings the step's own outputs — occupancy, done mask, every slot's
+        (tokens, probs), the device counters — to the host, their copy
+        begun at dispatch, and the settled rows are taken there (a round
+        trip to the chip costs ~1.5 ms whatever it carries; the bytes
+        never were the cost: PERF.md §6). No program is dispatched
+        here, so nothing the host queued behind the step — the next
+        chunk's prefill — stands between the step and this read. COPIES,
+        not views: the outputs are device buffers of their own, which the
+        next dispatch's donation of the arena cannot touch, and
+        ``np.array`` makes the host side writable and independent of
+        them. Items are materialized EAGERLY (a plain list, not a lazy
+        generator): the bookkeeping below must be whole before a caller's
+        refill()."""
         if self._faults is not None:
             self._faults.check("engine.harvest")
-        if self.retired:
-            return []  # abandoned by a watchdog; engine is dead
-        if self._cache is not None and self._pending_fills:
+        if self.retired or self._pending_out is None:
+            return []  # abandoned by a watchdog / no step since the last
+        if self._cache is not None and self._fills_due:
             # commit deferred miss-fills BEFORE any dedup bookkeeping is
             # popped below: a digest leaves _inflight only once its
             # entry is stored, so a repeat arriving next round finds
             # either the in-flight leader or the cached artifacts
-            self._drain_pending_fills()
+            self._drain_pending_fills(self._fills_due)
+            self._fills_due = 0
         stats = self.stats
-        # engine.harvest.wait: the first blocking reads — the HOST waiting
-        # for the dispatched step (device busy); everything after it in
-        # this method is engine.harvest.read — the DEVICE waiting for the
-        # host (one gather dispatch + one D2H read for all settled rows)
+        # engine.harvest.wait: the ONE blocking read — the HOST waiting for
+        # the dispatched step (device busy); everything after it in this
+        # method is host work. PHASE 1 — the readback only, no
+        # bookkeeping: a watchdog expiry mid-device_get abandons this
+        # thread with every settled slot still in _busy, so retire()
+        # requeues ALL of them. Phase 2 is pure host dict work —
+        # microseconds, nothing left to hang on.
         with profiling.span("engine.harvest.wait"):
-            occ_now = int(np.array(jax.device_get(self._pending_occ)))
+            got = jax.device_get(self._pending_out)
+            if self.retired:
+                return []  # abandoned by a watchdog mid-readback
+            self._pending_out = None
+            occ_now = int(got["occ"])
             stats.occupied_slot_steps += occ_now
-            if self._pending_spec is not None:
-                # drain the verify's device counters at the SAME sync boundary
-                # the occupancy/done readbacks already pay — spec metering
-                # adds no host sync of its own (decode/spec.run_verify)
-                tested, matched, iters = (
-                    int(x) for x in np.array(
-                        jax.device_get(self._pending_spec)))
-                if self.retired:
-                    # the counter readback is a sync window a watchdog expiry
-                    # can abandon this thread inside; survivors own the
-                    # engine's scheduling state now — touch nothing
-                    return []
-                self._pending_spec = None
+            stats.harvest_bytes_read += (got["tokens"].nbytes
+                                         + got["probs"].nbytes)
+            if "spec" in got:
+                # the verify's device counters ride the SAME transfer —
+                # spec metering adds no host sync of its own
+                # (decode/spec.run_verify)
+                tested, matched, iters = (int(x) for x in got["spec"])
                 stats.drafted += self._spec_k * occ_now
                 stats.accepted += matched
                 stats.steps_saved += tested - occ_now
                 stats.spec_frames += iters
                 if occ_now and matched == 0:
-                    # acceptance stalled (a rare-token span the drafter cannot
-                    # see): run a few plain dispatches before re-arming, so a
-                    # cold stretch does not pay draft+verify per emitted token
+                    # acceptance stalled (a rare-token span the drafter
+                    # cannot see): run a few plain dispatches before
+                    # re-arming, so a cold stretch does not pay
+                    # draft+verify per emitted token
                     self._spec_cd = spec_lib.STALL_COOLDOWN
-            done = np.array(jax.device_get(self._state["done"]))
-            if self.smodel.arena_counters:
-                # the model's device-side counts, at the SAME sync boundary
-                # (slot_model: model/axk1.COUNTERS); int32 on the device,
-                # so the window's share is the wrapped difference
-                now = np.array(jax.device_get(
-                    self._state["counters"])).astype(np.uint32)
-                if self.retired:
-                    return []  # abandoned by a watchdog mid-readback
+            if "counters" in got:
+                # the model's device-side counts (slot_model:
+                # model/axk1.COUNTERS); int32 on the device, so the
+                # window's share is the wrapped difference
+                now = np.array(got["counters"]).astype(np.uint32)
                 grown = now - (self._counters_seen
                                if self._counters_seen is not None else 0)
                 self._counters_seen = now
                 for name, n in zip(self.smodel.arena_counters,
                                    grown.tolist()):
                     setattr(stats, name, getattr(stats, name) + n)
+        done = got["done"]
         newly = [s for s in self._busy if done[s]]
+        self._settled_last = len(newly)
         items: List[EngineItem] = []
         if newly:   # a harvest that settles nothing records no read
             with profiling.span("engine.harvest.read", rows=len(newly)):
-                # PHASE 1 — the readback only, no bookkeeping: a watchdog
-                # expiry mid-device_get abandons this thread with every
-                # settled slot still in _busy, so retire() requeues ALL of
-                # them. Phase 2 is pure host dict work — microseconds,
-                # nothing left to hang on.
-                if self.retired:
-                    return []  # abandoned by a watchdog mid-harvest
-                idx = np.full((self.slots,), newly[0], dtype=np.int32)
-                idx[:len(newly)] = newly
-                rows = self._take_rows(self._state["tokens"],
-                                       self._state["probs"], idx)
-                # the ONE blocking read: harvest is the engine's designated
-                # output boundary (settled beams must reach the host to be
-                # cooked into text); both copies are in flight together
-                toks_np, probs_np = (np.array(a)
-                                     for a in jax.device_get(rows))
-                if self.retired:
-                    # the gather/readback above is exactly the window a
-                    # watchdog expiry abandons this thread inside: the
-                    # live loop owns the shared compile guard now
-                    return []
-                self._guard_step(self.label(HARVEST_LABEL))
+                toks_np, probs_np = np.array(got["tokens"]), \
+                    np.array(got["probs"])
                 stats.harvest_reads += 1
-                stats.harvest_bytes_read += toks_np.nbytes + probs_np.nbytes
                 # PHASE 2 — the readback landed: retire the bookkeeping
-                for i, s in enumerate(newly):
+                for s in newly:
                     pos_id, host, r = self._busy.pop(s)
                     self._free.append(s)
                     # the slot's block grant is RELEASED through the
@@ -1578,7 +1585,7 @@ class SlotEngine:
                     self._release_blocks(self._slot_blocks.pop(s, ()))
                     stats.commits += 1
                     stats.harvest_row_reads += 1
-                    toks_s, probs_s = toks_np[i], probs_np[i]
+                    toks_s, probs_s = toks_np[s], probs_np[s]
                     items.append(EngineItem(position=pos_id, host=host, row=r,
                                             tokens=toks_s, probs=probs_s))
                     # dedup fan-out delivery: every follower coalesced onto
@@ -1617,7 +1624,32 @@ class SlotEngine:
         self.begin_stream()
         feed_iter = iter(feed)
         exhausted = False
-        budget = self.smodel.prefill_budget     # dispatches a pass; 0: all
+        harvested = False
+        # prefill dispatches between two step dispatches, both admits of a
+        # pass counted (slot_model: prefill_budget); 0: no limit
+        budget = self.smodel.prefill_budget
+        admitted = 0
+
+        def admit_while(wanted) -> int:
+            """Admit from the feed while ``wanted()`` and the budget
+            allow; -> the prefill programs that dispatched."""
+            nonlocal exhausted, admitted
+            before = self.stats.prefills
+            while not exhausted and wanted() and not (
+                    budget and admitted >= budget):
+                admitted += 1
+                try:
+                    item = next(feed_iter)
+                except StopIteration:
+                    exhausted = True
+                    break
+                # a put=False feed (the fleet's shared queue) leaves
+                # item.device == item.host; admit re-ships it then
+                self.admit(item.host, item.index,
+                           None if item.device is item.host
+                           else item.device)
+            return self.stats.prefills - before
+
         # engine.run is a generator: its root span is opened and closed
         # explicitly and is the thread's parent only inside the `with
         # root` stretches — never across a yield, where the consumer's
@@ -1626,25 +1658,14 @@ class SlotEngine:
         try:
             while True:
                 with root:
-                    # prefill ahead: keep `depth` chunks staged, and at
-                    # least enough rows to refill every currently free slot
-                    # — at most `budget` dispatches a pass where the model
-                    # declares one (slot_model: prefill_budget)
-                    admitted = 0
-                    while not exhausted and self.wants_input() and not (
-                            budget and admitted >= budget):
-                        admitted += 1
-                        try:
-                            item = next(feed_iter)
-                        except StopIteration:
-                            exhausted = True
-                            break
-                        # a put=False feed (the fleet's shared queue)
-                        # leaves item.device == item.host; admit re-ships
-                        # it then
-                        self.admit(item.host, item.index,
-                                   None if item.device is item.host
-                                   else item.device)
+                    # top-up: rows for every free slot the rows staged
+                    # ahead do not cover (the first fill of the stream,
+                    # then only where a harvest freed more than was
+                    # staged for) — on the critical path
+                    n = admit_while(
+                        lambda: self._staged_rows < len(self._free))
+                    if harvested:
+                        self.stats.prefills_topup += n
 
                     # refill every free slot from the staged queue
                     self.refill(refill_order)
@@ -1652,10 +1673,20 @@ class SlotEngine:
                     if not self._busy:
                         if exhausted:
                             break
+                        admitted = 0
                         continue  # nothing in flight yet: pull more input
 
                     self.step_dispatch()
+                    admitted = 0
+                    # prefill ahead, queued on the device behind the step
+                    # in flight: `depth` chunks staged, and rows for what
+                    # the step is expected to free — the last harvest's
+                    # count — so the device runs the next chunk's prefill
+                    # while the host harvests
+                    self.stats.prefills_ahead += admit_while(
+                        lambda: self.wants_input(self._settled_last))
                     items = self.harvest()
+                    harvested = True
                 yield from items
         finally:
             root.end()

@@ -198,7 +198,7 @@ def run_test(model: FiraModel, params, dataset: FiraDataset,
                   flush=True)
         else:
             # unbucketed: pre-warm the single-geometry engine family
-            # (prefill + no-op insert/step + harvest gather) so the
+            # (prefill + no-op insert/step) so the
             # dispatch watchdog never reads a first-use XLA compile as a
             # hung replica (docs/FAULTS.md)
             from fira_tpu.data.batching import make_batch

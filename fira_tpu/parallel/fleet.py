@@ -143,15 +143,19 @@ class FleetStats:
             "replicas": len(self.replicas),
             "slots": tot("slots"),
             "prefills": tot("prefills"),
+            # where SlotEngine.run placed them (zero under the fleet's own
+            # round order, which admits before the step)
+            "prefills_ahead": tot("prefills_ahead"),
+            "prefills_topup": tot("prefills_topup"),
             "refills": tot("refills"),
             "slots_refilled": tot("slots_refilled"),
             "steps_run": tot("steps"),
             "step_dispatches": tot("step_dispatches"),
             "commits": self.commits,
             "dispatches": sum(r.dispatches for r in self.replicas),
-            # harvest readback accounting (decode/engine.py): batched
-            # reads, the rows they delivered and the per-replica D2H
-            # bytes, totalled across the fleet
+            # harvest readback accounting (decode/engine.py): reads that
+            # delivered settled rows, the rows they delivered and the
+            # per-replica D2H bytes, totalled across the fleet
             "harvest_reads": tot("harvest_reads"),
             "harvest_row_reads": tot("harvest_row_reads"),
             "harvest_bytes_read": tot("harvest_bytes_read"),

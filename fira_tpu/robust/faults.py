@@ -58,7 +58,7 @@ SITES = (
     #                       request re-ingests (never a wrong answer)
     "engine.prefill",     # the engine's prefill dispatch (admit)
     "engine.step",        # the engine's step dispatch
-    "engine.harvest",     # the done-mask readback + batched row gather
+    "engine.harvest",     # the one transfer of the step's outputs
     "fleet.replica",      # one replica's whole service round
     "serve.admit",        # a request's admission into the serve queue
     "cache.lookup",       # a prefix-cache lookup (decode/prefix_cache.py):

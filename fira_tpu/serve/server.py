@@ -48,7 +48,7 @@ per-sample beam math is batch-composition-invariant (every batched op is
 row-wise; the contract decode/engine.py's bit-exactness tests pin), and
 the writer keys by split position — and invariant to replica count,
 harvest cadence, and feeder worker count, with zero post-warmup retraces
-under the same declared (geometry x {prefill, step, insert, harvest})
+under the same declared (geometry x {prefill, step, insert})
 program family: serve-mode batches reuse the drain packer's exact
 geometries and batch size, so no new program ever compiles.
 
@@ -1410,7 +1410,7 @@ def prepare_templates(owner, split, cfg: FiraConfig, table, *,
     templates = {0: make_batch(split, np.arange(0), cfg, batch_size=bs)}
     if prewarm:
         # unbucketed: pre-warm the single-geometry program family too
-        # (prefill + no-op insert/step + harvest gather) — the dispatch
+        # (prefill + no-op insert/step) — the dispatch
         # watchdog depends on post-warmup dispatches never paying a
         # first-use XLA compile (docs/FAULTS.md)
         owner.prewarm([(templates[0], None)])

@@ -13,8 +13,12 @@ Pins the engine's whole contract:
   leaves a parseable plain prefix of the final file);
 - the compile-guard story: the (geometry x {prefill, step, insert})
   program family warms once, then zero post-warmup compiles;
-- the harvest's readback: one ``_take_rows`` dispatch and one transfer a
-  harvest, however many rows settled, for both model families.
+- the harvest's readback: no program of its own and one transfer a
+  harvest of what the step wrote out, however many rows settled, for both
+  model families;
+- the pass order: the next chunk's prefill is queued behind the step in
+  flight, before the harvest's read, and seats what the admit-first order
+  seated.
 """
 
 import dataclasses
@@ -133,35 +137,35 @@ REPEAT_CHUNKS = [np.array([0, 1, 2, 3]), np.array([0, 1, 2, 3]),
 
 
 class HarvestProbe:
-    """Wraps ONE engine's ``harvest`` and ``_take_rows``: chooses which
-    seated slots a harvest sees as settled (``mode``), copies the arena
-    before the harvest, counts the gather's dispatches and holds every
-    item against the arena rows it must equal.
+    """Wraps ONE engine's ``harvest``: chooses which seated slots a
+    harvest sees as settled (``mode``), copies the arena before the
+    harvest, counts the program dispatches inside it and holds every item
+    against the arena rows it must equal.
 
     ``one``: a harvest sees at most one of the slots that settled (the
     others stay seated and done, and settle at later harvests);
     ``every``: a harvest over a full arena sees every slot settled;
-    ``several``: the schedule's own rows. The mask the device keeps is
-    put back after the harvest, with the harvested slots done."""
+    ``several``: the schedule's own rows. The harvest reads the mask the
+    step wrote out; the mask the device keeps is put back after the
+    harvest, with the harvested slots done."""
 
     def __init__(self, eng, mode):
         self.eng, self.mode = eng, mode
-        self.takes = 0
         self.rows_a_read = []
         self.held = []          # (item, its arena tokens, its arena probs)
         self.followers = 0
-        self._take, self._harvest = eng._take_rows, eng.harvest
-        eng._take_rows, eng.harvest = self.take_rows, self.harvest
+        self.dispatches = 0     # programs dispatched inside a harvest
+        self._harvest = eng.harvest
+        eng.harvest = self.harvest
+        self._programs = _spy_programs(eng, self._count)
 
-    def take_rows(self, tokens, probs, idx):
-        self.takes += 1
-        assert isinstance(idx, np.ndarray) and idx.dtype == np.int32
-        assert idx.shape == (self.eng.slots,)      # ONE program a config
-        return self._take(tokens, probs, idx)
+    def _count(self, _name):
+        self.dispatches += 1
 
     def harvest(self):
         eng = self.eng
         done = np.array(eng._state["done"])
+        assert np.array_equal(np.array(eng._pending_out["done"]), done)
         busy = sorted(eng._busy)
         settled = [s for s in busy if done[s]]
         if self.mode == "one":
@@ -170,7 +174,7 @@ class HarvestProbe:
             settled = busy
         seen = np.zeros_like(done)
         seen[settled] = True
-        eng._state = dict(eng._state, done=jnp.asarray(seen))
+        eng._pending_out = dict(eng._pending_out, done=jnp.asarray(seen))
         arena_t = np.array(eng._state["tokens"])
         arena_p = np.array(eng._state["probs"])
         slot_of = {pid: s for s, (pid, _h, _r) in eng._busy.items()}
@@ -181,11 +185,11 @@ class HarvestProbe:
                     slot_of[fpos] = slot_of[leader]
                     owed.add(fpos)
                     self.followers += 1
-        before = self.takes
+        before = self.dispatches
         items = self._harvest()
         eng._state = dict(eng._state, done=jnp.asarray(done | seen))
-        # one dispatch where rows settled, none where none did
-        assert self.takes - before == (1 if settled else 0)
+        # the harvest reads what the step wrote out: no program of its own
+        assert self.dispatches == before
         if settled:
             self.rows_a_read.append(len(settled))
         assert sorted(it.position for it in items) == sorted(owed)
@@ -201,8 +205,24 @@ class HarvestProbe:
         return items
 
     def close(self):
-        self.eng._take_rows = self._take
         del self.eng.harvest
+        for name, prog in self._programs.items():
+            setattr(self.eng, name, prog)
+
+
+def _spy_programs(eng, on_call):
+    """Route every jitted program the engine holds through ``on_call(name)``.
+    -> {name: the program}, to put back."""
+    progs = {n: v for n, v in vars(eng).items()
+             if callable(v) and hasattr(v, "lower")}
+    for n, prog in progs.items():
+
+        def spy(*a, _n=n, _prog=prog, **kw):
+            on_call(_n)
+            return _prog(*a, **kw)
+        setattr(eng, n, spy)
+    assert {"_prefill", "_step", "_insert"} <= set(progs)
+    return progs
 
 
 @pytest.fixture(scope="module")
@@ -256,8 +276,8 @@ def _harvest_feed(name, eng, dataset):
     ("axk1", "one"), ("axk1", "several"), ("axk1", "every")])
 def test_harvest_reads_all_settled_rows_at_once(setup, harvest_engines,
                                                 name, mode):
-    """A harvest that settles rows dispatches ``_take_rows`` ONCE, with an
-    index vector as long as the arena, and none where nothing settled;
+    """A harvest dispatches no program, whatever settled: it takes the
+    settled rows from the step's own outputs, one transfer a harvest;
     every item's tokens and probs are bitwise the arena's rows of its slot
     as they stood before the harvest, followers hold their leader's very
     arrays, and all of them outlive every later dispatch (which donates
@@ -280,11 +300,12 @@ def test_harvest_reads_all_settled_rows_at_once(setup, harvest_engines,
         assert it.tokens.tobytes() == toks.tobytes()
         assert it.probs.tobytes() == probs.tobytes()
     reads = probe.rows_a_read
-    assert st.harvest_reads == probe.takes == len(reads) > 0
+    assert st.harvest_reads == len(reads) > 0
     assert st.harvest_reads <= st.harvest_row_reads == sum(reads)
     assert st.harvest_row_reads == st.commits - st.dedup_fanout
     assert st.dedup_fanout == probe.followers
-    assert st.harvest_bytes_read == st.harvest_reads * (
+    # every harvest's one transfer carries every slot's rows
+    assert st.harvest_bytes_read == st.step_dispatches * (
         eng._state["tokens"].nbytes + eng._state["probs"].nbytes)
     s = st.summary()
     assert s["harvest_reads"] == st.harvest_reads
@@ -296,6 +317,136 @@ def test_harvest_reads_all_settled_rows_at_once(setup, harvest_engines,
         assert 2 <= max(reads)
     if name == "fira-followers":
         assert probe.followers > 0
+
+
+def test_harvest_dispatches_no_program_and_declares_none(setup):
+    """Under the armed compile guard, a drain's family is the prefill, the
+    step and the insert: nothing named for a harvest is declared or
+    dispatched, a harvest that settles rows runs no program, and nothing
+    compiles after the warm-up."""
+    from fira_tpu.data.batching import make_batch
+
+    cfg0, dataset, _params, eos_params = setup
+    cfg = dataclasses.replace(cfg0, engine_slots=4)
+    data = dataset.splits["train"]
+    with sanitizer.sanitize(nans=False, infs=False) as guard:
+        eng = engine_lib.SlotEngine(FiraModel(cfg), eos_params, cfg,
+                                    guard=guard)
+        family = eng.labels()
+        assert family == ["engine_prefill", "engine_step", "engine_insert"]
+        guard.declare(family)
+        eng.prewarm([(make_batch(data, np.arange(0), cfg,
+                                 batch_size=cfg.test_batch_size), None)])
+        calls = []
+        in_harvest = []
+        inner = eng.harvest
+
+        def harvest():
+            mark = len(calls)
+            items = inner()
+            in_harvest.append((len(items), len(calls) - mark))
+            return items
+        eng.harvest = harvest
+        _spy_programs(eng, calls.append)
+        with Feeder(_decode_tasks(data, cfg)[0], num_workers=0,
+                    depth=1) as feed:
+            n = sum(1 for _ in eng.run(feed))
+        assert guard.compiles_after_warmup() == 0
+    assert n == len(data)
+    assert set(guard._seen) == set(family)
+    assert sum(1 for rows, _ in in_harvest if rows) > 1
+    assert all(dispatched == 0 for _rows, dispatched in in_harvest)
+    assert calls.count("_step") == eng.stats.step_dispatches \
+        == len(in_harvest)
+
+
+def _drive_admit_first(eng, feed):
+    """The admit-first pass order — admit -> refill -> step -> harvest, the
+    fleet's and the serve loop's — driven by hand through the same four
+    methods; -> the items in the order they settled."""
+    eng.begin_stream()
+    it, exhausted, out = iter(feed), False, []
+    while True:
+        while not exhausted and eng.wants_input():
+            try:
+                item = next(it)
+            except StopIteration:
+                exhausted = True
+                break
+            eng.admit(item.host, item.index, item.device)
+        eng.refill()
+        if not eng.in_flight():
+            if exhausted:
+                return out
+            continue
+        eng.step_dispatch()
+        out += eng.harvest()
+
+
+@pytest.fixture(scope="module")
+def ahead_drain(setup):
+    """One tiny drain stream through one engine twice: in the admit-first
+    order by hand, then through ``run`` with its step dispatches, harvests
+    and prefill programs logged in the order they happened."""
+    cfg0, dataset, _params, eos_params = setup
+    data = dataset.splits["train"]
+    eng = engine_lib.SlotEngine(FiraModel(cfg0), eos_params, cfg0)
+    with Feeder(_decode_tasks(data, cfg0)[0], num_workers=0,
+                depth=1) as feed:
+        first = {it.position: it for it in _drive_admit_first(eng, feed)}
+    first_stats = dataclasses.replace(eng.stats)
+    eng.stats = engine_lib.EngineStats(slots=eng.slots)
+    order = []
+    for meth in ("step_dispatch", "harvest"):
+        inner = getattr(eng, meth)
+
+        def traced(*a, _m=meth, _inner=inner, **kw):
+            order.append(_m)
+            return _inner(*a, **kw)
+        setattr(eng, meth, traced)
+    _spy_programs(eng, lambda name: order.append(name)
+                  if name == "_prefill" else None)
+    with Feeder(_decode_tasks(data, cfg0)[0], num_workers=0,
+                depth=1) as feed:
+        items = list(eng.run(feed))
+    return {"first": first, "first_stats": first_stats,
+            "items": items, "stats": eng.stats, "order": order}
+
+
+def test_run_queues_the_next_prefill_behind_the_step(ahead_drain):
+    """Every prefill ``run`` dispatches once its first step is out comes
+    after a step dispatch and before the harvest that reads that step:
+    the device has it queued when the step ends. ``prefills_ahead``
+    counts exactly those."""
+    order, st = ahead_drain["order"], ahead_drain["stats"]
+    first_step = order.index("step_dispatch")
+    last = None
+    for ev in order[first_step:]:
+        if ev == "_prefill":
+            assert last == "step_dispatch"   # queued behind a step
+        else:
+            last = ev
+    ahead = order[first_step:].count("_prefill")
+    assert st.prefills_ahead == ahead > 0
+    assert st.prefills == order.count("_prefill")
+    assert st.summary()["prefills_ahead"] == ahead
+
+
+def test_run_seats_what_the_admit_first_order_seated(ahead_drain):
+    """On a steady drain the rows staged ahead cover every slot a harvest
+    frees (no top-up), and ``run``'s order seats exactly what admit ->
+    refill -> step -> harvest seated: the same occupied slot-steps, step
+    dispatches and prefills, and the same beams request for request."""
+    st, pst = ahead_drain["stats"], ahead_drain["first_stats"]
+    first, items = ahead_drain["first"], ahead_drain["items"]
+    assert st.prefills_topup == 0 and st.summary()["prefills_topup"] == 0
+    assert st.occupied_slot_steps == pst.occupied_slot_steps > 0
+    assert st.step_dispatches == pst.step_dispatches
+    assert st.prefills == pst.prefills
+    assert sorted(it.position for it in items) == sorted(first)
+    for it in items:
+        assert it.tokens.tobytes() == first[it.position].tokens.tobytes()
+        assert it.probs.tobytes() == first[it.position].probs.tobytes()
 
 
 def test_engine_run_test_file_identical_and_zero_retraces(setup, tmp_path):

@@ -56,9 +56,12 @@ def setup(tmp_path_factory):
 
 # a drain stream with REPEATS: in-flight duplicates (within/adjacent
 # chunks) and cross-chunk repeats of already-harvested samples — both
-# reuse mechanisms fire on it
+# reuse mechanisms fire on it. The engine stages chunks ahead of the
+# harvest, so two fresh chunks stand between the last repeat and its
+# originals: it arrives once they have been harvested and cached
 REPEAT_CHUNKS = [np.array([0, 1, 2, 3]), np.array([0, 1, 2, 3]),
                  np.array([4, 5, 0, 1]), np.array([2, 3, 4, 5]),
+                 np.array([6, 7, 8, 9]), np.array([10, 11, 12, 13]),
                  np.array([0, 1, 2, 3])]
 
 

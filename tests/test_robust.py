@@ -401,7 +401,7 @@ def test_serve_watchdog_retires_hung_replica(setup, trace, drain_lines,
 
 
 def test_watchdog_inside_the_harvest_read_requeues_every_settled_slot(setup):
-    """A hang injected INSIDE the harvest's single batched readback: the
+    """A hang injected INSIDE the harvest's single transfer: the
     watchdog abandons the harvest with every settled slot still seated
     (the readback touches no bookkeeping), ``retire()`` requeues each owed
     request exactly once — the settled ones among them — and the abandoned
@@ -435,18 +435,25 @@ def test_watchdog_inside_the_harvest_read_requeues_every_settled_slot(setup):
     settled_pos = {eng._busy[s][0] for s in settled}
     entered, release, woke = (threading.Event() for _ in range(3))
     out = {}
-    take = eng._take_rows
 
-    def hung_take(*args):
-        entered.set()
-        release.wait(120.0)
-        return take(*args)
+    class HungTransfer:
+        """A leaf of the step's outputs whose host copy hangs: the harvest's
+        one ``device_get`` blocks on it."""
+
+        def __init__(self, a):
+            self.a = a
+
+        def __array__(self, dtype=None, copy=None):
+            entered.set()
+            release.wait(120.0)
+            return np.asarray(self.a)
 
     def abandoned_harvest():
         out["items"] = eng.harvest()
         woke.set()
 
-    eng._take_rows = hung_take
+    eng._pending_out = dict(eng._pending_out,
+                            tokens=HungTransfer(eng._pending_out["tokens"]))
     with pytest.raises(WatchdogTimeout):
         run_with_watchdog(abandoned_harvest, 1.0, label="harvest")
     assert entered.wait(60.0)           # the thread hangs in the ONE read

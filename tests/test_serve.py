@@ -155,7 +155,7 @@ def test_serve_replay_invariant_to_replica_count(setup, trace, drain_bytes,
 
 def test_serve_zero_retraces_on_bucketed_stream(setup, trace, tmp_path):
     """Bucketed serve under the armed sanitizer: the declared (geometry x
-    {prefill, step, insert, harvest}) family warms once, then zero
+    {prefill, step, insert}) family warms once, then zero
     post-warmup compiles — serve-mode online batch formation reuses the
     drain packer's exact geometries, so no new program exists to
     compile. Bytes still equal the drain-mode engine on the same
@@ -172,7 +172,10 @@ def test_serve_zero_retraces_on_bucketed_stream(setup, trace, tmp_path):
         assert guard.compiles_after_warmup() == 0
     assert (open(m["output_path"], "rb").read()
             == open(ref["output_path"], "rb").read())
-    assert "engine_harvest" in set(guard._seen)
+    # the family serve dispatched: prefill, step and insert — the harvest
+    # reads the step's own outputs and runs no program of its own
+    assert {lbl.split("[")[0] for lbl in guard._seen} == {
+        "engine_prefill", "engine_step", "engine_insert"}
 
 
 # --------------------------------------------------------------------------
@@ -385,9 +388,9 @@ def test_cli_serve_end_to_end(tmp_path):
 # --------------------------------------------------------------------------
 
 def test_harvest_sliced_readback_metered(setup):
-    """Harvest reads every settled slot's rows of a dispatch with one
-    gather and one transfer: one row delivered per commit, no more reads
-    than rows, and the metered bytes are what really crossed."""
+    """Harvest reads every settled slot's rows of a dispatch from the one
+    transfer of the step's outputs: one row delivered per commit, no more
+    reads than rows, and the metered bytes are what really crossed."""
     from fira_tpu.data.feeder import Feeder
     from fira_tpu.decode import engine as engine_lib
     from fira_tpu.decode.runner import _decode_tasks
@@ -401,11 +404,11 @@ def test_harvest_sliced_readback_metered(setup):
             pass
     st = eng.stats
     assert st.harvest_row_reads == st.commits == len(data)
-    # one batched read a harvest that settled rows, and the bytes that
-    # really crossed: the gather's whole padded result, every read
+    # one read a harvest that settled rows, and the bytes that really
+    # crossed: every slot's tokens and probs, at every harvest
     assert 0 < st.harvest_reads <= st.harvest_row_reads
     state = eng._state
-    assert st.harvest_bytes_read == st.harvest_reads * (
+    assert st.harvest_bytes_read == st.step_dispatches * (
         state["tokens"].nbytes + state["probs"].nbytes)
     s = st.summary()
     assert s["harvest_reads"] == st.harvest_reads

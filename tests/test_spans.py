@@ -33,8 +33,7 @@ ENGINE_NAMES = {"engine.admit", "engine.refill", "engine.step_dispatch",
                 "engine.harvest", "engine.harvest.wait",
                 "engine.harvest.read"}
 PREWARM_NAMES = {"engine.prewarm", "engine.prewarm.prefill",
-                 "engine.prewarm.insert", "engine.prewarm.step",
-                 "engine.prewarm.take_rows"}
+                 "engine.prewarm.insert", "engine.prewarm.step"}
 
 
 def inside(ev, outer):
@@ -270,11 +269,10 @@ def served(setup, tmp_path_factory):
     every program, then the window — the window under a profiler session."""
     cfg, dataset, model, params = setup
     split = dataset.splits["train"]
-    # jax keeps one in-process cache a jitted FUNCTION, and the harvest
-    # gather is a staticmethod every engine shares: an engine of the same
-    # (slots, beam, tar_len) built earlier on this worker (which files
-    # share a worker differs from run to run) would leave prewarm nothing
-    # to compile, and the tests below assert that compile. Start as a
+    # jax keeps one in-process cache a jitted FUNCTION: an engine of the
+    # same configuration built earlier on this worker (which files share a
+    # worker differs from run to run) could leave prewarm nothing to
+    # compile, and the tests below assert those compiles. Start as a
     # fresh process does.
     jax.clear_caches()
     eng = SlotEngine(model, params, cfg, slots=cfg.engine_slots)
@@ -321,7 +319,6 @@ def test_prewarm_spans_hold_the_compiles(served):
     assert under.get("engine.prewarm.step") == "jit(_step_fn)"
     assert under.get("engine.prewarm.prefill") == "jit(_prefill_fn)"
     assert under.get("engine.prewarm.insert") == "jit(_insert_fn)"
-    assert under.get("engine.prewarm.take_rows") == "jit(_take_rows_fn)"
 
 
 def test_window_after_the_burst_compiles_nothing(served):
@@ -335,13 +332,12 @@ def test_window_after_the_burst_compiles_nothing(served):
     assert served["window"]["engine"]["phases"]["compiles"] == 0
 
 
-def test_one_read_a_harvest_and_one_harvest_program(served):
+def test_one_read_a_harvest_and_no_harvest_program(served):
     """A harvest that settled rows holds ONE ``engine.harvest.read`` (the
-    batched readback), the reads are the engine's ``harvest_reads`` and
-    their ``rows`` its ``harvest_row_reads``; the harvest program was
-    compiled once, under ``engine.prewarm.take_rows``: the index length
-    prewarm warms is the one every harvest of the burst and the window
-    dispatched."""
+    host's share after the one transfer), the reads are the engine's
+    ``harvest_reads`` and their ``rows`` its ``harvest_row_reads``; no
+    program is built under any harvest, burst or window, nor under
+    prewarm for one: the harvest reads what the step wrote out."""
     events = served["events"]
     window_run = _roots(events, "serve.run")[-1]
     mine = [e for e in events if inside(e, window_run)]
@@ -352,11 +348,16 @@ def test_one_read_a_harvest_and_one_harvest_program(served):
     assert sum(e.ids["rows"] for e in reads) == stats.harvest_row_reads \
         == stats.commits
     assert max(e.ids["rows"] for e in reads) > 1     # rows shared a read
-    by_id = {e.span_id: e for e in events}
-    harvest_compiles = [e for e in events if e.name == profiling.COMPILE_EVENT
-                        and e.ids["program"] == "jit(_take_rows_fn)"]
-    assert [by_id[e.parent_id].name for e in harvest_compiles] == [
-        "engine.prewarm.take_rows"]
+    harvests = [e for e in events if e.name == "engine.harvest"]
+    assert harvests
+    builds = [e for e in events if e.name in (
+        profiling.TRACE_EVENT, profiling.LOWER_EVENT,
+        profiling.COMPILE_EVENT)]
+    assert builds
+    assert not [e for e in builds if any(inside(e, h) for h in harvests)]
+    assert {e.ids["program"] for e in builds
+            if e.name == profiling.COMPILE_EVENT} >= {
+        "jit(_step_fn)", "jit(_prefill_fn)", "jit(_insert_fn)"}
 
 
 def test_serve_round_structure_and_harvest_split(served):
