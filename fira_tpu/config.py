@@ -257,13 +257,73 @@ class JambaConfig(_PromptGeometry):
 
 
 @dataclasses.dataclass(frozen=True)
+class BrumbyConfig(_PromptGeometry):
+    """Brumby-14B-Base's key block (``arch="brumby"``): the published keys
+    of that power-retention decoder (a Qwen3 block whose attention's
+    softmax is replaced by power retention) by the names its
+    ``config.json`` gives them (``model_type: brumby``), its values as the
+    defaults
+    (https://huggingface.co/manifestai/Brumby-14B-Base/blob/main/config.json).
+    After them: the retention's degree and the normaliser's eps, which the
+    row does not carry (benchmark/configs/brumby-14b-l4.json lists them
+    under ``assumed``), and the prefill geometry."""
+
+    hidden_size: int = 5120
+    intermediate_size: int = 17408      # every layer's SwiGLU
+    num_hidden_layers: int = 40
+    num_attention_heads: int = 40
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    attention_bias: bool = False
+    tie_word_embeddings: bool = False
+    use_sliding_window: bool = False
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1000000.0
+    vocab_size: int = 151936
+    retention_degree: int = 2
+    retention_eps: float = 1e-6
+    prompt_buckets: tuple = (2048, 4096, 8192, 16384)
+    prefill_token_budget: int = 16384
+
+    @property
+    def kv_dim(self) -> int:
+        """What one generated position keeps a layer: [k | v]."""
+        return 2 * self.num_key_value_heads * self.head_dim
+
+    @property
+    def state_dim(self) -> int:
+        """D: the entries of the degree-2 feature map of a key, d(d+1)/2."""
+        return self.head_dim * (self.head_dim + 1) // 2
+
+    def errors(self) -> list:
+        errs = self.bucket_errors()
+        if self.retention_degree != 2:
+            errs.append(f"lm.retention_degree {self.retention_degree}: only "
+                        f"degree 2 is implemented")
+        if self.attention_bias or self.tie_word_embeddings \
+                or self.use_sliding_window:
+            errs.append("lm.attention_bias / lm.tie_word_embeddings / "
+                        "lm.use_sliding_window other than false are not "
+                        "implemented")
+        if self.num_attention_heads % self.num_key_value_heads \
+                or self.head_dim % 2:
+            errs.append(
+                f"lm.num_key_value_heads {self.num_key_value_heads} must "
+                f"divide lm.num_attention_heads {self.num_attention_heads} "
+                f"and lm.head_dim {self.head_dim} be even")
+        return errs
+
+
+@dataclasses.dataclass(frozen=True)
 class FiraConfig:
     # --- architecture (ARCH_TABLE below): "fira" (the paper's
     # encoder-decoder, every field below) or a decoder-only token model —
     # "axk1" (A.X-K1: latent attention, group-limited routed experts) or
     # "afmoe" (Trinity-Mini: window and full attention layers, 128 small
     # experts) or "jamba" (Jamba2-3B: state-space layers with a recurrent
-    # state a beam, two attention layers) — whose published keys ``lm`` holds in its own key block; of
+    # state a beam, two attention layers) or "brumby" (Brumby-14B-Base:
+    # power-retention layers, a prompt's state a slot) — whose published
+    # keys ``lm`` holds in its own key block; of
     # the fields below such a model reads beam_size, tar_len, the
     # engine/paging knobs and seed ---
     arch: str = "fira"
@@ -1026,6 +1086,29 @@ def jamba_tiny(**kw) -> FiraConfig:
         prefill_token_budget=64), base)
 
 
+def brumby_14b_l4(**kw) -> FiraConfig:
+    """Brumby-14B-Base at its published widths, one pipeline stage on one
+    chip (benchmark/configs/brumby-14b-l4.json says how it was cut):
+    published layers 0-3 of 40 and the whole vocabulary, 5.75 GB of
+    bfloat16 weights."""
+    base = dict(engine_slots=32, test_batch_size=16)
+    base.update(kw)
+    return _lm_preset("brumby", BrumbyConfig(num_hidden_layers=4), base)
+
+
+def brumby_tiny(**kw) -> FiraConfig:
+    """Every mechanism of Brumby-14B-Base at CPU-test widths: d 64, 2
+    layers, 4 query over 2 key/value heads of 16 (D = 136)."""
+    base = dict(engine_slots=4, test_batch_size=4, tar_len=16,
+                compute_dtype="float32")
+    base.update(kw)
+    return _lm_preset("brumby", BrumbyConfig(
+        hidden_size=64, intermediate_size=96, num_hidden_layers=2,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        vocab_size=512, prompt_buckets=(16, 32, 64),
+        prefill_token_budget=64), base)
+
+
 NAMED_CONFIGS = {
     "fira-tiny": fira_tiny,
     "fira-full": fira_full,
@@ -1036,6 +1119,8 @@ NAMED_CONFIGS = {
     "afmoe-tiny": afmoe_tiny,
     "jamba2-3b": jamba2_3b,
     "jamba-tiny": jamba_tiny,
+    "brumby-14b-l4": brumby_14b_l4,
+    "brumby-tiny": brumby_tiny,
 }
 
 
@@ -1094,6 +1179,8 @@ ARCH_TABLE = {
     "axk1": Arch(LMConfig, "fira_tpu.model.axk1", "LMSlotModel"),
     "afmoe": Arch(AfmoeConfig, "fira_tpu.model.afmoe", "AfmoeSlotModel"),
     "jamba": Arch(JambaConfig, "fira_tpu.model.jamba", "JambaSlotModel"),
+    "brumby": Arch(BrumbyConfig, "fira_tpu.model.brumby",
+                   "BrumbySlotModel"),
 }
 ARCHS = tuple(ARCH_TABLE)
 
@@ -1101,7 +1188,7 @@ ARCHS = tuple(ARCH_TABLE)
 def arch_errors(cfg: FiraConfig, command: Optional[str] = None) -> list:
     """What an architecture does not run yet is refused by name, never
     run silently as something else: every token model (``axk1``, ``afmoe``,
-    ``jamba``) goes through the same lines below. ``command``: the CLI's (``train`` /
+    ``jamba``, ``brumby``) goes through the same lines below. ``command``: the CLI's (``train`` /
     ``test`` / ``serve`` / ``message``), where there is one."""
     if cfg.arch not in ARCHS:
         return [f"arch {cfg.arch!r} not in {list(ARCHS)}"]

@@ -262,6 +262,12 @@ class EngineStats:
     # zero for a model without state-space layers)
     state_rows: int = 0           # slot-beams whose state a position
     #                               updated: occupied slots x beams
+    # retention accounting (model/brumby.COUNTERS, the same leaf — zero for
+    # a model without retention layers)
+    state_reads: int = 0          # slot-layers whose prompt state a
+    #                               position read: occupied slots x layers
+    own_keys_read: int = 0        # the beams' own generated positions
+    #                               attended, over the layers
     # per span name count/total_s/max_s and the compile counters, over
     # the spans that closed while THIS stats object lived (utils/
     # profiling.Phases) — a stats reset between timed windows resets the
@@ -353,6 +359,8 @@ class EngineStats:
             "attn_keys_read": self.attn_keys_read,
             "attn_keys_context": self.attn_keys_context,
             "state_rows": self.state_rows,
+            "state_reads": self.state_reads,
+            "own_keys_read": self.own_keys_read,
             "phases": self.phases.summary(),
         }
 

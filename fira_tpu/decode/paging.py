@@ -222,10 +222,11 @@ def leaves_kv_bytes_by_kind(leaves, slots: int) -> Dict[str, int]:
     """Per-slot bytes of the ``kv`` leaves, added up by what they DECLARE
     to hold (``Leaf.kv_kind``): ``"full"`` (prompts kept whole),
     ``"window"`` (rings of a prompt's last positions), ``"state"``
-    (recurrent state of fixed size a beam lane: bytes a slot that do not
-    grow with the prompt), ``""`` (the pools of generated positions, and
-    any cache a model does not tell apart by layer type). A model whose
-    layers are of one kind has one entry."""
+    (state of fixed size — a beam lane's recurrent state, or a prompt's
+    retention state once a slot, read-only and shared by its beams: bytes
+    a slot that do not grow with the prompt), ``""`` (the pools of
+    generated positions, and any cache a model does not tell apart by
+    layer type). A model whose layers are of one kind has one entry."""
     import numpy as np
 
     total: Dict[str, int] = {}

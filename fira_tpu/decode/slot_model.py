@@ -56,7 +56,10 @@ the first whose prompt leaves differ BY LAYER TYPE (``Leaf.kv_kind``: a
 whole prompt a full layer, a ring a window layer, a leaf a layer);
 :class:`JambaSlotModel` (``arch="jamba"``, model/jamba.py) the fourth, and
 the first with leaves that are not keys and values at all: a recurrent
-state a beam lane beside two attention layers' K/V (``beam_parent``).
+state a beam lane beside two attention layers' K/V (``beam_parent``);
+:class:`BrumbySlotModel` (``arch="brumby"``, model/brumby.py) the fifth,
+whose prompt leaves a state of fixed size ONCE A SLOT, read-only in the step
+and shared by the beams, beside the beams' own positions in the pool.
 config.ARCH_TABLE says which class an ``arch`` gets.
 """
 
@@ -86,11 +89,15 @@ class Leaf:
     kv_kind: str = ""               # of a ``kv`` leaf that holds PROMPTS:
     #                                 "full" (a prompt kept whole) or
     #                                 "window" (a ring of its last
-    #                                 positions); or "state": a recurrent
-    #                                 state of fixed size a beam lane,
-    #                                 rewritten whole at every position
-    #                                 (``beam_parent``); paging adds them
-    #                                 up by it
+    #                                 positions); or "state": a state of
+    #                                 fixed size whatever the prompt's
+    #                                 length — a beam LANE's, rewritten
+    #                                 whole at every position
+    #                                 (``beam_parent``: Jamba's), or a
+    #                                 SLOT's, written once at insert, only
+    #                                 read by a step and shared by the
+    #                                 slot's beams (``reorder=None``:
+    #                                 Brumby's); paging adds them up by it
 
 
 class StepView(NamedTuple):
@@ -516,6 +523,85 @@ class JambaSlotModel(LMSlotModel):
         writes.update(zip(self._ssm, ssm))
         writes.update(zip(self._conv, conv))
         return (logp,), writes
+
+
+class BrumbySlotModel(LMSlotModel):
+    """Brumby-14B-Base behind the seam (model/brumby.py). Per slot, a leaf a
+    layer: the prompt's retention state ``ret_state<j>`` (S, KV, D, hd) in
+    the compute dtype and its normaliser ``ret_norm<j>`` (S, KV, D) float32
+    — ``kv_kind="state"``, of fixed size whatever the prompt's length,
+    written once by ``insert`` and only READ by a step, one copy shared by
+    the slot's beams (``reorder=None``; no ``beam_parent``: a beam
+    continues from the prompt along its own tokens, and what differs
+    between beams lies in the pool). The head size lies last: the chip
+    tiles an array's last two axes. No prompt keys or values are kept at
+    all: the prompt is its state. Per beam, paged and reordered as
+    Trinity-Mini's:
+    every layer's generated positions' [k | v] (``kv_pool``) and the gates'
+    sum since the prompt's end at each of them (``gen_gate``, float32)."""
+
+    def __init__(self, model, cfg: FiraConfig, slots: int,
+                 block_size: int, pool_blocks: int):
+        from fira_tpu.model import brumby
+
+        super().__init__(model, cfg, slots, block_size, pool_blocks)
+        self.arena_counters = brumby.COUNTERS
+        L = self.lm.num_hidden_layers
+        self._state = [f"ret_state{j}" for j in range(L)]
+        self._norm = [f"ret_norm{j}" for j in range(L)]
+
+    def prefill(self, params, batch):
+        from fira_tpu.model import brumby
+
+        states, norms, counters = brumby.prefill(
+            params, self.lm, batch["tokens"], batch["lengths"], self.dtype)
+        return {"state": states, "norm": norms, "lengths": batch["lengths"],
+                "counters": counters}
+
+    def leaves(self, chunk) -> Dict[str, Leaf]:
+        lm, S, K = self.lm, self.slots, self.cfg.beam_size
+        KV, hd, D = lm.num_key_value_heads, lm.head_dim, lm.state_dim
+        L, dt = lm.num_hidden_layers, chunk["state"][0].dtype
+        out = {n: Leaf((S, KV, D, hd), dt, kv=True, kv_kind="state")
+               for n in self._state}
+        out.update({n: Leaf((S, KV, D), np.dtype(np.float32), kv=True,
+                            kv_kind="state") for n in self._norm})
+        pool = (L, self.pool_blocks, K, self.block_size)
+        out.update({
+            "prompt_len": Leaf((S,), np.dtype(np.int32)),
+            "kv_pool": Leaf(pool + (lm.kv_dim,), dt, reorder="pool",
+                            kv=True),
+            "gen_gate": Leaf(pool + (KV,), np.dtype(np.float32),
+                             reorder="pool", kv=True),
+            "counters": Leaf((len(self.arena_counters),),
+                             np.dtype(np.int32)),
+        })
+        return out
+
+    def insert(self, state, chunk, sid, fresh) -> Dict:
+        new = {
+            "prompt_len": state["prompt_len"].at[sid].set(
+                chunk["lengths"].astype(jnp.int32), mode="drop"),
+            "counters": state["counters"] + chunk["counters"] * fresh,
+        }
+        for names, key in ((self._state, "state"), (self._norm, "norm")):
+            for name, x in zip(names, chunk[key]):
+                new[name] = state[name].at[sid].set(x, mode="drop")
+        return new
+
+    def step(self, params, state, view: StepView):
+        from fira_tpu.model import brumby
+
+        S, K = self.slots, self.cfg.beam_size
+        tok = jnp.take_along_axis(view.flat, view.pos_bk[:, None], axis=1)
+        logp, pool, gates, counters = brumby.decode_step(
+            params, self.lm, tok.reshape(S, K), view.pos_c,
+            [state[n] for n in self._state], [state[n] for n in self._norm],
+            state["prompt_len"], state["kv_pool"], state["gen_gate"],
+            view.tab_step, view.active, self.dtype)
+        # the prompt's state is not among the writes: a step never changes it
+        return (logp,), {"kv_pool": pool, "gen_gate": gates,
+                         "counters": state["counters"] + counters}
 
 
 def for_config(model, cfg: FiraConfig, slots: int, block_size: int,
