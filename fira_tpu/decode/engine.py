@@ -60,9 +60,11 @@ among the exact zeros of the other beam lanes). The argument has three legs:
 
 The arena is paged (decode/paging.py, docs/DECODE_ENGINE.md "Paged KV
 arena"): the per-slot self-attention caches live in a FIXED POOL of KV
-blocks — ``k_pool``/``v_pool`` (L, P, beam, H, block, d_head) —
-addressed through a per-slot block table (S, W). The step program
-appends each beam's new K/V into ITS lane of the live slot's current tail
+blocks — ``k_pool``/``v_pool`` (L*P, G, H*d_head), a block a (layer,
+pool block) and a row a (beam lane, position), G = beam*block rounded up
+to whole sublane tiles, stored in the layout the step computes in —
+addressed through a per-slot block table (S, W). The step
+program appends each beam's new K/V into ITS lane of the live slot's tail
 block and that is the last time those bytes move: what follows the beams
 after a selection is the ``ancestry`` table (S, beam, tar_len) — the lane
 that holds each position of each beam's history — and the step attends a
@@ -819,39 +821,52 @@ class SlotEngine:
 
     # --- state ----------------------------------------------------------
 
+    def arena_shapes(self, chunk) -> Dict[str, jax.ShapeDtypeStruct]:
+        """The slot arena's leaves, shape and dtype each, from the first
+        chunk's (arrays or ``jax.ShapeDtypeStruct``s): what
+        :meth:`_ensure_state` allocates, and what a program over the arena
+        can be lowered from without allocating it."""
+        cfg = self.cfg
+        S, K, T = self.slots, cfg.beam_size, cfg.tar_len
+        spec = {
+            "tokens": ((S, K, T), np.int32),
+            "probs": ((S, K), np.float32),
+            "finished": ((S, K), bool),
+            "pos": ((S,), np.int32),
+            "live": ((S,), bool),
+            "done": ((S,), bool),
+            "limit": ((S,), np.int32),
+        }
+        # the model's leaves, as it declares them (slot_model.Leaf)
+        self._leaves = self.smodel.leaves(chunk)
+        for name, leaf in self._leaves.items():
+            spec[name] = (leaf.shape, leaf.dtype)
+        spec["block_tab"] = ((S, self._table_width), np.int32)
+        if self.smodel.beam_ancestry:
+            spec["ancestry"] = ((S, K, T), np.int32)
+        if self.smodel.beam_parent:
+            spec["parent"] = ((S, K), np.int32)
+        return {name: jax.ShapeDtypeStruct(shape, np.dtype(dtype))
+                for name, (shape, dtype) in spec.items()}
+
     def _ensure_state(self, chunk) -> None:
         """Allocate the slot arena (all slots dead) from the first chunk's
         shapes/dtypes. Plain host zeros + one device_put: no compiled
         program, so nothing for the compile guard to mis-attribute."""
         if self._state is not None:
             return
-        cfg = self.cfg
-        S, K, T = self.slots, cfg.beam_size, cfg.tar_len
-        z = {
-            "tokens": np.zeros((S, K, T), np.int32),
-            "probs": np.zeros((S, K), np.float32),
-            "finished": np.zeros((S, K), bool),
-            "pos": np.zeros((S,), np.int32),
-            "live": np.zeros((S,), bool),
-            "done": np.zeros((S,), bool),
-            # per-slot tar budget: full until an insert seats a
-            # shorter-budget sample (cfg.decode_tar_buckets, or a request
-            # that carries its own limit)
-            "limit": np.full((S,), T, np.int32),
-        }
-        # the model's leaves, as it declares them (slot_model.Leaf)
-        self._leaves = self.smodel.leaves(chunk)
-        for name, leaf in self._leaves.items():
-            z[name] = np.zeros(leaf.shape, leaf.dtype)
-        z["block_tab"] = np.full((S, self._table_width),
-                                 self._pool_blocks, np.int32)  # unmapped
-        if self.smodel.beam_ancestry:
-            z["ancestry"] = np.broadcast_to(
-                np.arange(K, dtype=np.int32)[None, :, None], (S, K, T)).copy()
-        if self.smodel.beam_parent:
-            z["parent"] = np.zeros((S, K), np.int32)
+        K, T = self.cfg.beam_size, self.cfg.tar_len
+        z = {name: np.zeros(a.shape, a.dtype)
+             for name, a in self.arena_shapes(chunk).items()}
+        # per-slot tar budget: full until an insert seats a shorter-budget
+        # sample (cfg.decode_tar_buckets, or a request that carries its
+        # own limit)
+        z["limit"][:] = T
+        z["block_tab"][:] = self._pool_blocks                  # unmapped
+        if "ancestry" in z:
+            z["ancestry"][:] = np.arange(K, dtype=np.int32)[None, :, None]
         self._kv_bytes_by_kind = paging.leaves_kv_bytes_by_kind(
-            self._leaves, S)
+            self._leaves, self.slots)
         # firacheck: allow[RETIRED-RECHECK] arena-state write: retire() deliberately leaves the arena in place ("the arena and stats stay") and a dead engine's _state is never read again — only scheduling/guard state needs the post-dispatch re-check
         self._state = jax.device_put(z, self.device)
 

@@ -226,12 +226,15 @@ def leaves_kv_bytes_by_kind(leaves, slots: int) -> Dict[str, int]:
     retention state once a slot, read-only and shared by its beams: bytes
     a slot that do not grow with the prompt), ``""`` (the pools of
     generated positions, and any cache a model does not tell apart by
-    layer type). A model whose layers are of one kind has one entry."""
+    layer type). A model whose layers are of one kind has one entry. A
+    leaf whose stored shape pads its values (``Leaf.kv_shape``: FIRA's
+    pool blocks, rounded up to whole sublane tiles) counts the values."""
     import numpy as np
 
     total: Dict[str, int] = {}
     for leaf in leaves.values():
         if leaf.kv:
             total[leaf.kv_kind] = total.get(leaf.kv_kind, 0) + int(
-                np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+                np.prod(leaf.kv_shape or leaf.shape)) \
+                * np.dtype(leaf.dtype).itemsize
     return {kind: n // max(1, int(slots)) for kind, n in total.items()}

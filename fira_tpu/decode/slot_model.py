@@ -74,6 +74,7 @@ import numpy as np
 from fira_tpu.config import ARCH_TABLE, FULL, SLIDING, FiraConfig
 from fira_tpu.decode import quant
 from fira_tpu.decode.beam import _select, _select_factored, step_valid_mask
+from fira_tpu.model.layers import pool_block_rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,6 +87,10 @@ class Leaf:
     #                                 moved by src_beam; None: never moved
     #                                 (shared by the beams, or per lane)
     kv: bool = False                # counted by kv_bytes_per_slot
+    kv_shape: Optional[Tuple[int, ...]] = None  # of a ``kv`` leaf whose
+    #                                 stored shape pads its values: the
+    #                                 shape they fill, which is what
+    #                                 kv_bytes_per_slot counts
     kv_kind: str = ""               # of a ``kv`` leaf that holds PROMPTS:
     #                                 "full" (a prompt kept whole) or
     #                                 "window" (a ring of its last
@@ -196,6 +201,9 @@ class FiraSlotModel:
         ck, sp = chunk["cross_k"], chunk["src_proj"]
         cd = chunk["cache_seed"].dtype
         P, BS = self.pool_blocks, self.block_size
+        G = pool_block_rows(K, BS, cd)
+        pool = Leaf((L * P, G, H * d_head), cd, kv=True,
+                    kv_shape=(L * P, K * BS, H * d_head))
         return {
             "diff": Leaf((S,) + chunk["diff"].shape[1:],
                          chunk["diff"].dtype),
@@ -207,9 +215,13 @@ class FiraSlotModel:
             "cross_k": Leaf((L, S) + ck.shape[2:], ck.dtype),
             "cross_v": Leaf((L, S) + ck.shape[2:], ck.dtype),
             "src_proj": Leaf((S,) + sp.shape[1:], sp.dtype),
-            # per beam LANE, not per beam: no reorder (beam_ancestry)
-            "k_pool": Leaf((L, P, K, H, BS, d_head), cd, kv=True),
-            "v_pool": Leaf((L, P, K, H, BS, d_head), cd, kv=True),
+            # per beam LANE, not per beam: no reorder (beam_ancestry); a
+            # block a (layer, pool block), a row a (lane, position), its
+            # heads side by side, the rows whole sublane tiles: the
+            # runtime lays this out as the step's scan computes in it
+            # (docs/DECODE_ENGINE.md "Paged KV arena")
+            "k_pool": pool,
+            "v_pool": pool,
         }
 
     def insert(self, state, chunk, sid, fresh) -> Dict:

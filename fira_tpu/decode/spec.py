@@ -213,11 +213,14 @@ def make_drafter(model: FiraModel, cfg: FiraConfig, slots: int):
         # Beam 0's history does not lie in lane 0: it is followed
         # through the engine's ancestry table, position by position
         tab, anc = state["block_tab"], state["ancestry"]
+        P = state["k_pool"].shape[0] // L            # blocks a layer
         k_sc = jnp.stack([
-            gather_block_kv_beam(state["k_pool"][l], tab, 0, anc)
+            gather_block_kv_beam(state["k_pool"], tab + l * P, anc, 0,
+                                 cfg.num_head)
             for l in range(L)])
         v_sc = jnp.stack([
-            gather_block_kv_beam(state["v_pool"][l], tab, 0, anc)
+            gather_block_kv_beam(state["v_pool"], tab + l * P, anc, 0,
+                                 cfg.num_head)
             for l in range(L)])
 
         def body(flat0, tok0, pos0):

@@ -316,97 +316,115 @@ class Attention(nn.Module):
                            causal=causal)
 
 
-def gather_block_kv(pool_l: jnp.ndarray, block_tab: jnp.ndarray
-                    ) -> jnp.ndarray:
-    """One layer's K (or V) cache of every slot out of a paged block pool,
-    ALL beam lanes (decode/engine.py; docs/DECODE_ENGINE.md "Paged KV
-    arena").
+def pool_block_rows(lanes: int, block: int, dtype) -> int:
+    """Rows of one block of the paged pool: its K beam lanes x BS
+    positions, rounded up to the chip's sublane tile for ``dtype`` (8 rows
+    of 4-byte values, 16 of 2-byte ones).
 
-    pool_l: one layer's pool slice, (P, K, H, BS, d_head): P fixed pool
-    blocks, each holding BS cache positions in K beam LANES of the owning
-    slot. The pool is written once — a step puts beam k's new K/V into
-    lane k of the slot's tail block (:func:`append_block_kv`) — and never
-    moved: after the selections that re-sorted the beams, position t of
-    beam q's history lies in lane ``ancestry[s, q, t]`` (the engine's
-    table), not in lane q. block_tab: (S, W) int32 — slot s's position
-    range [w*BS, (w+1)*BS) lives in block ``block_tab[s, w]``; the
-    sentinel id P marks unmapped entries (gather CLAMPS them to a garbage
-    block whose values are exactly zeroed by the mask's -1e9).
-
-    Every slot's blocks are read ONCE. Returns (S, H, W*K*BS, d_head):
-    the slot's W*K*BS cached entries as ONE key axis, ordered (block,
-    lane, offset) — what ``Attention.attend`` consumes with the slot's K
-    beams as its query axis and :func:`lane_mask` as its mask. A
-    low-precision pool (cfg.kv_dtype="bf16" — decode/quant.py) UPCASTS on
-    read to the stable dtype, so the attention math downstream runs full
-    precision whatever the arena stores; for an f32 pool the cast is a
-    no-op."""
-    P, K, H, BS, d_head = pool_l.shape
-    S, W = block_tab.shape
-    blocks = pool_l[block_tab]                      # (S, W, K, H, BS, dh)
-    blocks = blocks.transpose(0, 3, 1, 2, 4, 5)     # (S, H, W, K, BS, dh)
-    return blocks.reshape(S, H, W * K * BS, d_head).astype(
-        stable_dtype(pool_l.dtype))
+    The pool (decode/engine.py; docs/DECODE_ENGINE.md "Paged KV arena")
+    is (L*P, G, H*d_head): a block a (layer, pool block), a row a (beam
+    lane, position) — lane ``j``'s position ``b`` in row ``j*BS + b`` —
+    and a position's heads side by side in the row. With G whole
+    sublane tiles and H*d_head whole lanes (d 256 and 512) the row-major
+    layout pads nothing, so the chip's runtime keeps the pool row-major —
+    the layout the step's scan computes in — and a gather of blocks
+    moves whole (8, 128) tiles. Rows ``K*BS .. G-1`` of a block are
+    never written and always masked (:func:`lane_mask`)."""
+    tile = 32 // np.dtype(dtype).itemsize
+    return -(-lanes * block // tile) * tile
 
 
-def lane_mask(ancestry: jnp.ndarray, valid: jnp.ndarray, block_size: int
-              ) -> jnp.ndarray:
+def gather_block_kv(pool: jnp.ndarray, blocks: jnp.ndarray,
+                    num_heads: int) -> jnp.ndarray:
+    """One layer's K (or V) cache of every slot out of the paged pool,
+    ALL beam lanes. pool: (L*P, G, H*d_head) (:func:`pool_block_rows`);
+    blocks: (S, W) int32 — slot s's position range [w*BS, (w+1)*BS)
+    lives in block ``blocks[s, w]``, the engine's block table plus the
+    layer's first block ``l*P``. The table's sentinel id P marks unmapped
+    entries, which read the next layer's first block (the last layer's
+    CLAMP to its last): garbage whose values the mask's -1e9 zeroes
+    exactly. The pool is written once — a step puts beam k's new K/V
+    into lane k of the slot's tail block (:func:`append_block_kv`) — and
+    never moved: after the selections that re-sorted the beams, position
+    t of beam q's history lies in lane ``ancestry[s, q, t]`` (the
+    engine's table), not in lane q.
+
+    Every slot's blocks are read ONCE, whole. Returns (S, H, W*G,
+    d_head): the slot's W*G rows as ONE key axis, ordered (block, lane,
+    offset, then the block's unwritten rows) — what ``Attention.attend``
+    consumes with the slot's K beams as its query axis and
+    :func:`lane_mask` as its mask; the heads are split after the gather.
+    A low-precision pool (cfg.kv_dtype="bf16" — decode/quant.py) UPCASTS
+    on read to the stable dtype, so the attention math downstream runs
+    full precision whatever the arena stores; for an f32 pool the cast is
+    a no-op."""
+    S, W = blocks.shape
+    _LP, G, HD = pool.shape
+    keys = pool[blocks].reshape(S, W * G, num_heads, HD // num_heads)
+    return keys.transpose(0, 2, 1, 3).astype(stable_dtype(pool.dtype))
+
+
+def gather_block_kv_beam(pool: jnp.ndarray, blocks: jnp.ndarray,
+                         ancestry: jnp.ndarray, beam: int,
+                         num_heads: int) -> jnp.ndarray:
+    """One BEAM's dense cache view from the paged pool, (S, H, T,
+    d_head): what a whole-sequence cache would hold for beam ``beam`` of
+    every slot. The speculative draft-tier roll (decode/spec.py) copies
+    the top beam's history into a dense scratch cache once per draft and
+    rolls on that — the pool itself is never written by a drafter. The
+    pool is written once and never moved, so lane ``beam`` does NOT hold
+    the beam's history: position t is row ``ancestry[s, beam, t]*BS + t %
+    BS`` (the engine's table, (S, K, T)) of block ``blocks[s, t // BS]``
+    (pool and blocks as :func:`gather_block_kv`) — the stored bits, no
+    arithmetic. Read-upcast as :func:`gather_block_kv`."""
+    S, W = blocks.shape
+    _S, _K, T = ancestry.shape
+    BS = T // W
+    t = jnp.arange(T, dtype=blocks.dtype)
+    keys = pool[blocks[:, t // BS], ancestry[:, beam] * BS + t % BS]
+    keys = keys.reshape(S, T, num_heads, pool.shape[-1] // num_heads)
+    return keys.transpose(0, 2, 1, 3).astype(stable_dtype(pool.dtype))
+
+
+def lane_mask(ancestry: jnp.ndarray, valid: jnp.ndarray, block_size: int,
+              block_rows: int) -> jnp.ndarray:
     """Which of a slot's cached entries each of its beams attends:
     entry (block w, lane j, offset b) belongs to beam q's history iff
     position t = w*BS + b is a valid one of q's (``valid``: (S, K, T)
-    bool, beam.step_valid_mask) and ``ancestry[s, q, t] == j``. Returns
-    (S, 1, K, W*K*BS) bool over :func:`gather_block_kv`'s key axis, the
-    heads broadcast. An entry outside a beam's history gets the -1e9 of
-    an unwritten position, so its softmax weight is an exact 0.0: per
-    beam the same 1..T keys and values are attended as over a
-    whole-sequence cache reordered after every selection."""
+    bool, beam.step_valid_mask) and ``ancestry[s, q, t] == j``; a block's
+    rows past its K*BS (``block_rows``: :func:`pool_block_rows`) belong
+    to no history. Returns (S, 1, K, W*block_rows) bool over
+    :func:`gather_block_kv`'s key axis, the heads broadcast. An entry
+    outside a beam's history gets the -1e9 of an unwritten position, so
+    its softmax weight is an exact 0.0: per beam the same 1..T keys and
+    values are attended as over a whole-sequence cache reordered after
+    every selection."""
     S, K, T = ancestry.shape
     W = T // block_size
     lanes = jnp.arange(K, dtype=ancestry.dtype)[:, None]
     own = ancestry.reshape(S, K, W, 1, block_size) == lanes
     own = own & valid.reshape(S, K, W, 1, block_size)   # (S, K, W, Kj, BS)
-    return own.reshape(S, 1, K, W * K * block_size)
+    own = own.reshape(S, K, W, K * block_size)
+    own = jnp.pad(own, ((0, 0), (0, 0), (0, 0),
+                        (0, block_rows - K * block_size)))
+    return own.reshape(S, 1, K, W * block_rows)
 
 
-def gather_block_kv_beam(pool_l: jnp.ndarray, block_tab: jnp.ndarray,
-                         beam: int, ancestry: jnp.ndarray) -> jnp.ndarray:
-    """One BEAM's dense cache view from the paged pool,
-    (S, H, W*BS, d_head): what a whole-sequence cache would hold for beam
-    ``beam`` of every slot. The speculative draft-tier roll
-    (decode/spec.py) copies the top beam's history into a dense scratch
-    cache once per draft and rolls on that — the pool itself is never
-    written by a drafter. The pool is written once and never moved, so
-    lane ``beam`` does NOT hold the beam's history: position t is taken
-    from lane ``ancestry[s, beam, t]`` (the engine's table,
-    (S, K, W*BS)) — a chain of K-1 selects over the slot's blocks, no
-    arithmetic: the stored bits. Read-upcast as :func:`gather_block_kv`
-    (no-op for an f32 pool)."""
-    P, K, H, BS, d_head = pool_l.shape
-    S, W = block_tab.shape
-    lanes = pool_l[block_tab]                       # (S, W, K, H, BS, dh)
-    lane = ancestry[:, beam].reshape(S, W, 1, BS, 1)
-    blocks = lanes[:, :, 0]
-    for j in range(1, K):
-        blocks = jnp.where(lane == j, lanes[:, :, j], blocks)
-    blocks = blocks.transpose(0, 2, 1, 3, 4)        # (S, H, W, BS, dh)
-    return blocks.reshape(S, H, W * BS, d_head).astype(
-        stable_dtype(pool_l.dtype))
-
-
-def append_block_kv(pool: jnp.ndarray, layer: int, blk: jnp.ndarray,
-                    krow: jnp.ndarray, off: jnp.ndarray, new: jnp.ndarray
-                    ) -> jnp.ndarray:
+def append_block_kv(pool: jnp.ndarray, block: jnp.ndarray, row: jnp.ndarray,
+                    new: jnp.ndarray) -> jnp.ndarray:
     """Write one decode position into the paged pool: row r's projected
-    K (or V) at this step lands at ``pool[layer, blk[r], krow[r], :,
-    off[r], :]``. pool: (L, P, K, H, BS, d_head); blk/krow/off: (B,) int32
-    per-row block id / beam lane / in-block offset; new: (B, H, d_head).
-    ``mode="drop"`` makes sentinel block ids (idle/done rows the engine
-    masked out) write NOWHERE — a freed block can never be scribbled on by
-    the slot that used to own it. The write CASTS to the pool's storage
-    dtype (cfg.kv_dtype="bf16" stores the arena half-width —
-    decode/quant.py; a no-op for the f32 pool)."""
-    return pool.at[layer, blk, krow, :, off, :].set(
-        new.astype(pool.dtype), mode="drop")
+    K (or V) at this step lands at ``pool[block[r], row[r]]``, its heads
+    side by side. pool: (L*P, G, H*d_head) (:func:`pool_block_rows`);
+    block: (B,) int32, the row's tail block plus the layer's first block;
+    row: (B,) int32, ``lane*BS + offset`` in it; new: (B, H, d_head).
+    ``mode="drop"``: a row the engine masked out (idle/done slots, whose
+    table rows are the sentinel) carries a block past the pool's last and
+    writes NOWHERE — a freed block can never be scribbled on by the slot
+    that used to own it. The write CASTS to the pool's storage dtype
+    (cfg.kv_dtype="bf16" stores the arena half-width — decode/quant.py;
+    a no-op for the f32 pool)."""
+    return pool.at[block, row].set(
+        new.reshape(new.shape[0], -1).astype(pool.dtype), mode="drop")
 
 
 class FeedForward(nn.Module):

@@ -373,10 +373,14 @@ class Decoder(nn.Module):
         cache behind BLOCK-TABLE INDIRECTION: instead of each row owning
         a whole-sequence (tar_len) cache stripe as in the batched beam's
         :meth:`decode_step`, the cache lives in a fixed pool of KV
-        blocks — k_pool/v_pool: (L, P, K, H, block, d_head) — and
-        ``block_tab`` (S, W) maps slot s's position range
-        [w*block, (w+1)*block) to a pool block (sentinel id P = unmapped:
-        reads clamp to garbage the mask zeroes exactly, writes drop).
+        blocks — k_pool/v_pool: (L*P, G, H*d_head), a block a (layer,
+        pool block), a row a (beam lane, position) with its heads side by
+        side, G = K*block rounded up to whole sublane tiles
+        (layers.pool_block_rows), so the layout the runtime gives the pool
+        is the one this step computes in — and ``block_tab`` (S, W) maps
+        slot s's position range [w*block, (w+1)*block) to a pool block
+        (sentinel id P = unmapped: reads land on garbage the mask zeroes
+        exactly, writes drop).
 
         The pool is WRITTEN ONCE AND NEVER MOVED: row (s, k)'s new K/V
         goes into lane k of slot s's tail block, and ``ancestry``
@@ -385,7 +389,7 @@ class Decoder(nn.Module):
         lane k — says where a beam's past lies after the selections that
         re-sorted the beams. Each layer reads every slot's blocks ONCE,
         all K lanes (layers.gather_block_kv), and the slot's K beams
-        attend over its K x tar_len cached entries together, each under
+        attend over its W x G cached rows together, each under
         its own mask ``valid[t] & (ancestry[s, k, t] == lane)``
         (layers.lane_mask). A masked entry gets the -1e9 of an unwritten
         position — softmax weight an exact 0.0 — so per beam this is the
@@ -407,20 +411,32 @@ class Decoder(nn.Module):
         of one slot share theirs); self_mask: (S*K, 1, 1, tar_len) per-row
         validity; W*block must equal tar_len. Returns x (S, K, D) and the
         pools."""
-        _L, _P, K, _H, BS, _dh = k_pool.shape
+        LP, G, HD = k_pool.shape
+        L, D, H, K = (self.cfg.num_layers, self.cfg.embedding_dim,
+                      self.cfg.num_head, self.cfg.beam_size)
         B = tok.shape[0]
         S, W = block_tab.shape
-        if W * BS != self_mask.shape[-1] or B != S * K:
+        T = self_mask.shape[-1]
+        BS = T // W
+        P = LP // L                                  # blocks a layer
+        if (W * BS != T or B != S * K or P * L != LP or G < K * BS
+                or HD != D):
             raise ValueError(
-                f"paged cache geometry mismatch: table {W} x block {BS} "
-                f"must tile the {self_mask.shape[-1]}-position budget and "
-                f"pool beam lanes {K} x {S} slots must equal the {B} rows")
+                f"paged cache geometry mismatch: table {W} blocks must "
+                f"tile the {T}-position budget, beam lanes {K} x {S} slots "
+                f"must equal the {B} rows, pool blocks {LP} must be {L} "
+                f"layers of blocks, a block of {G} rows must hold {K} "
+                f"lanes x {BS} positions and a pool row of {HD} must hold "
+                f"the {H} heads of {D // H}")
         pos = pos_idx.astype(jnp.int32)
         slot = jnp.arange(B, dtype=jnp.int32) // K
         krow = jnp.arange(B, dtype=jnp.int32) % K
         blk = block_tab[slot, pos // BS]             # (B,) current tail block
-        off = pos % BS
-        mask = lane_mask(ancestry, self_mask.reshape(S, K, W * BS), BS)
+        # a masked row (sentinel block) lies past the last layer's blocks,
+        # so its write drops whatever the layer
+        blk = jnp.where(blk < P, blk, LP)
+        row = krow * BS + pos % BS                   # its row in the block
+        mask = lane_mask(ancestry, self_mask.reshape(S, K, W * BS), BS, G)
         # a slot's K beams share its blocks and its source: they are the
         # query axis of both attentions, so x is (S, K, D) throughout
         x = (self.embed(tok) + self._pos_table()[pos][:, None, :]
@@ -429,12 +445,12 @@ class Decoder(nn.Module):
             sa = getattr(self, f"self_attn_{i}")
             rows = x.reshape(B, 1, -1)
             k_new, v_new = sa.project_kv(rows, rows)  # (B, H, 1, d_head)
-            k_pool = append_block_kv(k_pool, i, blk, krow, off,
+            k_pool = append_block_kv(k_pool, blk + i * P, row,
                                      k_new[:, :, 0, :])
-            v_pool = append_block_kv(v_pool, i, blk, krow, off,
+            v_pool = append_block_kv(v_pool, blk + i * P, row,
                                      v_new[:, :, 0, :])
-            x = sa.attend(x, gather_block_kv(k_pool[i], block_tab),
-                          gather_block_kv(v_pool[i], block_tab),
+            x = sa.attend(x, gather_block_kv(k_pool, block_tab + i * P, H),
+                          gather_block_kv(v_pool, block_tab + i * P, H),
                           mask, deterministic=True)
             x = getattr(self, f"cross_attn_{i}").attend(
                 x, cross_k[i], cross_v[i], sou_mask, deterministic=True)

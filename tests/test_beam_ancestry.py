@@ -63,10 +63,26 @@ def setup(tmp_path_factory):
     return cfg, dataset, eos_biased_params(params, delta=4.0)
 
 
+HEADS = fira_tiny().num_head
+
+
+def blocked(state, name: str) -> np.ndarray:
+    """The paged pool ``name`` — (L*P, G, H*d_head), a block a (layer,
+    pool block), a row a (lane, position), G = K*BS rounded up to whole
+    sublane tiles — as its blocks (L, P, K, H, BS, d_head)."""
+    pool = np.asarray(state[name])
+    _S, K, T = state["ancestry"].shape
+    BS = T // state["block_tab"].shape[1]
+    L = fira_tiny().num_layers
+    LP, _G, HD = pool.shape
+    return pool[:, :K * BS].reshape(L, LP // L, K, BS, HEADS,
+                                    HD // HEADS).transpose(0, 1, 2, 4, 3, 5)
+
+
 def dense_view(state, s: int, name: str) -> np.ndarray:
     """Slot ``s``'s per-beam cache (L, K, H, T, d_head) out of the paged
     pool ``name``, through the block table and the ancestry table."""
-    pool = np.asarray(state[name])
+    pool = blocked(state, name)
     _L, P, _K, _H, BS, _dh = pool.shape
     tab = np.minimum(np.asarray(state["block_tab"])[s], P - 1)
     anc = np.asarray(state["ancestry"])[s]                  # (K, T)
@@ -121,7 +137,7 @@ def test_dense_view_through_ancestry_is_the_permuted_stripe_cache(setup):
             np.testing.assert_array_equal(state["ancestry"][s, :, p],
                                           src_beam)
             for kv in "kv":
-                pool = state[f"{kv}_pool"]          # (L,P,K,H,BS,dh)
+                pool = blocked(state, f"{kv}_pool")  # (L,P,K,H,BS,dh)
                 L, _P, _K, H, _BS, dh = pool.shape
                 c = stripes.setdefault(
                     (pid, kv), np.zeros((L, K, H, cfg.tar_len, dh),
@@ -196,26 +212,30 @@ def test_the_mask_selects_the_dense_view_bit_for_bit(setup):
     S = eng.slots
     written = np.arange(T)[None, None, :] < st["pos"][:, None, None]
     valid = np.broadcast_to(written, (S, K, T))
+    G = st["k_pool"].shape[1]
+    assert G == layers.pool_block_rows(K, BS, st["k_pool"].dtype) >= K * BS
     mask = np.asarray(layers.lane_mask(
-        jnp.asarray(st["ancestry"]), jnp.asarray(valid), BS))[:, 0]
-    assert mask.shape == (S, K, W * K * BS)
+        jnp.asarray(st["ancestry"]), jnp.asarray(valid), BS, G))[:, 0]
+    assert mask.shape == (S, K, W * G)
     for name in ("k_pool", "v_pool"):
-        for l in range(st[name].shape[0]):
+        for l in range(eng.cfg.num_layers):
+            tab = jnp.asarray(st["block_tab"]) + l * eng._pool_blocks
             keys = np.asarray(layers.gather_block_kv(
-                jnp.asarray(st[name][l]), jnp.asarray(st["block_tab"])))
+                jnp.asarray(st[name]), tab, HEADS))
             top = np.asarray(layers.gather_block_kv_beam(
-                jnp.asarray(st[name][l]), jnp.asarray(st["block_tab"]), 0,
-                jnp.asarray(st["ancestry"])))
+                jnp.asarray(st[name]), tab, jnp.asarray(st["ancestry"]), 0,
+                HEADS))
             for s in live:
                 p = int(st["pos"][s])
                 dense = dense_view(st, s, name)[l]          # (K,H,T,dh)
                 for q in range(K):
                     assert mask[s, q].sum() == p
-                    # key order is (block, lane, offset): one entry a
-                    # position, so within a block positions stay in order
-                    # only lane by lane — sort the picks by position
+                    # key order is (block, lane, offset, the block's
+                    # unwritten rows): one entry a position, so within a
+                    # block positions stay in order only lane by lane —
+                    # sort the picks by position
                     picks = np.flatnonzero(mask[s, q])
-                    w, rest = np.divmod(picks, K * BS)
+                    w, rest = np.divmod(picks, G)
                     t = w * BS + rest % BS
                     got = keys[s][:, picks[np.argsort(t)]]  # (H,p,dh)
                     np.testing.assert_array_equal(
@@ -290,7 +310,7 @@ def _lowered_step(setup):
     eng._ensure_state(eng._prefill(eng.params, wire))
     text = eng._step.lower(eng._decode_params, eng._state).as_text(
         debug_info=True)
-    layer = int(np.prod(eng._state["k_pool"].shape[1:]))
+    layer = eng._state["k_pool"].size // cfg.num_layers
     return text, layer
 
 
@@ -309,7 +329,22 @@ def test_step_lowers_without_a_pool_sized_reorder(setup, monkeypatch):
             out[name] = dataclasses.replace(out[name], reorder="pool")
         return out
 
+    # ``permute_pool`` moves a pool's blocks, (L, P, K, ...): it is handed
+    # FIRA's blocks lane by lane, and the slices and reshapes there and
+    # back add no op the counts below look for
+    permute = slot_model.permute_pool
+
+    def permute_blocks(pool, tab_step, src_beam):
+        K, W = src_beam.shape[1], tab_step.shape[1]
+        BS, L = setup[0].tar_len // W, setup[0].num_layers
+        LP, _G, HD = pool.shape
+        blocks = pool[:, :K * BS].reshape(L, LP // L, K, BS, HD)
+        moved = permute(blocks, tab_step, src_beam)
+        return jnp.concatenate([moved.reshape(LP, K * BS, HD),
+                                pool[:, K * BS:]], axis=1)
+
     monkeypatch.setattr(slot_model.FiraSlotModel, "leaves", reordered)
+    monkeypatch.setattr(slot_model, "permute_pool", permute_blocks)
     text, layer = _lowered_step(setup)
     hits = _reorder_ops_at_least(text, layer)
     # a pool: the gather of every slot's blocks, the beam axis taken by

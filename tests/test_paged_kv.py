@@ -280,6 +280,42 @@ def test_insert_never_zeroes_cache_and_dirty_arena_reuse(setup, tmp_path):
     assert runs[0] == runs[1]
 
 
+@pytest.mark.parametrize("bad", ("rows", "width"))
+def test_step_refuses_a_pool_of_another_geometry(setup, bad):
+    """A pool is (L*P, G, H*d_head): blocks that are not P of every
+    layer, a block whose rows cannot hold its K lanes x BS positions, or
+    a row that does not hold the heads, are a geometry mismatch the step
+    names before it traces any attention."""
+    import jax
+
+    from fira_tpu.data.batching import make_batch
+    from fira_tpu.model.layers import pool_block_rows
+
+    cfg, dataset, _dir, params = setup
+    eng = engine_lib.SlotEngine(FiraModel(cfg), params, cfg)
+    host = make_batch(dataset.splits["train"], np.arange(0), cfg,
+                      batch_size=cfg.test_batch_size)
+    wire = {k: v for k, v in host.items() if not k.startswith("_")}
+    arena = eng.arena_shapes(jax.eval_shape(eng._prefill_fn, params, wire))
+    K, BS = cfg.beam_size, eng._block_size
+    blocks, rows, width = arena["k_pool"].shape
+    assert blocks == cfg.num_layers * eng._pool_blocks and width == \
+        cfg.embedding_dim
+    assert rows == pool_block_rows(K, BS, arena["k_pool"].dtype) >= K * BS
+    shape = {"rows": (blocks, K * BS - 1, width),
+             "width": (blocks, rows, width // 2)}[bad]
+    for name in ("k_pool", "v_pool"):
+        arena[name] = jax.ShapeDtypeStruct(shape, arena[name].dtype)
+    want = {"rows": f"a block of {K * BS - 1} rows must hold {K} lanes x "
+                    f"{BS} positions",
+            "width": f"a pool row of {width // 2} must hold the "
+                     f"{cfg.num_head} heads"}[bad]
+    with pytest.raises(ValueError, match="paged cache geometry mismatch"
+                       ) as err:
+        jax.eval_shape(eng._step_fn, eng._decode_params, arena)
+    assert want in str(err.value)
+
+
 # --------------------------------------------------------------------------
 # knob resolution + parse-time validation
 # --------------------------------------------------------------------------

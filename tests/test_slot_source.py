@@ -29,6 +29,7 @@ from fira_tpu.data.dataset import FiraDataset
 from fira_tpu.data.synthetic import write_corpus_dir
 from fira_tpu.decode import prefix_cache
 from fira_tpu.decode.engine import SlotEngine
+from fira_tpu.model.layers import pool_block_rows
 from fira_tpu.model.model import FiraModel
 from fira_tpu.train.state import init_state
 
@@ -84,7 +85,10 @@ def test_chunk_has_a_row_a_request_and_the_arena_a_row_a_slot(setup, beam):
     assert eng._state["src_proj"].shape == (SLOTS, src_len, d)
     assert eng._state["src_mask"].shape == (SLOTS, src_len)
     # the per-beam leaves did not shrink with them
-    assert eng._state["k_pool"].shape[2] == beam
+    assert eng._state["k_pool"].shape == (
+        L * eng._pool_blocks,
+        pool_block_rows(beam, eng._block_size, eng._state["k_pool"].dtype),
+        d)
     assert eng._state["ancestry"].shape == (SLOTS, beam, cfg.tar_len)
 
 
