@@ -314,6 +314,108 @@ class BrumbyConfig(_PromptGeometry):
         return errs
 
 
+CONV = "conv"
+
+# LFM2-8B-A1B's 24 layers as published: 18 gated short convolutions, 6
+# attention layers (one in three of the layers from 2 on)
+_LFM2_LAYERS = ((CONV, CONV, FULL) + (CONV, CONV, CONV, FULL) * 4
+                + (CONV, CONV, FULL, CONV, CONV))
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2Config(_PromptGeometry):
+    """LFM2-8B-A1B's key block (``arch="lfm2"``): the published keys of that
+    gated-short-convolution, attention and routed-expert decoder by the
+    names its ``config.json`` gives them (``model_type: lfm2_moe``), its
+    values as the defaults
+    (https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json).
+    A layer's token mixer is ``layer_types[i]``: ``"conv"`` or
+    ``"full_attention"``; layers from ``num_dense_layers`` on route. After
+    them: the prefill geometry. Every expert is held (one chip holds each
+    layer whole). The head is the embedding
+    (benchmark/configs/lfm2-8b-a1b-l12.json, ``assumed`` (a))."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 7168        # the leading dense layers' SwiGLU
+    moe_intermediate_size: int = 1792    # each routed expert
+    num_hidden_layers: int = 24
+    num_dense_layers: int = 2
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    layer_types: tuple = _LFM2_LAYERS
+    conv_L_cache: int = 3                # the short convolution's taps
+    conv_bias: bool = False
+    num_experts: int = 32                # the router's outputs
+    num_experts_per_tok: int = 4
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    use_expert_bias: bool = True
+    norm_eps: float = 1e-5
+    rope_theta: float = 1000000.0
+    vocab_size: int = 65536
+    prompt_buckets: tuple = (256, 512, 1024, 2048, 4096)
+    prefill_token_budget: int = 16384
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def kv_dim(self) -> int:
+        """What one token caches an ATTENTION layer: [k | v]."""
+        return 2 * self.num_key_value_heads * self.head_dim
+
+    # the published keys under the names the shared pieces know them by
+    # (model/afmoe.route, model/axk1.routed_experts, model/jamba.lm_head);
+    # every expert held
+    @property
+    def n_routed_experts(self) -> int:
+        return self.num_experts
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts
+
+    expert_offset = 0
+
+    @property
+    def route_norm(self) -> bool:
+        return self.norm_topk_prob
+
+    @property
+    def route_scale(self) -> float:
+        return self.routed_scaling_factor
+
+    @property
+    def rms_norm_eps(self) -> float:
+        return self.norm_eps
+
+    def layers_of(self, kind: str) -> tuple:
+        return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
+
+    def errors(self) -> list:
+        errs = self.bucket_errors()
+        if len(self.layer_types) != self.num_hidden_layers or any(
+                t not in (CONV, FULL) for t in self.layer_types):
+            errs.append(
+                f"lm.layer_types {self.layer_types} must name {CONV!r} or "
+                f"{FULL!r} for each of lm.num_hidden_layers "
+                f"{self.num_hidden_layers}")
+        if self.conv_bias or not self.use_expert_bias \
+                or self.conv_L_cache < 2:
+            errs.append("lm.conv_bias / lm.use_expert_bias other than false "
+                        "/ true, or lm.conv_L_cache under 2, are not "
+                        "implemented")
+        if self.hidden_size % self.num_attention_heads \
+                or self.num_attention_heads % self.num_key_value_heads:
+            errs.append(
+                f"lm.num_attention_heads {self.num_attention_heads} must "
+                f"divide lm.hidden_size {self.hidden_size} and be a "
+                f"multiple of lm.num_key_value_heads "
+                f"{self.num_key_value_heads}")
+        return errs
+
+
 @dataclasses.dataclass(frozen=True)
 class FiraConfig:
     # --- architecture (ARCH_TABLE below): "fira" (the paper's
@@ -322,7 +424,9 @@ class FiraConfig:
     # "afmoe" (Trinity-Mini: window and full attention layers, 128 small
     # experts) or "jamba" (Jamba2-3B: state-space layers with a recurrent
     # state a beam, two attention layers) or "brumby" (Brumby-14B-Base:
-    # power-retention layers, a prompt's state a slot) — whose published
+    # power-retention layers, a prompt's state a slot) or "lfm2"
+    # (LFM2-8B-A1B: gated short convolutions with a two-token tail a beam,
+    # attention layers, 32 routed experts) — whose published
     # keys ``lm`` holds in its own key block; of
     # the fields below such a model reads beam_size, tar_len, the
     # engine/paging knobs and seed ---
@@ -1109,6 +1213,38 @@ def brumby_tiny(**kw) -> FiraConfig:
         prefill_token_budget=64), base)
 
 
+# published layers 0-11 of LFM2-8B-A1B's 24: both dense layers, then ten
+# expert layers, three of them attention (two and a half periods)
+_LFM2_STAGE = _LFM2_LAYERS[:12]
+
+
+def lfm2_8b_a1b_l12(**kw) -> FiraConfig:
+    """LFM2-8B-A1B at its published widths, one pipeline stage on one chip
+    (benchmark/configs/lfm2-8b-a1b-l12.json says how it was cut): published
+    layers 0-11 of 24 with all 32 experts of every expert layer and the
+    whole vocabulary, 7.86 GB of bfloat16 weights."""
+    base = dict(engine_slots=64, test_batch_size=16)
+    base.update(kw)
+    return _lm_preset("lfm2", Lfm2Config(num_hidden_layers=12,
+                                         layer_types=_LFM2_STAGE), base)
+
+
+def lfm2_tiny(**kw) -> FiraConfig:
+    """Every mechanism of LFM2-8B-A1B at CPU-test widths: d 64, the stage's
+    first five layer types (2 dense + 3 expert: conv, conv, attention,
+    conv, conv), 4 query over 2 key/value heads of 16, 8 experts of width
+    32, top-2, the convolution's 3 taps."""
+    base = dict(engine_slots=4, test_batch_size=4, tar_len=16,
+                compute_dtype="float32")
+    base.update(kw)
+    return _lm_preset("lfm2", Lfm2Config(
+        hidden_size=64, intermediate_size=96, moe_intermediate_size=32,
+        num_hidden_layers=5, num_attention_heads=4, num_key_value_heads=2,
+        layer_types=_LFM2_STAGE[:5], num_experts=8, num_experts_per_tok=2,
+        vocab_size=64, prompt_buckets=(16, 32, 64), prefill_token_budget=64),
+        base)
+
+
 NAMED_CONFIGS = {
     "fira-tiny": fira_tiny,
     "fira-full": fira_full,
@@ -1121,6 +1257,8 @@ NAMED_CONFIGS = {
     "jamba-tiny": jamba_tiny,
     "brumby-14b-l4": brumby_14b_l4,
     "brumby-tiny": brumby_tiny,
+    "lfm2-8b-a1b-l12": lfm2_8b_a1b_l12,
+    "lfm2-tiny": lfm2_tiny,
 }
 
 
@@ -1181,6 +1319,7 @@ ARCH_TABLE = {
     "jamba": Arch(JambaConfig, "fira_tpu.model.jamba", "JambaSlotModel"),
     "brumby": Arch(BrumbyConfig, "fira_tpu.model.brumby",
                    "BrumbySlotModel"),
+    "lfm2": Arch(Lfm2Config, "fira_tpu.model.lfm2", "Lfm2SlotModel"),
 }
 ARCHS = tuple(ARCH_TABLE)
 
@@ -1188,7 +1327,7 @@ ARCHS = tuple(ARCH_TABLE)
 def arch_errors(cfg: FiraConfig, command: Optional[str] = None) -> list:
     """What an architecture does not run yet is refused by name, never
     run silently as something else: every token model (``axk1``, ``afmoe``,
-    ``jamba``, ``brumby``) goes through the same lines below. ``command``: the CLI's (``train`` /
+    ``jamba``, ``brumby``, ``lfm2``) goes through the same lines below. ``command``: the CLI's (``train`` /
     ``test`` / ``serve`` / ``message``), where there is one."""
     if cfg.arch not in ARCHS:
         return [f"arch {cfg.arch!r} not in {list(ARCHS)}"]

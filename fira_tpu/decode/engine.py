@@ -270,6 +270,10 @@ class EngineStats:
     #                               position read: occupied slots x layers
     own_keys_read: int = 0        # the beams' own generated positions
     #                               attended, over the layers
+    # expert reads (model/lfm2.COUNTERS, the same leaf — zero for a model
+    # that does not count them)
+    moe_experts_read: int = 0     # held experts some row of a decode
+    #                               position routed to, over expert layers
     # per span name count/total_s/max_s and the compile counters, over
     # the spans that closed while THIS stats object lived (utils/
     # profiling.Phases) — a stats reset between timed windows resets the
@@ -363,6 +367,7 @@ class EngineStats:
             "state_rows": self.state_rows,
             "state_reads": self.state_reads,
             "own_keys_read": self.own_keys_read,
+            "moe_experts_read": self.moe_experts_read,
             "phases": self.phases.summary(),
         }
 

@@ -59,7 +59,10 @@ the first with leaves that are not keys and values at all: a recurrent
 state a beam lane beside two attention layers' K/V (``beam_parent``);
 :class:`BrumbySlotModel` (``arch="brumby"``, model/brumby.py) the fifth,
 whose prompt leaves a state of fixed size ONCE A SLOT, read-only in the step
-and shared by the beams, beside the beams' own positions in the pool.
+and shared by the beams, beside the beams' own positions in the pool;
+:class:`Lfm2SlotModel` (``arch="lfm2"``, model/lfm2.py) the sixth: Jamba's
+arena with a short convolution's two-token tail a beam lane in place of the
+Mamba leaves, beside routed experts.
 config.ARCH_TABLE says which class an ``arch`` gets.
 """
 
@@ -71,7 +74,7 @@ from typing import Dict, NamedTuple, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from fira_tpu.config import ARCH_TABLE, FULL, SLIDING, FiraConfig
+from fira_tpu.config import ARCH_TABLE, CONV, FULL, SLIDING, FiraConfig
 from fira_tpu.decode import quant
 from fira_tpu.decode.beam import _select, _select_factored, step_valid_mask
 from fira_tpu.model.layers import pool_block_rows
@@ -614,6 +617,75 @@ class BrumbySlotModel(LMSlotModel):
         # the prompt's state is not among the writes: a step never changes it
         return (logp,), {"kv_pool": pool, "gen_gate": gates,
                          "counters": state["counters"] + counters}
+
+
+class Lfm2SlotModel(JambaSlotModel):
+    """LFM2-8B-A1B behind the seam (model/lfm2.py): Jamba's arena without
+    its recurrent state. Per beam LANE, a leaf a conv layer: the short
+    convolution's tail ``conv_tail<j>`` (taps - 1, S * K, hidden) — the last
+    two ``v = B * u`` a lane, 8 KB a lane a layer at the published widths —
+    ``kv_kind="state"``, read through ``parent`` and rewritten whole at
+    every position, as Jamba's ``conv_state<j>``; ``insert`` (Jamba's) writes
+    a request's tails into lane 0 of its slot only. Per slot, shared by the
+    beams: the attention layers' prompt keys and values whole
+    (``prompt_k_full<j>`` / ``prompt_v_full<j>``, ``"full"``); per beam,
+    paged and reordered: their generated positions (``kv_pool``, whose
+    layer axis counts ATTENTION layers). ``arena_counters``: the expert
+    layer's three and ``moe_experts_read`` (model/lfm2.COUNTERS)."""
+
+    def __init__(self, model, cfg: FiraConfig, slots: int,
+                 block_size: int, pool_blocks: int):
+        from fira_tpu.model import lfm2
+
+        LMSlotModel.__init__(self, model, cfg, slots, block_size,
+                             pool_blocks)
+        self.arena_counters = lfm2.COUNTERS
+        self._ssm = []                  # no recurrent state: tails only
+        self._conv = [f"conv_tail{j}"
+                      for j in range(len(self.lm.layers_of(CONV)))]
+        self._prompt = [(f"prompt_k_full{j}", f"prompt_v_full{j}")
+                        for j in range(len(self.lm.layers_of(FULL)))]
+
+    def prefill(self, params, batch):
+        from fira_tpu.model import lfm2
+
+        tails, kvs, counters = lfm2.prefill(
+            params, self.lm, batch["tokens"], batch["lengths"], self.dtype)
+        return {"ssm": [], "conv": tails, "kv_full": kvs,
+                "lengths": batch["lengths"], "counters": counters}
+
+    def leaves(self, chunk) -> Dict[str, Leaf]:
+        lm, S, K = self.lm, self.slots, self.cfg.beam_size
+        dt, c = chunk["conv"][0].dtype, lm.kv_dim
+        out = {n: Leaf((lm.conv_L_cache - 1, S * K, lm.hidden_size), dt,
+                       kv=True, kv_kind="state") for n in self._conv}
+        out.update({n: Leaf((S, c // 2, lm.prompt_len_max), dt, kv=True,
+                            kv_kind="full")
+                    for pair in self._prompt for n in pair})
+        out.update({
+            "prompt_len": Leaf((S,), np.dtype(np.int32)),
+            "kv_pool": Leaf((len(self._prompt), self.pool_blocks, K,
+                             self.block_size, c), dt, reorder="pool",
+                            kv=True),
+            "counters": Leaf((len(self.arena_counters),),
+                             np.dtype(np.int32)),
+        })
+        return out
+
+    def step(self, params, state, view: StepView):
+        from fira_tpu.model import lfm2
+
+        S, K = self.slots, self.cfg.beam_size
+        tok = jnp.take_along_axis(view.flat, view.pos_bk[:, None], axis=1)
+        logp, conv, pool, counters = lfm2.decode_step(
+            params, self.lm, tok.reshape(S, K), view.pos_c,
+            [state[n] for n in self._conv], view.parent,
+            [(state[k], state[v]) for k, v in self._prompt],
+            state["prompt_len"], state["kv_pool"], view.tab_step,
+            view.active, self.dtype)
+        writes = {"kv_pool": pool, "counters": state["counters"] + counters}
+        writes.update(zip(self._conv, conv))
+        return (logp,), writes
 
 
 def for_config(model, cfg: FiraConfig, slots: int, block_size: int,

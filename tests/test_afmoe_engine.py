@@ -221,7 +221,8 @@ def test_one_table_says_what_an_arch_is():
 
     from fira_tpu.decode import engine, slot_model
 
-    assert set(ARCH_TABLE) == {"fira", "axk1", "afmoe", "jamba", "brumby"}
+    assert set(ARCH_TABLE) == {"fira", "axk1", "afmoe", "jamba", "brumby",
+                               "lfm2"}
     for name, arch in ARCH_TABLE.items():
         assert hasattr(slot_model, arch.slot_model), name
     src = inspect.getsource(engine)

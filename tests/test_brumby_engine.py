@@ -224,7 +224,8 @@ def test_one_table_says_what_an_arch_is():
 
     from fira_tpu.decode import engine, slot_model
 
-    assert set(ARCH_TABLE) == {"fira", "axk1", "afmoe", "jamba", "brumby"}
+    assert set(ARCH_TABLE) == {"fira", "axk1", "afmoe", "jamba", "brumby",
+                               "lfm2"}
     src = inspect.getsource(engine)
     assert "cfg.arch" not in src and ".arch ==" not in src
     assert "brumby" not in src.replace("model/brumby.COUNTERS", "")
