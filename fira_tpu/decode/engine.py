@@ -254,6 +254,9 @@ class EngineStats:
     moe_assignments_held: int = 0  # ... of them, to experts held here
     moe_held_load_max: int = 0    # the busiest held expert's load, summed
     #                               over expert layers and dispatches
+    moe_rows_expert_major: int = 0  # held assignments an expert-major pass
+    #                               computed (model/axk1.expert_capacity:
+    #                               decode positions; 0 for prefill)
     # window-attention accounting (model/afmoe.COUNTERS, the same leaf —
     # zero for a model without window layers)
     attn_keys_read: int = 0       # keys the steps' attention was ASKED to
@@ -362,6 +365,7 @@ class EngineStats:
             "moe_assignments": self.moe_assignments,
             "moe_assignments_held": self.moe_assignments_held,
             "moe_held_load_max": self.moe_held_load_max,
+            "moe_rows_expert_major": self.moe_rows_expert_major,
             "attn_keys_read": self.attn_keys_read,
             "attn_keys_context": self.attn_keys_context,
             "state_rows": self.state_rows,

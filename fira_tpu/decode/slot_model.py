@@ -631,7 +631,7 @@ class Lfm2SlotModel(JambaSlotModel):
     (``prompt_k_full<j>`` / ``prompt_v_full<j>``, ``"full"``); per beam,
     paged and reordered: their generated positions (``kv_pool``, whose
     layer axis counts ATTENTION layers). ``arena_counters``: the expert
-    layer's three and ``moe_experts_read`` (model/lfm2.COUNTERS)."""
+    layer's four and ``moe_experts_read`` (model/lfm2.COUNTERS)."""
 
     def __init__(self, model, cfg: FiraConfig, slots: int,
                  block_size: int, pool_blocks: int):

@@ -56,10 +56,10 @@ import jax
 import jax.numpy as jnp
 
 from fira_tpu.config import FULL, SLIDING, AfmoeConfig
-from fira_tpu.model.axk1 import (COUNTERS as MOE_COUNTERS, mm, rms_norm,
-                                 rotate, routed_experts, swiglu)
+from fira_tpu.model.axk1 import (COUNTERS as MOE_COUNTERS, mm, moe_counters,
+                                 rms_norm, rotate, routed_experts, swiglu)
 
-# the counters a call returns, in this order: the expert layer's three
+# the counters a call returns, in this order: the expert layer's four
 # (model/axk1.COUNTERS), then the keys a step's attention is ASKED to cover
 # (occupied slots x layers x the keys inside window or context) and the
 # same with every layer full
@@ -356,7 +356,7 @@ def route(scores, bias, lm: AfmoeConfig):
 
 def moe_layer(p, x, valid, lm: AfmoeConfig, dtype):
     """x (N, d) normed -> (shared + held routed part (N, d) float32, the
-    expert layer's three counters int32)."""
+    expert layer's four counters int32)."""
     with jax.named_scope("moe.route"):
         scores = jax.nn.sigmoid(jnp.dot(
             x.astype(jnp.float32), p["router"].astype(jnp.float32),
@@ -367,14 +367,11 @@ def moe_layer(p, x, valid, lm: AfmoeConfig, dtype):
                         p["shared_down"], dtype)
     with jax.named_scope("moe.experts"):
         routed, loads = routed_experts(p, x, ids, weights, valid, lm, dtype)
-    counters = jnp.stack([
-        jnp.sum(valid, dtype=jnp.int32) * lm.num_experts_per_tok,
-        jnp.sum(loads), jnp.max(loads)])
-    return shared + routed, counters
+    return shared + routed, moe_counters(lm, valid, loads)
 
 
 def _mlp(p, h, valid, layer: int, lm: AfmoeConfig, dtype):
-    """h (N, d) normed -> (output (N, d) float32, the three counters)."""
+    """h (N, d) normed -> (output (N, d) float32, the four counters)."""
     if layer_is_dense(lm, layer):
         return (swiglu(h, p["w_gate"], p["w_up"], p["w_down"], dtype),
                 jnp.zeros((len(MOE_COUNTERS),), jnp.int32))

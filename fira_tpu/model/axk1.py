@@ -43,7 +43,8 @@ from fira_tpu.config import LMConfig
 
 # the counters a call returns, in this order (decode/slot_model.py adds
 # them into the arena; EngineStats carries them under these names)
-COUNTERS = ("moe_assignments", "moe_assignments_held", "moe_held_load_max")
+COUNTERS = ("moe_assignments", "moe_assignments_held", "moe_held_load_max",
+            "moe_rows_expert_major")
 
 # queries a block of prefill attention: a bucket is scored in blocks of this
 # many (a bucket under it in one), so no (heads, P, P) tensor exists
@@ -341,6 +342,14 @@ def route(scores, lm: LMConfig):
 # instead of one whose float32 result alone is 1.3 GB
 EXPERT_CHUNK_ROWS_MAX = 32768
 
+# a held expert's expected rows a pass at or under which the grouped
+# product runs EXPERT-MAJOR (:func:`expert_capacity`): one MXU tile's
+# height. Every decode position is under it (24, 9 and ~11 rows at the
+# three expert cells) and every prefill far over (256 to 1,024), where the
+# row-major form has not been timed against it at every cell's load
+# (PERF.md §7 (rr))
+EXPERT_MAJOR_ROWS = 128
+
 
 def expert_chunk_rows(lm, n_tokens: int) -> int:
     """Rows one pass of the grouped product holds: a quarter over the
@@ -356,15 +365,39 @@ def expert_chunk_rows(lm, n_tokens: int) -> int:
     return max(8, min(rows, n_tokens * min(k, lm.experts_held)))
 
 
+def expert_capacity(lm, n_tokens: int) -> int:
+    """Rows of EACH held expert one expert-major pass holds — 2.5 x the
+    expected rows an expert (``expert_chunk_rows / experts_held``), in
+    whole bfloat16 tiles of 16 rows: 64 | 32 | 32 at LFM2's, Trinity-Mini's
+    and A.X-K1's decode positions (PERF.md §6: the fastest capacity
+    measured at the last two, within 4 % of it at LFM2's with room for a
+    busier expert; a capacity under the busiest load costs a second pass
+    that reads every expert again) — or 0 where that expectation is over
+    EXPERT_MAJOR_ROWS and the grouped product runs row-major
+    (``jax.lax.ragged_dot``)."""
+    per = expert_chunk_rows(lm, n_tokens) / lm.experts_held
+    if per > EXPERT_MAJOR_ROWS:
+        return 0
+    return int(math.ceil(2.5 * per / 16)) * 16
+
+
 def routed_experts(p, x, ids, weights, valid, lm, dtype):
     """The held experts' part of the routed sum (shared with
-    model/afmoe.py: ``lm`` is any key block with ``num_experts_per_tok``,
-    ``experts_held``, ``expert_offset``). x (N, d) normed; ids /
-    weights (N, k); valid (N,) bool — padding takes no expert's time.
-    Assignments to held experts are sorted by expert and computed as
-    grouped products (``jax.lax.ragged_dot``: one matmul over rows in
-    expert order, never a (tokens x experts) masked product), a chunk of
-    rows a pass, as many passes as the imbalance asks: none is dropped.
+    model/afmoe.py and model/lfm2.py: ``lm`` is any key block with
+    ``num_experts_per_tok``, ``experts_held``, ``expert_offset``). x (N, d)
+    normed; ids / weights (N, k); valid (N,) bool — padding takes no
+    expert's time. Assignments to held experts are sorted by expert and
+    computed a pass at a time, as many passes as the imbalance asks: none is
+    dropped. The pass's tiling follows the group size (:func:`expert_capacity`):
+
+    - few rows an expert (every decode position): EXPERT-MAJOR, pass i holds
+      rows [i C, (i + 1) C) of every held expert, (E, C, d), one batched
+      product an expert matrix — each expert's weights stream from HBM once a
+      pass and meet all of its rows; one pass unless an expert has over C;
+    - many (every prefill): ROW-MAJOR, a chunk of rows in expert order a pass
+      through grouped products (``jax.lax.ragged_dot``), never a (tokens x
+      experts) masked product.
+
     -> (out (N, d) float32, per-expert loads (experts_held,) int32)."""
     N, d = x.shape
     k, E = lm.num_experts_per_tok, lm.experts_held
@@ -376,29 +409,63 @@ def routed_experts(p, x, ids, weights, valid, lm, dtype):
                     dtype=jnp.int32)
     ends = jnp.cumsum(loads)
     starts, n_held = ends - loads, ends[-1]
-    M = expert_chunk_rows(lm, N)
-    order = jnp.concatenate([order, jnp.zeros((M,), jnp.int32)])
     w_flat = weights.reshape(-1)
     xc = x.astype(dtype)
     wg, wu, wd = (p[n].astype(dtype) for n in
                   ("experts_gate", "experts_up", "experts_down"))
+    C = expert_capacity(lm, N)
+    if C:
+        # pass i: ranks [i C, (i + 1) C) of every expert, while any has them
+        step, total = C, jnp.max(loads)
+        rank = jnp.arange(C, dtype=jnp.int32)[None, :]
+
+        def rows(i):
+            r = i * C + rank                                     # (1, C)
+            sel = order[jnp.minimum(starts[:, None] + r, N * k - 1)]
+            return sel.reshape(-1), (r < loads[:, None]).reshape(-1)
+
+        def products(xs, i):
+            def bmm(a, w):
+                return jnp.einsum("ecd,edm->ecm", a.reshape(E, C, -1), w,
+                                  preferred_element_type=jnp.float32)
+
+            def stored(y):
+                # rounded to ``dtype`` as ``ragged_dot`` stores gate and
+                # up: the chip's compiler drops a bare cast pair (float32
+                # -> dtype -> float32) inside a fusion
+                f = jnp.finfo(dtype)
+                return jax.lax.reduce_precision(y, f.nexp, f.nmant
+                                                ).astype(dtype)
+            g, u = stored(bmm(xs, wg)), stored(bmm(xs, wu))
+            return bmm(gated(g, u, dtype), wd).reshape(E * C, d)
+    else:
+        # pass i: sorted rows [i M, (i + 1) M), while any is held
+        M = expert_chunk_rows(lm, N)
+        step, total = M, n_held
+        order = jnp.concatenate([order, jnp.zeros((M,), jnp.int32)])
+
+        def rows(i):
+            at = i * M
+            return (jax.lax.dynamic_slice_in_dim(order, at, M),
+                    at + jnp.arange(M) < n_held)
+
+        def products(xs, i):
+            at = i * M
+            sizes = jnp.clip(ends - at, 0, M) - jnp.clip(starts - at, 0, M)
+            g = jax.lax.ragged_dot(xs, wg, sizes, preferred_element_type=dtype)
+            u = jax.lax.ragged_dot(xs, wu, sizes, preferred_element_type=dtype)
+            return jax.lax.ragged_dot(gated(g, u, dtype), wd, sizes,
+                                      preferred_element_type=jnp.float32)
 
     def more(carry):
-        return carry[0] * M < n_held
+        return carry[0] * step < total
 
     def one_pass(carry):
         i, out = carry
-        at = i * M
-        sel = jax.lax.dynamic_slice_in_dim(order, at, M)
-        real = at + jnp.arange(M) < n_held
+        sel, real = rows(i)
         tok = sel // k
-        sizes = jnp.clip(ends - at, 0, M) - jnp.clip(starts - at, 0, M)
-        xs = xc[tok]
-        g = jax.lax.ragged_dot(xs, wg, sizes, preferred_element_type=dtype)
-        u = jax.lax.ragged_dot(xs, wu, sizes, preferred_element_type=dtype)
-        y = jax.lax.ragged_dot(gated(g, u, dtype), wd, sizes,
-                               preferred_element_type=jnp.float32)
-        # rows past the last group hold whatever the kernel left there
+        y = products(xc[tok], i)
+        # rows past an expert's load hold whatever the product left there
         y = jnp.where(real[:, None], y * w_flat[sel][:, None], 0.0)
         return i + 1, out.at[jnp.where(real, tok, N)].add(y, mode="drop")
 
@@ -407,9 +474,20 @@ def routed_experts(p, x, ids, weights, valid, lm, dtype):
     return out, loads
 
 
+def moe_counters(lm, valid, loads):
+    """The first four of COUNTERS for one expert layer's call over rows
+    ``valid`` (N,) whose held experts took ``loads``: assignments, those to
+    held experts, the busiest held expert's load, and the held assignments
+    an expert-major pass computed (each expert's first C; none row-major)."""
+    C = expert_capacity(lm, valid.shape[0])
+    return jnp.stack([
+        jnp.sum(valid, dtype=jnp.int32) * lm.num_experts_per_tok,
+        jnp.sum(loads), jnp.max(loads), jnp.sum(jnp.minimum(loads, C))])
+
+
 def moe_layer(p, x, valid, lm: LMConfig, dtype):
     """x (N, d) normed -> (shared + held routed part (N, d) float32,
-    counters (3,) int32 in COUNTERS' order)."""
+    counters (4,) int32 in COUNTERS' order)."""
     with jax.named_scope("moe.route"):
         scores = jax.nn.sigmoid(jnp.dot(
             x.astype(jnp.float32), p["router"].astype(jnp.float32),
@@ -420,10 +498,7 @@ def moe_layer(p, x, valid, lm: LMConfig, dtype):
                         p["shared_down"], dtype)
     with jax.named_scope("moe.experts"):
         routed, loads = routed_experts(p, x, ids, weights, valid, lm, dtype)
-    counters = jnp.stack([
-        jnp.sum(valid, dtype=jnp.int32) * lm.num_experts_per_tok,
-        jnp.sum(loads), jnp.max(loads)])
-    return shared + routed, counters
+    return shared + routed, moe_counters(lm, valid, loads)
 
 
 def _mlp(p, h, valid, layer: int, lm: LMConfig, dtype):
@@ -465,7 +540,7 @@ def lm_head(params, x, lm: LMConfig, dtype):
 def prefill(params, lm: LMConfig, tokens, lengths, dtype
             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """tokens (B, P) int32, real up to lengths (B,). -> (latents
-    (L, B, P, 576) in ``dtype``, counters (3,) int32). No logits: the
+    (L, B, P, 576) in ``dtype``, counters (4,) int32). No logits: the
     first prediction is the first decode position's."""
     _x, lats, counters = _trunk(params, lm, tokens, lengths, dtype)
     return lats, counters

@@ -61,11 +61,11 @@ import jax.numpy as jnp
 from fira_tpu.config import CONV, Lfm2Config
 from fira_tpu.model.afmoe import (attend_decode, attend_prefill,
                                   rope_cos_sin, route)
-from fira_tpu.model.axk1 import (COUNTERS as MOE_COUNTERS, mm, rms_norm,
-                                 rotate, routed_experts, swiglu)
+from fira_tpu.model.axk1 import (COUNTERS as MOE_COUNTERS, mm, moe_counters,
+                                 rms_norm, rotate, routed_experts, swiglu)
 from fira_tpu.model.jamba import lm_head
 
-# the counters a call returns, in this order: the expert layer's three
+# the counters a call returns, in this order: the expert layer's four
 # (model/axk1.COUNTERS), then the held experts that at least one row of a
 # DECODE position routed to, summed over expert layers and positions — what
 # a step must read of the experts (a prefill adds 0 here: its bytes are not
@@ -225,7 +225,7 @@ def attention_prefill(p, h, cos, sin, lm: Lfm2Config, dtype):
 
 def moe_layer(p, x, valid, lm: Lfm2Config, dtype):
     """x (N, d) normed -> (the weighted experts (N, d) float32, COUNTERS'
-    four int32). No shared expert."""
+    five int32). No shared expert."""
     with jax.named_scope("moe.route"):
         scores = jax.nn.sigmoid(jnp.dot(
             x.astype(jnp.float32), p["router"].astype(jnp.float32),
@@ -233,9 +233,8 @@ def moe_layer(p, x, valid, lm: Lfm2Config, dtype):
         ids, weights = route(scores, p["expert_bias"], lm)
     with jax.named_scope("moe.experts"):
         out, loads = routed_experts(p, x, ids, weights, valid, lm, dtype)
-    return out, jnp.stack([
-        jnp.sum(valid, dtype=jnp.int32) * lm.num_experts_per_tok,
-        jnp.sum(loads), jnp.max(loads), jnp.sum(loads > 0, dtype=jnp.int32)])
+    return out, jnp.concatenate([moe_counters(lm, valid, loads),
+                                 jnp.sum(loads > 0, dtype=jnp.int32)[None]])
 
 
 def _ffn(p, x, valid, layer: int, lm: Lfm2Config, dtype):

@@ -112,7 +112,8 @@ def test_prefill_then_decode_through_ring_arena_and_pool(tiny, monkeypatch):
     # layer asked for its whole context, a window layer for at most 8 keys
     ctx = sum(int(n) + g + 1 for n in plen for g in range(n_gen))
     win = sum(min(int(n) + g + 1, 8) for n in plen for g in range(n_gen))
-    assert state["counters"][3:].tolist() == [ctx + 4 * win, 5 * ctx]
+    keys = afmoe.COUNTERS.index("attn_keys_read")
+    assert state["counters"][keys:].tolist() == [ctx + 4 * win, 5 * ctx]
 
     # 1: the ring written one entry off
     inner = afmoe.window_ring

@@ -163,11 +163,15 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny):
 
 @pytest.mark.parametrize("picks", [[0, 1, 2, 3], [0, 5, 9, 13]],
                          ids=["all-held-two-passes", "one-expert-takes-all"])
-def test_no_token_is_dropped_whatever_the_imbalance(tiny, picks):
-    """Every token picks the same experts. With 4 of 16 held the grouped
-    product's pass holds 128 rows; 64 tokens x 4 held picks are 256
-    assignments: a second pass, and nothing dropped. With one held expert
-    taking every token, its load is the whole batch."""
+def test_no_token_is_dropped_whatever_the_imbalance(tiny, picks,
+                                                    monkeypatch):
+    """Every token picks the same experts, under both tilings of the
+    grouped product. With 4 of 16 held, 64 tokens expect 32 rows a held
+    expert: expert-major, a capacity of 80 rows an expert, one pass even
+    for an expert that takes every token. Row-major (the prefill's tiling, forced
+    here), a pass holds 128 rows; 64 tokens x 4 held picks are 256
+    assignments: a second pass. Nothing is dropped either way. With one
+    held expert taking every token, its load is the whole batch."""
     lm, _rc, params = tiny
     lm4 = dataclasses.replace(lm, experts_held=4, expert_offset=0)
     p = {k: v[:4] if k.startswith("experts_") else v
@@ -177,16 +181,19 @@ def test_no_token_is_dropped_whatever_the_imbalance(tiny, picks):
     x = jax.random.normal(jax.random.PRNGKey(5), (N, lm.hidden_size))
     ids = jnp.tile(jnp.asarray(picks, jnp.int32), (N, 1))
     w = jax.random.uniform(jax.random.PRNGKey(6), (N, 4)) + 0.5
-    out, loads = jax.jit(lambda x, ids, w: axk1.routed_experts(
-        p, x, ids, w, jnp.ones((N,), bool), lm4, F32))(x, ids, w)
-    want = jnp.zeros_like(out)
+    assert axk1.expert_capacity(lm4, N) == 80
+    want = 0.0
     for j, e in enumerate(picks):
         if e < 4:
             want = want + w[:, j, None] * axk1.swiglu(
                 x, p["experts_gate"][e], p["experts_up"][e],
                 p["experts_down"][e], F32)
-    assert loads.tolist() == [N if e in picks else 0 for e in range(4)]
-    assert float(jnp.abs(out - want).max()) < TOL
+    for rows_max in (axk1.EXPERT_MAJOR_ROWS, 0):
+        monkeypatch.setattr(axk1, "EXPERT_MAJOR_ROWS", rows_max)
+        out, loads = axk1.routed_experts(p, x, ids, w, jnp.ones((N,), bool),
+                                         lm4, F32)
+        assert loads.tolist() == [N if e in picks else 0 for e in range(4)]
+        assert float(jnp.abs(out - want).max()) < TOL
     # padding takes no expert's time: invalid tokens are not routed
     half = jnp.arange(N) < N // 2
     out2, loads2 = axk1.routed_experts(p, x, ids, w, half, lm4, F32)

@@ -20,7 +20,13 @@ v5e from ``jax.ShapeDtypeStruct``s (nothing is allocated, nothing runs), at
 fira-tiny's depth and vocabulary and each FIRA decode cell's width, beam,
 position budget and slots, and at fira-tiny's own: no ``copy`` in the optimized program has a
 pool's element count, and every gather from a pool takes whole blocks.
-Skipped where the TPU compiler cannot be loaded.
+
+The same described chip pins the grouped expert products' tiling
+(model/axk1.routed_experts): LFM2-8B-A1B's expert layer at its published
+widths (top-4 of 32 experts, 2,048 -> 1,792) compiled at a decode position's
+192 rows holds no ``ragged-dot`` (expert-major: batched products that read
+each expert's matrices once), and at a prefill dispatch's 16,384 rows keeps
+it (row-major). Skipped where the TPU compiler cannot be loaded.
 """
 
 import re
@@ -31,10 +37,11 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from fira_tpu.config import fira_tiny
+from fira_tpu.config import fira_tiny, get_config
 from fira_tpu.data.batching import make_batch
 from fira_tpu.data.synthetic import make_memory_split
 from fira_tpu.decode.engine import SlotEngine
+from fira_tpu.model import lfm2
 from fira_tpu.model.model import FiraModel
 
 # fira-tiny's depth and vocabulary, at each decode cell's width, heads,
@@ -146,3 +153,32 @@ def test_the_count_sees_a_pool_copy(one_chip):
     x = jax.ShapeDtypeStruct((256, 512), jnp.float32, sharding=one_chip)
     text = jax.jit(lambda a: a.T * 2).lower(x).compile().as_text()
     assert len(_pool_copies(text, x.size)) == 1
+
+
+def _compiled_expert_layer(one_chip, rows: int) -> str:
+    """LFM2-8B-A1B's first expert layer (router, bias, 32 experts) over
+    ``rows`` normed rows in bfloat16, compiled for one described v5e ->
+    the optimized HLO text."""
+    lm = get_config("lfm2-8b-a1b-l12").lm
+    layer = lm.num_dense_layers
+    p = {k: jax.ShapeDtypeStruct(v, jnp.bfloat16, sharding=one_chip)
+         for k, v in lfm2.param_shapes(lm)["layers"][layer].items()}
+    x = jax.ShapeDtypeStruct((rows, lm.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    valid = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip)
+    return jax.jit(lambda p, x, v: lfm2.moe_layer(
+        p, x, v, lm, jnp.bfloat16)).lower(p, x, valid).compile().as_text()
+
+
+def test_decode_expert_products_are_expert_major(one_chip):
+    """192 rows (64 slots x 3 beams) x top-4 of 32: ~24 rows an expert.
+    No ragged-dot; gate and up come out (experts, capacity 64, 1,792)."""
+    text = _compiled_expert_layer(one_chip, 192)
+    assert "ragged-dot" not in text
+    assert re.search(r"bf16\[32,64,1792\]", text)      # (E, C, m): gate/up
+
+
+def test_prefill_expert_products_keep_ragged_dot(one_chip):
+    """16,384 rows: 1,024 expected rows an expert, row-major passes."""
+    text = _compiled_expert_layer(one_chip, 16384)
+    assert "ragged-dot" in text
