@@ -255,8 +255,9 @@ class EngineStats:
     moe_held_load_max: int = 0    # the busiest held expert's load, summed
     #                               over expert layers and dispatches
     moe_rows_expert_major: int = 0  # held assignments an expert-major pass
-    #                               computed (model/axk1.expert_capacity:
-    #                               decode positions; 0 for prefill)
+    #                               computed (model/axk1.moe_counters:
+    #                               decode positions; a prefill's where
+    #                               its loads chose expert-major)
     # window-attention accounting (model/afmoe.COUNTERS, the same leaf —
     # zero for a model without window layers)
     attn_keys_read: int = 0       # keys the steps' attention was ASKED to

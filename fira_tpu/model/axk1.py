@@ -336,6 +336,9 @@ def route(scores, lm: LMConfig):
     return ids, w * lm.routed_scaling_factor
 
 
+# an expert layer's stacked expert matrices, (experts_held, in, out) each
+EXPERT_MATRICES = ("experts_gate", "experts_up", "experts_down")
+
 # rows one pass of the grouped product holds at the most: a prefill
 # dispatch that holds every expert (16,384 tokens x top-8 = 131,072
 # assignments) takes several passes of this many rows, in expert order,
@@ -343,11 +346,10 @@ def route(scores, lm: LMConfig):
 EXPERT_CHUNK_ROWS_MAX = 32768
 
 # a held expert's expected rows a pass at or under which the grouped
-# product runs EXPERT-MAJOR (:func:`expert_capacity`): one MXU tile's
-# height. Every decode position is under it (24, 9 and ~11 rows at the
-# three expert cells) and every prefill far over (256 to 1,024), where the
-# row-major form has not been timed against it at every cell's load
-# (PERF.md §7 (rr))
+# product runs EXPERT-MAJOR whatever the loads (:func:`expert_capacity`):
+# one MXU tile's height. Every decode position is under it (24, 9 and ~11
+# rows at the three expert cells) and every prefill far over (256 to
+# 1,024), where the loads choose the tiling (:func:`expert_major_engages`)
 EXPERT_MAJOR_ROWS = 128
 
 
@@ -373,32 +375,70 @@ def expert_capacity(lm, n_tokens: int) -> int:
     measured at the last two, within 4 % of it at LFM2's with room for a
     busier expert; a capacity under the busiest load costs a second pass
     that reads every expert again) — or 0 where that expectation is over
-    EXPERT_MAJOR_ROWS and the grouped product runs row-major
-    (``jax.lax.ragged_dot``)."""
+    EXPERT_MAJOR_ROWS and the loads choose the tiling
+    (:func:`expert_major_engages`)."""
     per = expert_chunk_rows(lm, n_tokens) / lm.experts_held
     if per > EXPERT_MAJOR_ROWS:
         return 0
     return int(math.ceil(2.5 * per / 16)) * 16
 
 
+def prefill_capacity(lm, n_tokens: int) -> int:
+    """Rows of each held expert one expert-major pass holds where the
+    expectation is over EXPERT_MAJOR_ROWS: a row-major pass's rows shared
+    evenly (``expert_chunk_rows / experts_held``), rounded DOWN to whole
+    tiles of 16 rows, so that a pass of every held expert computes no more
+    rows than a row-major pass — 1,024 | 256 | 416 at LFM2's,
+    Trinity-Mini's and A.X-K1's prefill dispatches."""
+    return max(16, expert_chunk_rows(lm, n_tokens) // lm.experts_held
+               // 16 * 16)
+
+
+def expert_major_engages(lm, n_tokens: int, loads):
+    """On the device, for a call whose expectation is over
+    EXPERT_MAJOR_ROWS: whether the expert-major passes of
+    :func:`prefill_capacity` compute no more rows than the row-major
+    passes would — ``ceil(max(loads) / C) E C <= ceil(n_held / M) M``.
+    A busy expert costs a pass over EVERY expert there; padding and an
+    even load leave the row-major passes' last one part empty."""
+    C, M = prefill_capacity(lm, n_tokens), expert_chunk_rows(lm, n_tokens)
+    passes = -(-jnp.max(loads) // C)
+    return passes * (lm.experts_held * C) <= -(-jnp.sum(loads) // M) * M
+
+
 def routed_experts(p, x, ids, weights, valid, lm, dtype):
     """The held experts' part of the routed sum (shared with
-    model/afmoe.py and model/lfm2.py: ``lm`` is any key block with
+    model/afmoe.py and model/lfm2.py: ``lm`` is any hashable key block with
     ``num_experts_per_tok``, ``experts_held``, ``expert_offset``). x (N, d)
     normed; ids / weights (N, k); valid (N,) bool — padding takes no
     expert's time. Assignments to held experts are sorted by expert and
     computed a pass at a time, as many passes as the imbalance asks: none is
-    dropped. The pass's tiling follows the group size (:func:`expert_capacity`):
+    dropped. Two tilings of a pass:
 
-    - few rows an expert (every decode position): EXPERT-MAJOR, pass i holds
-      rows [i C, (i + 1) C) of every held expert, (E, C, d), one batched
-      product an expert matrix — each expert's weights stream from HBM once a
-      pass and meet all of its rows; one pass unless an expert has over C;
-    - many (every prefill): ROW-MAJOR, a chunk of rows in expert order a pass
-      through grouped products (``jax.lax.ragged_dot``), never a (tokens x
-      experts) masked product.
+    - EXPERT-MAJOR: pass i holds ranks [i C, (i + 1) C) of every held
+      expert, (E, C, d), one batched product an expert matrix — each
+      expert's weights stream from HBM once a pass and meet all of its rows;
+    - ROW-MAJOR: a chunk of rows in expert order a pass through grouped
+      products (``jax.lax.ragged_dot``), never a (tokens x experts) masked
+      product.
+
+    Few rows an expert (every decode position, :func:`expert_capacity`):
+    expert-major, one pass unless an expert has over C. Many (every
+    prefill): a ``lax.cond`` on the loads — expert-major in passes of
+    :func:`prefill_capacity` where that computes no more rows than the
+    row-major passes (:func:`expert_major_engages`), else row-major. A
+    prefill's call is traced and lowered once a program, not once a layer
+    (``_prefill_grouped``): its layers share their shapes, and the cond's
+    two branches at every layer cost set-up time.
 
     -> (out (N, d) float32, per-expert loads (experts_held,) int32)."""
+    if expert_capacity(lm, x.shape[0]):
+        return _grouped(p, x, ids, weights, valid, lm, dtype)
+    return _prefill_grouped({n: p[n] for n in EXPERT_MATRICES}, x, ids,
+                            weights, valid, lm, dtype)
+
+
+def _grouped(p, x, ids, weights, valid, lm, dtype):
     N, d = x.shape
     k, E = lm.num_experts_per_tok, lm.experts_held
     local = ids - lm.expert_offset
@@ -411,10 +451,9 @@ def routed_experts(p, x, ids, weights, valid, lm, dtype):
     starts, n_held = ends - loads, ends[-1]
     w_flat = weights.reshape(-1)
     xc = x.astype(dtype)
-    wg, wu, wd = (p[n].astype(dtype) for n in
-                  ("experts_gate", "experts_up", "experts_down"))
-    C = expert_capacity(lm, N)
-    if C:
+    wg, wu, wd = (p[n].astype(dtype) for n in EXPERT_MATRICES)
+
+    def expert_major(C):
         # pass i: ranks [i C, (i + 1) C) of every expert, while any has them
         step, total = C, jnp.max(loads)
         rank = jnp.arange(C, dtype=jnp.int32)[None, :]
@@ -438,15 +477,16 @@ def routed_experts(p, x, ids, weights, valid, lm, dtype):
                                                 ).astype(dtype)
             g, u = stored(bmm(xs, wg)), stored(bmm(xs, wu))
             return bmm(gated(g, u, dtype), wd).reshape(E * C, d)
-    else:
+        return passes(step, total, rows, products)
+
+    def row_major(M):
         # pass i: sorted rows [i M, (i + 1) M), while any is held
-        M = expert_chunk_rows(lm, N)
         step, total = M, n_held
-        order = jnp.concatenate([order, jnp.zeros((M,), jnp.int32)])
+        padded = jnp.concatenate([order, jnp.zeros((M,), jnp.int32)])
 
         def rows(i):
             at = i * M
-            return (jax.lax.dynamic_slice_in_dim(order, at, M),
+            return (jax.lax.dynamic_slice_in_dim(padded, at, M),
                     at + jnp.arange(M) < n_held)
 
         def products(xs, i):
@@ -456,33 +496,50 @@ def routed_experts(p, x, ids, weights, valid, lm, dtype):
             u = jax.lax.ragged_dot(xs, wu, sizes, preferred_element_type=dtype)
             return jax.lax.ragged_dot(gated(g, u, dtype), wd, sizes,
                                       preferred_element_type=jnp.float32)
+        return passes(step, total, rows, products)
 
-    def more(carry):
-        return carry[0] * step < total
+    def passes(step, total, rows, products):
+        def more(carry):
+            return carry[0] * step < total
 
-    def one_pass(carry):
-        i, out = carry
-        sel, real = rows(i)
-        tok = sel // k
-        y = products(xc[tok], i)
-        # rows past an expert's load hold whatever the product left there
-        y = jnp.where(real[:, None], y * w_flat[sel][:, None], 0.0)
-        return i + 1, out.at[jnp.where(real, tok, N)].add(y, mode="drop")
+        def one_pass(carry):
+            i, out = carry
+            sel, real = rows(i)
+            tok = sel // k
+            y = products(xc[tok], i)
+            # rows past an expert's load hold whatever the product left there
+            y = jnp.where(real[:, None], y * w_flat[sel][:, None], 0.0)
+            return i + 1, out.at[jnp.where(real, tok, N)].add(y, mode="drop")
 
-    _, out = jax.lax.while_loop(
-        more, one_pass, (jnp.int32(0), jnp.zeros((N, d), jnp.float32)))
+        return jax.lax.while_loop(
+            more, one_pass, (jnp.int32(0), jnp.zeros((N, d), jnp.float32)))[1]
+
+    C = expert_capacity(lm, N)
+    if C:
+        return expert_major(C), loads
+    out = jax.lax.cond(expert_major_engages(lm, N, loads),
+                       lambda: expert_major(prefill_capacity(lm, N)),
+                       lambda: row_major(expert_chunk_rows(lm, N)))
     return out, loads
+
+
+_prefill_grouped = jax.jit(_grouped, static_argnames=("lm", "dtype"))
 
 
 def moe_counters(lm, valid, loads):
     """The first four of COUNTERS for one expert layer's call over rows
     ``valid`` (N,) whose held experts took ``loads``: assignments, those to
     held experts, the busiest held expert's load, and the held assignments
-    an expert-major pass computed (each expert's first C; none row-major)."""
-    C = expert_capacity(lm, valid.shape[0])
+    an expert-major pass computed (a decode position's: each expert's
+    first C; a prefill's: every one where :func:`expert_major_engages`,
+    none where the row-major passes ran)."""
+    N = valid.shape[0]
+    C = expert_capacity(lm, N)
     return jnp.stack([
         jnp.sum(valid, dtype=jnp.int32) * lm.num_experts_per_tok,
-        jnp.sum(loads), jnp.max(loads), jnp.sum(jnp.minimum(loads, C))])
+        jnp.sum(loads), jnp.max(loads),
+        jnp.sum(jnp.minimum(loads, C)) if C else jnp.where(
+            expert_major_engages(lm, N, loads), jnp.sum(loads), 0)])
 
 
 def moe_layer(p, x, valid, lm: LMConfig, dtype):

@@ -165,13 +165,18 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny):
                          ids=["all-held-two-passes", "one-expert-takes-all"])
 def test_no_token_is_dropped_whatever_the_imbalance(tiny, picks,
                                                     monkeypatch):
-    """Every token picks the same experts, under both tilings of the
+    """Every token picks the same experts, under every tiling of the
     grouped product. With 4 of 16 held, 64 tokens expect 32 rows a held
     expert: expert-major, a capacity of 80 rows an expert, one pass even
-    for an expert that takes every token. Row-major (the prefill's tiling, forced
-    here), a pass holds 128 rows; 64 tokens x 4 held picks are 256
-    assignments: a second pass. Nothing is dropped either way. With one
-    held expert taking every token, its load is the whole batch."""
+    for an expert that takes every token. With a prefill's group sizes
+    (forced here) the loads choose: passes of 32 rows of every held expert
+    where those compute no more rows than the row-major passes (all four
+    held experts at 64 rows: two passes of 128 rows either way), else
+    row-major (one expert at 64: one pass of 128 rows against two of four
+    experts); and row-major forced, a pass holds 128 rows; 64 tokens x 4
+    held picks are 256 assignments: a second pass. Nothing is dropped
+    either way. With one held expert taking every token, its load is the
+    whole batch."""
     lm, _rc, params = tiny
     lm4 = dataclasses.replace(lm, experts_held=4, expert_offset=0)
     p = {k: v[:4] if k.startswith("experts_") else v
@@ -188,12 +193,19 @@ def test_no_token_is_dropped_whatever_the_imbalance(tiny, picks,
             want = want + w[:, j, None] * axk1.swiglu(
                 x, p["experts_gate"][e], p["experts_up"][e],
                 p["experts_down"][e], F32)
-    for rows_max in (axk1.EXPERT_MAJOR_ROWS, 0):
+    assert axk1.prefill_capacity(lm4, N) == 32
+    engages = axk1.expert_major_engages
+    for rows_max, rule in ((axk1.EXPERT_MAJOR_ROWS, engages), (0, engages),
+                           (0, lambda *a: False)):
         monkeypatch.setattr(axk1, "EXPERT_MAJOR_ROWS", rows_max)
+        monkeypatch.setattr(axk1, "expert_major_engages", rule)
+        jax.clear_caches()      # a prefill's products are traced once a shape
         out, loads = axk1.routed_experts(p, x, ids, w, jnp.ones((N,), bool),
                                          lm4, F32)
         assert loads.tolist() == [N if e in picks else 0 for e in range(4)]
         assert float(jnp.abs(out - want).max()) < TOL
+    jax.clear_caches()
+    assert bool(engages(lm4, N, loads)) == (picks == [0, 1, 2, 3])
     # padding takes no expert's time: invalid tokens are not routed
     half = jnp.arange(N) < N // 2
     out2, loads2 = axk1.routed_experts(p, x, ids, w, half, lm4, F32)

@@ -26,7 +26,9 @@ The same described chip pins the grouped expert products' tiling
 widths (top-4 of 32 experts, 2,048 -> 1,792) compiled at a decode position's
 192 rows holds no ``ragged-dot`` (expert-major: batched products that read
 each expert's matrices once), and at a prefill dispatch's 16,384 rows keeps
-it (row-major). Skipped where the TPU compiler cannot be loaded.
+it (row-major) as one branch of a ``conditional`` whose other is
+expert-major at a prefill capacity of 1,024 rows an expert (the loads
+choose on the device). Skipped where the TPU compiler cannot be loaded.
 """
 
 import re
@@ -179,6 +181,10 @@ def test_decode_expert_products_are_expert_major(one_chip):
 
 
 def test_prefill_expert_products_keep_ragged_dot(one_chip):
-    """16,384 rows: 1,024 expected rows an expert, row-major passes."""
+    """16,384 rows: 1,024 expected rows an expert. Row-major passes stay,
+    beside expert-major passes of 1,024 rows an expert, (experts, 1,024,
+    1,792) gate and up; a ``conditional`` on the loads picks one."""
     text = _compiled_expert_layer(one_chip, 16384)
     assert "ragged-dot" in text
+    assert re.search(r"bf16\[32,1024,1792\]", text)    # (E, C_p, m)
+    assert "conditional" in text
